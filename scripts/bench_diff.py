@@ -6,7 +6,7 @@ breakdown) in every bench record and PR 6 added wire-byte estimates;
 until now nothing read them back.  This tool diffs two records and
 prints a regression table:
 
-    python scripts/bench_diff.py BENCH_r04.json BENCH_r05.json
+    python scripts/bench_diff.py old.json new.json
     python scripts/bench_diff.py old.json new.json --informational
 
 Rows: headline throughput, step time, each step-phase's share of

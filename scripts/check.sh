@@ -1,8 +1,8 @@
 #!/usr/bin/env bash
 # Tier-1 regression gate (ISSUE 4 satellite): run the suite EXACTLY as
 # ROADMAP.md specifies, then compare the FAILED/ERROR set against the
-# committed baseline (tests/known_failures.txt — the pre-existing
-# jax.shard_map environment failures).  Exit nonzero only on NEW
+# committed baseline (tests/known_failures.txt — empty since PR 21:
+# every test passes under jax 0.9.0).  Exit nonzero only on NEW
 # failures, so "tier-1 no worse than seed" is machine-checkable:
 #
 #   ./scripts/check.sh            # full tier-1 + diff vs baseline

@@ -254,6 +254,12 @@ def run_model_parallel(args) -> Dict[str, float]:
         model = BertMLM(cfg, shapes, compute_dtype=cdt,
                         attention_impl=impl, sp_axis="sp")
         step = make_sp_train_step(model, sp_param, mesh)
+        if impl == "ring":
+            from ..parallel.sequence import ring_engine
+
+            s_local = seq // mesh.shape["sp"]
+            print(f"BertApp[sp]: ring engine={ring_engine(s_local)} "
+                  f"(S_local={s_local})")
     elif mode == "tp":
         from ..parallel.tensor import make_tp_train_step
 
@@ -427,9 +433,9 @@ def parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> Dict[str, float]:
-    from ..tools._common import honor_platform_env
+    from ..utils import compile_cache
 
-    honor_platform_env()
+    compile_cache.enable()
     args = parser().parse_args(argv)
     multihost.initialize()  # no-op without SPARKNET_COORDINATOR
     if args.parallel in ("tp", "sp", "pp", "ep"):

@@ -393,8 +393,6 @@ def resolve_feed_workers(args, nproc: int) -> int:
     workers = resolve_data_workers(requested)
     if nproc > 1 and (requested is None or requested < 0):
         return 0
-    if workers and multihost.is_primary():
-        print(f"data pipeline: {workers} preprocessing workers")
     return workers
 
 
@@ -403,12 +401,25 @@ def record_loader_meta(solver, train_feed) -> None:
     fallen back) in the solverstate, so an ``--auto-resume`` in a
     changed environment (lib no longer builds, cache cap differs) warns
     about the silently different shuffle/augmentation RNG stream
-    instead of hiding it."""
+    instead of hiding it — and print it, one ``train feed:`` line, so a
+    run cannot mistake one feed for another."""
     from .. import native
 
-    solver.env_meta["loader"] = (
-        "native" if isinstance(train_feed, native.NativeLoader) else "python"
-    )
+    if isinstance(train_feed, native.NativeLoader):
+        loader, how = "native", "native loader (C++ worker threads)"
+    else:
+        loader = "python"
+        workers = getattr(train_feed, "workers", 0)
+        how = (
+            f"python feed, {workers} worker processes" if workers
+            else "python feed, serial"
+        )
+        why_not = native.unavailable_reason()
+        if why_not:
+            how += f" (native loader unavailable: {why_not})"
+    solver.env_meta["loader"] = loader
+    if multihost.is_primary():
+        print(f"train feed: {how}")
 
 
 def train_loop(
@@ -778,9 +789,9 @@ def maybe_supervise(module: str, argv, args, solver_path=None):
 
 
 def main(argv=None):
-    from ..tools._common import honor_platform_env
+    from ..utils import compile_cache
 
-    honor_platform_env()
+    compile_cache.enable()
     ap = argparse.ArgumentParser(parents=[arg_parser()],
                                  description="CIFAR-10 training (CifarApp)")
     args = ap.parse_args(argv)

@@ -358,9 +358,9 @@ def parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None):
-    from ..tools._common import honor_platform_env
+    from ..utils import compile_cache
 
-    honor_platform_env()
+    compile_cache.enable()
     args = parser().parse_args(argv)
     from .cifar_app import maybe_supervise
 

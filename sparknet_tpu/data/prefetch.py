@@ -117,15 +117,22 @@ def prefetch_to_device(
 
 
 def maybe_prefetch(feed, args, parallel: str):
-    """Stage host preprocessing + H2D ahead of the step loop (single
-    -process solvers only: multi-host global assembly must stay on the
-    main thread; order-preserving, so determinism is unchanged).
+    """Stage host preprocessing + H2D ahead of the step loop
+    (single-device, single-process solvers only: multi-host global
+    assembly must stay on the main thread, and a mesh places its own
+    batches; order-preserving, so determinism is unchanged).
     Shared by every app; ``--prefetch 0`` disables.  The wrapped feed's
     own ``PipelineMetrics`` (pipeline or packed reader) absorbs the
     staging hit/wait counts, so one ``input pipeline:`` line carries
     the whole host-side story."""
     size = getattr(args, "prefetch", 2)
-    if size and parallel == "none" and jax.process_count() == 1:
+    # --layout is a parallel solver too (its --parallel stays "none"):
+    # staging there would put every whole batch on device 0 and leave
+    # the step to reshard it; jit's in_shardings place the host batch
+    if (
+        size and parallel == "none" and not getattr(args, "layout", None)
+        and jax.process_count() == 1
+    ):
         return prefetch_to_device(
             feed, size=size, metrics=getattr(feed, "metrics", None)
         )

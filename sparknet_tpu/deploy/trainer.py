@@ -190,9 +190,9 @@ class IncrementalTrainer:
 
 
 def main(argv=None) -> int:
-    from ..tools._common import honor_platform_env
+    from ..utils import compile_cache
 
-    honor_platform_env()
+    compile_cache.enable()
     ap = argparse.ArgumentParser(
         prog="sparknet-deploy-trainer",
         description="incremental trainer over a deploy tee log",

@@ -25,34 +25,44 @@ _LIB_PATH = os.path.join(_NATIVE_DIR, "libsparknet_data.so")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_why_not: Optional[str] = None  # set when the build or the load failed
 
 _f32p = ctypes.POINTER(ctypes.c_float)
 _i32p = ctypes.POINTER(ctypes.c_int32)
 _u8p = ctypes.POINTER(ctypes.c_uint8)
 
 
-def _build() -> bool:
+def _build() -> Optional[str]:
+    """``make -C native`` — a no-op when the library is newer than its
+    source, a rebuild when it is not (an ``.so`` on disk may predate
+    ``sparknet_data.cpp``; git tracks only the source).  Returns why the
+    build failed, or None."""
     try:
         subprocess.run(
             ["make", "-C", _NATIVE_DIR],
             check=True, capture_output=True, timeout=120,
         )
-        return os.path.exists(_LIB_PATH)
-    except Exception:
-        return False
+    except subprocess.CalledProcessError as e:
+        said = " ".join((e.stderr or b"").decode(errors="replace").split())
+        return f"make failed: {said[-300:]}"
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"make failed: {type(e).__name__}: {e}"
+    return None
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+    global _lib, _tried, _why_not
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_LIB_PATH) and not _build():
+        _why_not = _build()
+        if _why_not is not None:
             return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
+        except OSError as e:
+            _why_not = f"dlopen failed: {e}"
             return None
         lib.sn_version.restype = ctypes.c_int
         lib.sn_cifar_decode.argtypes = [_u8p, ctypes.c_int, _u8p, _i32p]
@@ -77,6 +87,13 @@ def _load() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return _load() is not None
+
+
+def unavailable_reason() -> Optional[str]:
+    """Why the library could not be built or loaded; None while it is
+    available or was never asked for.  The apps print it, so
+    ``--native-loader auto`` never changes the feed without saying so."""
+    return _why_not
 
 
 def _as_u8p(a: np.ndarray):

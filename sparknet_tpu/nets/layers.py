@@ -647,11 +647,11 @@ class LRN:
         # under bf16 the conv activations feeding this are already
         # bf16-rounded, and keeping LRN's conv-sized temp chain at f32
         # doubles its HBM bytes for ~3 extra digits in d that the
-        # surrounding net can't use. On-chip (v5e, AlexNet bs512) the
-        # bf16 temp chain is worth 5 ms/step: 42.7 -> 37.6 ms, MFU
-        # 0.234 -> 0.266 (RESULTS.md "Round-5 A/B"). f32 nets are
-        # untouched (x is f32); SPARKNET_LRN_F32=1 restores f32 temps
-        # under bf16 for an apples-to-apples numerics comparison.
+        # surrounding net can't use. (The bf16 temp chain read faster
+        # at AlexNet bs512 — measured once in round 5 on a set-up that
+        # no longer exists; not re-measured.) f32 nets are untouched (x
+        # is f32); SPARKNET_LRN_F32=1 restores f32 temps under bf16 for
+        # an apples-to-apples numerics comparison.
         out_dtype = x.dtype
         if os.environ.get("SPARKNET_LRN_F32", "0") not in ("", "0"):
             x = x.astype(jnp.float32)
@@ -669,13 +669,13 @@ class LRN:
         ssum = lax.reduce_window(sq, 0.0, lax.add, window, (1, 1, 1, 1), padding)
         d = k + scale * ssum
         # x * d^-beta. Round-4 rewrote the pow into rsqrt/sqrt chains on
-        # VPU-transcendental theory; the round-5 on-chip A/B (v5e,
-        # AlexNet bs512, 50 timed iters — RESULTS.md "Round-5 A/B")
-        # measured the chain ~2.5 ms/step SLOWER — LRN is HBM-bound,
-        # and the longer chain plus its VJP materialises more conv-sized
-        # temps than it saves in transcendentals. A single pow (and its
-        # single-temp VJP) wins; SPARKNET_LRN_CHAIN=1 keeps the chain
-        # reachable for re-measurement on other topologies.
+        # VPU-transcendental theory; the chain read slower at AlexNet
+        # bs512 (measured once in round 5 on a set-up that no longer
+        # exists; not re-measured) — LRN is HBM-bound, and the longer
+        # chain plus its VJP materialises more conv-sized temps than it
+        # saves in transcendentals. A single pow (and its single-temp
+        # VJP) is the default; SPARKNET_LRN_CHAIN=1 keeps the chain
+        # reachable (ROADMAP D3 deletes the loser).
         chain = os.environ.get("SPARKNET_LRN_CHAIN", "0") not in ("", "0")
         if chain and beta == 0.75:
             t = jnp.sqrt(lax.rsqrt(d))  # d^(-1/4)

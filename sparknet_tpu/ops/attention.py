@@ -29,18 +29,14 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pallas TPU backend is unavailable on some hosts; import lazily
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
-
-# 512, not 128: the round-5 on-chip block sweep (RESULTS.md) measured
-# 128x128 blocks 2.3x slower at S=512 (BERT shapes) and 1.6x slower at
-# S=8192 — with D=64 heads a 128-row block is a sliver of the MXU and
-# per-grid-step overhead dominates. 512x512 keeps VMEM tiny (the f32
-# score tile is 1 MB) and _resolve_blocks still shrinks to the largest
-# conforming divisor for short or non-conforming sequences.
+# 512, not 128: with D=64 heads a 128-row block is a sliver of the MXU
+# and per-grid-step overhead dominates (128x128 blocks read markedly
+# slower at S=512 and S=8192 — measured once in round 5 on a set-up
+# that no longer exists; not re-measured). 512x512 keeps VMEM small
+# (the f32 score tile is 1 MB) and _resolve_blocks still shrinks to the
+# largest conforming divisor for short or non-conforming sequences.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30  # large-but-finite: keeps fully-masked rows NaN-free
@@ -903,7 +899,6 @@ def attention(
     use_flash = force == "flash" or (
         force is None
         and jax.default_backend() == "tpu"
-        and pltpu is not None
         and (not dropping or flash_dropout_ok)
     )
     if use_flash:

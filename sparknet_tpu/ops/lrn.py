@@ -9,9 +9,9 @@ AlexNet/GoogLeNet's only non-conv normalization:
 The jnp path (``nets/layers.py``) is numerically fine but XLA
 materialises the squared tensor, the windowed sum, the power and its
 VJP chain as separate conv-sized HBM temps — cost analysis reports
-~5x the activation size in bytes accessed, which on a v5e makes the
-two AlexNet LRNs a measurable slice of the whole train step (RESULTS.md
-round-5 roofline table). LRN is a pure 1-D stencil along the minor
+~5x the activation size in bytes accessed, which makes the two AlexNet
+LRNs a candidate slice of the whole train step (ROADMAP S6: not
+measured on the current code). LRN is a pure 1-D stencil along the minor
 (channel) axis, so one Pallas pass holds the whole window in VMEM:
 
 - forward: read x, write y and the residual d — no squared/windowed
@@ -28,12 +28,13 @@ view; C rides the 128-lane axis (C < 128 pads — zero lanes contribute
 zero to the window sum and d = k > 0 keeps the power finite).
 
 The jnp path remains the oracle and the DEFAULT (the kernel is opt-in
-via SPARKNET_LRN_PALLAS=1): the round-5 on-chip A/B measured the
-kernel 2x slower *inside the AlexNet train step* — XLA assigns the
-neighbouring convs exotic layouts (batch-minor {0,3,2,1} activations)
-and a pallas_call pins row-major operands, so each LRN pays two
-conv-sized relayout copies that dwarf the temp-chain saving (RESULTS.md
-"Round-5 A/B"). The kernel wins only where the operand is already
+via SPARKNET_LRN_PALLAS=1): inside the AlexNet train step the kernel
+read about twice as slow (measured once in round 5 on a set-up that no
+longer exists; not re-measured) — XLA assigns the neighbouring convs
+exotic layouts (batch-minor {0,3,2,1} activations) and a pallas_call
+pins row-major operands, so each LRN pays two conv-sized relayout
+copies that dwarf the temp-chain saving. ROADMAP D3 deletes this
+module. The kernel wins only where the operand is already
 row-major (standalone use); equivalence incl. grads is pinned in
 tests/test_lrn_pallas.py (interpret mode on CPU).
 """

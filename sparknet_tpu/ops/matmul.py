@@ -30,12 +30,11 @@ import os
 import jax
 import jax.numpy as jnp
 
-# Round-5 on-chip A/B (v5e, AlexNet bs512, 50 timed iters — table in
-# RESULTS.md "Round-5 A/B"): the custom VJP is ~0.8 ms/step FASTER than
-# the default transpose rule (42.66 vs 43.42 ms), confirming the
-# bf16-rate theory, so it stays the default. SPARKNET_MXU_VJP=0 drops
-# to a plain dot (still bf16 operands + f32 accumulation forward) so
-# the comparison stays re-runnable on other models/topologies.
+# The custom VJP read slightly faster than the default transpose rule at
+# AlexNet bs512 (measured once in round 5 on a set-up that no longer
+# exists; not re-measured), so it is the default. SPARKNET_MXU_VJP=0
+# drops to a plain dot (still bf16 operands + f32 accumulation forward)
+# so the comparison stays re-runnable (ROADMAP D3).
 _USE_VJP = os.environ.get("SPARKNET_MXU_VJP", "1") not in ("", "0")
 
 

@@ -28,10 +28,6 @@ the host-side knobs are ``SPARKNET_COMM`` (``bucketed``/``monolithic``),
 ``--grad-compress``) and ``SPARKNET_COMM_BUCKET_MB``.  See
 docs/COMMUNICATION.md.
 
-This module also owns the jax compat shims for the manual-sharding API
-(``shard_map`` moved from ``jax.experimental`` to ``jax.``;
-``lax.pcast`` is newer still): the parallel modes route through them so
-one source runs on every jax this framework meets.
 """
 
 from __future__ import annotations
@@ -59,77 +55,11 @@ _INT8_ACC_DTYPE = jnp.int16
 _INT8_MAX_WORKERS = 256
 
 
-# --------------------------------------------------------------------------
-# jax compat: the manual-sharding API across jax versions
-# --------------------------------------------------------------------------
-
-def shard_map(f: Callable, *, mesh, in_specs, out_specs) -> Callable:
-    """``jax.shard_map`` when it exists, else the ``jax.experimental``
-    spelling — with replication/vma checking off in both (the comm
-    programs mix invariant params with per-bucket collectives through a
-    ``custom_vjp``, which the checkers cannot see through)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        for kw in ({"check_vma": False}, {"check_rep": False}, {}):
-            try:
-                return sm(
-                    f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    **kw,
-                )
-            except TypeError:
-                continue
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
-
-
-def jit_manual(fn: Callable, **jit_kw) -> Callable:
-    """``jax.jit`` for manual-sharding (shard_map) programs.
-
-    On current jax this IS ``jax.jit``.  On the old-API fallback these
-    programs must never land in the persistent compilation cache: that
-    jaxlib segfaults DESERIALIZING cached executables carrying
-    manual-collective thunks (same serialization bug family
-    tests/conftest.py works around via the min-compile-time floor —
-    these programs compile in whole seconds, so the floor can't exclude
-    them).  Neither the cache-dir config nor the enable flag can be
-    toggled per program (``is_cache_used`` latches once per process),
-    but ``_cache_write`` consults the min-compile-time config LIVE — so
-    the wrapper raises it past any real compile around every call.
-    Never written means never read back, and a cache MISS is harmless;
-    the in-memory jit cache still applies, so only the first call per
-    shape pays a real compile."""
-    jfn = jax.jit(fn, **jit_kw)
-    if getattr(jax, "shard_map", None) is not None:
-        return jfn
-
-    knob = "jax_persistent_cache_min_compile_time_secs"
-
-    def call(*a, **k):
-        prev = getattr(jax.config, knob, None)
-        if prev is None:
-            return jfn(*a, **k)
-        jax.config.update(knob, 1e9)
-        try:
-            return jfn(*a, **k)
-        finally:
-            jax.config.update(knob, prev)
-
-    return call
-
-
 def pcast_varying(tree: Any, axis_name: str) -> Any:
-    """Mark a replicated tree device-varying for shard_map's typing
-    (newer jax); a no-op where ``lax.pcast`` does not exist (older jax
-    has no varying type to satisfy)."""
-    pc = getattr(lax, "pcast", None)
-    if pc is None:
-        return tree
+    """Mark a replicated tree device-varying along ``axis_name`` for
+    shard_map's typing."""
     return jax.tree_util.tree_map(
-        lambda x: pc(x, axis_name, to="varying"), tree
+        lambda x: lax.pcast(x, axis_name, to="varying"), tree
     )
 
 

@@ -181,13 +181,14 @@ def make_bucketed_dp_train_step(
     out_opt_spec = dict(opt_spec) if config.wants_residual else {
         k: P() for k in okeys
     }
-    fn = comm.shard_map(
+    fn = jax.shard_map(
         per_worker,
         mesh=mesh,
         in_specs=(P(), P(), opt_spec, batch_spec, P(), P()),
         out_specs=(P(), P(), out_opt_spec, P()),
+        check_vma=False,
     )
-    return comm.jit_manual(
+    return jax.jit(
         fn, donate_argnums=(0, 1, 2) if donate else (), **step_compile_kw()
     )
 

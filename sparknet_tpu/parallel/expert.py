@@ -21,7 +21,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..solver.caffe_solver import make_update_fn, mults_for_params
-from . import comm
 from .moe import moe_pspecs
 
 
@@ -147,12 +146,13 @@ def make_ep_train_step(
         key = tuple(sorted(opt_state))
         if key not in compiled:
             ospec = {k: pspecs for k in opt_state}
-            compiled[key] = comm.jit_manual(
-                comm.shard_map(
+            compiled[key] = jax.jit(
+                jax.shard_map(
                     local_step,
                     mesh=mesh,
                     in_specs=(pspecs, ospec, batch_spec, P(), P()),
                     out_specs=(pspecs, ospec, P()),
+                    check_vma=False,
                 ),
                 donate_argnums=(0, 1),
             )

@@ -146,13 +146,14 @@ def make_local_sgd_round(
         opt_out = jax.tree_util.tree_map(lambda x: x[None], opt_local)
         return p, st, opt_out, metrics
 
-    fn = comm.shard_map(
+    fn = jax.shard_map(
         per_worker,
         mesh=mesh,
         in_specs=(P(), P(), P(dp_axis), _batch_spec(sp, dp_axis), P(), P()),
         out_specs=(P(), P(), P(dp_axis), P()),
+        check_vma=False,
     )
-    return comm.jit_manual(
+    return jax.jit(
         fn, donate_argnums=(0, 1, 2) if donate else (), **step_compile_kw()
     )
 
@@ -185,13 +186,14 @@ def make_local_scan(
         lift = lambda t: jax.tree_util.tree_map(lambda x: x[None], t)
         return params, lift(p), lift(st), lift(opt_local), metrics
 
-    fn = comm.shard_map(
+    fn = jax.shard_map(
         per_worker,
         mesh=mesh,
         in_specs=(P(), P(), P(dp_axis), _batch_spec(sp, dp_axis), P(), P()),
         out_specs=(P(), P(dp_axis), P(dp_axis), P(dp_axis), P()),
+        check_vma=False,
     )
-    return comm.jit_manual(
+    return jax.jit(
         fn, donate_argnums=(0, 1, 2) if donate else (), **step_compile_kw()
     )
 
@@ -234,13 +236,14 @@ def make_round_reduce(
         p = jax.tree_util.tree_map(lambda s, d: s + d, p_start, red)
         return p, st, lift(new_res)
 
-    fn = comm.shard_map(
+    fn = jax.shard_map(
         per_worker,
         mesh=mesh,
         in_specs=(P(), P(dp_axis), P(dp_axis), P(dp_axis)),
         out_specs=(P(), P(), P(dp_axis)),
+        check_vma=False,
     )
-    return comm.jit_manual(
+    return jax.jit(
         fn, donate_argnums=(0, 1, 2, 3) if donate else (), **step_compile_kw()
     )
 

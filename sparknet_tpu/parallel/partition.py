@@ -4,9 +4,8 @@ The strategy zoo this module replaces grew one hand-built ``shard_map``
 step builder per parallelism flavour (dp/tp/pp/sp/ep/local-SGD), each
 with its own manual collectives.  Following the declarative
 dataflow-partitioning design of the TensorFlow paper (PAPERS.md,
-arXiv:1605.08695) and the mesh/``NamedSharding`` idiom in SNIPPETS.md
-[1]/[3], the unified path expresses a parallel layout as DATA, not
-code:
+arXiv:1605.08695) and jax's mesh/``NamedSharding`` idiom, the unified
+path expresses a parallel layout as DATA, not code:
 
 - **Layout** = a mesh shape (``dp``/``tp``/``pp``/``ep`` axes over
   :func:`~sparknet_tpu.parallel.mesh.make_mesh`) plus an ORDERED table
@@ -239,9 +238,8 @@ def match_spec(
     mesh: Optional[Mesh] = None,
 ) -> P:
     """First-match-wins spec for one leaf; replicated fallback.  Scalar
-    (0-d / single-element) leaves are never partitioned (SNIPPETS.md
-    [1] discipline).  When ``mesh`` is given, rule axes the mesh lacks
-    resolve to ``None``."""
+    (0-d / single-element) leaves are never partitioned.  When ``mesh``
+    is given, rule axes the mesh lacks resolve to ``None``."""
     ndim = getattr(leaf, "ndim", len(getattr(leaf, "shape", ())))
     size = getattr(leaf, "size", None)
     if ndim == 0 or size == 1:
@@ -548,24 +546,25 @@ def place(tree, shardings):
 _FORCE_FLAG = "--xla_force_host_platform_device_count"
 
 
+def backend_initialized() -> bool:
+    """Whether this process already holds a jax backend — asked without
+    creating one.  The one reach into jax's private bridge: 0.9.0 has no
+    public spelling of the question."""
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
+
+
 def ensure_virtual_devices(n: int) -> bool:
-    """``honor_platform_env``-style guard for the virtual-CPU mesh:
-    make ``XLA_FLAGS=--xla_force_host_platform_device_count=n``
-    effective when the backend is not yet initialized, and a LOUD
-    no-op (warning, return False) when it is — instead of the silent
-    1-device mesh that makes every divisibility check downstream fail
-    confusingly.  Returns True when n devices are (or will be)
-    available."""
+    """Guard for the virtual-CPU mesh: make
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=n`` effective
+    when the backend is not yet initialized, and a LOUD no-op (warning,
+    return False) when it is — instead of the silent 1-device mesh that
+    makes every divisibility check downstream fail confusingly.
+    Returns True when n devices are (or will be) available."""
     flags = os.environ.get("XLA_FLAGS", "")
     have = re.search(_FORCE_FLAG + r"=(\d+)", flags)
-    backend_up = False
-    try:  # detect init WITHOUT triggering it
-        from jax._src import xla_bridge as _xb
-
-        backend_up = bool(getattr(_xb, "_backends", None))
-    except Exception:  # pragma: no cover - jax internals moved
-        pass
-    if backend_up:
+    if backend_initialized():
         ok = len(jax.devices()) >= n
         if not ok:
             warnings.warn(

@@ -26,7 +26,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..solver.caffe_solver import make_update_fn, mults_for_params
-from . import comm
 
 
 def stack_layer_params(params: Dict[str, Dict[str, jax.Array]], num_layers: int):
@@ -325,12 +324,13 @@ def make_pp_train_step(
         key = tuple(sorted(opt_state))
         if key not in compiled:
             ospec = {k: pspec for k in opt_state}
-            compiled[key] = comm.jit_manual(
-                comm.shard_map(
+            compiled[key] = jax.jit(
+                jax.shard_map(
                     local_step,
                     mesh=mesh,
                     in_specs=(pspec, ospec, batch_spec, P(), P()),
                     out_specs=(pspec, ospec, P()),
+                    check_vma=False,
                 ),
                 donate_argnums=(0, 1),
             )

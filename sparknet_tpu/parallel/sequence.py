@@ -51,6 +51,20 @@ def _block_logits(q, k, scale, kv_mask_blk, causal, q_off, kv_off):
     return jnp.where(valid, s, NEG_INF)
 
 
+def ring_engine(s_local: int) -> str:
+    """The block engine :func:`ring_attention` uses by default for a
+    shard of ``s_local`` positions: ``SPARKNET_RING_IMPL`` when set,
+    else the Pallas flash kernels on a TPU for lane-aligned shards
+    (``s_local % 128 == 0``) and the einsum ring everywhere else.  The
+    app prints it, so a run cannot mistake one engine for the other."""
+    impl = os.environ.get("SPARKNET_RING_IMPL")
+    if impl:
+        return impl
+    if jax.default_backend() == "tpu" and s_local % 128 == 0:
+        return "flash"
+    return "einsum"
+
+
 def ring_attention(
     q: jax.Array,
     k: jax.Array,
@@ -94,20 +108,7 @@ def ring_attention(
     ``p/sum(p)``-then-drop semantics in expectation.
     """
     b, h, s_loc, d = q.shape
-    if impl is None:
-        impl = os.environ.get("SPARKNET_RING_IMPL") or None
-    if impl is None:
-        from ..ops.attention import pltpu
-
-        impl = (
-            "flash"
-            if (
-                jax.default_backend() == "tpu"
-                and pltpu is not None
-                and s_loc % 128 == 0
-            )
-            else "einsum"
-        )
+    impl = impl or ring_engine(s_loc)
     if impl not in ("flash", "einsum"):
         raise ValueError(
             f"ring impl {impl!r}: want 'flash' or 'einsum' "
@@ -435,12 +436,11 @@ def make_sp_train_step(model, sp, mesh, dp_axis: str = "dp", sp_axis: str = "sp"
         "mlm_labels": P(dp_axis, sp_axis),
         "mlm_weights": P(dp_axis, sp_axis),
     }
-    from . import comm
-
-    step = comm.shard_map(
+    step = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(P(), P(), batch_spec, P(), P()),
         out_specs=(P(), P(), P()),
+        check_vma=False,
     )
-    return comm.jit_manual(step, donate_argnums=(0, 1))
+    return jax.jit(step, donate_argnums=(0, 1))

@@ -25,7 +25,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..solver.caffe_solver import make_update_fn, mults_for_params
-from . import comm
 
 
 def bert_param_pspecs(model, tp_axis: str = "tp") -> Dict[str, Dict[str, P]]:
@@ -154,12 +153,13 @@ def make_tp_train_step(
         key = tuple(sorted(opt_state))
         if key not in compiled:
             ospec = {k: pspecs for k in opt_state}
-            compiled[key] = comm.jit_manual(
-                comm.shard_map(
+            compiled[key] = jax.jit(
+                jax.shard_map(
                     local_step,
                     mesh=mesh,
                     in_specs=(pspecs, ospec, batch_spec, P(), P()),
                     out_specs=(pspecs, ospec, P()),
+                    check_vma=False,
                 ),
                 donate_argnums=(0, 1),
             )

@@ -21,12 +21,10 @@ params as executable *arguments*, so every weight hot-swap of the same
 arch reuses both the in-memory and the on-disk cache; a different arch
 gets a different directory and can never collide.
 
-Platform note (this jaxlib, 0.4.37): entries below the ambient
-``jax_persistent_cache_min_compile_time_secs`` floor are never
-persisted — the floor exists because serializing near-instant compiles
-segfaults this jaxlib (see tests/conftest.py) — so toy nets may not
-benefit; real nets (whole-second compiles) do, and the
-``BENCH_MODEL=serving_tier`` record measures the win.
+Placement follows the one rule in ``utils/compile_cache.py``: where
+``JAX_COMPILATION_CACHE_DIR`` is set, that directory is the cache and
+the per-net subdirectory is skipped (jax's own entry key already covers
+the program text, so nets cannot collide there either).
 """
 
 from __future__ import annotations
@@ -125,25 +123,15 @@ def cache_entries(path: str) -> int:
 def enable_persistent_cache(
     root: str,
     fingerprint: Optional[str] = None,
-    min_compile_time_s: Optional[float] = None,
 ) -> Optional[Dict[str, Any]]:
     """Point jax's persistent compilation cache at
-    ``root[/fingerprint]`` for THIS process.  Safe to call before or
-    after backend init: this jaxlib latches cache initialization once
-    (``_initialize_cache``; ``set_cache_dir`` alone does NOT unlatch),
-    so the latch is explicitly reset — the next compile re-initializes
-    against the new directory.  Returns ``{"dir", "entries"}`` —
-    ``entries`` is the pre-warmup count, so callers can diff it after
-    warmup to tell a cache-hit restart from a cold compile.
-
-    ``min_compile_time_s``: override the persistence floor (default:
-    ``SPARKNET_SERVE_CACHE_FLOOR_S``, 0.05).  Serving replicas *want*
-    sub-second inference compiles persisted — a replica restart's
-    warmup is the sum of them — and these single-device programs
-    round-trip the serializer safely (the known jaxlib crash is
-    specific to manual-collective executables, which ``jit_manual``
-    already keeps out of the cache; see tests/conftest.py and
-    parallel/comm.py).
+    ``root[/fingerprint]`` for THIS process — unless
+    ``JAX_COMPILATION_CACHE_DIR`` places the cache from outside, which
+    wins (``utils/compile_cache.py`` holds the rule and the one config
+    update).  Safe to call before or after backend init.  Returns
+    ``{"dir", "entries"}`` — ``entries`` is the pre-warmup count, so
+    callers can diff it after warmup to tell a cache-hit restart from a
+    cold compile.
 
     Degradation: a storage fault here (cache root unwritable, disk
     full, injected ``io.*@site=compile_cache`` chaos) disables the
@@ -154,11 +142,10 @@ def enable_persistent_cache(
     global _io_disabled
     if _io_disabled:
         return None
-    import jax
-
+    from ..utils import compile_cache as placement
     from ..utils import safeio
 
-    path = os.path.join(root, fingerprint) if fingerprint else root
+    path = placement.resolve(root, fingerprint)
     try:
         safeio.check_faults("compile_cache")
         os.makedirs(path, exist_ok=True)
@@ -171,24 +158,5 @@ def enable_persistent_cache(
             file=sys.stderr, flush=True,
         )
         return None
-    if min_compile_time_s is None:
-        min_compile_time_s = float(
-            os.environ.get("SPARKNET_SERVE_CACHE_FLOOR_S", "") or 0.05
-        )
-    # size floor off: serving executables are small
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update(
-        "jax_persistent_cache_min_compile_time_secs",
-        float(min_compile_time_s),
-    )
-    jax.config.update("jax_compilation_cache_dir", path)
-    try:
-        from jax.experimental.compilation_cache import (
-            compilation_cache as cc,
-        )
-
-        cc.reset_cache()  # drop the once-only init latch (see above)
-    except Exception:
-        # very old/new jax: the config route still applies at first use
-        pass
+    placement.enable(root, fingerprint)
     return {"dir": path, "entries": cache_entries(path)}

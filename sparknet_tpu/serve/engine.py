@@ -25,9 +25,9 @@ swap bumps a monotone ``generation`` the HTTP layer tags responses
 with.  The same fingerprint keys the on-disk persistent compile cache
 (``serve/compile_cache.py``), so replica restarts skip AOT warmup.
 
-Input buffers are donated to XLA on accelerators (they are
-request-scoped temporaries); donation is skipped on CPU where it only
-produces "donated buffer unused" noise.
+The decode step donates its carry (the session state the step
+supersedes) on every backend, the CPU included, so tests run the path
+the chip runs; the bucketed forward donates nothing.
 """
 
 from __future__ import annotations
@@ -485,11 +485,11 @@ class InferenceEngine:
             shape_of = lambda t: jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), t
             )
-            # donate the batch (arg 2) on accelerators: it is a
-            # request-scoped temporary; params/state (args 0/1) are the
-            # resident weights and must never be donated
-            donate = () if jax.default_backend() == "cpu" else (2,)
-            jit_kw: Dict[str, Any] = {"donate_argnums": donate}
+            # nothing is donated: params/state are the resident weights,
+            # and the batch cannot alias the (smaller) output — on the
+            # chip XLA answered "donated buffers were not usable" for
+            # every bucket
+            jit_kw: Dict[str, Any] = {}
             if self._mesh is not None:
                 jit_kw["in_shardings"] = (
                     self._params_sh, self._state_sh,
@@ -649,8 +649,8 @@ class InferenceEngine:
     def _step_executable(self, n: int = 1, weights=None):
         """The compiled single-token decode step for ``n`` parallel
         session rows (``serve/session.py``) — ``step(params, state,
-        carry, token)`` with the carry donated on accelerators, AOT-
-        compiled once per (fingerprint, n).  The same key discipline as
+        carry, token)`` with the carry donated, AOT-compiled once per
+        (fingerprint, n).  The same key discipline as
         the bucketed cache: a hot-swap of the same arch reuses it (a
         pointer exchange), an arch change re-keys it."""
         params, state, _, fingerprint = (
@@ -672,11 +672,9 @@ class InferenceEngine:
                 (n,) + stepper.row_shape, jnp.dtype(stepper.token_dtype)
             )
             # donate the carry (arg 2): the step's output carry
-            # supersedes it — the session-state pointer exchange.  CPU
-            # skips donation like the bucketed path (noise only).
-            donate = () if jax.default_backend() == "cpu" else (2,)
+            # supersedes it — the session-state pointer exchange
             exe = (
-                jax.jit(stepper.step_fn, donate_argnums=donate)
+                jax.jit(stepper.step_fn, donate_argnums=(2,))
                 .lower(
                     shape_of(params), shape_of(state),
                     shape_of(stepper.init_carry(n)), token_struct,

@@ -226,9 +226,9 @@ def write_portfile(path: str, server, engine, cache_info) -> None:
 
 def main(argv=None) -> int:
     from ..telemetry import reqtrace
-    from ..tools._common import honor_platform_env
+    from ..utils import compile_cache
 
-    honor_platform_env()
+    compile_cache.enable()
     # request tracing rides the inherited env (the router's operator
     # sets SPARKNET_REQTRACE once for the whole tier); re-resolve it
     # explicitly so a respawn under a scrubbed env behaves the same

@@ -210,17 +210,19 @@ class DecodeStepper:
         carry: Dict[str, Tuple[jax.Array, ...]] = {}
         for lp in self._recurrents:
             h = int(lp.sub("recurrent_param").get("num_output"))
-            zeros = jnp.zeros((n, h), jnp.float32)
-            carry[lp.name] = (
-                (zeros, zeros) if lp.type == "LSTM" else (zeros,)
+            # one buffer per slot: the engine donates the whole carry,
+            # and a buffer cannot be donated twice in one call
+            carry[lp.name] = tuple(
+                jnp.zeros((n, h), jnp.float32)
+                for _ in range(2 if lp.type == "LSTM" else 1)
             )
         return carry
 
     def step_fn(self, params, state, carry, token):
         """Pure: one token per session row -> (output row (N, ...),
         new carry).  Jit/AOT-compile this; the engine donates ``carry``
-        on accelerators (the pointer-exchange discipline — the old
-        state is consumed by the step that supersedes it)."""
+        (the pointer-exchange discipline — the old state is consumed by
+        the step that supersedes it)."""
         n = token.shape[0]
         blobs: Dict[str, jax.Array] = {self.primary: token[None]}
         for name in self.cont_inputs:

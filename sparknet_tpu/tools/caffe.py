@@ -171,9 +171,9 @@ def _device_query(argv: List[str]):
 
 
 def main(argv=None):
-    from ._common import honor_platform_env
+    from ..utils import compile_cache
 
-    honor_platform_env()
+    compile_cache.enable()
     argv = list(sys.argv[1:] if argv is None else argv)
     cmds = {
         "train": _train,

@@ -61,9 +61,6 @@ def compare_trees(a, b):
 
 
 def main(argv=None) -> int:
-    from ._common import honor_platform_env
-
-    honor_platform_env()
     from ..apps import cifar_app
 
     ap = argparse.ArgumentParser(

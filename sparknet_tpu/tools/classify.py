@@ -73,9 +73,6 @@ def classify(
 
 
 def main(argv=None):
-    from ._common import honor_platform_env
-
-    honor_platform_env()
     ap = argparse.ArgumentParser(description="deploy-net image classification")
     ap.add_argument("--model", required=True, help="deploy .prototxt")
     ap.add_argument("--weights", default=None, help=".caffemodel")

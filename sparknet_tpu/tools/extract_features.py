@@ -79,9 +79,6 @@ def extract(
 
 
 def main(argv=None) -> int:
-    from ._common import honor_platform_env
-
-    honor_platform_env()
     ap = argparse.ArgumentParser(prog="extract_features")
     ap.add_argument("--model", required=True)
     ap.add_argument("--weights", default=None)

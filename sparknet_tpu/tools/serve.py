@@ -42,9 +42,9 @@ import sys
 
 
 def main(argv=None):
-    from ._common import honor_platform_env
+    from ..utils import compile_cache
 
-    honor_platform_env()
+    compile_cache.enable()
     from ..serve.replica import add_engine_args
 
     ap = argparse.ArgumentParser(
@@ -153,7 +153,29 @@ def main(argv=None):
                      "--tee-dir")
 
     if args.replicas > 0:
-        return _run_router(args)
+        # one process per chip: on a TPU host every replica child gets
+        # exactly one chip and this router process stays off JAX
+        from ..utils import chips
+
+        n_chips = chips.local_chip_count()
+        width = max(args.replicas, args.autoscale_max)
+        if n_chips and width > n_chips:
+            ap.error(
+                f"{width} replica processes need {width} TPU chips and "
+                f"this host has {n_chips}: each replica initialises JAX "
+                f"and holds one chip (--replicas/--autoscale-max <= "
+                f"{n_chips}, or JAX_PLATFORMS=cpu for a CPU tier)"
+            )
+        if n_chips and args.deploy_dir:
+            ap.error(
+                "--deploy-dir on a TPU host: the eval gate builds its "
+                "engines inside this router process and the trainer "
+                "child needs a chip as well, so the loop would take "
+                "chips its replicas hold (one process per chip; "
+                "ROADMAP R7/D6 — run the closed loop with "
+                "JAX_PLATFORMS=cpu)"
+            )
+        return _run_router(args, n_chips)
 
     from ..serve.loadgen import run_loadgen
     from ..serve.replica import build_stack, write_portfile
@@ -239,18 +261,21 @@ def _portfile(run_dir: str, index: int, spawn: int) -> str:
     return os.path.join(run_dir, f"replica-{index}-s{spawn}.json")
 
 
-def _run_router(args):
+def _run_router(args, n_chips: int):
     import tempfile
 
     from ..serve.replica import write_portfile
     from ..serve.router import Router
     from ..supervise.pool import ChildPool
+    from ..utils import chips
 
     run_dir = args.run_dir or tempfile.mkdtemp(prefix="sparknet_serve_")
     os.makedirs(run_dir, exist_ok=True)
     pool = ChildPool(
         lambda i, s: _replica_argv(args, run_dir, i, s),
         args.replicas,
+        # replica i (every spawn of it) owns chip i
+        make_env=(lambda i, s: chips.one_chip_env(i)) if n_chips else None,
         name="serve-replica",
     )
     admit_on = (

@@ -40,11 +40,20 @@ PEAK_TFLOPS = [
 
 
 def device_peak_flops(device=None) -> Optional[float]:
+    """bf16 peak FLOP/s of one chip.  None on a backend with no peak to
+    speak of (CPU); a TPU whose ``device_kind`` is not in the table is
+    an error, not a silently missing MFU."""
     device = device or jax.devices()[0]
     kind = getattr(device, "device_kind", "").lower()
     for key, peak in PEAK_TFLOPS:
         if key in kind:
             return peak
+    if getattr(device, "platform", "") == "tpu":
+        raise ValueError(
+            f"no peak FLOP/s known for TPU device_kind "
+            f"{device.device_kind!r}: add it to PEAK_TFLOPS "
+            f"(utils/profiling.py) with its source"
+        )
     return None
 
 

@@ -135,7 +135,7 @@ def test_wire_decodes_protobuf_encoding():
 
 def test_import_matches_nchw_math():
     """Imported weights must reproduce Caffe's NCHW forward bit-for-bit
-    (torch conv/linear as the NCHW oracle) — VERDICT missing #4."""
+    (torch conv/linear as the NCHW oracle)."""
     import torch
     import torch.nn.functional as F
 

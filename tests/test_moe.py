@@ -9,7 +9,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from sparknet_tpu.parallel import comm
 from sparknet_tpu.parallel.mesh import make_mesh
 from sparknet_tpu.parallel.moe import init_moe_params, moe_ffn, moe_pspecs
 
@@ -53,8 +52,9 @@ def test_moe_ep_matches_single_device():
             out, aux = moe_ffn(x, params, ep_axis="ep", capacity_factor=2.0)
             return jnp.sum(jnp.sin(out)) + 0.01 * aux
 
-        return comm.shard_map(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=(pspecs, P()), out_specs=P(),
+            check_vma=False,
         )(params, x)
 
     l0 = float(jax.jit(loss_single)(params, x))
@@ -114,8 +114,9 @@ def test_moe_top2_ep_matches_single_device(dispatch):
             out, aux = moe_ffn(x, params, ep_axis="ep", **kw)
             return jnp.sum(jnp.sin(out)) + 0.01 * aux
 
-        return comm.shard_map(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=(moe_pspecs(), P()), out_specs=P(),
+            check_vma=False,
         )(params, x)
 
     l0 = float(jax.jit(loss_single)(params, x))
@@ -150,7 +151,8 @@ def test_moe_rejects_indivisible_experts():
     x, params = setup(e=6)
     mesh = make_mesh({"ep": 4}, jax.devices()[:4])
     with pytest.raises(ValueError):
-        comm.shard_map(
+        jax.shard_map(
             lambda p, x: moe_ffn(x, p, ep_axis="ep")[0],
             mesh=mesh, in_specs=(moe_pspecs(), P()), out_specs=P(),
+            check_vma=False,
         )(params, x)

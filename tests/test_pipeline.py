@@ -317,27 +317,17 @@ def test_app_feed_constructor_uses_pipeline():
 
 
 @pytest.mark.slow
-def test_bench_input_pipeline_record():
-    """BENCH_MODEL=input_pipeline emits the serial-vs-parallel A/B
-    record (slow: subprocess + real AlexNet-shaped preprocessing)."""
-    import json
-    import subprocess
-    import sys
-
+def test_bench_input_pipeline_record(monkeypatch):
+    """The input_pipeline arm assembles the serial-vs-parallel A/B
+    record (slow: real AlexNet-shaped preprocessing).  Called as a
+    function: ``python bench.py`` itself refuses to run off a TPU."""
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(
-        os.environ,
-        JAX_PLATFORMS="cpu",
-        BENCH_MODEL="input_pipeline",
-        BENCH_BATCH="16",
-        BENCH_ITERS="6",
-    )
-    out = subprocess.run(
-        [sys.executable, os.path.join(here, "bench.py")],
-        capture_output=True, text=True, timeout=600, env=env, cwd=here,
-    )
-    line = out.stdout.strip().splitlines()[-1]
-    rec = json.loads(line)
+    monkeypatch.syspath_prepend(here)
+    import bench
+
+    monkeypatch.setenv("BENCH_BATCH", "16")
+    monkeypatch.setenv("BENCH_ITERS", "6")
+    rec = bench.bench_input_pipeline("cpu")
     assert rec["metric"] == "input_pipeline_images_per_sec", rec
     assert rec["value"] > 0, rec
     assert rec["serial_img_per_sec"] > 0

@@ -158,16 +158,28 @@ def test_int8_pack_bit_stable_across_processes(tmp_path):
 
 
 # --------------------------------------------------------------- agreement
-def test_int8_and_bf16_top1_agreement():
-    f32 = toy_engine()
-    int8 = toy_engine(quant="int8")
-    bf16 = toy_engine(quant="bf16")
-    rows = toy_rows(128)
-    ref, _ = f32.topk(rows, 1)
-    for eng in (int8, bf16):
-        idx, _ = eng.topk(rows, 1)
-        agree = float((idx[:, 0] == ref[:, 0]).mean())
-        assert agree >= 0.995, (eng.quant, agree)
+def test_int8_and_bf16_outputs_within_tolerance():
+    """Quantized outputs against the f32 engine on the same seeded rows,
+    compared as probabilities, not as arg-max: with random weights the
+    closest f32 top-2 margin on these 128 rows is 9e-4, far inside any
+    quantizer's rounding, so the top class flips on rounding (126/128
+    agree under jax 0.9) and an agreement rate measures the seed, not
+    the quantizer (model-configs guide, section 3).
+
+    Tolerances (absolute, on softmax outputs in [0, 1]): int8 carries
+    per-channel weight and per-row activation rounding of about 1/127
+    through two layers — observed max 0.016, bound 0.05; bf16 keeps 8
+    mantissa bits — observed max 0.004, bound 0.01.  Both bounds are
+    about 3x the observed error and an order of magnitude below what
+    the next coarser format (int4, fp8) would produce, so computing in
+    a lower precision than the mode states fails."""
+    ref = np.asarray(toy_engine().infer(toy_rows(128)))
+    for quant, atol in (("int8", 0.05), ("bf16", 0.01)):
+        out = np.asarray(toy_engine(quant=quant).infer(toy_rows(128)))
+        assert np.isfinite(out).all(), quant
+        err = float(np.abs(out - ref).max())
+        assert err <= atol, (quant, err)
+        assert err > 0.0, quant  # the quantized path really ran
 
 
 def test_int8_padded_rows_bit_identical():

@@ -1,4 +1,4 @@
-"""Realistic-shape parallelism steps (VERDICT r03 weak #5): the toy
+"""Realistic-shape parallelism steps: the toy
 dryrun shapes (bert_tiny, S=64) can hide pspec/memory logic that only
 trips at size — e.g. a block size that divides 64 but not 512, a
 capacity computation that overflows a shard, a reshape that silently

@@ -32,17 +32,13 @@ def rand(rng, shape):
 
 
 def _run_sp(fn, mesh, q, k, v, mask):
-    # comm.shard_map: the version-compat spelling (jax.shard_map where
-    # it exists, jax.experimental fallback otherwise) the parallel
-    # modes themselves route through
-    from sparknet_tpu.parallel import comm
-
-    mapped = comm.shard_map(
+    mapped = jax.shard_map(
         lambda q_, k_, v_, m_: fn(q_, k_, v_, kv_mask=m_),
         mesh=mesh,
         in_specs=(P(None, None, "sp"), P(None, None, "sp"),
                   P(None, None, "sp"), P(None, "sp")),
         out_specs=P(None, None, "sp"),
+        check_vma=False,
     )
     return mapped(q, k, v, mask)
 

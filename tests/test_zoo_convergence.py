@@ -1,4 +1,4 @@
-"""Synthetic convergence smokes for the big zoo nets (VERDICT r04 #7).
+"""Synthetic convergence smokes for the big zoo nets.
 
 The env ships no datasets (SURVEY.md §0), so these memorize a small
 deterministic batch cycle — the same oracle tau_sweep.py uses: a net
