@@ -11,6 +11,7 @@
 //   sn_cifar_decode       — CIFAR binary records -> NHWC uint8 + labels
 //   sn_transform_batch    — uint8 NHWC -> cropped/mirrored/mean-sub f32
 //   sn_loader_create/next/destroy — threaded prefetching batch loader
+//   sn_loader_stats       — the loader's cumulative counters (always on)
 //   sn_version            — ABI version stamp
 //
 // Determinism: every random decision derives from splitmix64(seed,
@@ -19,6 +20,7 @@
 // lineage contract as the Python ShardedDataset path).
 
 #include <atomic>
+#include <chrono>
 #include <condition_variable>
 #include <cstdint>
 #include <cstring>
@@ -29,7 +31,13 @@
 
 extern "C" {
 
-int sn_version() { return 1; }
+int sn_version() { return 2; }
+
+static inline int64_t now_ns() {
+  return std::chrono::duration_cast<std::chrono::nanoseconds>(
+             std::chrono::steady_clock::now().time_since_epoch())
+      .count();
+}
 
 // ---------------------------------------------------------------------------
 // RNG: splitmix64 -> bounded ints / floats. Counter-based, stateless.
@@ -158,6 +166,20 @@ struct Loader {
   std::vector<std::thread> workers;
   std::atomic<bool> stop{false};
 
+  // Cumulative counters, read by sn_loader_stats in this order.  Always
+  // on: six clock reads a batch beside the batch's pixel work.
+  enum {
+    BATCHES_BUILT,     // batches a worker finished building
+    BUILD_NS,          // worker time inside build(), all threads together
+    PUT_WAIT_NS,       // worker time blocked on a full window (back-pressure)
+    BATCHES_TAKEN,     // batches handed to the consumer
+    GET_WAIT_NS,       // consumer time until its in-order batch was ready
+    COPY_NS,           // consumer time copying the batch out, after the unlock
+    DEPTH_ON_ARRIVAL,  // sum of the queue's depth as each consumer call arrived
+    N_STATS
+  };
+  std::atomic<int64_t> stats[N_STATS] = {};
+
   int ch() const { return crop > 0 ? crop : h; }
   int cw() const { return crop > 0 ? crop : w; }
 
@@ -205,7 +227,11 @@ struct Loader {
     while (!stop.load()) {
       int64_t bidx = next_batch.fetch_add(1);
       Ready r;
+      int64_t t0 = now_ns();
       build(bidx, r);
+      int64_t t1 = now_ns();
+      stats[BUILD_NS] += t1 - t0;
+      stats[BATCHES_BUILT] += 1;
       std::unique_lock<std::mutex> lk(mu);
       // admit by index window, not queue size: the worker holding the
       // next in-order batch must always be able to enqueue, or the
@@ -214,6 +240,7 @@ struct Loader {
       cv_put.wait(lk, [&] {
         return stop.load() || bidx < next_out + queue_cap;
       });
+      stats[PUT_WAIT_NS] += now_ns() - t1;
       if (stop.load()) return;
       queue.push_back(std::move(r));
       cv_get.notify_all();
@@ -249,7 +276,9 @@ void* sn_loader_create(const uint8_t* images, const int32_t* labels, int n,
 int sn_loader_next(void* handle, float* out_data, int32_t* out_labels) {
   Loader* L = (Loader*)handle;
   if (!L) return -1;
+  int64_t t0 = now_ns();
   std::unique_lock<std::mutex> lk(L->mu);
+  L->stats[Loader::DEPTH_ON_ARRIVAL] += (int64_t)L->queue.size();
   for (;;) {
     for (size_t i = 0; i < L->queue.size(); ++i) {
       if (L->queue[i].index == L->next_out) {
@@ -258,15 +287,28 @@ int sn_loader_next(void* handle, float* out_data, int32_t* out_labels) {
         L->next_out++;
         lk.unlock();
         L->cv_put.notify_all();
+        int64_t t1 = now_ns();
         std::memcpy(out_data, r.data.data(), r.data.size() * sizeof(float));
         std::memcpy(out_labels, r.labels.data(),
                     r.labels.size() * sizeof(int32_t));
+        L->stats[Loader::GET_WAIT_NS] += t1 - t0;
+        L->stats[Loader::COPY_NS] += now_ns() - t1;
+        L->stats[Loader::BATCHES_TAKEN] += 1;
         return 0;
       }
     }
     if (L->stop.load()) return -2;
     L->cv_get.wait(lk);
   }
+}
+
+// Copies the first min(n, N_STATS) cumulative counters (Loader's enum
+// order) into out; returns how many the library has.
+int sn_loader_stats(void* handle, int64_t* out, int n) {
+  Loader* L = (Loader*)handle;
+  if (!L) return -1;
+  for (int i = 0; i < n && i < Loader::N_STATS; ++i) out[i] = L->stats[i].load();
+  return Loader::N_STATS;
 }
 
 void sn_loader_destroy(void* handle) {

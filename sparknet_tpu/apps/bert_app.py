@@ -479,7 +479,7 @@ def main(argv=None) -> Dict[str, float]:
 
     # --trace / SPARKNET_TRACE: span tracer + step-time attribution on
     # the Solver path (see cifar_app.main; docs/OBSERVABILITY.md)
-    telemetry.install_for_training(solver, args.trace)
+    telemetry.install_for_training(solver, args.trace, args.profile_dir)
     t0 = time.time()
     metrics = {}
     try:

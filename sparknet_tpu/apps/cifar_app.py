@@ -852,7 +852,7 @@ def main(argv=None):
 
     # --trace / SPARKNET_TRACE / SPARKNET_TIMELINE: span tracer +
     # step-time attribution (docs/OBSERVABILITY.md)
-    telemetry.install_for_training(solver, args.trace)
+    telemetry.install_for_training(solver, args.trace, args.profile_dir)
     try:
         with trace(args.profile_dir):
             result = train_loop(solver, train_feed, test_feed)
@@ -868,6 +868,10 @@ def main(argv=None):
         # a multiprocess train feed owns worker processes + shm slots;
         # stop them even when the loop raises (and report its per-stage
         # waits — the host-bound vs device-bound answer — on the way out)
+        # the staging thread first (data/prefetch.py): it must be out
+        # of the raw feed, and of jax, before either goes away under it
+        if train_feed is not raw_train_feed:
+            train_feed.close()
         pm = getattr(raw_train_feed, "metrics", None)
         if pm is not None and multihost.is_primary():
             print(f"input pipeline: {pm.json_line()}")

@@ -15,6 +15,13 @@ unwrapped iterator's. Not for multi-host global assembly —
 ``make_array_from_process_local_data`` must stay on the main thread
 with identical ordering across processes.
 
+The staging thread's time is three phases, reported to the current
+timeline (telemetry/timeline.py; background rows, beside the loop's):
+``feed.source`` inside ``next(it)``, ``feed.h2d`` inside ``put``,
+``feed.backpressure`` blocked on the full queue.  The thread is serial,
+so over any window they add up to its wall time, and which one fills it
+says what sets the pace: the source, the transfer, or the consumer.
+
 Two double-buffering surfaces live here, both reporting hit/wait
 counts through :class:`~.pipeline.PipelineMetrics` (``prefetch``
 block) instead of being standalone:
@@ -28,6 +35,7 @@ block) instead of being standalone:
 
 from __future__ import annotations
 
+import atexit
 import queue
 import threading
 import time
@@ -35,7 +43,10 @@ from typing import Any, Callable, Iterator, Optional
 
 import jax
 
+from ..telemetry import timeline as _timeline
+
 _SENTINEL = object()
+_JOIN_S = 5.0  # a closed feed waits this long for its staging thread
 _NONE = object()  # DoubleBuffer's "no staged slot" marker (None is a key)
 
 
@@ -75,10 +86,22 @@ def prefetch_to_device(
     stop = threading.Event()
 
     def worker():
+        source = _timeline.background_phase("feed.source")
+        h2d = _timeline.background_phase("feed.h2d")
+        backpressure = _timeline.background_phase("feed.backpressure")
         try:
-            for b in it:
-                staged = putter(b)
-                _put_checked(q, stop, staged)
+            source_it = iter(it)
+            while True:
+                with source:
+                    b = next(source_it, _SENTINEL)
+                if b is _SENTINEL:
+                    break
+                if stop.is_set():  # closed meanwhile: stage nothing more
+                    return
+                with h2d:
+                    staged = putter(b)
+                with backpressure:
+                    _put_checked(q, stop, staged)
                 if stop.is_set():
                     return
         except BaseException as e:  # noqa: BLE001 — relayed to consumer
@@ -86,7 +109,20 @@ def prefetch_to_device(
             return
         _put_checked(q, stop, (_SENTINEL, None))
 
-    threading.Thread(target=worker, daemon=True).start()
+    thread = threading.Thread(target=worker, daemon=True)
+
+    def shutdown():
+        """Stop the thread and wait for it to leave jax.  Also at exit,
+        for a feed nobody closed: an interpreter that finalises while a
+        daemon thread is inside ``device_put`` kills the thread there,
+        and the forced unwind through jaxlib's C++ aborts the process
+        ("FATAL: exception not rethrown", exit by SIGABRT after a run
+        that had finished)."""
+        stop.set()
+        thread.join(timeout=_JOIN_S)
+
+    atexit.register(shutdown)
+    thread.start()
     try:
         while True:
             t0 = time.perf_counter()
@@ -108,7 +144,8 @@ def prefetch_to_device(
                 return
             yield item
     finally:
-        stop.set()
+        atexit.unregister(shutdown)
+        shutdown()
         while not q.empty():  # drop staged batches so they can free
             try:
                 q.get_nowait()

@@ -18,6 +18,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from ..telemetry import timeline as _timeline
+
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _NATIVE_DIR = os.path.abspath(os.path.join(_HERE, "..", "..", "native"))
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libsparknet_data.so")
@@ -29,17 +31,37 @@ _why_not: Optional[str] = None  # set when the build or the load failed
 
 _f32p = ctypes.POINTER(ctypes.c_float)
 _i32p = ctypes.POINTER(ctypes.c_int32)
+_i64p = ctypes.POINTER(ctypes.c_int64)
 _u8p = ctypes.POINTER(ctypes.c_uint8)
+
+# the newest entry point: a library on disk without it predates this
+# source whatever its mtime says
+_NEWEST_SYMBOL = b"sn_loader_stats"
+# sn_loader_stats' order (Loader's enum in sparknet_data.cpp)
+STATS = (
+    "batches_built", "build_ns", "put_wait_ns", "batches_taken",
+    "get_wait_ns", "copy_ns", "depth_on_arrival",
+)
+
+
+def _is_stale() -> bool:
+    """A library on disk that lacks the newest entry point (make goes
+    by mtimes, and a copied tree's say nothing)."""
+    try:
+        with open(_LIB_PATH, "rb") as fh:
+            return _NEWEST_SYMBOL not in fh.read()
+    except OSError:
+        return False  # not there: make builds it
 
 
 def _build() -> Optional[str]:
     """``make -C native`` — a no-op when the library is newer than its
     source, a rebuild when it is not (an ``.so`` on disk may predate
-    ``sparknet_data.cpp``; git tracks only the source).  Returns why the
-    build failed, or None."""
+    ``sparknet_data.cpp``; git tracks only the source) or when it lacks
+    the newest entry point.  Returns why the build failed, or None."""
     try:
         subprocess.run(
-            ["make", "-C", _NATIVE_DIR],
+            ["make", "-C", _NATIVE_DIR] + (["-B"] if _is_stale() else []),
             check=True, capture_output=True, timeout=120,
         )
     except subprocess.CalledProcessError as e:
@@ -81,6 +103,14 @@ def _load() -> Optional[ctypes.CDLL]:
         lib.sn_loader_next.restype = ctypes.c_int
         lib.sn_loader_next.argtypes = [ctypes.c_void_p, _f32p, _i32p]
         lib.sn_loader_destroy.argtypes = [ctypes.c_void_p]
+        try:
+            lib.sn_loader_stats.restype = ctypes.c_int
+            lib.sn_loader_stats.argtypes = [
+                ctypes.c_void_p, _i64p, ctypes.c_int,
+            ]
+        except AttributeError:
+            _why_not = f"{_LIB_PATH} is stale: no sn_loader_stats"
+            return None
         _lib = lib
         return _lib
 
@@ -182,6 +212,15 @@ class NativeLoader:
     indefinitely (epochs wrap with a fresh deterministic shuffle). The
     full pipeline — shuffle, crop/mirror/mean, batch assembly — runs in
     native worker threads ahead of the consumer.
+
+    ``metrics`` is a :class:`~sparknet_tpu.data.pipeline.PipelineMetrics`
+    (registry source ``native_loader``) fed at each ``__next__`` from the
+    deltas of the library's counters (:meth:`stats`): ``produce`` is a
+    worker's time to build a batch, ``worker_wait`` its wait for room
+    (back-pressure), ``consumer_wait`` the caller's wait for its batch,
+    ``reorder_depth`` the batches it found ready.  The same deltas go to
+    the current timeline as ``feed.loader_blocked``, ``feed.copy_out``
+    and ``feed.produce`` (telemetry/timeline.py).
     """
 
     def __init__(
@@ -224,25 +263,74 @@ class NativeLoader:
         if not self._handle:
             raise ValueError("sn_loader_create failed (check batch <= n)")
         self.batches_per_epoch = n // batch_size
+        from ..data.pipeline import PipelineMetrics
+
+        self.metrics = PipelineMetrics(source_name="native_loader")
+        self._seen = dict.fromkeys(STATS, 0)
+        # one caller inside the library at a time: close() from the main
+        # thread waits for a staging thread's __next__ to return, and
+        # never frees the loader under it
+        self._lock = threading.RLock()
+
+    def stats(self) -> dict:
+        """The library's cumulative counters, by ``STATS``' names; the
+        last reading once closed."""
+        with self._lock:
+            if not self._handle:
+                return dict(self._seen)
+            out = (ctypes.c_int64 * len(STATS))()
+            self._lib.sn_loader_stats(self._handle, out, len(STATS))
+            return dict(zip(STATS, out))
+
+    def _account(self, started: Optional[float] = None) -> None:
+        """Feed ``metrics`` and the current timeline from what the
+        counters moved by since the last reading.  ``started`` is when
+        the ``__next__`` that just returned began: its wait lies at its
+        start, so a timeline made during the call takes only its part."""
+        now, before = self.stats(), self._seen
+        self._seen = now
+        delta = {k: now[k] - before[k] for k in STATS}
+        built = delta["batches_built"]
+        for _ in range(built):
+            self.metrics.record_batch(
+                self.batch_size, 1e-9 * delta["build_ns"] / built,
+                1e-9 * delta["put_wait_ns"] / built,
+            )
+        tl = _timeline.current()
+        if built:
+            tl.add("feed.produce", 1e-9 * delta["build_ns"], built)
+        if delta["batches_taken"]:  # one: the call that just returned
+            waited = 1e-9 * delta["get_wait_ns"]
+            copied = 1e-9 * delta["copy_ns"]
+            self.metrics.record_consumer_wait(waited)
+            self.metrics.reorder_depth.set(delta["depth_on_arrival"])
+            tl.add("feed.loader_blocked", waited, began=started)
+            # the copy lies at the call's end, which is about now
+            tl.add("feed.copy_out", copied, began=_timeline.clock() - copied)
 
     def __iter__(self):
         return self
 
     def __next__(self):
+        started = _timeline.clock()
         data = np.empty(self.shape, np.float32)
         labels = np.empty((self.batch_size,), np.int32)
-        rc = self._lib.sn_loader_next(
-            self._handle, data.ctypes.data_as(_f32p),
-            labels.ctypes.data_as(_i32p),
-        )
-        if rc != 0:
-            raise StopIteration
+        with self._lock:
+            rc = self._lib.sn_loader_next(
+                self._handle, data.ctypes.data_as(_f32p),
+                labels.ctypes.data_as(_i32p),
+            )
+            if rc != 0:
+                raise StopIteration
+            self._account(started)
         return {"data": data, "label": labels}
 
     def close(self) -> None:
-        if getattr(self, "_handle", None):
-            self._lib.sn_loader_destroy(self._handle)
-            self._handle = None
+        with self._lock:
+            if getattr(self, "_handle", None):
+                self._account()  # what the workers built since the last batch
+                self._lib.sn_loader_destroy(self._handle)
+                self._handle = None
 
     def __del__(self):  # pragma: no cover
         try:

@@ -252,7 +252,6 @@ class ParallelSolver(Solver):
             from ..telemetry import timeline as _ttl
 
             self.timeline = _ttl.Timeline(fence=True)
-            _ttl.set_current(self.timeline)
             self.timeline.start()
 
     # ------------------------------------------------------------------

@@ -257,8 +257,9 @@ class Solver:
         # per-iteration phase attribution (telemetry/timeline.py): the
         # apps swap in an enabled Timeline under --trace /
         # SPARKNET_TIMELINE=1; the default NULL costs one falsy test
-        # per phase boundary
-        self.timeline = _timeline.NULL
+        # per phase boundary.  Not through the setter: building a second
+        # solver must not take the current timeline from the first
+        self._timeline = _timeline.NULL
         # average_loss display smoothing; deque(maxlen) evicts itself
         self._loss_window = deque(maxlen=max(1, solver.average_loss))
         kw = step_compile_kw()
@@ -286,6 +287,18 @@ class Solver:
         ) not in ("", "0")
         self._fused_step: Optional[Callable] = None
         self._it_dev = None
+
+    @property
+    def timeline(self):
+        return self._timeline
+
+    @timeline.setter
+    def timeline(self, tl) -> None:
+        """Assigning a timeline also makes it the process's current one,
+        so call sites without a solver (``multihost.put_global``, the
+        feed's staging thread) report to the loop that is stepping."""
+        self._timeline = tl
+        _timeline.set_current(tl)
 
     def step(self, batches: Iterator[Dict[str, Any]], n: int = 1, log_fn=None):
         """Run ``n`` iterations (the reference's ``Solver::Step(n)``).

@@ -44,7 +44,9 @@ cross-host sync, or snapshot I/O?  This package is the one substrate:
   dashboard the serve server mounts on ``GET /dash``.
 
 Enable per run with ``--trace OUT.json`` on the apps / ``caffe train``
-(or ``SPARKNET_TRACE=OUT.json``); see docs/OBSERVABILITY.md.
+(or ``SPARKNET_TRACE=OUT.json``); see docs/OBSERVABILITY.md.  With
+``--profile-dir`` beside it the loop is not fenced and the profiler's
+device plane joins the same file as a ``device`` track.
 
 Everything here is stdlib-only: no jax import, so the supervisor and
 forked pipeline workers use it without touching a backend.
@@ -99,26 +101,35 @@ __all__ = [
 # finish_run can restore it (in-process reruns must not inherit a
 # stale trace path)
 _saved_trace_env: Optional[tuple] = None
+# --profile-dir of this run, for finish_run's merge of the device track
+_profile_dir: Optional[str] = None
 
 
-def install_for_training(solver, trace_path: Optional[str] = None):
+def install_for_training(
+    solver, trace_path: Optional[str] = None,
+    profile_dir: Optional[str] = None,
+):
     """App-side wiring, shared by the image apps, BertApp and the
     ``caffe`` CLI: resolve ``--trace``/``SPARKNET_TRACE``, enable the
     span tracer, and (when tracing or ``SPARKNET_TIMELINE=1``) attach
     an enabled :class:`~sparknet_tpu.telemetry.timeline.Timeline` to
-    the solver so its step loop attributes phases.  The path is
+    the solver so its step loop attributes phases.  With
+    ``profile_dir`` (``--profile-dir``) the timeline does not fence:
+    the device's time comes from the profiler's trace, and the loop
+    stays the user's.  The path is
     exported to ``SPARKNET_TRACE`` so supervised children and forked
     workers inherit it (restored by :func:`finish_run`).  Returns the
     resolved trace path (or None)."""
-    global _saved_trace_env
+    global _saved_trace_env, _profile_dir
+    _profile_dir = profile_dir or None
     path = trace_path or os.environ.get(trace.TRACE_ENV, "").strip() or None
     if path:
         _saved_trace_env = (os.environ.get(trace.TRACE_ENV),)
         os.environ[trace.TRACE_ENV] = path
         trace.enable(path)
     if path or os.environ.get("SPARKNET_TIMELINE", "") not in ("", "0"):
-        solver.timeline = timeline.Timeline()
-        timeline.set_current(solver.timeline)
+        # the setter makes it the process's current timeline too
+        solver.timeline = timeline.Timeline(fence=not profile_dir)
     # arm the crash flight recorder where a postmortem consumer exists
     # (supervised children, or SPARKNET_FLIGHT=1); disabled it stays
     # the allocation-free no-op
@@ -141,16 +152,67 @@ def training_loop(tl, emit=print):
         stop_flush()
 
 
+def _device_track(profile_dir: str, emit=print) -> list:
+    """The profiler's executions as Chrome events on the spans' clock,
+    and on ``emit`` the anchor's reading and the longest gaps between
+    executions of the step program with the phases that cover them.
+    Empty, with a line saying why, where the trace holds no device plane
+    or the anchor does not hold."""
+    from ..utils import profiling  # jax; only when both were asked for
+
+    anchor = profiling.last_anchor()
+    planes = profiling.device_modules(profile_dir)
+    if not planes or not anchor or anchor["log_dir"] != profile_dir:
+        emit(f"trace: no device plane under {profile_dir}; no device track")
+        return []
+    try:
+        # the one plane the anchor ran on: whether the other devices'
+        # planes share its clock is not known, so they get no track
+        plane, first = next(
+            (p, m) for p, m in sorted(planes.items())
+            if any(e[0].startswith(profiling.ANCHOR_PROGRAM) for e in m)
+        )
+        offset_ns, width_ns = trace.anchor_offset(
+            first, profiling.ANCHOR_PROGRAM,
+            anchor["before_ns"], anchor["after_ns"],
+        )
+        program = trace.step_program(first, but=profiling.ANCHOR_PROGRAM)
+    except (StopIteration, ValueError) as e:
+        emit(f"trace: the anchor does not hold ({e}); no device track")
+        return []
+    events = trace.device_track(first, offset_ns, label=f"device {plane}")
+    steps = [
+        (n, s + offset_ns, d) for n, s, d in first if n == program
+    ]
+    phases = [
+        (e["name"], 1000 * int(e["ts"]), int(1000 * e["dur"]))
+        for e in trace.events() if e.get("cat") == "timeline"
+    ]
+    beside = [p for p in phases if p[0].startswith(timeline.BACKGROUND_PREFIX)]
+    loop = [p for p in phases if not p[0].startswith(timeline.BACKGROUND_PREFIX)]
+    emit(
+        f"trace: device track of {len(steps)} executions of {program}; "
+        f"anchor offset {offset_ns} ns, bracket {width_ns} ns; longest gaps "
+        f"between executions:\n"
+        + trace.gap_table(trace.longest_gaps(steps, loop, beside))
+    )
+    return events
+
+
 def finish_run() -> None:
     """End-of-run hook (apps' ``finally``): write the merged Chrome
-    trace when this process owns one, then reset tracer + current
+    trace when this process owns one (with the device's track when
+    ``--profile-dir`` was on too), then reset tracer + current
     timeline (and the SPARKNET_TRACE export) so an in-process rerun
     (tests driving ``main()`` twice) starts clean.  Safe to call when
     telemetry was never enabled."""
-    global _saved_trace_env
+    global _saved_trace_env, _profile_dir
+    profile_dir, _profile_dir = _profile_dir, None
     if trace.enabled():
         try:
-            trace.write()
+            trace.write(
+                extra=_device_track(profile_dir) if profile_dir else ()
+            )
         finally:
             errs = trace.sidecar_errors()
             if errs:

@@ -13,8 +13,7 @@ Contracts:
 
 - **Near-zero when disabled.**  ``span(...)`` returns one shared no-op
   context manager when tracing is off — no allocation, no clock read;
-  the enabled check is a module bool.  ``@traced`` functions test the
-  same bool per call.
+  the enabled check is a module bool.
 - **Thread-aware.**  Events carry ``tid`` (`threading.get_ident`) and
   the export emits thread-name metadata, so batcher/prefetch/handler
   threads render as separate tracks.
@@ -33,12 +32,19 @@ Contracts:
 Timestamps are wall-clock microseconds (``time.time_ns`` at span
 entry) so spans from different processes land on one timeline;
 durations come from ``perf_counter`` deltas.
+
+**The device's track.**  With ``--profile-dir`` beside ``--trace`` the
+profiler's device plane joins the same file: :func:`anchor_offset`
+finds what to add to the plane's clock from the anchor that
+``utils/profiling.trace`` ran, :func:`device_track` turns the
+executions into events, and :func:`longest_gaps` says which phases of
+the loop and of the feed cover the device's longest idle stretches.
+Plain functions over ``(name, start_ns, duration_ns)`` tuples.
 """
 
 from __future__ import annotations
 
 import atexit
-import functools
 import glob as _glob
 import json
 import os
@@ -46,7 +52,8 @@ import sys
 import threading
 import time
 from collections import deque
-from typing import Dict, Optional
+from collections import Counter
+from typing import Dict, List, Optional, Sequence, Tuple
 
 OWNER_PID_ENV = "SPARKNET_TRACE_OWNER_PID"
 TRACE_ENV = "SPARKNET_TRACE"
@@ -126,26 +133,6 @@ def span(name: str, cat: str = "", **args):
     if not _enabled:
         return _NULL
     return _Span(name, cat, args)
-
-
-def traced(name: Optional[str] = None, cat: str = ""):
-    """Decorator form: ``@traced()`` wraps the call in a span named
-    after the function (override with ``name``).  The disabled path is
-    one bool test + the direct call."""
-
-    def deco(fn):
-        label = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            if not _enabled:
-                return fn(*a, **kw)
-            with _Span(label, cat, None):
-                return fn(*a, **kw)
-
-        return wrapper
-
-    return deco
 
 
 def record(
@@ -319,18 +306,21 @@ def flush_sidecar() -> Optional[str]:
     return out
 
 
-def write(path: Optional[str] = None) -> Optional[str]:
+def write(
+    path: Optional[str] = None, extra: Sequence[dict] = ()
+) -> Optional[str]:
     """Owner-side export: merge this process's events with every
-    ``{path}.part-*.json`` sidecar (consumed on merge) into the final
-    Chrome trace document, sorted by timestamp.  Returns the written
-    path, or None when there is nothing to write."""
+    ``{path}.part-*.json`` sidecar (consumed on merge) and the ``extra``
+    events (the device's track) into the final Chrome trace document,
+    sorted by timestamp.  Returns the written path, or None when there
+    is nothing to write."""
     path = path or _path
     if not path:
         return None
     if _role == "sidecar":
         return flush_sidecar()
     global _sidecar_errors
-    evts = _meta_events(events()) + events()
+    evts = _meta_events(events()) + events() + list(extra)
     for part in sorted(_glob.glob(f"{path}.part-*.json")):
         try:
             with open(part) as fh:
@@ -353,6 +343,118 @@ def write(path: Optional[str] = None) -> Optional[str]:
         path, doc, site="flight", indent=None, fsync=False
     )
     return path
+
+
+# ------------------------------------------------------ the device's track
+Event = Tuple[str, int, int]  # (name, start_ns, duration_ns)
+DEVICE_TID = 1  # thread idents are addresses: no thread has it
+
+
+def anchor_offset(
+    modules: Sequence[Event], program: str, before_ns: int, after_ns: int
+) -> Tuple[int, int]:
+    """``(offset_ns, width_ns)``: what to add to the device plane's
+    times to land them on the wall clock, and how sure that is.  The
+    first execution of ``program`` (the anchor of
+    ``utils/profiling.trace``) ran between the ``time.time_ns()``
+    readings ``before_ns`` and ``after_ns``: the offset centres its
+    event in that bracket, and is right to half the bracket's width
+    less the event's.  An event longer than the bracket cannot have run
+    inside it: ValueError."""
+    runs = sorted((s, d) for name, s, d in modules if name.startswith(program))
+    if not runs:
+        raise ValueError(f"the device plane holds no execution of {program}")
+    start, dur = runs[0]
+    width = after_ns - before_ns
+    if dur > width:
+        raise ValueError(
+            f"{program} ran {dur} ns on the device, longer than the "
+            f"{width} ns between the readings around it"
+        )
+    return (before_ns + after_ns) // 2 - (start + dur // 2), width
+
+
+def step_program(modules: Sequence[Event], but: str = "") -> str:
+    """The program that ran most often (the training step), leaving out
+    those whose name starts with ``but`` (the anchor)."""
+    names = Counter(
+        name for name, _s, _d in modules if not (but and name.startswith(but))
+    )
+    if not names:
+        raise ValueError("the device plane holds no execution of a program")
+    return names.most_common(1)[0][0]
+
+
+def device_track(
+    modules: Sequence[Event], offset_ns: int, label: str = "device"
+) -> List[dict]:
+    """Chrome events for one device's executions, shifted onto the wall
+    clock, on a track of their own under this process."""
+    pid, tid = os.getpid(), DEVICE_TID
+    track = [{
+        "name": "thread_name", "ph": "M", "pid": pid, "tid": tid,
+        "args": {"name": label},
+    }]
+    for name, start, dur in modules:
+        track.append({
+            "name": name, "ph": "X", "ts": (start + offset_ns) / 1e3,
+            "dur": dur / 1e3, "pid": pid, "tid": tid, "cat": "device",
+        })
+    return track
+
+
+def _most_of(spans: Sequence[Event], lo: int, hi: int) -> Optional[tuple]:
+    """``(name, share)`` of the span name that covers most of
+    ``[lo, hi]``; None where none touches it."""
+    cover: Dict[str, int] = {}
+    for name, start, dur in spans:
+        part = min(start + dur, hi) - max(start, lo)
+        if part > 0:
+            cover[name] = cover.get(name, 0) + part
+    if not cover:
+        return None
+    name = max(cover, key=cover.get)
+    return name, cover[name] / (hi - lo)
+
+
+def longest_gaps(
+    executions: Sequence[Event], loop: Sequence[Event],
+    beside: Sequence[Event], n: int = 5,
+) -> List[dict]:
+    """The ``n`` longest stretches between successive ``executions`` (the
+    device idle between two steps), longest first, each with the ``loop``
+    phase and the ``beside`` (``feed.*``) phase that cover most of it.
+    All three on one clock, in ns."""
+    runs = sorted((s, s + d) for _name, s, d in executions)
+    gaps = sorted(
+        (
+            (nxt[0] - cur[1], i, cur[1], nxt[0])
+            for i, (cur, nxt) in enumerate(zip(runs, runs[1:]))
+            if nxt[0] > cur[1]
+        ),
+        reverse=True,
+    )[:n]
+    return [
+        {
+            "after": i, "gap_ns": gap,
+            "loop": _most_of(loop, lo, hi), "beside": _most_of(beside, lo, hi),
+        }
+        for gap, i, lo, hi in gaps
+    ]
+
+
+def gap_table(gaps: Sequence[dict]) -> str:
+    """:func:`longest_gaps` as the lines the apps print."""
+    said = lambda m: f"{m[0]} {m[1]:.0%}" if m else "-"
+    lines = [
+        f"{'gap_ms':>9} {'after':>6}  {'loop phase':<24} {'beside the loop':<24}"
+    ]
+    for g in gaps:
+        lines.append(
+            f"{g['gap_ns'] / 1e6:>9.2f} {'#' + str(g['after']):>6}  "
+            f"{said(g['loop']):<24} {said(g['beside']):<24}"
+        )
+    return "\n".join(lines)
 
 
 def _atexit_write() -> None:

@@ -5,8 +5,11 @@ lines; on TPU the equivalents are XLA's profiler (op-level timeline in
 TensorBoard format) and step-level throughput/MFU counters, both
 exposed here:
 
-- :func:`trace` — context manager around ``jax.profiler.trace``; view
-  the dump with TensorBoard's profile plugin or xprof.
+- :func:`trace` — context manager around ``jax.profiler.trace``,
+  device only, with an *anchor* that ties the device plane's clock to
+  the wall clock of the program's own spans; view the dump with
+  TensorBoard's profile plugin or xprof, or merged into ``--trace``'s
+  Chrome JSON (``telemetry.finish_run``).
 - :class:`StepTimer` — windowed step-time / items-per-second / MFU
   meter for app training loops (items = images or tokens).
 - :func:`compiled_flops` — actual per-execution FLOPs of a lowered
@@ -21,8 +24,10 @@ per-step phase attribution, Prometheus export — lives in
 from __future__ import annotations
 
 import contextlib
+import glob
+import os
 import time
-from typing import Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 
@@ -82,14 +87,83 @@ def compiled_flops(jitted, *args, **kwargs) -> Optional[float]:
     return cost_numbers(compiled)[0]
 
 
+def sparknet_anchor(x):
+    return x + 1
+
+
+ANCHOR_PROGRAM = "jit_sparknet_anchor"  # the anchor's name on XLA Modules
+_anchor: Optional[dict] = None
+
+
+def last_anchor() -> Optional[dict]:
+    """``{"log_dir", "before_ns", "after_ns"}`` of the newest
+    :func:`trace`: the ``time.time_ns()`` readings that bracket the
+    anchor program's one execution under the profiler."""
+    return _anchor
+
+
 @contextlib.contextmanager
 def trace(log_dir: Optional[str]):
-    """``with trace("/tmp/prof"):`` — no-op when log_dir is falsy."""
+    """``with trace("/tmp/prof"):`` — no-op when log_dir is falsy.
+
+    Only the device is traced (``host_tracer_level`` and
+    ``python_tracer_level`` 0).  With the host tracer at its default a
+    633 MB batch takes 2.0 s to reach the device instead of 0.11 s and
+    ``stop_trace`` grows past 25 GB (PERF.md section 3): the traced loop
+    was not the user's.  What the host does comes from the program's own
+    spans (``--trace``).
+
+    So that the two can be laid on one clock, the first thing to run
+    under the profiler is the *anchor*: a tiny jitted program, compiled
+    beforehand, dispatched and waited for between two ``time.time_ns()``
+    readings.  Its ``XLA Modules`` event lies inside that bracket, which
+    fixes the offset between the device plane's clock and the wall clock
+    to the bracket's width (:func:`sparknet_tpu.telemetry.trace.
+    anchor_offset`)."""
+    global _anchor
     if not log_dir:
         yield
         return
-    with jax.profiler.trace(log_dir):
+    import jax.numpy as jnp
+
+    probe, x = jax.jit(sparknet_anchor), jnp.zeros((8, 128), jnp.float32)
+    jax.block_until_ready(probe(x))  # compiled before the profiler starts
+    device_only = jax.profiler.ProfileOptions()
+    device_only.host_tracer_level = 0
+    device_only.python_tracer_level = 0
+    with jax.profiler.trace(log_dir, profiler_options=device_only):
+        before_ns = time.time_ns()
+        jax.block_until_ready(probe(x))
+        _anchor = {
+            "log_dir": log_dir, "before_ns": before_ns,
+            "after_ns": time.time_ns(),
+        }
         yield
+
+
+def device_modules(log_dir: str) -> Dict[str, List[Tuple[str, int, int]]]:
+    """``{device plane: [(name, start_ns, duration_ns), ...]}``: the
+    ``XLA Modules`` line (one event per execution of a program) of every
+    device plane in the newest ``.xplane.pb`` under ``log_dir``.  Empty
+    where no device was traced (a CPU run)."""
+    from jax.profiler import ProfileData
+
+    found = glob.glob(
+        os.path.join(log_dir, "plugins", "profile", "*", "*.xplane.pb")
+    )
+    if not found:
+        return {}
+    out = {}
+    for plane in ProfileData.from_file(max(found, key=os.path.getmtime)).planes:
+        if not plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            if line.name == "XLA Modules":
+                out[plane.name] = [
+                    (ev.name, int(ev.start_ns), int(ev.duration_ns))
+                    for ev in line.events
+                ]
+    return out
 
 
 class StepTimer:

@@ -104,3 +104,40 @@ def assert_no_pipeline_leaks(tmp_path_factory):
         f"cleaning up its tmp, or a torn tee shard was not "
         f"quarantined): {stale[:20]}"
     )
+
+
+TEST_ALARM_S = 300.0
+
+
+@pytest.fixture(autouse=True)
+def _per_test_alarm(request):
+    """A test that hangs costs itself, not the run's clock: after
+    ``TEST_ALARM_S`` seconds a SIGALRM fails the one test, by name.
+    (``pytest-timeout`` is not installed.)  Main thread only — a signal
+    handler runs there, and so does every test body; it interrupts a
+    blocking ``wait``/``join``/``communicate``, which is where a
+    subprocess test hangs."""
+    import signal
+    import threading
+
+    if (
+        not hasattr(signal, "setitimer")
+        or threading.current_thread() is not threading.main_thread()
+    ):
+        yield
+        return
+
+    def fail(_signum, _frame):
+        pytest.fail(
+            f"{request.node.nodeid} still running after {TEST_ALARM_S:.0f} s "
+            f"(tests/conftest.py per-test alarm)",
+            pytrace=True,
+        )
+
+    before = signal.signal(signal.SIGALRM, fail)
+    signal.setitimer(signal.ITIMER_REAL, TEST_ALARM_S)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, before)

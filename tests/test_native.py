@@ -146,3 +146,145 @@ def test_crop_larger_than_image_raises():
         native.transform_batch(images, crop=16)
     with pytest.raises(ValueError):
         native.NativeLoader(images, np.zeros(2, np.int32), 1, crop=16)
+
+
+# ------------------------------------------------- the loader's counters
+
+def _counted_loader(threads, side=48, **kw):
+    rng = np.random.default_rng(9)
+    images = rng.integers(0, 256, (64, side, side, 3)).astype(np.uint8)
+    labels = np.arange(64, dtype=np.int32)
+    return native.NativeLoader(
+        images, labels, batch_size=16, crop=side - 8, train=True, mirror=True,
+        seed=2, num_threads=threads, **kw,
+    )
+
+
+def test_loader_stats_are_monotone_and_count_the_batches_taken():
+    ld = _counted_loader(2)
+    try:
+        assert set(ld.stats()) == set(native.STATS)
+        before = ld.stats()
+        for taken in range(1, 13):
+            next(ld)
+            now = ld.stats()
+            assert all(now[k] >= before[k] for k in native.STATS), (before, now)
+            assert now["batches_taken"] == taken
+            # nothing is handed out before it is built, and the workers
+            # run at most the window (4) plus one batch each ahead
+            assert taken <= now["batches_built"] <= taken + 4 + 2
+            before = now
+        assert before["build_ns"] > 0 and before["copy_ns"] > 0
+    finally:
+        ld.close()
+    assert ld.stats() == ld.stats()  # the last reading, once closed
+    assert ld.stats()["batches_taken"] == 12
+
+
+def test_a_slow_consumer_reads_as_worker_backpressure():
+    import time
+
+    ld = _counted_loader(2, queue_cap=2)
+    try:
+        next(ld)
+        time.sleep(0.3)  # the window fills; both workers park on it
+        for _ in range(3):
+            next(ld)
+        stats = ld.stats()
+    finally:
+        ld.close()
+    # two workers parked for most of the sleep, the consumer for none
+    assert stats["put_wait_ns"] > 0.3e9, stats
+    assert stats["get_wait_ns"] < 0.1e9, stats
+    assert stats["depth_on_arrival"] >= 2  # it found batches waiting,
+    # which the input pipeline: line shows as the queue's depth
+    assert ld.metrics.snapshot()["reorder_depth"]["max"] >= 1
+    assert ld.metrics.snapshot()["worker_wait"]["count"] >= 4
+
+
+def test_one_thread_and_a_fast_consumer_read_as_loader_blocked():
+    from sparknet_tpu.telemetry import timeline
+
+    tl = timeline.Timeline(fence=False)
+    timeline.set_current(tl)
+    try:
+        ld = _counted_loader(1, side=200)  # a batch takes milliseconds
+        try:
+            for _ in range(24):
+                next(ld)
+            stats = ld.stats()
+        finally:
+            ld.close()
+    finally:
+        timeline.set_current(None)
+    # the consumer outruns the one worker: it waits for every batch about
+    # as long as the batch takes to build, and the worker never waits
+    assert stats["get_wait_ns"] > 0.5 * stats["build_ns"], stats
+    assert stats["put_wait_ns"] < 0.2 * stats["build_ns"], stats
+    snap = ld.metrics.snapshot()
+    assert snap["consumer_wait"]["count"] == 24
+    assert snap["produce"]["count"] == stats["batches_built"]
+    # the same deltas reached the current timeline, as background rows
+    seconds = tl.phase_seconds()
+    assert seconds["feed.loader_blocked"] == pytest.approx(
+        1e-9 * stats["get_wait_ns"], rel=1e-6
+    )
+    assert seconds["feed.produce"] == pytest.approx(
+        1e-9 * stats["build_ns"], rel=1e-6
+    )
+    assert tl.snapshot()["background"]["feed.produce"]["count"] == (
+        stats["batches_built"]
+    )
+    # and the copy out of the queue, the other part of a next() of it
+    assert seconds["feed.copy_out"] == pytest.approx(
+        1e-9 * stats["copy_ns"], rel=1e-6
+    )
+    assert tl.snapshot()["background"]["feed.copy_out"]["count"] == 24
+    assert tl.snapshot()["phases"] == {}
+
+
+def test_loader_metrics_are_a_registry_source_and_survive_close():
+    import json
+
+    from sparknet_tpu.data.pipeline import PipelineMetrics
+    from sparknet_tpu.data.prefetch import maybe_prefetch
+    from sparknet_tpu.telemetry import REGISTRY
+
+    ld = _counted_loader(2)
+    assert isinstance(ld.metrics, PipelineMetrics)
+    assert REGISTRY.sources()["native_loader"] is ld.metrics
+    # maybe_prefetch hands the staging thread the loader's own metrics
+    import argparse
+
+    feed = maybe_prefetch(ld, argparse.Namespace(prefetch=2), "none")
+    for _ in range(5):
+        next(feed)
+    feed.close()
+    ld.close()
+    line = json.loads(ld.metrics.json_line())  # the apps' input pipeline: line
+    assert line["rows"] == 16 * line["batches"] and line["batches"] >= 5
+    assert line["prefetch"]["hits"] + line["prefetch"]["misses"] == 5
+    for block in ("produce", "worker_wait", "consumer_wait"):
+        assert line[block]["count"] > 0, (block, line)
+    assert line["prefetch"]["wait"]["count"] == 5
+    with pytest.raises(StopIteration):
+        next(ld)  # closed
+
+
+def test_a_library_without_the_newest_symbol_is_stale(tmp_path, monkeypatch):
+    """make goes by mtimes; a copied tree's say nothing.  A library on disk
+    that lacks sn_loader_stats is rebuilt (make -B), never loaded."""
+    assert not native._is_stale()  # the one this process loaded
+    old = tmp_path / "libsparknet_data.so"
+    with open(native._LIB_PATH, "rb") as fh:
+        old.write_bytes(fh.read().replace(b"sn_loader_stats", b"sn_loader_stat_"))
+    monkeypatch.setattr(native, "_LIB_PATH", str(old))
+    assert native._is_stale()
+    monkeypatch.setattr(native, "_LIB_PATH", str(tmp_path / "absent.so"))
+    assert not native._is_stale()  # not there: make builds it
+    ran = []
+    monkeypatch.setattr(native, "_LIB_PATH", str(old))
+    monkeypatch.setattr(
+        native.subprocess, "run", lambda argv, **kw: ran.append(argv)
+    )
+    assert native._build() is None and ran[0][-1] == "-B"
