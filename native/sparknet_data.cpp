@@ -11,8 +11,20 @@
 //   sn_cifar_decode       — CIFAR binary records -> NHWC uint8 + labels
 //   sn_transform_batch    — uint8 NHWC -> cropped/mirrored/mean-sub f32
 //   sn_loader_create/next/destroy — threaded prefetching batch loader
+//   sn_buffer_release     — give back the batch memory sn_loader_next lent
 //   sn_loader_stats       — the loader's cumulative counters (always on)
 //   sn_version            — ABI version stamp
+//
+// Who owns a batch's memory: the loader's buffer pool allocates a batch
+// buffer once and it then cycles — a worker writes every element of it in
+// place, the in-order queue holds it, sn_loader_next lends the buffer
+// itself to the caller (nothing is copied), and sn_buffer_release, which
+// the caller makes when its last reference to that memory is gone, puts it
+// back for a worker to rewrite.  A buffer is never rewritten while lent; a
+// worker that finds none free allocates one more, so the set grows to what
+// the caller holds plus what the loader works ahead and stays there.  The
+// pool outlives the loader while buffers are lent: sn_loader_destroy never
+// frees memory under a reader.
 //
 // Determinism: every random decision derives from splitmix64(seed,
 // epoch, index) counters, never from thread scheduling — a batch stream
@@ -23,15 +35,17 @@
 #include <chrono>
 #include <condition_variable>
 #include <cstdint>
+#include <cstdlib>
 #include <cstring>
 #include <deque>
 #include <mutex>
+#include <new>
 #include <thread>
 #include <vector>
 
 extern "C" {
 
-int sn_version() { return 2; }
+int sn_version() { return 3; }
 
 static inline int64_t now_ns() {
   return std::chrono::duration_cast<std::chrono::nanoseconds>(
@@ -136,6 +150,63 @@ void sn_transform_batch(const uint8_t* in, int n, int h, int w, int c,
 }
 
 // ---------------------------------------------------------------------------
+// Batch buffers: allocated once (first touch of fresh pages costs twenty
+// times the write itself at 633 MB), then reused.  Shared by the loader and
+// by every buffer it has lent: whoever lets go last deletes the pool.
+// ---------------------------------------------------------------------------
+struct BufferPool {
+  size_t bytes;
+  std::mutex mu;
+  std::vector<float*> free;  // newest last: the working set stays small
+  int64_t out = 0;           // with a worker, in the queue, or lent
+  bool closed = false;       // the loader is gone: what comes back is freed
+
+  // A free buffer, or a new one where none is (*fresh). Not zeroed: the
+  // taker writes every element.
+  float* take(bool* fresh) {
+    {
+      std::lock_guard<std::mutex> lk(mu);
+      ++out;
+      *fresh = free.empty();
+      if (!*fresh) {
+        float* buf = free.back();
+        free.pop_back();
+        return buf;
+      }
+    }
+    void* buf = nullptr;
+    // 64: what XLA:CPU asks of host memory it aliases instead of copying
+    if (posix_memalign(&buf, 64, bytes ? bytes : 64) != 0)
+      throw std::bad_alloc();
+    return (float*)buf;
+  }
+
+  void give_back(float* buf) {
+    bool last;
+    {
+      std::lock_guard<std::mutex> lk(mu);
+      --out;
+      if (closed) std::free(buf); else free.push_back(buf);
+      last = closed && out == 0;
+    }
+    if (last) delete this;
+  }
+
+  // The loader's own buffers are back; those still lent come back later.
+  void close() {
+    bool last;
+    {
+      std::lock_guard<std::mutex> lk(mu);
+      closed = true;
+      for (float* buf : free) std::free(buf);
+      free.clear();
+      last = out == 0;
+    }
+    if (last) delete this;
+  }
+};
+
+// ---------------------------------------------------------------------------
 // Prefetching loader: owns a copy of the dataset; worker threads build
 // shuffled, transformed batches ahead of the consumer into a bounded
 // queue. Batch order and contents are functions of (seed, epoch, batch
@@ -154,9 +225,10 @@ struct Loader {
   std::atomic<int64_t> next_batch{0};
   int64_t batches_per_epoch;
 
+  BufferPool* pool;
   struct Ready {
     int64_t index;
-    std::vector<float> data;
+    float* data;  // one of pool's buffers
     std::vector<int32_t> labels;
   };
   std::mutex mu;
@@ -174,8 +246,9 @@ struct Loader {
     PUT_WAIT_NS,       // worker time blocked on a full window (back-pressure)
     BATCHES_TAKEN,     // batches handed to the consumer
     GET_WAIT_NS,       // consumer time until its in-order batch was ready
-    COPY_NS,           // consumer time copying the batch out, after the unlock
+    COPY_NS,           // consumer time in the hand-over (the labels' copy), unlocked
     DEPTH_ON_ARRIVAL,  // sum of the queue's depth as each consumer call arrived
+    BUFFERS_ALLOCATED, // batch buffers allocated; every other batch reused one
     N_STATS
   };
   std::atomic<int64_t> stats[N_STATS] = {};
@@ -208,8 +281,11 @@ struct Loader {
     int64_t epoch = bidx / batches_per_epoch;
     int64_t off = (bidx % batches_per_epoch) * batch;
     out.index = bidx;
-    out.data.resize((int64_t)batch * ch() * cw() * c);
+    bool fresh;
+    out.data = pool->take(&fresh);
+    if (fresh) stats[BUFFERS_ALLOCATED] += 1;
     out.labels.resize(batch);
+    // every element of the buffer is written below: batch x ch x cw x c
     for (int j = 0; j < batch; ++j) {
       int64_t src;
       perm_index(epoch, off + j, &src);
@@ -219,7 +295,7 @@ struct Loader {
           mirror_on, rng_at(seed, (uint64_t)epoch + 17, (uint64_t)(off + j)),
           mean_image.empty() ? nullptr : mean_image.data(),
           mean_channel.empty() ? nullptr : mean_channel.data(), scale,
-          out.data.data() + (int64_t)j * ch() * cw() * c);
+          out.data + (int64_t)j * ch() * cw() * c);
     }
   }
 
@@ -241,7 +317,10 @@ struct Loader {
         return stop.load() || bidx < next_out + queue_cap;
       });
       stats[PUT_WAIT_NS] += now_ns() - t1;
-      if (stop.load()) return;
+      if (stop.load()) {
+        pool->give_back(r.data);
+        return;
+      }
       queue.push_back(std::move(r));
       cv_get.notify_all();
     }
@@ -262,6 +341,8 @@ void* sn_loader_create(const uint8_t* images, const int32_t* labels, int n,
   L->batch = batch; L->crop = crop; L->train = train;
   L->mirror_on = mirror_on; L->scale = scale; L->seed = seed;
   L->queue_cap = queue_cap > 0 ? queue_cap : 4;
+  L->pool = new BufferPool();
+  L->pool->bytes = sizeof(float) * (size_t)batch * L->ch() * L->cw() * c;
   if (mean_image)
     L->mean_image.assign(mean_image, mean_image + (int64_t)h * w * c);
   if (mean_channel) L->mean_channel.assign(mean_channel, mean_channel + c);
@@ -273,7 +354,11 @@ void* sn_loader_create(const uint8_t* images, const int32_t* labels, int n,
 }
 
 // Blocks until the next in-order batch is ready; returns 0 on success.
-int sn_loader_next(void* handle, float* out_data, int32_t* out_labels) {
+// Lends the batch's own buffer: *out_data is valid, and is not rewritten,
+// until sn_buffer_release(*out_pool, *out_data), which the caller makes
+// exactly once, from any thread, before or after sn_loader_destroy.
+int sn_loader_next(void* handle, float** out_data, void** out_pool,
+                   int32_t* out_labels) {
   Loader* L = (Loader*)handle;
   if (!L) return -1;
   int64_t t0 = now_ns();
@@ -288,7 +373,8 @@ int sn_loader_next(void* handle, float* out_data, int32_t* out_labels) {
         lk.unlock();
         L->cv_put.notify_all();
         int64_t t1 = now_ns();
-        std::memcpy(out_data, r.data.data(), r.data.size() * sizeof(float));
+        *out_data = r.data;
+        *out_pool = (void*)L->pool;
         std::memcpy(out_labels, r.labels.data(),
                     r.labels.size() * sizeof(int32_t));
         L->stats[Loader::GET_WAIT_NS] += t1 - t0;
@@ -300,6 +386,10 @@ int sn_loader_next(void* handle, float* out_data, int32_t* out_labels) {
     if (L->stop.load()) return -2;
     L->cv_get.wait(lk);
   }
+}
+
+void sn_buffer_release(void* pool, float* data) {
+  if (pool && data) ((BufferPool*)pool)->give_back(data);
 }
 
 // Copies the first min(n, N_STATS) cumulative counters (Loader's enum
@@ -321,6 +411,8 @@ void sn_loader_destroy(void* handle) {
   L->cv_put.notify_all();
   L->cv_get.notify_all();
   for (auto& t : L->workers) t.join();
+  for (auto& r : L->queue) L->pool->give_back(r.data);
+  L->pool->close();
   delete L;
 }
 

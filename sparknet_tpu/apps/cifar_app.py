@@ -166,7 +166,8 @@ def make_native_feed(
 ):
     """Feed served by the C++ prefetching loader (sparknet_tpu.native):
     shuffle + crop/mirror/mean + batch assembly in native worker threads,
-    Python only memcpys ready batches. Falls back to :func:`make_feed`
+    Python only wraps the ready batch's buffer (lent until the last
+    reference to it dies, never copied). Falls back to :func:`make_feed`
     (which honours ``workers`` — the multiprocess python pipeline) when
     the library can't be built, or when the dataset won't fit the
     loader's in-RAM cache (it materialises every partition —

@@ -152,6 +152,7 @@ class PipelineMetrics:
         self.worker_respawns = 0
         self.prefetch_hits = 0
         self.prefetch_misses = 0
+        self.buffers_allocated = None  # a feed that reuses batch buffers sets it
         self.produce = LatencyHistogram()
         self.worker_wait = LatencyHistogram()
         self.consumer_wait = LatencyHistogram()
@@ -180,6 +181,12 @@ class PipelineMetrics:
         with self._lock:
             self.consumer_wait.observe(seconds)
 
+    def record_buffers(self, allocated: int) -> None:
+        """The batch buffers the feed has allocated so far (the native
+        loader's pool); every other batch was written into a used one."""
+        with self._lock:
+            self.buffers_allocated = allocated
+
     def record_respawn(self) -> None:
         with self._lock:
             self.worker_respawns += 1
@@ -198,7 +205,7 @@ class PipelineMetrics:
     def snapshot(self) -> dict:
         with self._lock:
             dt = max(time.perf_counter() - self._t0, 1e-9)
-            return {
+            snap = {
                 "uptime_s": round(dt, 3),
                 "batches": self.batches,
                 "rows": self.rows,
@@ -228,6 +235,13 @@ class PipelineMetrics:
                     ).snapshot(),
                 },
             }
+            if self.buffers_allocated is not None:
+                reused = max(self.batches - self.buffers_allocated, 0)
+                snap["buffers"] = {
+                    "allocated": self.buffers_allocated,
+                    "reused_pct": round(100.0 * reused / max(self.batches, 1), 2),
+                }
+            return snap
 
     def json_line(self) -> str:
         import json

@@ -14,6 +14,7 @@ import ctypes
 import os
 import subprocess
 import threading
+import weakref
 from typing import Optional, Tuple
 
 import numpy as np
@@ -36,11 +37,11 @@ _u8p = ctypes.POINTER(ctypes.c_uint8)
 
 # the newest entry point: a library on disk without it predates this
 # source whatever its mtime says
-_NEWEST_SYMBOL = b"sn_loader_stats"
+_NEWEST_SYMBOL = b"sn_buffer_release"
 # sn_loader_stats' order (Loader's enum in sparknet_data.cpp)
 STATS = (
     "batches_built", "build_ns", "put_wait_ns", "batches_taken",
-    "get_wait_ns", "copy_ns", "depth_on_arrival",
+    "get_wait_ns", "copy_ns", "depth_on_arrival", "buffers_allocated",
 )
 
 
@@ -101,15 +102,20 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.c_int, ctypes.c_int,
         ]
         lib.sn_loader_next.restype = ctypes.c_int
-        lib.sn_loader_next.argtypes = [ctypes.c_void_p, _f32p, _i32p]
+        lib.sn_loader_next.argtypes = [
+            ctypes.c_void_p, ctypes.POINTER(ctypes.c_void_p),
+            ctypes.POINTER(ctypes.c_void_p), _i32p,
+        ]
         lib.sn_loader_destroy.argtypes = [ctypes.c_void_p]
         try:
             lib.sn_loader_stats.restype = ctypes.c_int
             lib.sn_loader_stats.argtypes = [
                 ctypes.c_void_p, _i64p, ctypes.c_int,
             ]
-        except AttributeError:
-            _why_not = f"{_LIB_PATH} is stale: no sn_loader_stats"
+            lib.sn_buffer_release.restype = None
+            lib.sn_buffer_release.argtypes = [ctypes.c_void_p, ctypes.c_void_p]
+        except AttributeError as e:
+            _why_not = f"{_LIB_PATH} is stale: {e}"
             return None
         _lib = lib
         return _lib
@@ -205,6 +211,20 @@ def transform_batch(
     return out
 
 
+class _Lent:
+    """One batch buffer of the library's pool, lent by ``sn_loader_next``:
+    the ``.base`` of the array made over it, and so behind every view of
+    that array.  Its finalizer gives the buffer back."""
+
+    __slots__ = ("__array_interface__", "__weakref__")
+
+    def __init__(self, address: int, shape: Tuple[int, ...]):
+        self.__array_interface__ = {
+            "version": 3, "typestr": "<f4", "shape": shape,
+            "data": (address, False),
+        }
+
+
 class NativeLoader:
     """Threaded prefetching batch loader over an in-memory dataset.
 
@@ -212,6 +232,18 @@ class NativeLoader:
     indefinitely (epochs wrap with a fresh deterministic shuffle). The
     full pipeline — shuffle, crop/mirror/mean, batch assembly — runs in
     native worker threads ahead of the consumer.
+
+    **A batch's memory is yours while you hold it.**  ``data`` is not a
+    copy: it is the buffer a worker wrote the batch into, lent by the
+    library's pool.  It goes back, to be rewritten, when the last
+    reference to it dies — the array, any view of it, or whatever either
+    was handed to (``jax.device_put`` keeps the host array until its
+    transfer ends, and for the life of the jax array where the CPU backend
+    aliases it).  Nothing has to be released by hand, and ``close()``
+    frees no batch that is still held.  Hold batches and the loader
+    allocates more buffers (``buffers_allocated`` of :meth:`stats`); the
+    set grows to what is held plus the window the workers run ahead, and
+    is reused from then on.
 
     ``metrics`` is a :class:`~sparknet_tpu.data.pipeline.PipelineMetrics`
     (registry source ``native_loader``) fed at each ``__next__`` from the
@@ -296,6 +328,7 @@ class NativeLoader:
                 self.batch_size, 1e-9 * delta["build_ns"] / built,
                 1e-9 * delta["put_wait_ns"] / built,
             )
+        self.metrics.record_buffers(now["buffers_allocated"])
         tl = _timeline.current()
         if built:
             tl.add("feed.produce", 1e-9 * delta["build_ns"], built)
@@ -313,17 +346,22 @@ class NativeLoader:
 
     def __next__(self):
         started = _timeline.clock()
-        data = np.empty(self.shape, np.float32)
         labels = np.empty((self.batch_size,), np.int32)
+        data, pool = ctypes.c_void_p(), ctypes.c_void_p()
         with self._lock:
             rc = self._lib.sn_loader_next(
-                self._handle, data.ctypes.data_as(_f32p),
+                self._handle, ctypes.byref(data), ctypes.byref(pool),
                 labels.ctypes.data_as(_i32p),
             )
             if rc != 0:
                 raise StopIteration
+            lent = _Lent(data.value, self.shape)
+            # not at exit: a thread may still read the memory then
+            weakref.finalize(
+                lent, self._lib.sn_buffer_release, pool.value, data.value
+            ).atexit = False
             self._account(started)
-        return {"data": data, "label": labels}
+        return {"data": np.asarray(lent), "label": labels}
 
     def close(self) -> None:
         with self._lock:

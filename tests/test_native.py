@@ -273,11 +273,12 @@ def test_loader_metrics_are_a_registry_source_and_survive_close():
 
 def test_a_library_without_the_newest_symbol_is_stale(tmp_path, monkeypatch):
     """make goes by mtimes; a copied tree's say nothing.  A library on disk
-    that lacks sn_loader_stats is rebuilt (make -B), never loaded."""
+    that lacks the newest entry point is rebuilt (make -B), never loaded."""
     assert not native._is_stale()  # the one this process loaded
+    newest = native._NEWEST_SYMBOL
     old = tmp_path / "libsparknet_data.so"
     with open(native._LIB_PATH, "rb") as fh:
-        old.write_bytes(fh.read().replace(b"sn_loader_stats", b"sn_loader_stat_"))
+        old.write_bytes(fh.read().replace(newest, newest[:-1] + b"_"))
     monkeypatch.setattr(native, "_LIB_PATH", str(old))
     assert native._is_stale()
     monkeypatch.setattr(native, "_LIB_PATH", str(tmp_path / "absent.so"))
@@ -288,3 +289,212 @@ def test_a_library_without_the_newest_symbol_is_stale(tmp_path, monkeypatch):
         native.subprocess, "run", lambda argv, **kw: ran.append(argv)
     )
     assert native._build() is None and ran[0][-1] == "-B"
+
+
+# ------------------------------------------- the life cycle of a batch buffer
+#
+# A batch is lent, not copied: its memory goes back to the loader's pool when
+# the last reference dies, and is rewritten only then.
+
+_WINDOW = 4  # NativeLoader's default queue_cap
+
+
+def _most_buffers(threads, lent=2):
+    """The window, one batch in each worker's hands, and what the caller
+    holds: a loop that drops a batch as it takes the next holds two."""
+    return _WINDOW + threads + lent
+
+
+def _plain_loader(threads, train=False, seed=11):
+    """Label == sample id, and with ``train=False`` a transform that
+    ``transform_batch`` repeats sample for sample (centre crop)."""
+    rng = np.random.default_rng(10)
+    images = rng.integers(0, 256, (64, 20, 20, 3)).astype(np.uint8)
+    mc = np.array([100.0, 110.0, 120.0], np.float32)
+    ld = native.NativeLoader(
+        images, np.arange(64, dtype=np.int32), batch_size=8, crop=16,
+        train=train, mirror=train, mean_channel=mc, scale=0.5, seed=seed,
+        num_threads=threads,
+    )
+    expected = native.transform_batch(
+        images, crop=16, train=False, mean_channel=mc, scale=0.5
+    )
+    return ld, expected
+
+
+def _same_bytes(a, b):
+    return np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+@pytest.mark.parametrize("train", [False, True])
+def test_streams_are_byte_identical_at_any_thread_count_as_buffers_cycle(train):
+    loaders = {t: _plain_loader(t, train=train) for t in (1, 2, 4)}
+    try:
+        for _ in range(3 * _most_buffers(4)):
+            # compared and dropped as it goes, so every loader's buffers
+            # come back and are written again
+            one, two, four = (next(ld) for ld, _ in loaders.values())
+            for other in (two, four):
+                assert _same_bytes(one["label"], other["label"])
+                assert _same_bytes(one["data"], other["data"])
+            if not train:
+                assert _same_bytes(one["data"], loaders[1][1][one["label"]])
+            del one, two, four, other
+        for threads, (ld, _) in loaders.items():
+            allocated = ld.stats()["buffers_allocated"]
+            assert 1 <= allocated <= _most_buffers(threads, lent=1), threads
+    finally:
+        for ld, _ in loaders.values():
+            ld.close()
+
+
+def test_a_held_batch_and_a_view_of_a_dropped_one_are_never_rewritten():
+    ld, _ = _plain_loader(2, train=True)
+    try:
+        held = next(ld)
+        piece = next(ld)["data"][2:5, 1]  # its parent array is gone
+        assert not piece.flags.owndata
+        then = held["data"].copy(), piece.copy()
+        for _ in range(3 * _most_buffers(2)):
+            next(ld)
+        assert _same_bytes(held["data"], then[0])
+        assert _same_bytes(piece, then[1])
+    finally:
+        ld.close()
+
+
+def test_batches_outlive_close_and_may_be_dropped_after_it():
+    import gc
+
+    ld, expected = _plain_loader(2)
+    batches = [next(ld) for _ in range(5)]
+    view = batches[0]["data"][1]
+    ld.close()
+    for b in batches:  # lent memory is not freed under its reader
+        assert _same_bytes(b["data"], expected[b["label"]])
+    first = batches[0]["label"][1]
+    del ld, batches, b
+    gc.collect()  # the loader is gone; the view still holds its buffer
+    assert _same_bytes(view, expected[first])
+    del view  # the last buffer goes back to a pool nobody else holds
+    # and a loader dropped unclosed with a batch out does the same
+    ld, expected = _plain_loader(4)
+    b = next(ld)
+    del ld
+    gc.collect()
+    assert _same_bytes(b["data"], expected[b["label"]])
+
+
+def test_buffers_allocated_stops_growing_and_grows_by_what_is_held():
+    import json
+
+    assert "buffers_allocated" in native.STATS
+    ld, _ = _plain_loader(2)
+    try:
+        for _ in range(10 * _most_buffers(2)):
+            next(ld)
+        settled = ld.stats()["buffers_allocated"]
+        assert 1 <= settled <= _most_buffers(2)
+        held = [next(ld) for _ in range(5)]
+        for _ in range(3 * _most_buffers(2)):
+            next(ld)
+        grown = ld.stats()["buffers_allocated"]
+        # five are out for good, and a sixth is on loan at any time
+        assert len(held) < grown <= settled + len(held)
+        del held
+        for _ in range(3 * _most_buffers(2)):
+            next(ld)
+        assert ld.stats()["buffers_allocated"] == grown  # they came back
+    finally:
+        ld.close()
+    # the input pipeline: line says so
+    line = json.loads(ld.metrics.json_line())
+    assert line["buffers"]["allocated"] == grown
+    assert line["buffers"]["reused_pct"] > 80.0
+    assert line["buffers"]["reused_pct"] == pytest.approx(
+        100.0 * (1 - grown / line["batches"]), abs=0.01
+    )
+
+
+def test_staged_batches_keep_their_values_where_device_put_aliases_host_memory():
+    """``prefetch_to_device`` with its default ``jax.device_put``: on the CPU
+    backend the jax array may *be* the loader's buffer.  It must not be
+    rewritten while the jax array lives, whatever the loader does meanwhile."""
+    import jax
+
+    from sparknet_tpu.data.prefetch import prefetch_to_device
+
+    staged_ld, _ = _plain_loader(2, train=True)
+    plain_ld, _ = _plain_loader(2, train=True)
+    feed = prefetch_to_device(staged_ld, size=2)
+    in_flight = []  # staged batches held as a step holds them, with copies
+    try:
+        for _ in range(3 * _most_buffers(2, lent=2 + 1 + 3)):
+            staged, plain = next(feed), next(plain_ld)
+            assert isinstance(staged["data"], jax.Array)
+            in_flight.append((staged, plain["data"].copy(), plain["label"]))
+            if len(in_flight) > 3:
+                old, data, label = in_flight.pop(0)
+                assert _same_bytes(old["data"], data)
+                assert _same_bytes(old["label"], label)
+    finally:
+        feed.close()
+        staged_ld.close()
+        plain_ld.close()
+    for old, data, _ in in_flight:  # and after the loader is gone
+        assert _same_bytes(old["data"], data)
+
+
+def test_buffers_come_back_from_other_threads_and_none_is_rewritten_under_a_reader():
+    """Stress: one consumer hands batches to more checker threads than cores,
+    which verify them late and drop them while four workers refill the
+    pool.  A buffer rewritten under its reader fails its check."""
+    import os
+    import queue
+    import sys
+    import threading
+    import time
+
+    ld, expected = _plain_loader(4)
+    handed: "queue.Queue" = queue.Queue(maxsize=32)
+    wrong, checked = [], [0]
+    count = threading.Lock()
+
+    def checker():
+        while True:
+            b = handed.get()
+            if b is None:
+                return
+            time.sleep(0.0005)
+            if not _same_bytes(b["data"], expected[b["label"]]):
+                wrong.append(b["label"].tolist())
+            with count:
+                checked[0] += 1
+
+    threads = [
+        threading.Thread(target=checker, daemon=True)
+        for _ in range((os.cpu_count() or 4) + 4)
+    ]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    taken = 0
+    try:
+        for t in threads:
+            t.start()
+        until = time.monotonic() + 1.5
+        while time.monotonic() < until:
+            handed.put(next(ld), timeout=30)
+            taken += 1
+        for _ in threads:
+            handed.put(None, timeout=30)
+        for t in threads:
+            t.join(timeout=30)
+    finally:
+        sys.setswitchinterval(interval)
+        ld.close()
+    assert not any(t.is_alive() for t in threads)
+    assert not wrong and checked[0] == taken and taken > 100
+    # at most what the queue and the checkers held at once
+    assert ld.stats()["buffers_allocated"] <= (
+        _most_buffers(4, lent=1) + handed.maxsize + len(threads)
+    )
