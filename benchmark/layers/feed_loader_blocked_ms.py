@@ -1,8 +1,7 @@
 """Input feed: of ``feed_source_ms``, the milliseconds a step that the native
 loader's consumer waits for a worker to finish the in-order batch (the C++
-``get_wait_ns`` counter); the rest of ``feed_source_ms`` is the copy out of
-the loader's queue.  Nothing to read where the feed is not the native
-loader."""
+``get_wait_ns`` counter); the rest of ``feed_source_ms`` is the hand-over of
+the lent buffer.  Nothing to read where the feed is not the native loader."""
 
 
 def read(run):

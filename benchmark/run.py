@@ -27,8 +27,11 @@ timeline on and its fence off (what the host needs to enqueue a step, and
 the loop's pace), the same with the fence on (where the host's time goes,
 phase by phase), and the profiler's trace of the same live loop (device
 time, kernels, busy and idle), with the profiler's host tracers off
-(``traced_steps``).  Every per-layer metric is a reader under ``layers/``
-that takes what these parts recorded.
+(``traced_steps``).  Each part first runs the traffic file's uncounted
+steps (``lead_steps``, ``skip_steps``) at the live pace: the feed fills
+its queues while the harness is between parts, and a part has to read
+the steady state, not the draining of that backlog.  Every per-layer
+metric is a reader under ``layers/`` that takes what these parts recorded.
 
 Everything but the result goes on ``bench:`` lines; the last line of
 stdout is the one JSON object.  Anything but a TPU with the chips the cell
@@ -47,6 +50,7 @@ import gc
 import importlib
 import importlib.util
 import io
+import itertools
 import json
 import math
 import os
@@ -55,7 +59,7 @@ import shutil
 import statistics
 import sys
 import threading
-from typing import Any, Callable, Dict, Iterator, List, Optional
+from typing import Any, Callable, ContextManager, Dict, Iterator, List, Optional
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -273,7 +277,7 @@ def close_feed(built: Dict) -> None:
         getattr(raw, "close", lambda: None)()
 
 
-def step_program(solver, batch, min_custom_calls: int) -> Dict[str, Any]:
+def step_program(solver, batch) -> Dict[str, Any]:
     """The program ``Solver.step`` dispatches for ``batch``: the Pallas
     kernels in its lowered text, and the device memory it needs (arguments
     + outputs - aliased + temporaries).  The runtime's
@@ -293,11 +297,7 @@ def step_program(solver, batch, min_custom_calls: int) -> Dict[str, Any]:
         parts["arguments"] + parts["outputs"] - parts["aliased"]
         + parts["temporaries"]
     )
-    return {
-        "tpu_custom_calls": kernels,
-        "bytes": {**parts, "total": total},
-        "ok": kernels >= min_custom_calls,
-    }
+    return {"tpu_custom_calls": kernels, "bytes": {**parts, "total": total}}
 
 
 def run_steps(
@@ -306,42 +306,53 @@ def run_steps(
     loss_key: str,
     seconds: Optional[float] = None,
     count: Optional[int] = None,
+    lead: int = 0,
+    window: Optional[ContextManager] = None,
 ) -> Dict[str, Any]:
-    """The loop, one step ahead.  Its first completion opens the window:
-    by then a second step is in flight, as in every later reading, so the
-    first step time is like the others (opening on the caller's last warm
-    step instead makes the first reading wait for two batches).  It closes
-    on the first completion ``seconds`` after that, or after ``count``
-    more.  ``attempted`` are the steps dispatched inside the window: the
+    """The loop, one step ahead.  After ``lead`` uncounted completions the
+    next one opens the window: by then a second step is in flight, as in
+    every later reading, so the first step time is like the others
+    (opening on the caller's last warm step instead makes the first
+    reading wait for two batches).  It closes on the first completion
+    ``seconds`` after that, or after ``count`` more.  ``window`` is entered
+    as the window opens and left as it closes, before the step in flight
+    is waited for: what a traced part switches on for its counted steps.
+    ``attempted`` are the steps dispatched inside the window: the
     completed ones and the one in flight at the close, which is waited for
-    after it; the step that opened the window is ``primed``."""
+    after it; the steps up to the one that opened it are ``primed``."""
     import jax
 
     completions: List[float] = []
     cpu: List[float] = []
     cpu_all: List[float] = []
     losses: List[float] = []
-    pending = solver.step(feed, 1)
-    while True:
-        ahead = solver.step(feed, 1)
-        jax.block_until_ready(pending)
-        completions.append(time.perf_counter())
-        cpu.append(time.thread_time())
-        cpu_all.append(time.process_time())
-        losses.append(float(pending[loss_key]))
-        pending = ahead
-        if count is not None and len(completions) > count:
-            break
-        if seconds is not None and completions[-1] - completions[0] >= seconds:
-            break
+    with contextlib.ExitStack() as opened:
+        pending = solver.step(feed, 1)
+        while True:
+            ahead = solver.step(feed, 1)
+            jax.block_until_ready(pending)
+            now = time.perf_counter(), time.thread_time(), time.process_time()
+            losses.append(float(pending[loss_key]))
+            pending = ahead
+            if len(losses) <= lead:
+                continue
+            completions.append(now[0])
+            cpu.append(now[1])
+            cpu_all.append(now[2])
+            if len(completions) == 1 and window is not None:
+                opened.enter_context(window)
+            if count is not None and len(completions) > count:
+                break
+            if seconds is not None and completions[-1] - completions[0] >= seconds:
+                break
     jax.block_until_ready(pending)
     losses.append(float(pending[loss_key]))
     return {
         "opened": completions[0],
         "window_s": completions[-1] - completions[0],
         "completed": len(completions) - 1,
-        "attempted": len(losses) - 1,
-        "primed": 1,
+        "attempted": len(completions),
+        "primed": lead + 1,
         "losses": losses,
         "step_s": [b - a for a, b in zip(completions, completions[1:])],
         # CPU seconds in each step, of this thread and of the process with
@@ -351,25 +362,40 @@ def run_steps(
     }
 
 
-def timed_phases(solver, feed, loss_key: str, count: int, fence: bool) -> Dict:
-    """Steps with ``Solver.step``'s own timeline on: seconds per phase
-    (``input_wait``, ``device_put``, ``compiled_step``), the calls of the
-    step, and the wall time of the same steps.  With the fence the step
-    waits for the device inside ``compiled_step``; without it that phase is
-    the dispatch alone."""
+def timed_phases(
+    solver, feed, loss_key: str, count: int, fence: bool, lead: int = 0
+) -> Dict:
+    """``count`` steps of the live loop with ``Solver.step``'s own timeline
+    on: seconds per phase (``input_wait``, ``device_put``,
+    ``compiled_step``, the feed's background phases), the calls of the
+    step, and the wall time of the same steps, which is the window's.
+    With the fence the step waits for the device inside ``compiled_step``;
+    without it that phase is the dispatch alone.  The ``lead`` steps
+    before them run under a timeline of their own with the same fence, so
+    the counted steps follow steps like themselves."""
     from sparknet_tpu.telemetry.timeline import Timeline
 
+    timeline = Timeline(fence=fence)
+
+    @contextlib.contextmanager
+    def counted():
+        solver.timeline = timeline
+        try:
+            yield
+        finally:
+            solver.timeline = uncounted
+
     before = solver.timeline
-    solver.timeline = timeline = Timeline(fence=fence)
-    started = time.perf_counter()
+    solver.timeline = uncounted = Timeline(fence=fence)
     try:
-        log = run_steps(solver, feed, loss_key, count=count)
+        log = run_steps(
+            solver, feed, loss_key, count=count, lead=lead, window=counted()
+        )
     finally:
         solver.timeline = before
-    wall_s = time.perf_counter() - started
     calls = timeline.snapshot()["phases"].get("compiled_step", {}).get("count", 0)
     return {
-        **log, "steps": calls, "wall_s": wall_s,
+        **log, "steps": calls, "wall_s": log["window_s"],
         "phases": timeline.phase_seconds(),
     }
 
@@ -425,24 +451,42 @@ def device_report(devices, program_bytes: int) -> Dict[str, Any]:
     }
 
 
+# completions a reading of the pace averages over: twice what the feed
+# holds (4 batches queued, 2 staged, 2 in build), the smallest of 2, 4, 8
+# and 16 at which alexnet_live's readings agree from run to run (PERF.md
+# section 2), and an even number, so that a loop that completes its steps in
+# pairs reads the same in either phase
+PACE_RUN = 16
+
+
+def pace_p90(step_s: List[float], k: int = PACE_RUN) -> float:
+    """The 90th percentile, over the window, of the mean time between
+    completions over every run of ``k`` consecutive steps, sliding by one:
+    the pace a progress line that averages ``k`` steps would show in its
+    slow tenth.  A stall, a run of collections or a slow stretch that
+    lasts or recurs over a tenth of the window raises it; on which half
+    of a short/long alternation a percentile of single steps would land
+    does not.  A window of fewer than ``2 k`` steps reads its mean."""
+    if len(step_s) < 2 * k:
+        return statistics.fmean(step_s)
+    ends = [0.0, *itertools.accumulate(step_s)]
+    runs = [(b - a) / k for a, b in zip(ends, ends[k:])]
+    return statistics.quantiles(runs, n=10, method="inclusive")[8]
+
+
 def end_to_end(recorded: Dict[str, Any]) -> Dict[str, float]:
     """The end-to-end metrics of an untraced run, which the benchmark takes
     itself: samples completed per second over the span from the window's
-    opening to its last completion; the 90th percentile of the times
-    between successive completions, over every step of the window; the
-    device memory the step program needs; and the set-up time, less the
-    harness's own reference check."""
+    opening to its last completion; the slow tenth of the loop's pace over
+    every step of the window (:func:`pace_p90`); the device memory the step
+    program needs; and the set-up time, less the harness's own reference
+    check."""
     window = recorded["window"]
-    steps = window["step_s"]
-    p90 = (
-        statistics.quantiles(steps, n=10, method="inclusive")[8]
-        if len(steps) > 1 else steps[0]
-    )
     return {
         "samples_per_s": (
             window["completed"] * recorded["samples"] / window["window_s"]
         ),
-        "step_ms_p90": 1e3 * p90,
+        "pace_ms_p90": 1e3 * pace_p90(window["step_s"]),
         "step_hbm_gb": recorded["program"]["bytes"]["total"] / 1e9,
         "setup_s": (
             window["opened"] - _PROCESS_T0 - recorded["reference"]["seconds"]
@@ -463,6 +507,18 @@ def per_layer(
 
 
 # ----------------------------------------------------------------------- a run
+
+def holds(numbers: Dict[str, float]) -> bool:
+    """A compared number against the limits beside it (``at_most``,
+    ``at_least``, ``equal_to``); a nan holds none."""
+    value = numbers["value"]
+    return (
+        value <= numbers.get("at_most", value)
+        and value >= numbers.get("at_least", value)
+        and value == numbers.get("equal_to", value)
+    )
+
+
 
 def set_up(cell: Dict[str, Any], built: Dict[str, Any], clock: CompileClock) -> Dict:
     """From the built cell to the last warm step: the first batch and the
@@ -524,22 +580,24 @@ def traced_parts(recorded: Dict, built: Dict, trace_dir: str) -> List[Dict]:
     """The three parts of a traced run, recorded under ``dispatch``,
     ``fenced`` and ``trace``; returns their step logs.  All three run the
     live loop: the first two with ``Solver.step``'s timeline on, the third
-    under the profiler."""
+    under the profiler.  Each counts its steps after uncounted ones
+    (``lead_steps`` of the traffic file's ``trace``, 0 where it names
+    none; ``skip_steps`` under the profiler)."""
     solver, feed = built["solver"], built["feed"]
     loss_key = recorded["traffic"].get("loss_key", "loss")
     spec = recorded["traffic"]["trace"]
+    lead = spec.get("lead_steps", 0)
     recorded["dispatch"] = timed_phases(
-        solver, feed, loss_key, spec["dispatch_steps"], fence=False
+        solver, feed, loss_key, spec["dispatch_steps"], fence=False, lead=lead
     )
     recorded["fenced"] = timed_phases(
-        solver, feed, loss_key, spec["fenced_steps"], fence=True
+        solver, feed, loss_key, spec["fenced_steps"], fence=True, lead=lead
     )
     for name in ("dispatch", "fenced"):
         part = recorded[name]
         say(
-            f"timeline, {name}: {part['steps']} steps in "
-            f"{part['wall_s']:.4f}s, window {part['window_s']:.4f}s of "
-            f"{part['completed']} steps, phases {part['phases']}"
+            f"timeline, {name}: after {lead} uncounted steps {part['steps']} "
+            f"steps in {part['wall_s']:.4f}s, phases {part['phases']}"
         )
     live_step_s = (
         recorded["dispatch"]["window_s"] / recorded["dispatch"]["completed"]
@@ -569,11 +627,12 @@ def say_window(recorded: Dict, peaks: Dict[str, float]) -> None:
     window = recorded["window"]
     steps_s = window["step_s"]
     say(
-        "window %.3fs, %d completed; step time n=%d median %.3f ms max "
-        "%.3f ms (step %d)" % (
+        "window %.3fs, %d completed; step time n=%d median %.3f ms p90 %.3f "
+        "ms max %.3f ms (step %d): the single intervals, for diagnosis; the "
+        "metric is the pace over runs of %d" % (
             window["window_s"], window["completed"], len(steps_s),
-            1e3 * statistics.median(steps_s), 1e3 * max(steps_s),
-            steps_s.index(max(steps_s)),
+            1e3 * statistics.median(steps_s), 1e3 * pace_p90(steps_s, 1),
+            1e3 * max(steps_s), steps_s.index(max(steps_s)), PACE_RUN,
         )
     )
     say("step times, ms: " + " ".join(f"{1e3 * s:.1f}" for s in steps_s))
@@ -616,6 +675,7 @@ def run_cell(
     import jax
 
     from benchmark.trace_reduce import top
+    from sparknet_tpu.telemetry.timeline import BACKGROUND_PREFIX
 
     built = build_cell(cell["config"], cell["traffic"], seed)
     try:
@@ -637,10 +697,7 @@ def run_cell(
             logs = [recorded["window"]]
         in_window = clock.since(compiles_before)
         stepped_to = solver.iter
-        recorded["program"] = step_program(
-            solver, next(built["feed"]),
-            cell["config"].get("min_tpu_custom_calls", 0),
-        )
+        recorded["program"] = step_program(solver, next(built["feed"]))
         say(f"step program {recorded['program']}")
     finally:
         close_feed(built)
@@ -650,15 +707,23 @@ def run_cell(
     stepped = attempted + sum(log["primed"] for log in logs)
     failed = sum(not math.isfinite(x) for x in losses)
     published = cell["config"].get("parameters", recorded["parameters"])
-    checks = {
-        "losses_finite": failed == 0,
-        "iter_advanced_by_steps": stepped_to - iter_before == stepped,
-        "no_compile_in_window": in_window["compiles"] == 0,
-        "feed_is_the_cells": feed_check["ok"],
-        "step_holds_its_kernels": recorded["program"]["ok"],
-        "agrees_with_reference": recorded["reference"]["ok"],
-        "parameters_as_published": recorded["parameters"] == published,
+    # every number that `correct` compares, beside its limit
+    program, reference = recorded["program"], recorded["reference"]
+    compared = {
+        "reference_abs_diff": {
+            "value": reference["abs_diff"], "at_most": reference["abs_tolerance"],
+        },
+        "losses_not_finite": {"value": failed, "at_most": 0},
+        "iter_advance": {"value": stepped_to - iter_before, "equal_to": stepped},
+        "compiles_in_window": {"value": in_window["compiles"], "at_most": 0},
+        "feed_is_the_cells": {"value": int(feed_check["ok"]), "equal_to": 1},
+        "tpu_custom_calls": {
+            "value": program["tpu_custom_calls"],
+            "at_least": cell["config"].get("min_tpu_custom_calls", 0),
+        },
+        "parameters": {"value": recorded["parameters"], "equal_to": published},
     }
+    checks = {name: holds(numbers) for name, numbers in compared.items()}
     say(
         f"{stepped} steps of {recorded['samples']} samples, loss first "
         f"{losses[0]} last {losses[-1]}; compile events in the window "
@@ -686,8 +751,13 @@ def run_cell(
         device["window_s"] = reduced["window_s"]
         # with the fence on the device idles whenever the host is not
         # inside compiled_step, and inside it for what is not device time:
-        # the dispatch, a batch still on its way to the device, the sync
-        idle = dict(fenced["phases"])
+        # the dispatch, a batch still on its way to the device, the sync.
+        # The feed.* rows are threads beside the loop: they overlap
+        # input_wait (feed.produce is worker-seconds) and are no gaps
+        idle = {
+            name: seconds for name, seconds in fenced["phases"].items()
+            if not name.startswith(BACKGROUND_PREFIX)
+        }
         idle["compiled_step, not device time"] = max(
             0.0,
             idle.pop("compiled_step", 0.0)
@@ -708,6 +778,7 @@ def run_cell(
         for m in metrics if m["name"] in values
     }
     result["device"] = device
+    result["compared"] = compared  # last on the line
     return result
 
 
@@ -750,13 +821,16 @@ def main(argv=None) -> int:
         f"traffic={cell['traffic']['name']} chips={cell['chips']} "
         f"seed={args.seed} seconds={args.seconds} trace={args.trace} "
         f"kind={devices[0].device_kind} count={len(devices)} "
-        f"compile cache {cache_dir}"
+        f"compile cache {cache_dir}; imports and the start of the TPU took "
+        f"{time.perf_counter() - _PROCESS_T0:.2f}s of imports_and_build"
     )
     result = run_cell(
         cell, args.seed, args.seconds, bool(args.trace), clock,
         trace_dir=os.path.join(ROOT, "runs", "benchmark", cell["name"]),
         peaks=peaks,
     )
+    for name, numbers in result["compared"].items():
+        print(f"bench: compared {name}: {numbers}", file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -9,6 +9,7 @@ import json
 import os
 import re
 import shutil
+import statistics
 import subprocess
 import sys
 from types import SimpleNamespace
@@ -64,8 +65,82 @@ def test_manifest_names_units_and_files_resolve():
             assert metric["moves"] in end_to_end
             assert callable(run.metric_reader(cell["layers_dir"], metric["name"]))
         assert {x["name"] for x in cell["end_to_end"]} <= {
-            "samples_per_s", "step_ms_p90", "step_hbm_gb", "setup_s",
+            "samples_per_s", "pace_ms_p90", "step_hbm_gb", "setup_s",
         }  # what run.end_to_end takes
+
+
+# ------------------------------------------------------ the pace's slow tenth
+
+def _pairs(first, n=260, late=()):
+    """Steps that complete in pairs, as ``alexnet_live``'s do since the
+    loader's two workers finish together: 68.5 ms (the second batch is
+    ready, the device paces) and 150 ms (the wait for the next pair) in
+    turn, starting on ``first``; at each index of ``late`` a batch comes
+    100 ms late and the device catches up on the next."""
+    short, long = 0.0685, 0.150
+    steps = [(short, long)[(i + (first == "long")) % 2] for i in range(n)]
+    for i in late:
+        steps[i] += 0.100
+        steps[i + 1] = max(0.025, steps[i + 1] - 0.100)
+    return steps
+
+
+_mean, _median = statistics.fmean, statistics.median
+
+_STRETCH = [0.1] * 105 + [0.2] * 30 + [0.1] * 105  # an eighth at twice the pace
+_RECURS = ([0.1] * 21 + [0.2] * 9) * 8             # the same, every 3 s
+_PACE_CASES = {
+    # steps, what the statistic has to read (lo, hi) as shares of `of`
+    "pairs_short_first": (_pairs("short"), (0.97, 1.03), _mean),
+    "pairs_long_first": (_pairs("long"), (0.97, 1.03), _mean),
+    "pairs_with_late_batches_short_first": (
+        _pairs("short", late=(31, 90, 171, 222)), (0.97, 1.03), _mean),
+    "pairs_with_late_batches_long_first": (
+        _pairs("long", late=(31, 90, 171, 222)), (0.97, 1.03), _mean),
+    "pairs_with_stalls_of_200_to_300_ms": (
+        [x + y for x, y in zip(
+            _pairs("long"), [{40: 0.05, 200: 0.15}.get(i, 0.0)
+                             for i in range(260)])],
+        (0.97, 1.03), _mean),
+    "steady_275_ms": ([0.275] * 109, (0.99999, 1.00001), _mean),
+    "one_stretch_in_eight_at_twice_the_pace": (_STRETCH, (1.4, 2.0), _median),
+    "a_slow_stretch_that_recurs": (_RECURS, (1.4, 2.0), _median),
+    "a_window_shorter_than_two_runs": (
+        [0.1] * 16 + [0.9] * 15, (0.99999, 1.00001), _mean),
+    "one_step": ([0.3], (0.99999, 1.00001), _mean),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PACE_CASES))
+def test_pace_p90_keeps_a_slow_stretch_and_loses_the_phase_of_a_pairing(case):
+    steps, (lo, hi), of = _PACE_CASES[case]
+    got = run.pace_p90(steps)
+    assert lo * of(steps) <= got <= hi * of(steps), (got, of(steps))
+    if case.startswith("pairs"):
+        # what the retired statistic did on the same steps: the 90th
+        # percentile of the single intervals sits on the long half
+        assert run.pace_p90(steps, 1) >= 1.3 * _mean(steps)
+
+
+def test_end_to_end_is_the_pace_and_the_manifest_names_no_single_step_tail():
+    m = _manifest()
+    names = [x["name"] for x in m["end_to_end"]]
+    assert names == ["samples_per_s", "pace_ms_p90", "step_hbm_gb", "setup_s"]
+    assert "step_ms_p90" not in json.dumps(m)
+    assert all(x["moves"] in names for x in m["per_layer"])
+    pace = next(x for x in m["end_to_end"] if x["name"] == "pace_ms_p90")
+    assert (pace["unit"], pace["better"], pace["source"]) == (
+        "ms", "lower", "host_clock")
+    steps = _pairs("long", late=(31, 90))
+    got = run.end_to_end({
+        "window": {"step_s": steps, "completed": len(steps),
+                   "window_s": sum(steps), "opened": run._PROCESS_T0 + 40.0},
+        "samples": 1024, "program": {"bytes": {"total": 5.5e9}},
+        "reference": {"seconds": 4.0},
+    })
+    assert got["pace_ms_p90"] == pytest.approx(1e3 * run.pace_p90(steps))
+    assert got["samples_per_s"] == pytest.approx(1024 / _mean(steps))
+    assert got["setup_s"] == pytest.approx(36.0) and got["step_hbm_gb"] == 5.5
 
 
 def _digests(top):
@@ -150,6 +225,8 @@ def _tiny_cell(name):
     cell["traffic"]["trace"].update(
         dispatch_steps=2, skip_steps=1, steps=2, fenced_steps=2,
     )
+    if "lead_steps" in cell["traffic"]["trace"]:  # bert_mlm names none
+        cell["traffic"]["trace"]["lead_steps"] = 3
     return cell
 
 
@@ -301,16 +378,19 @@ def test_a_fallen_back_feed_or_a_missing_kernel_is_not_correct(clock, tmp_path):
     assert out["correct"] is False
 
 
+def _recorded_steps(solver, feed, loss_key, skip, count, trace_dir):
+    """``run.traced_steps`` without a device plane to trace: the same loop,
+    and the recorded list reduced in the trace's place."""
+    log = run.run_steps(solver, feed, loss_key, count=skip + count)
+    reduced = trace_reduce.reduce_trace(_recorded()["devices"], skip, count)
+    return {**log, "trace": reduced}
+
+
 def test_traced_run_reports_the_cells_layer_metrics(clock, tmp_path, monkeypatch):
     """The CPU has no device plane to trace, so the recorded list stands in
     for the profiler; the timeline parts and the readers run for real."""
 
-    def recorded_steps(solver, feed, loss_key, skip, count, trace_dir):
-        log = run.run_steps(solver, feed, loss_key, count=skip + count)
-        reduced = trace_reduce.reduce_trace(_recorded()["devices"], skip, count)
-        return {**log, "trace": reduced}
-
-    monkeypatch.setattr(run, "traced_steps", recorded_steps)
+    monkeypatch.setattr(run, "traced_steps", _recorded_steps)
     cell = _tiny_cell("bert_mlm")
     out = run.run_cell(
         cell, seed=7, seconds=0.5, trace=True, clock=clock,

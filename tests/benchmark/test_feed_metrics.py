@@ -6,11 +6,13 @@ on the recorded chip trace.  A file of its own beside
 ``test_benchmark.py``, whose helpers it borrows: a PR that changes the
 program adds files to the benchmark and edits none."""
 
+import os
+
 import pytest
 
 from benchmark import run, trace_reduce  # no jax at import
 from tests.benchmark.test_benchmark import (  # noqa: F401  (clock: a fixture)
-    _manifest, _recorded, _tiny_cell, clock,
+    _manifest, _recorded, _recorded_steps, _tiny_cell, clock,
 )
 
 
@@ -22,7 +24,6 @@ _FEED_PHASES = {
     "feed_backpressure_ms": "feed.backpressure",
     "feed_loader_blocked_ms": "feed.loader_blocked",
     "feed_produce_ms": "feed.produce",
-    "feed_copy_out_ms": "feed.copy_out",
 }
 
 
@@ -34,7 +35,6 @@ def test_feed_reader_gives_ms_a_step_and_none_without_its_phase(metric):
         "input_wait": 7.9, "device_put": 0.001, "compiled_step": 0.0085,
         "feed.source": 7.1, "feed.h2d": 1.2, "feed.backpressure": 0.2,
         "feed.loader_blocked": 5.9, "feed.produce": 17.0,
-        "feed.copy_out": 1.1,
     }
     read = run.metric_reader(run.load_cell("alexnet_live")["layers_dir"], metric)
     probe = {"steps": 10, "wall_s": 8.5, "phases": phases}
@@ -62,15 +62,20 @@ def test_feed_metrics_cells_are_the_manifests():
     )
     assert m["feed_loader_blocked_ms"]["workloads"] == ["alexnet_live"]
     assert m["feed_produce_ms"]["workloads"] == ["alexnet_live"]
-    assert m["feed_copy_out_ms"]["workloads"] == ["alexnet_live"]
+    # retired with PR 28: since the loader lends its buffers it timed a
+    # 4 KB copy of labels (the counter stays on the `input pipeline:` line)
+    assert "feed_copy_out_ms" not in m
+    assert not os.path.exists(os.path.join(
+        run.load_cell("alexnet_live")["layers_dir"], "feed_copy_out_ms.py"))
 
 
 def test_traced_rehearsal_lists_the_feed_metrics_and_they_add_up(
     clock, tmp_path, monkeypatch
 ):
     """``alexnet_live`` tiny, through the native loader and the staging
-    thread: all six feed metrics are on the line, and the staging
-    thread's three add up to the wall time of the part that read them.
+    thread: all five feed metrics are on the line, and the staging
+    thread's three add up to the wall time of the part that read them,
+    which are its counted steps alone.
     Every staging thread of the process reports to the current timeline:
     the feeds that other tests of this module keep open sit in
     ``feed.backpressure`` all the while, a wall's worth each."""
@@ -79,18 +84,13 @@ def test_traced_rehearsal_lists_the_feed_metrics_and_they_add_up(
     recorded_runs = {}
     parked = len(timeline._in_flight)
 
-    def recorded_steps(solver, feed, loss_key, skip, count, trace_dir):
-        log = run.run_steps(solver, feed, loss_key, count=skip + count)
-        reduced = trace_reduce.reduce_trace(_recorded()["devices"], skip, count)
-        return {**log, "trace": reduced}
-
     real = run.traced_parts
 
     def keep(recorded, built, trace_dir):
         recorded_runs["run"] = recorded
         return real(recorded, built, trace_dir)
 
-    monkeypatch.setattr(run, "traced_steps", recorded_steps)
+    monkeypatch.setattr(run, "traced_steps", _recorded_steps)
     monkeypatch.setattr(run, "traced_parts", keep)
     cell = _tiny_cell("alexnet_live")
     cell["traffic"]["trace"].update(dispatch_steps=6)
@@ -110,14 +110,104 @@ def test_traced_rehearsal_lists_the_feed_metrics_and_they_add_up(
     a_step = 1e3 * probe["wall_s"] / probe["steps"]
     assert serial == pytest.approx((1 + parked) * a_step, rel=0.1)
     assert value("feed_source_ms") + value("feed_h2d_ms") <= 1.01 * a_step
-    # the loader's wait and its copy are the two parts of a next() of it
-    assert (
-        value("feed_loader_blocked_ms") + value("feed_copy_out_ms")
-        <= value("feed_source_ms") * 1.01
-    )
-    # the ledger's idle_gaps lists the feed.* rows beside input_wait
+    # the loader's wait is a part of a next() of it
+    assert value("feed_loader_blocked_ms") <= value("feed_source_ms") * 1.01
+    # the ledger's idle_gaps holds the loop's own phases: the feed.* rows
+    # overlap input_wait (feed.produce is worker-seconds) and are no gaps
     gaps = {name for name, _s in out["breakdown"]["idle_gaps"]}
-    assert "feed.source" in gaps and "input_wait" in gaps
+    assert "input_wait" in gaps
+    assert not any(name.startswith("feed.") for name in gaps), gaps
+    assert any(name.startswith("feed.") for name in probe["phases"])
+    # each part counted its steps after the traffic file's uncounted ones
+    lead = cell["traffic"]["trace"]["lead_steps"]
+    assert lead == 3
+    for part, count in (("dispatch", 6), ("fenced", 2)):
+        log = recorded_runs["run"][part]
+        assert log["primed"] == lead + 1
+        assert log["steps"] == log["completed"] == count
+        assert log["wall_s"] == log["window_s"]
+        assert len(log["losses"]) == lead + count + 2
+
+
+# ------------------------------------------- uncounted steps before a part
+
+class _FakeSolver:
+    """``Solver.step``'s side of a part: it reads its timeline at every
+    call, brackets ``compiled_step`` with it, and returns the metrics."""
+
+    def __init__(self):
+        self.timeline = None
+        self.under = []  # the timeline each call ran under
+
+    def step(self, feed, n):
+        self.under.append(self.timeline)
+        with self.timeline.phase("compiled_step"):
+            return {"loss": float(next(feed))}
+
+
+@pytest.mark.parametrize("lead, count, fence", [
+    (0, 12, False),   # mlm_s512_bs64: no key, the part opens on the loop's
+    (0, 8, True),     # first completion as it always did
+    (24, 48, False),  # live_bs1024
+    (24, 32, True),
+    (3, 1, False),
+])
+def test_counted_steps_of_a_part_follow_its_uncounted_ones(lead, count, fence):
+    solver, before = _FakeSolver(), object()
+    solver.timeline = before
+    feed = iter(range(1000))
+    part = run.timed_phases(solver, feed, "loss", count, fence=fence, lead=lead)
+    assert solver.timeline is before
+    # the step that opens the window and the one in flight then are the
+    # last two uncounted ones; `count` are dispatched inside the window,
+    # and none after it
+    assert len(solver.under) == lead + 2 + count
+    uncounted, counted = solver.under[0], solver.under[-1]
+    assert uncounted is not counted and uncounted.fence == counted.fence == fence
+    assert all(t is uncounted for t in solver.under[: lead + 2])
+    assert all(t is counted for t in solver.under[lead + 2:])
+    assert part["steps"] == part["completed"] == count
+    assert part["primed"] == lead + 1 and part["attempted"] == count + 1
+    assert part["losses"] == [float(i) for i in range(lead + count + 2)]
+    assert next(feed) == lead + count + 2  # no batch taken and dropped
+    assert part["wall_s"] == part["window_s"] == pytest.approx(
+        sum(part["step_s"]))
+    assert set(part["phases"]) == {"compiled_step"}
+
+
+def test_a_traffic_file_without_lead_steps_runs_its_parts_as_before(
+    clock, tmp_path, monkeypatch
+):
+    """``mlm_s512_bs64`` names no ``lead_steps``: its parts open on the
+    loop's first completion, and the breakdown names the loop's phases."""
+    parts = {}
+    real = run.timed_phases
+
+    def keep(solver, feed, loss_key, count, fence, lead=0):
+        parts[fence] = (lead, real(solver, feed, loss_key, count, fence, lead))
+        return parts[fence][1]
+
+    monkeypatch.setattr(run, "timed_phases", keep)
+    monkeypatch.setattr(run, "traced_steps", _recorded_steps)
+    assert "lead_steps" not in run.load_cell("bert_mlm")["traffic"]["trace"]
+    assert run.load_cell("alexnet_live")["traffic"]["trace"]["lead_steps"] == 24
+    out = run.run_cell(
+        _tiny_cell("bert_mlm"), seed=28, seconds=0.5, trace=True, clock=clock,
+        trace_dir=str(tmp_path), peaks={"bf16_flops_per_s": 197e12},
+    )
+    assert out["correct"] is True, out
+    for fence in (False, True):
+        lead, part = parts[fence]
+        assert lead == 0 and part["primed"] == 1
+        assert part["steps"] == part["completed"] == 2
+    gaps = [name for name, _s in out["breakdown"]["idle_gaps"]]
+    assert "input_wait" in gaps and not any(n.startswith("feed.") for n in gaps)
+    # every number `correct` compared is on the line beside its limit, last
+    assert list(out)[-1] == "compared"
+    assert out["compared"]["reference_abs_diff"]["value"] <= (
+        out["compared"]["reference_abs_diff"]["at_most"])
+    assert out["compared"]["iter_advance"]["value"] == (
+        out["compared"]["iter_advance"]["equal_to"])
 
 
 # --------------------------------------------------- one clock, and the gaps
