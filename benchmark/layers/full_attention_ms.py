@@ -1,0 +1,9 @@
+"""Kernels: device milliseconds per step in the Pallas flash kernels
+(forward, dq, dkv) of the full-attention layers, told by their head count
+(``decoder_ops.attention_ms``)."""
+
+from benchmark.layers import decoder_ops
+
+
+def read(run):
+    return decoder_ops.attention_ms(run, "full_attention")

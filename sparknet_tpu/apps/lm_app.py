@@ -1,0 +1,177 @@
+"""LmApp — causal-LM pre-training entrypoint for the decoder family.
+
+The entrypoint shape is BertApp's: pick a config, build the feed, drive
+the :class:`~sparknet_tpu.solver.trainer.Solver` (single chip), AdamW with
+BertApp's schedule, batches staged ahead by ``maybe_prefetch``.
+
+    python -m sparknet_tpu.apps.lm_app --config tiny --max-iter 20
+    python -m sparknet_tpu.apps.lm_app --config benchmark/configs/laguna_xs2.json \\
+        --bf16 --remat --seq-len 8192 --batch-size 2 --synthetic-tokens 4194304
+
+``--config`` is ``tiny`` or the path of a JSON file holding a published
+``config.json``'s keys (``DecoderConfig.from_published``: ``layer_types``,
+``mlp_layer_types``, ``num_attention_heads_per_layer``, ``rope_parameters``
+...), as cut to this chip's share if it is (``num_experts`` held of
+``deployment.num_experts_routed``).  Token ids come from the config's
+``vocab_size``.  The progress line carries the sparse layers' counters
+(``moe_slots_held``, ``moe_load_max_over_mean``, ``moe_slots_dropped``);
+the telemetry registry has them, as every solver's newest step metrics,
+under its source ``train_step``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import os
+import time
+from typing import Dict
+
+import jax.numpy as jnp
+
+from ..data.text import clm_dataset, clm_feed
+from ..models.decoder import COUNTERS, DecoderConfig, DecoderLM
+from ..solver.trainer import Solver
+from .bert_app import make_solver_param
+
+
+def make_config(args) -> DecoderConfig:
+    if args.config == "tiny":
+        cfg = DecoderConfig.tiny()
+    else:
+        with open(args.config) as fh:
+            cfg = DecoderConfig.from_published(json.load(fh))
+    return dataclasses.replace(cfg, remat=True) if args.remat else cfg
+
+
+def build(args):
+    """(solver, feed, cfg): one chip through the Solver."""
+    cfg = make_config(args)
+    ds = clm_dataset(
+        vocab_size=cfg.vocab_size, n_tokens=args.synthetic_tokens,
+        seq_len=args.seq_len, seed=args.seed,
+    )
+    shapes = {"input_ids": (args.batch_size, args.seq_len)}
+    model = DecoderLM(
+        cfg, shapes,
+        compute_dtype=jnp.bfloat16 if args.bf16 else jnp.float32,
+        attention_impl=args.attention or None,
+    )
+    solver = Solver(make_solver_param(args), shapes, model=model, seed=args.seed)
+    return solver, clm_feed(ds, args.batch_size, seed=args.seed), cfg
+
+
+def parser() -> argparse.ArgumentParser:
+    ap = argparse.ArgumentParser(description="Causal-LM pre-training (LmApp)")
+    ap.add_argument("--config", default="tiny",
+                    help="'tiny' or a JSON file of published config keys")
+    ap.add_argument("--seq-len", type=int, default=64)
+    ap.add_argument("--batch-size", type=int, default=4)
+    ap.add_argument("--max-iter", type=int, default=1000)
+    ap.add_argument("--lr", type=float, default=1e-4)
+    ap.add_argument("--display", type=int, default=20)
+    ap.add_argument("--synthetic-tokens", type=int, default=1 << 16)
+    # one choice, but not a knob of this app's: every app's args carry
+    # ``parallel`` for ``maybe_prefetch``, and benchmark/run.py reads it
+    ap.add_argument("--parallel", choices=("none",), default="none",
+                    help="single chip through the Solver")
+    ap.add_argument("--bf16", action="store_true")
+    ap.add_argument("--attention", choices=("flash", "reference"),
+                    default=None, help="default: flash on a TPU")
+    ap.add_argument("--remat", action="store_true",
+                    help="recompute each layer in the backward pass")
+    ap.add_argument("--prefetch", type=int, default=2,
+                    help="batches staged ahead on device (0 disables)")
+    ap.add_argument("--snapshot", type=int, default=0,
+                    help="snapshot the solver state every N iters")
+    ap.add_argument("--snapshot-prefix", default=os.path.join("runs", "lm"))
+    ap.add_argument("--restore", default=None, metavar="SOLVERSTATE")
+    ap.add_argument("--profile-dir", default=None,
+                    help="dump a jax.profiler trace of the training loop")
+    ap.add_argument("--trace", default=None, metavar="OUT.json",
+                    help="host-side span trace + step-time breakdown")
+    ap.add_argument("--seed", type=int, default=0)
+    return ap
+
+
+def main(argv=None) -> Dict[str, float]:
+    from .. import telemetry
+    from ..data.prefetch import maybe_prefetch
+    from ..solver.snapshot import resolve_prefix
+    from ..utils import compile_cache
+    from ..utils.profiling import StepTimer, trace
+
+    compile_cache.enable()
+    args = parser().parse_args(argv)
+    solver, feed, cfg = build(args)
+    args.snapshot_prefix = resolve_prefix(args.snapshot_prefix)
+    if args.restore:
+        solver.restore(args.restore, feed)
+        print(f"Restoring previous solver status from {args.restore} "
+              f"(iter {solver.iter})")
+    feed = maybe_prefetch(feed, args, args.parallel)  # after restore
+    print(
+        f"LmApp: config={args.config} vocab={cfg.vocab_size} "
+        f"layers={cfg.num_layers} hidden={cfg.hidden_size} experts_held="
+        f"{cfg.experts_held} of {cfg.num_experts} "
+        f"params={solver.train_net.num_params(solver.params)}"
+    )
+    timer = StepTimer(
+        items_per_step=args.batch_size * args.seq_len, unit="tokens"
+    )
+    telemetry.install_for_training(solver, args.trace, args.profile_dir)
+    t0 = time.time()
+    metrics: Dict[str, float] = {}
+    try:
+        with trace(args.profile_dir), telemetry.training_loop(
+            solver.timeline, emit=print
+        ):
+            metrics = _fit(solver, feed, args, timer)
+    finally:
+        telemetry.finish_run()
+    dt = time.time() - t0
+    print(f"Optimization Done. {solver.iter} iters in {dt:.1f}s "
+          f"({solver.iter / max(dt, 1e-9):.1f} it/s)")
+    if solver.timeline.enabled:
+        print("telemetry: step-time breakdown")
+        for line in solver.timeline.table().splitlines():
+            print(f"  {line}")
+    return metrics
+
+
+def _fit(solver, feed, args, timer) -> Dict[str, float]:
+    """Step in chunks that end at the next display or snapshot boundary."""
+    metrics: Dict[str, float] = {}
+
+    def log_iter(it, mm):
+        print(
+            f"Iteration {it}, loss = {float(mm['loss']):.5f}, token_acc = "
+            f"{float(mm['token_acc']):.4f}, " + ", ".join(
+                f"{name} = {float(mm[name]):.4g}" for name in COUNTERS
+            )
+        )
+
+    while solver.iter < args.max_iter:
+        targets = [args.max_iter]
+        for interval in (args.display or 20, args.snapshot):
+            if interval:
+                targets.append((solver.iter // interval + 1) * interval)
+        before = solver.iter
+        timer.update(0)
+        m = solver.step(feed, min(targets) - solver.iter, log_fn=log_iter)
+        metrics = {k: float(v) for k, v in m.items()}  # host sync
+        if args.display:
+            print(f"    speed: {timer.update(solver.iter - before).format()}")
+        if args.snapshot and (
+            solver.iter % args.snapshot == 0 or solver.iter >= args.max_iter
+        ):
+            path = (f"{args.snapshot_prefix}_iter_{solver.iter}"
+                    f"{solver.snapshot_suffix}")
+            solver.save(path)
+            print(f"Snapshotting solver state to {path}")
+    return metrics
+
+
+if __name__ == "__main__":
+    main()

@@ -1,4 +1,5 @@
-"""Text data layer for the BERT family: corpus -> MLM batches.
+"""Text data layer for the BERT family and the decoder: corpus -> MLM
+batches (:func:`mlm_feed`) or next-token batches (:func:`clm_feed`).
 
 No reference counterpart (SparkNet has no text path — SURVEY.md §2);
 follows the framework's RDD-style contract: partitions are pure
@@ -199,6 +200,47 @@ def mlm_feed_tokens(
             ).copy(),
             "mlm_labels": labels,
             "mlm_weights": weights,
+        }
+
+    return ds.batches(batch_size, shuffle=True, seed=seed, transform=transform)
+
+
+def clm_dataset(
+    *,
+    vocab_size: int,
+    n_tokens: int = 1 << 16,
+    seq_len: int = 128,
+    num_partitions: int = 8,
+    seed: int = 0,
+) -> ShardedDataset:
+    """Dataset of {"tokens": (seq_len + 1,)} windows of the synthetic
+    stream, back to back: no padding, no packing, no special tokens
+    added, every id under ``vocab_size`` (a slice of a larger vocabulary
+    is a smaller vocabulary)."""
+    stream = synthetic_token_stream(n_tokens, vocab_size, seed)
+    n_seq = len(stream) // (seq_len + 1)
+    if n_seq == 0:
+        raise ValueError(
+            f"{n_tokens} tokens hold no window of {seq_len} + 1"
+        )
+    windows = stream[: n_seq * (seq_len + 1)].reshape(n_seq, seq_len + 1)
+    return ShardedDataset.from_arrays(
+        {"tokens": windows}, min(num_partitions, n_seq)
+    )
+
+
+def clm_feed(
+    ds: ShardedDataset, batch_size: int, seed: int = 0
+) -> Iterator[Dict[str, np.ndarray]]:
+    """Batches in the DecoderLM blob layout (host numpy): ``input_ids``
+    and ``labels``, the same window shifted by one, so that every
+    position predicts the next token."""
+
+    def transform(batch, rng):
+        toks = batch["tokens"].astype(np.int32)
+        return {
+            "input_ids": np.ascontiguousarray(toks[:, :-1]),
+            "labels": np.ascontiguousarray(toks[:, 1:]),
         }
 
     return ds.batches(batch_size, shuffle=True, seed=seed, transform=transform)

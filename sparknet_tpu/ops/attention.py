@@ -12,6 +12,17 @@ for the BERT family and the long-context path:
   kernels. Supports causal masking, key-padding masks, and global
   position offsets (``q_offset``/``kv_offset``) so ring-attention shards
   can run the same kernel on their local slice of a longer sequence.
+  ``window=w`` (with ``causal``) is a sliding window: query *i* sees keys
+  ``i - w < j <= i``; key blocks wholly outside it are neither computed
+  nor fetched, in the forward, dq and dkv kernels alike (the last grid
+  axis walks the band of blocks a q block — or, in dkv, a key block —
+  can touch, not the whole axis). Grouped-query attention: ``k``/``v``
+  may carry fewer heads than ``q`` (``H_q % H_kv == 0``); query head *h*
+  reads KV head ``h // (H_q / H_kv)``, addressed by group in the block
+  specs (K and V are never repeated in HBM) and dk/dv summed over the
+  group inside the dkv kernel. Head counts may differ from call to call.
+  With ``window=None`` and equal head counts the traced kernels are the
+  plain ones, unchanged.
 - :func:`attention` — dispatcher: flash on TPU (or ``force="flash"``),
   reference elsewhere.
 
@@ -58,12 +69,20 @@ def mha_reference(
     kv_offset: int = 0,
     dropout_rate: float = 0.0,
     dropout_rng: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Plain attention. q,k,v: (B,H,S,D); kv_mask: (B,Sk) True=valid.
+    ``window`` (needs ``causal``): query i sees keys i - window < j <= i.
+    ``k``/``v`` may have fewer heads than ``q`` (grouped-query).
 
     A query row with *no* valid key (fully padded) outputs exactly zero
     and propagates zero gradients — same contract as the flash kernel.
     """
+    _check_window(window, causal)
+    group = _kv_group(q, k)
+    if group > 1:
+        k = jnp.repeat(k, group, axis=1)
+        v = jnp.repeat(v, group, axis=1)
     *_, sq, d = q.shape
     sk = k.shape[2]
     scale = (1.0 / math.sqrt(d)) if scale is None else scale
@@ -75,6 +94,8 @@ def mha_reference(
         qi = jnp.arange(sq)[:, None] + q_offset
         ki = jnp.arange(sk)[None, :] + kv_offset
         valid = valid & (ki <= qi)[None, None]
+        if window is not None:
+            valid = valid & (qi - ki < window)[None, None]
     if kv_mask is not None:
         valid = valid & kv_mask[:, None, None, :].astype(bool)
     logits = jnp.where(valid, logits, NEG_INF)
@@ -87,6 +108,24 @@ def mha_reference(
         "bhqk,bhkd->bhqd", p.astype(v.dtype), v,
         preferred_element_type=jnp.float32,
     ).astype(q.dtype)
+
+
+def _check_window(window, causal) -> None:
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"window={window!r} is a causal sliding window: it needs "
+            f"causal=True and window >= 1"
+        )
+
+
+def _kv_group(q, k) -> int:
+    """Query heads per KV head (1 = plain multi-head attention)."""
+    h, hkv = q.shape[1], k.shape[1]
+    if h % hkv:
+        raise ValueError(
+            f"grouped-query attention needs H_q ({h}) % H_kv ({hkv}) == 0"
+        )
+    return h // hkv
 
 
 def mha_reference_lse(q, k, v, **kw):
@@ -118,6 +157,49 @@ def mha_reference_lse(q, k, v, **kw):
 # those grid steps.  VMEM residency is O(blk_q*d + blk_k*d) regardless
 # of sequence length — S=32k runs in the same footprint as S=512.
 # ---------------------------------------------------------------------------
+
+
+def _band(i, shift, blk_i, blk_j, nj, lo_back, hi_fwd):
+    """(first, last) block along the other axis that block ``i`` of this
+    axis can touch.  Block ``i`` covers local positions ``i*blk_i ..
+    i*blk_i + blk_i - 1``; a position p touches ``p + shift - lo_back ..
+    p + shift + hi_fwd`` of the other axis (a bound of None is open).
+    Forward and dq walk key blocks from a q block: ``shift = q_offset -
+    kv_offset``, ``lo_back = window - 1`` (None without a window),
+    ``hi_fwd = 0`` if causal.  dkv walks q blocks from a key block:
+    ``shift = kv_offset - q_offset``, ``lo_back = 0`` if causal,
+    ``hi_fwd = window - 1``.  Works on Python ints and on traced scalars
+    (index maps and kernels call it with the same arguments, so the block
+    a kernel computes on is the block that was fetched)."""
+    static = isinstance(i, int) and isinstance(shift, int)
+    most, least = (max, min) if static else (jnp.maximum, jnp.minimum)
+    lo = 0 if lo_back is None else (
+        most(i * blk_i + shift - lo_back, 0) // blk_j
+    )
+    hi = nj - 1 if hi_fwd is None else least(
+        most(i * blk_i + blk_i - 1 + shift + hi_fwd, 0) // blk_j, nj - 1
+    )
+    return lo, hi
+
+
+def _band_steps(ni, shift, blk_i, blk_j, nj, lo_back, hi_fwd) -> int:
+    """Static length of the last grid axis: the most blocks any block
+    ``i`` touches — exact where ``shift`` is known (the offsets were
+    Python ints), else (``shift`` None) the worst alignment of an interval of ``blk_i + lo_back + hi_fwd``."""
+    if lo_back is None or hi_fwd is None:
+        return nj
+    if shift is not None:
+        return max(
+            1,
+            max(
+                hi - lo + 1
+                for lo, hi in (
+                    _band(i, shift, blk_i, blk_j, nj, lo_back, hi_fwd)
+                    for i in range(ni)
+                )
+            ),
+        )
+    return min(nj, (blk_i + lo_back + hi_fwd - 1) // blk_j + 2)
 
 
 def _dropout_keep(seed, rate, head_id, qi, kb, blk_q, blk_k):
@@ -162,15 +244,32 @@ def _fwd_kernel(
     nkb: int,
     dropout_rate: float,
     fold: int,
+    window: Optional[int] = None,
+    kv_fold: Optional[int] = None,
+    total_kb: Optional[int] = None,
 ):
+    """``nkb`` is the length of the last grid axis.  Plain calls walk
+    every key block; a banded call (``total_kb`` given: a window or
+    grouped KV heads) walks the ``nkb`` blocks from the first one its q
+    block can see, of ``total_kb``.  ``kv_fold`` KV heads arrive per
+    step (``fold`` where every query head has its own)."""
     qi = pl.program_id(2)
-    kb = pl.program_id(3)
+    step = pl.program_id(3)
     blk_q, d = q_ref.shape[2], q_ref.shape[3]
     blk_k = k_ref.shape[2]
     q_offset = off_ref[0]
     kv_offset = off_ref[1]
+    kv_fold = fold if kv_fold is None else kv_fold
+    if total_kb is None:
+        kb = step
+    else:
+        first_kb, last_kb = _band(
+            qi, q_offset - kv_offset, blk_q, blk_k, total_kb,
+            None if window is None else window - 1, 0 if causal else None,
+        )
+        kb = first_kb + step
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         acc_s[...] = jnp.zeros_like(acc_s)
         m_s[...] = jnp.full_like(m_s, NEG_INF)
@@ -188,10 +287,13 @@ def _fwd_kernel(
                 + kb * blk_k + kv_offset
             )
             causal_keep = k_pos <= q_pos
+            if window is not None:
+                causal_keep &= q_pos - k_pos < window
         for hh in range(fold):
+            kv = hh * kv_fold // fold
             q = q_ref[0, hh].astype(jnp.float32) * scale  # (blk_q, d)
-            k_blk = k_ref[0, hh].astype(jnp.float32)
-            v_blk = v_ref[0, hh].astype(jnp.float32)
+            k_blk = k_ref[0, kv].astype(jnp.float32)
+            v_blk = v_ref[0, kv].astype(jnp.float32)
             s = jax.lax.dot_general(
                 q, k_blk, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -222,7 +324,14 @@ def _fwd_kernel(
                 preferred_element_type=jnp.float32,
             )
 
-    if causal:
+    if total_kb is not None:
+        # a step past the band's end (above the diagonal, or past the
+        # last block) re-addresses the band's last block, which is not
+        # fetched again, and computes nothing
+        @pl.when(kb <= last_kb)
+        def _():
+            compute()
+    elif causal:
         # blocks fully above the diagonal contribute nothing: skip the
         # matmuls (state simply persists to the next grid step)
         last_q = qi * blk_q + blk_q - 1 + q_offset
@@ -234,7 +343,7 @@ def _fwd_kernel(
     else:
         compute()
 
-    @pl.when(kb == nkb - 1)
+    @pl.when(step == nkb - 1)
     def _finalize():
         for hh in range(fold):
             m_i = m_s[hh, :, 0:1]
@@ -258,18 +367,28 @@ def _fwd_kernel(
 def _bwd_dq_kernel(
     off_ref, q_ref, k_ref, v_ref, m_ref, do_ref, lse_ref, delta_ref,
     dq_ref, dq_s, *, causal: bool, scale: float, nkb: int,
-    dropout_rate: float, fold: int,
+    dropout_rate: float, fold: int, window: Optional[int] = None,
+    kv_fold: Optional[int] = None, total_kb: Optional[int] = None,
 ):
     """Grid (b, h/F, nq, nk): K/V stream over the last dim, dq (per
     folded head) accumulates in VMEM scratch, written on the final k
-    step."""
+    step.  ``window``/``kv_fold``/``total_kb`` as in the forward."""
     qi = pl.program_id(2)
-    kb = pl.program_id(3)
+    step = pl.program_id(3)
     blk_q, d = q_ref.shape[2], q_ref.shape[3]
     blk_k = k_ref.shape[2]
     q_offset, kv_offset = off_ref[0], off_ref[1]
+    kv_fold = fold if kv_fold is None else kv_fold
+    if total_kb is None:
+        kb = step
+    else:
+        first_kb, last_kb = _band(
+            qi, q_offset - kv_offset, blk_q, blk_k, total_kb,
+            None if window is None else window - 1, 0 if causal else None,
+        )
+        kb = first_kb + step
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         dq_s[...] = jnp.zeros_like(dq_s)
 
@@ -285,13 +404,16 @@ def _bwd_dq_kernel(
                 + kb * blk_k + kv_offset
             )
             causal_keep = k_pos <= q_pos
+            if window is not None:
+                causal_keep &= q_pos - k_pos < window
         for hh in range(fold):
+            kv = hh * kv_fold // fold
             q = q_ref[0, hh].astype(jnp.float32) * scale
             do = do_ref[0, hh].astype(jnp.float32)
             lse = lse_ref[0, hh, :, 0:1]    # (blk_q, 1), lane-replicated
             delta = delta_ref[0, hh, :, 0:1]
-            k_blk = k_ref[0, hh].astype(jnp.float32)
-            v_blk = v_ref[0, hh].astype(jnp.float32)
+            k_blk = k_ref[0, kv].astype(jnp.float32)
+            v_blk = v_ref[0, kv].astype(jnp.float32)
             s = jax.lax.dot_general(
                 q, k_blk, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -318,7 +440,11 @@ def _bwd_dq_kernel(
                 preferred_element_type=jnp.float32,
             )
 
-    if causal:
+    if total_kb is not None:
+        @pl.when(kb <= last_kb)
+        def _():
+            compute()
+    elif causal:
         last_q = qi * blk_q + blk_q - 1 + q_offset
         first_k = kb * blk_k + kv_offset
 
@@ -328,7 +454,7 @@ def _bwd_dq_kernel(
     else:
         compute()
 
-    @pl.when(kb == nkb - 1)
+    @pl.when(step == nkb - 1)
     def _finalize():
         for hh in range(fold):
             dq_ref[0, hh] = (dq_s[hh] * scale).astype(dq_ref.dtype)
@@ -337,18 +463,33 @@ def _bwd_dq_kernel(
 def _bwd_dkv_kernel(
     off_ref, q_ref, k_ref, v_ref, m_ref, do_ref, lse_ref, delta_ref,
     dk_ref, dv_ref, dk_s, dv_s, *, causal: bool, scale: float, nqb: int,
-    dropout_rate: float, fold: int,
+    dropout_rate: float, fold: int, window: Optional[int] = None,
+    kv_fold: Optional[int] = None, total_qb: Optional[int] = None,
+    band_qb: Optional[int] = None,
 ):
     """Grid (b, h/F, nk, nq): Q/dO/lse/delta stream over the last dim,
     dk/dv (per folded head) accumulate in VMEM scratch, written once on
-    the final q step."""
+    the final q step.  A banded call (``total_qb`` given) has the grid
+    (b, H_kv/kv_fold, nk, R * band_qb): the last axis walks, for each of
+    the R blocks of ``fold`` query heads that read these KV heads, the
+    ``band_qb`` q blocks from the first that can see this key block, so
+    dk/dv come out summed over the group; ``nqb`` is that axis' length."""
     ki = pl.program_id(2)
-    qb = pl.program_id(3)
+    step = pl.program_id(3)
     blk_k, d = k_ref.shape[2], k_ref.shape[3]
     blk_q = q_ref.shape[2]
     q_offset, kv_offset = off_ref[0], off_ref[1]
+    kv_fold = fold if kv_fold is None else kv_fold
+    if total_qb is None:
+        qb = step
+    else:
+        first_qb, last_qb = _band(
+            ki, kv_offset - q_offset, blk_k, blk_q, total_qb,
+            0 if causal else None, None if window is None else window - 1,
+        )
+        qb = first_qb + step % band_qb
 
-    @pl.when(qb == 0)
+    @pl.when(step == 0)
     def _init():
         dk_s[...] = jnp.zeros_like(dk_s)
         dv_s[...] = jnp.zeros_like(dv_s)
@@ -365,9 +506,12 @@ def _bwd_dkv_kernel(
                 + ki * blk_k + kv_offset
             )
             causal_keep = k_pos <= q_pos
+            if window is not None:
+                causal_keep &= q_pos - k_pos < window
         for hh in range(fold):
-            k_blk = k_ref[0, hh].astype(jnp.float32)
-            v_blk = v_ref[0, hh].astype(jnp.float32)
+            kv = hh * kv_fold // fold
+            k_blk = k_ref[0, kv].astype(jnp.float32)
+            v_blk = v_ref[0, kv].astype(jnp.float32)
             q = q_ref[0, hh].astype(jnp.float32) * scale
             do = do_ref[0, hh].astype(jnp.float32)
             lse = lse_ref[0, hh, :, 0:1]   # (blk_q, 1), lane-replicated
@@ -393,7 +537,7 @@ def _bwd_dkv_kernel(
                 p_drop = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
             else:
                 p_drop = p
-            dv_s[hh] += jax.lax.dot_general(
+            dv_s[kv] += jax.lax.dot_general(
                 p_drop, do, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
@@ -404,12 +548,16 @@ def _bwd_dkv_kernel(
             if dropout_rate > 0.0:
                 dp = jnp.where(keep, dp / (1.0 - dropout_rate), 0.0)
             ds = p * (dp - delta)
-            dk_s[hh] += jax.lax.dot_general(
+            dk_s[kv] += jax.lax.dot_general(
                 ds, q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
 
-    if causal:
+    if total_qb is not None:
+        @pl.when(qb <= last_qb)
+        def _():
+            compute()
+    elif causal:
         # q blocks fully before the diagonal can't see this k block
         last_q = qb * blk_q + blk_q - 1 + q_offset
         first_k = ki * blk_k + kv_offset
@@ -420,10 +568,10 @@ def _bwd_dkv_kernel(
     else:
         compute()
 
-    @pl.when(qb == nqb - 1)
+    @pl.when(step == nqb - 1)
     def _finalize():
         # q entered the matmuls pre-scaled, so ds^T @ q carries `scale`
-        for hh in range(fold):
+        for hh in range(kv_fold):
             dk_ref[0, hh] = dk_s[hh].astype(dk_ref.dtype)
             dv_ref[0, hh] = dv_s[hh].astype(dv_ref.dtype)
 
@@ -496,45 +644,127 @@ def _qk_specs(blk_q, blk_k, d, fold):
     ]
 
 
+def _banded_fold(fold, h, group, blk_q, blk_k, d):
+    """(fold, kv_fold, R) of a banded call: query heads and KV heads per
+    grid step, and the blocks of ``fold`` query heads per block of KV
+    heads.  Grouped heads fold within one group, so that a step reads
+    one KV head; plain heads fold as ever."""
+    if fold is None:
+        fold = _fold_heads(h if group == 1 else group, blk_q, blk_k, d)
+    if group == 1:
+        return fold, fold, 1
+    if group % fold:
+        raise ValueError(
+            f"fold ({fold}) must divide the query heads per KV head ({group})"
+        )
+    return fold, 1, group // fold
+
+
 def _flash_fwd(
     q, k, v, kv_mask, offsets, causal, scale, blk_q, blk_k, interpret,
-    dropout_rate, fold=None,
+    dropout_rate, fold=None, band=None, shift=None,
 ):
+    """``band`` = (window, group) makes the call banded (see the module
+    header): scalar-prefetched offsets, a last grid axis over the band of
+    key blocks, K/V addressed by group.  ``shift`` is ``q_offset -
+    kv_offset`` where both are Python ints (an exact band), else None."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
     nkb = sk // blk_k
-    if fold is None:
-        fold = _fold_heads(h, blk_q, blk_k, d)
-    grid = (b, h // fold, sq // blk_q, nkb)
-    kernel = functools.partial(
-        _fwd_kernel, causal=causal, scale=scale, nkb=nkb,
-        dropout_rate=dropout_rate, fold=fold,
+    out_shape = [
+        jax.ShapeDtypeStruct(q.shape, q.dtype),
+        # lane-replicated: TPU blocks need a 128-lane trailing dim
+        jax.ShapeDtypeStruct((b, h, sq, 128), jnp.float32),
+    ]
+    if band is None:
+        if fold is None:
+            fold = _fold_heads(h, blk_q, blk_k, d)
+        grid = (b, h // fold, sq // blk_q, nkb)
+        kernel = functools.partial(
+            _fwd_kernel, causal=causal, scale=scale, nkb=nkb,
+            dropout_rate=dropout_rate, fold=fold,
+        )
+        out, lse = pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=_qk_specs(blk_q, blk_k, d, fold),
+            out_specs=[
+                pl.BlockSpec(
+                    (1, fold, blk_q, d), lambda b_, g, i, j: (b_, g, i, 0)
+                ),
+                pl.BlockSpec(
+                    (1, fold, blk_q, 128), lambda b_, g, i, j: (b_, g, i, 0)
+                ),
+            ],
+            out_shape=out_shape,
+            scratch_shapes=[
+                pltpu.VMEM((fold, blk_q, d), jnp.float32),
+                pltpu.VMEM((fold, blk_q, 128), jnp.float32),
+                pltpu.VMEM((fold, blk_q, 128), jnp.float32),
+            ],
+            name="flash_attention_fwd",
+            **_params(interpret),
+        )(offsets, q, k, v, kv_mask)
+        return out, lse
+
+    window, group = band
+    fold, kv_fold, _ = _banded_fold(fold, h, group, blk_q, blk_k, d)
+    nqb = sq // blk_q
+    back, fwd = (None if window is None else window - 1), (0 if causal else None)
+    steps = _band_steps(nqb, shift, blk_q, blk_k, nkb, back, fwd)
+    in_specs, q_spec, lane_spec = _banded_qk_specs(
+        blk_q, blk_k, d, fold, kv_fold, group, nkb, back, fwd
     )
     out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=_qk_specs(blk_q, blk_k, d, fold),
-        out_specs=[
-            pl.BlockSpec(
-                (1, fold, blk_q, d), lambda b_, g, i, j: (b_, g, i, 0)
-            ),
-            pl.BlockSpec(
-                (1, fold, blk_q, 128), lambda b_, g, i, j: (b_, g, i, 0)
-            ),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
-            # lane-replicated: TPU blocks need a 128-lane trailing dim
-            jax.ShapeDtypeStruct((b, h, sq, 128), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((fold, blk_q, d), jnp.float32),
-            pltpu.VMEM((fold, blk_q, 128), jnp.float32),
-            pltpu.VMEM((fold, blk_q, 128), jnp.float32),
-        ],
+        functools.partial(
+            _fwd_kernel, causal=causal, scale=scale, nkb=steps,
+            dropout_rate=dropout_rate, fold=fold, window=window,
+            kv_fold=kv_fold, total_kb=nkb,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h // fold, nqb, steps),
+            in_specs=in_specs,
+            out_specs=[q_spec, lane_spec],
+            scratch_shapes=[
+                pltpu.VMEM((fold, blk_q, d), jnp.float32),
+                pltpu.VMEM((fold, blk_q, 128), jnp.float32),
+                pltpu.VMEM((fold, blk_q, 128), jnp.float32),
+            ],
+        ),
+        out_shape=out_shape,
+        name="flash_attention_fwd",
         **_params(interpret),
     )(offsets, q, k, v, kv_mask)
     return out, lse
+
+
+def _banded_qk_specs(blk_q, blk_k, d, fold, kv_fold, group, nkb, back, fwd):
+    """(in_specs for (q, k, v, mask), the q-shaped spec, the lane-
+    replicated spec) of a banded call on a (b, h/F, nq, band) grid; the
+    offsets are scalar-prefetched and arrive last in every index map.
+    Step j of q block i addresses key block ``min(first + j, last)``."""
+
+    def kv_block(i, j, off):
+        first, last = _band(i, off[0] - off[1], blk_q, blk_k, nkb, back, fwd)
+        return jnp.minimum(first + j, last)
+
+    q_spec = pl.BlockSpec(
+        (1, fold, blk_q, d), lambda b_, g, i, j, off: (b_, g, i, 0)
+    )
+    lane_spec = pl.BlockSpec(
+        (1, fold, blk_q, 128), lambda b_, g, i, j, off: (b_, g, i, 0)
+    )
+    kv_spec = pl.BlockSpec(
+        (1, kv_fold, blk_k, d),
+        lambda b_, g, i, j, off: (
+            b_, g * fold // (group * kv_fold), kv_block(i, j, off), 0
+        ),
+    )
+    mask_spec = pl.BlockSpec(
+        (1, 8, blk_k), lambda b_, g, i, j, off: (b_, 0, kv_block(i, j, off))
+    )
+    return [q_spec, kv_spec, kv_spec, mask_spec], q_spec, lane_spec
 
 
 @functools.partial(
@@ -565,7 +795,8 @@ def _flash_vjp_fwd(
 
 
 def _flash_vjp_bwd(
-    causal, scale, blk_q, blk_k, interpret, dropout_rate, fold, res, do
+    causal, scale, blk_q, blk_k, interpret, dropout_rate, fold, res, do,
+    band=None, shift=None,
 ):
     q, k, v, kv_mask, offsets, out, lse = res
     b, h, sq, _ = q.shape
@@ -580,14 +811,14 @@ def _flash_vjp_bwd(
     dq, dk, dv = _flash_bwd(
         q, k, v, kv_mask, offsets, do, lse, delta, causal=causal,
         scale=scale, blk_q=blk_q, blk_k=blk_k, interpret=interpret,
-        dropout_rate=dropout_rate, fold=fold,
+        dropout_rate=dropout_rate, fold=fold, band=band, shift=shift,
     )
     return dq, dk, dv, None, None
 
 
 def _flash_bwd(
     q, k, v, kv_mask, offsets, do, lse, delta, *, causal, scale,
-    blk_q, blk_k, interpret, dropout_rate, fold=None,
+    blk_q, blk_k, interpret, dropout_rate, fold=None, band=None, shift=None,
 ):
     """The two backward pallas calls, reusable per ring block: ``lse``
     and ``delta`` arrive lane-replicated (b, h, sq, 128) and may be the
@@ -597,6 +828,12 @@ def _flash_bwd(
     b, h, sq, d = q.shape
     sk = k.shape[2]
     nqb, nkb = sq // blk_q, sk // blk_k
+    if band is not None:
+        return _flash_bwd_banded(
+            q, k, v, kv_mask, offsets, do, lse, delta, causal=causal,
+            scale=scale, blk_q=blk_q, blk_k=blk_k, interpret=interpret,
+            dropout_rate=dropout_rate, fold=fold, band=band, shift=shift,
+        )
     if fold is None:
         fold = _fold_heads(h, blk_q, blk_k, d)
 
@@ -624,6 +861,7 @@ def _flash_bwd(
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((fold, blk_q, d), jnp.float32)],
+        name="flash_attention_dq",
         **_params(interpret),
     )(offsets, q, k, v, kv_mask, do, lse, delta)
 
@@ -674,12 +912,135 @@ def _flash_bwd(
             pltpu.VMEM((fold, blk_k, d), jnp.float32),
             pltpu.VMEM((fold, blk_k, d), jnp.float32),
         ],
+        name="flash_attention_dkv",
+        **_params(interpret),
+    )(offsets, q, k, v, kv_mask, do, lse, delta)
+    return dq, dk, dv
+
+
+def _flash_bwd_banded(
+    q, k, v, kv_mask, offsets, do, lse, delta, *, causal, scale,
+    blk_q, blk_k, interpret, dropout_rate, fold, band, shift,
+):
+    """The two backward calls of a banded forward (``_flash_fwd``'s
+    ``band``).  dq walks the same band of key blocks as the forward.
+    dkv's grid is (b, H_kv/kv_fold, nk, R * band): for each of the R
+    blocks of query heads that read a block of KV heads, the band of q
+    blocks that can see the key block; dk/dv leave summed over them."""
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
+    nqb, nkb = sq // blk_q, sk // blk_k
+    window, group = band
+    fold, kv_fold, reps = _banded_fold(fold, h, group, blk_q, blk_k, d)
+    span = None if window is None else window - 1
+    edge = 0 if causal else None
+    static = dict(
+        causal=causal, scale=scale, dropout_rate=dropout_rate, fold=fold,
+        window=window, kv_fold=kv_fold,
+    )
+
+    steps = _band_steps(nqb, shift, blk_q, blk_k, nkb, span, edge)
+    in_specs, q_spec, lane_spec = _banded_qk_specs(
+        blk_q, blk_k, d, fold, kv_fold, group, nkb, span, edge
+    )
+    dq = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, nkb=steps, total_kb=nkb, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h // fold, nqb, steps),
+            in_specs=in_specs + [q_spec, lane_spec, lane_spec],
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((fold, blk_q, d), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        name="flash_attention_dq",
+        **_params(interpret),
+    )(offsets, q, k, v, kv_mask, do, lse, delta)
+
+    steps = _band_steps(
+        nkb, None if shift is None else -shift, blk_k, blk_q, nqb, edge, span
+    )
+
+    def q_block(i, t, off):
+        first, last = _band(i, off[1] - off[0], blk_k, blk_q, nqb, edge, span)
+        return jnp.minimum(first + t % steps, last)
+
+    q_rows = lambda width: pl.BlockSpec(
+        (1, fold, blk_q, width),
+        lambda b_, g, i, t, off: (b_, g * reps + t // steps, q_block(i, t, off), 0),
+    )
+    kv_spec = pl.BlockSpec(
+        (1, kv_fold, blk_k, d), lambda b_, g, i, t, off: (b_, g, i, 0)
+    )
+    dk, dv = pl.pallas_call(
+        functools.partial(
+            _bwd_dkv_kernel, nqb=reps * steps, total_qb=nqb, band_qb=steps,
+            **static,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h // (group * kv_fold), nkb, reps * steps),
+            in_specs=[
+                q_rows(d), kv_spec, kv_spec,
+                pl.BlockSpec(
+                    (1, 8, blk_k), lambda b_, g, i, t, off: (b_, 0, i)
+                ),
+                q_rows(d), q_rows(128), q_rows(128),
+            ],
+            out_specs=[kv_spec, kv_spec],
+            scratch_shapes=[
+                pltpu.VMEM((kv_fold, blk_k, d), jnp.float32),
+                pltpu.VMEM((kv_fold, blk_k, d), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        name="flash_attention_dkv",
         **_params(interpret),
     )(offsets, q, k, v, kv_mask, do, lse, delta)
     return dq, dk, dv
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+
+@functools.partial(
+    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12)
+)
+def _flash_banded(
+    q, k, v, kv_mask, offsets, causal, scale, blk_q, blk_k, interpret,
+    fold, band, shift,
+):
+    """``_flash`` for a window and/or grouped KV heads (no dropout)."""
+    return _flash_fwd(
+        q, k, v, kv_mask, offsets, causal, scale, blk_q, blk_k, interpret,
+        0.0, fold=fold, band=band, shift=shift,
+    )[0]
+
+
+def _flash_banded_vjp_fwd(
+    q, k, v, kv_mask, offsets, causal, scale, blk_q, blk_k, interpret,
+    fold, band, shift,
+):
+    out, lse = _flash_fwd(
+        q, k, v, kv_mask, offsets, causal, scale, blk_q, blk_k, interpret,
+        0.0, fold=fold, band=band, shift=shift,
+    )
+    return out, (q, k, v, kv_mask, offsets, out, lse[..., 0])
+
+
+def _flash_banded_vjp_bwd(
+    causal, scale, blk_q, blk_k, interpret, fold, band, shift, res, do
+):
+    return _flash_vjp_bwd(
+        causal, scale, blk_q, blk_k, interpret, 0.0, fold, res, do,
+        band=band, shift=shift,
+    )
+
+
+_flash_banded.defvjp(_flash_banded_vjp_fwd, _flash_banded_vjp_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -816,8 +1177,12 @@ def flash_attention(
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
     fold: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
-    """Flash attention on (B,H,S,D). Any sequence length works:
+    """Flash attention on (B,H,S,D); ``k``/``v`` may be (B,H_kv,S,D) with
+    ``H % H_kv == 0`` (grouped-query), and ``window`` (with ``causal``)
+    restricts query i to keys ``i - window < j <= i`` — both described in
+    the module header; neither takes dropout. Any sequence length works:
     non-conforming lengths are zero-padded up to Mosaic's block
     granularity (sublane multiple for q, lane multiple for k) with the
     padded keys masked out and the padded query rows sliced off, so the
@@ -828,6 +1193,8 @@ def flash_attention(
     Attention-probability dropout runs inside the kernels via the TPU
     PRNG, seeded per (batch, head, q-block, k-block) so forward and both
     backward passes regenerate identical keep masks."""
+    _check_window(window, causal)
+    group = _kv_group(q, k)
     b, h, sq, d = q.shape
     sk = k.shape[2]
     pad_q, pad_k, block_q, block_k = _resolve_blocks(
@@ -868,17 +1235,29 @@ def flash_attention(
     # Passing it here (rather than flipping SPARKNET_FLASH_FOLD after a
     # trace) keys the jit cache honestly — a different fold is a
     # different traced argument, so an A/B actually recompiles.
-    out = _flash(
-        q, k, v, kv_mask, offsets, causal, scale, block_q, block_k,
-        interpret, float(dropout_rate), fold,
-    )
+    if window is None and group == 1:
+        out = _flash(
+            q, k, v, kv_mask, offsets, causal, scale, block_q, block_k,
+            interpret, float(dropout_rate), fold,
+        )
+    else:
+        if dropout_rate > 0.0:
+            raise NotImplementedError(
+                "attention dropout with a window or grouped KV heads"
+            )
+        static = isinstance(q_offset, int) and isinstance(kv_offset, int)
+        out = _flash_banded(
+            q, k, v, kv_mask, offsets, causal, scale, block_q, block_k,
+            interpret, fold, (window, group),
+            q_offset - kv_offset if static else None,
+        )
     return out[:, :, :sq] if pad_q else out
 
 
 def attention(
     q, k, v, *, causal=False, kv_mask=None, scale=None,
     q_offset=0, kv_offset=0, dropout_rate=0.0, dropout_rng=None,
-    force: Optional[str] = None, **flash_kw
+    window: Optional[int] = None, force: Optional[str] = None, **flash_kw
 ):
     """Dispatch: Pallas flash on TPU, reference elsewhere.
 
@@ -905,10 +1284,11 @@ def attention(
         return flash_attention(
             q, k, v, causal=causal, kv_mask=kv_mask, scale=scale,
             q_offset=q_offset, kv_offset=kv_offset,
-            dropout_rate=dropout_rate, dropout_rng=dropout_rng, **flash_kw
+            dropout_rate=dropout_rate, dropout_rng=dropout_rng,
+            window=window, **flash_kw
         )
     return mha_reference(
         q, k, v, causal=causal, kv_mask=kv_mask, scale=scale,
         q_offset=q_offset, kv_offset=kv_offset,
-        dropout_rate=dropout_rate, dropout_rng=dropout_rng,
+        dropout_rate=dropout_rate, dropout_rng=dropout_rng, window=window,
     )

@@ -1,8 +1,26 @@
-"""Mixture-of-Experts FFN with expert parallelism over an "ep" axis.
+"""Mixture-of-Experts FFNs: two routings, which differ in what happens
+to a token when an expert is full.
+
+- :func:`moe_ffn` — **drops tokens over capacity.** Switch/GShard
+  routing (softmax router, GELU experts with biases) with a fixed
+  per-expert capacity ``ceil(T * top_k / E * capacity_factor)``; a slot
+  past it contributes zero. All experts live here, or shard over an
+  "ep" mesh axis with an all-to-all. ``models/bert.py``'s MoE variant.
+- :func:`held_experts_ffn` — **drops nothing.** One chip's share of an
+  expert-parallel layer: it is told which experts it holds
+  (``experts_held = (first, count)`` of the router's ``num_experts``),
+  routes over all of them (sigmoid scores, the ``top_k`` largest,
+  weights normalised over every chosen expert, held here or not, times
+  ``routed_scale``), and computes the part of the result its own
+  SwiGLU experts give, for every slot routed to them whatever the
+  imbalance: slots sorted by expert, the held ones gathered, grouped
+  matrix products (``jax.lax.ragged_dot``) over the experts held,
+  combined by scatter-add. Slots of absent experts cost nothing, and
+  nothing stands in for the absent chips or their traffic. The decoder
+  (``models/decoder.py``) uses it.
 
 No reference counterpart (SURVEY.md §2: data parallelism only; EP is a
-task-spec obligation). Switch/GShard-style routing with a fixed
-per-expert capacity:
+task-spec obligation). :func:`moe_ffn` in detail:
 
 - ``top_k=1`` — Switch semantics: gate is the chosen expert's raw
   router probability.
@@ -32,6 +50,7 @@ semantics.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Optional, Tuple
 
@@ -233,3 +252,236 @@ def moe_ffn(
         )  # back to (E, C, h) token-owner layout
     out = combine_fn(y)
     return out.reshape(orig_shape).astype(x.dtype), aux
+
+
+# ---------------------------------------------------------------------------
+# One chip's share of an expert-parallel layer, without dropping a token
+# ---------------------------------------------------------------------------
+
+
+def init_held_experts_params(
+    rng: jax.Array, hidden: int, ffn: int, num_experts: int, held: int,
+    std: float = 0.02,
+) -> Dict[str, jax.Array]:
+    """Router over all ``num_experts``; SwiGLU weights of the ``held``
+    experts: ``experts_gate_up`` (held, hidden, 2*ffn) holds gate in its
+    first ``ffn`` columns and up in the rest, so one grouped product
+    makes both."""
+    k1, k2, k3 = jax.random.split(rng, 3)
+    trunc = lambda k, shape: (
+        jax.random.truncated_normal(k, -2.0, 2.0, shape, jnp.float32) * std
+    )
+    return {
+        "router_w": trunc(k1, (hidden, num_experts)),
+        "experts_gate_up": trunc(k2, (held, hidden, 2 * ffn)),
+        "experts_down": trunc(k3, (held, ffn, hidden)),
+    }
+
+
+def route_sigmoid(
+    xt: jax.Array, router_w: jax.Array, top_k: int, routed_scale: float
+) -> Tuple[jax.Array, jax.Array]:
+    """(weights, experts), both (T, K): sigmoid scores in float32, the
+    ``top_k`` largest, ``routed_scale * s / sum(s)`` over the chosen."""
+    scores = jax.nn.sigmoid(
+        jnp.dot(
+            xt.astype(jnp.float32), router_w,
+            preferred_element_type=jnp.float32,
+        )
+    )
+    top, idx = lax.top_k(scores, top_k)
+    return routed_scale * top / jnp.sum(top, axis=-1, keepdims=True), idx
+
+
+def _swiglu_rows(xg, gate_up, down, sizes, cdt):
+    """SwiGLU experts on rows grouped by expert: (R, h) -> (R, h) f32.
+    Rows past ``sum(sizes)`` belong to no expert; the caller masks them."""
+    ffn = down.shape[1]
+    hid = lax.ragged_dot(
+        xg.astype(cdt), gate_up.astype(cdt), sizes,
+        preferred_element_type=jnp.float32,
+    )
+    act = jax.nn.silu(hid[:, :ffn]) * hid[:, ffn:]
+    return lax.ragged_dot(
+        act.astype(cdt), down.astype(cdt), sizes,
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _chunk_rows(xg, gate_up, down, wgt, live, sizes, cdt):
+    """One chunk of sorted held slots, from their gathered tokens ``xg``:
+    run their experts and weight the rows.  ``live`` marks rows that are
+    slots (the last chunk's tail is not): a row outside every group is
+    whatever the grouped product left there, so it is zeroed."""
+    y = _swiglu_rows(xg, gate_up, down, sizes, cdt)
+    return jnp.where(live[:, None], y * wgt[:, None], 0.0)
+
+
+def _chunk_of(tok, wgt, offsets, n_held, c, rows):
+    """Chunk ``c``'s tokens, weights, live rows and group sizes."""
+    lo = c * rows
+    edges = jnp.clip(offsets, lo, lo + rows)
+    return (
+        lax.dynamic_slice_in_dim(tok, lo, rows),
+        lax.dynamic_slice_in_dim(wgt, lo, rows),
+        lo + jnp.arange(rows) < n_held,
+        edges[1:] - edges[:-1],
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _held_chunks(xt, gate_up, down, tok, wgt, offsets, n_held, rows, cdt):
+    """Sum over the held slots, ``rows`` at a time: ``out[tok[r]] +=
+    wgt[r] * E(xt[tok[r]])`` for the sorted slots r < n_held, whose
+    expert boundaries are ``offsets`` (held + 1,).  The static number of
+    chunks covers every slot being held; a chunk past ``n_held`` is
+    skipped (``lax.cond``), so time follows the slots there are and
+    memory one chunk.  Returns (out (T, h) f32, rows computed)."""
+    return _held_chunks_fwd(
+        xt, gate_up, down, tok, wgt, offsets, n_held, rows, cdt
+    )[0]
+
+
+def _held_chunks_fwd(xt, gate_up, down, tok, wgt, offsets, n_held, rows, cdt):
+    def chunk(carry, c):
+        def run(carry):
+            out, done = carry
+            tok_c, wgt_c, live, sizes = _chunk_of(
+                tok, wgt, offsets, n_held, c, rows
+            )
+            y = _chunk_rows(xt[tok_c], gate_up, down, wgt_c, live, sizes, cdt)
+            return out.at[tok_c].add(y), done + jnp.sum(sizes)
+
+        return lax.cond(c * rows < n_held, run, lambda carry: carry, carry), None
+
+    init = (jnp.zeros(xt.shape, jnp.float32), jnp.zeros((), jnp.int32))
+    carry, _ = lax.scan(chunk, init, jnp.arange(tok.shape[0] // rows))
+    return carry, (xt, gate_up, down, tok, wgt, offsets, n_held)
+
+
+def _held_chunks_bwd(rows, cdt, res, cts):
+    """The same walk backwards: a live chunk recomputes its rows and adds
+    its share to dxt, to the weights' gradients and to its slots'
+    weights; a skipped one passes the sums on untouched."""
+    xt, gate_up, down, tok, wgt, offsets, n_held = res
+    dout = cts[0]
+
+    def chunk(carry, c):
+        def run(carry):
+            dxt, dgu, ddown, dwgt = carry
+            tok_c, wgt_c, live, sizes = _chunk_of(
+                tok, wgt, offsets, n_held, c, rows
+            )
+            _, vjp = jax.vjp(
+                lambda xg, gu, dn, w: _chunk_rows(
+                    xg, gu, dn, w, live, sizes, cdt
+                ),
+                xt[tok_c], gate_up, down, wgt_c,
+            )
+            dxg, dgu_c, ddown_c, dw_c = vjp(dout[tok_c])
+            dxg = jnp.where(live[:, None], dxg, 0).astype(jnp.float32)
+            return (
+                dxt.at[tok_c].add(dxg), dgu + dgu_c, ddown + ddown_c,
+                lax.dynamic_update_slice_in_dim(
+                    dwgt, jnp.where(live, dw_c, 0.0), c * rows, 0
+                ),
+            )
+
+        return lax.cond(c * rows < n_held, run, lambda carry: carry, carry), None
+
+    init = (
+        jnp.zeros(xt.shape, jnp.float32), jnp.zeros_like(gate_up),
+        jnp.zeros_like(down), jnp.zeros_like(wgt),
+    )
+    (dxt, dgu, ddown, dwgt), _ = lax.scan(
+        chunk, init, jnp.arange(tok.shape[0] // rows)
+    )
+    return dxt.astype(xt.dtype), dgu, ddown, None, dwgt, None, None
+
+
+_held_chunks.defvjp(_held_chunks_fwd, _held_chunks_bwd)
+
+
+# a chunk of gathered slots: this many times the even share of the slots
+CHUNK_SHARE = 1.25
+
+
+def held_chunk_rows(slots: int, held: int, num_experts: int) -> int:
+    """Rows of one chunk of :func:`held_experts_ffn`: ``CHUNK_SHARE`` x
+    the slots an even router sends to ``held`` of ``num_experts``, in
+    whole 512-row tiles of the products, and no more than all slots."""
+    return min(
+        512 * math.ceil(CHUNK_SHARE * slots * held / num_experts / 512),
+        8 * math.ceil(slots / 8),
+    )
+
+
+def held_experts_ffn(
+    x: jax.Array,
+    params: Dict[str, jax.Array],
+    *,
+    experts_held: Tuple[int, int],
+    top_k: int,
+    routed_scale: float = 1.0,
+    chunk_rows: Optional[int] = None,
+    compute_dtype=jnp.float32,
+):
+    """The held experts' part of a sparse FFN (module header). ``x``:
+    (..., h), flattened to T tokens.  Returns ``(out, counters)``:
+    ``out`` has x's shape — add the shared expert and the residual
+    outside — and the counters are scalars of this call:
+    ``moe_slots_held`` (slots routed to held experts; T * top_k * held /
+    num_experts if the router is even), ``moe_load_max_over_mean`` (the
+    fullest held expert over their mean) and ``moe_slots_dropped``
+    (held slots that were not computed: 0, by construction).
+
+    Rows are processed ``chunk_rows`` at a time
+    (:func:`held_chunk_rows` where not given; tests pass small ones), in
+    as many chunks as hold all T * top_k slots."""
+    first, held = experts_held
+    num_experts = params["router_w"].shape[-1]
+    if held != params["experts_down"].shape[0]:
+        raise ValueError(
+            f"experts_held counts {held}, the weights hold "
+            f"{params['experts_down'].shape[0]}"
+        )
+    if not 0 <= first <= first + held <= num_experts:
+        raise ValueError(f"experts_held {experts_held} of {num_experts}")
+    orig_shape = x.shape
+    xt = x.reshape(-1, orig_shape[-1])
+    t = xt.shape[0]
+    slots = t * top_k
+
+    with jax.named_scope("moe.route"):
+        weights, experts = route_sigmoid(
+            xt, params["router_w"], top_k, routed_scale
+        )
+        local = experts.reshape(-1) - first  # (S,) token-major slots
+        key = jnp.where((local >= 0) & (local < held), local, held)
+        order = jnp.argsort(key, stable=True)  # held slots first, by expert
+        offsets = jnp.searchsorted(
+            key[order], jnp.arange(held + 1), side="left"
+        ).astype(jnp.int32)
+        n_held = offsets[-1]
+        tok = (order // top_k).astype(jnp.int32)
+        wgt = weights.reshape(-1)[order]
+
+    rows = chunk_rows or held_chunk_rows(slots, held, num_experts)
+    pad = -slots % rows
+    if pad:
+        tok = jnp.pad(tok, (0, pad))
+        wgt = jnp.pad(wgt, (0, pad))
+    with jax.named_scope("moe.experts"):
+        out, done = _held_chunks(
+            xt, params["experts_gate_up"], params["experts_down"], tok, wgt,
+            offsets, n_held, rows, compute_dtype,
+        )
+    loads = (offsets[1:] - offsets[:-1]).astype(jnp.float32)
+    counters = {
+        "moe_slots_held": n_held.astype(jnp.float32),
+        "moe_load_max_over_mean": jnp.max(loads) / jnp.maximum(
+            jnp.mean(loads), 1.0 / held
+        ),
+        "moe_slots_dropped": (n_held - done).astype(jnp.float32),
+    }
+    return out.reshape(orig_shape).astype(x.dtype), counters

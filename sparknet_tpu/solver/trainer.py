@@ -144,7 +144,8 @@ def make_train_step(
         specs = net.param_specs()
         lr_m, dec_m = mults_for_params(params, specs)
         update = make_update_fn(sp, lr_m, dec_m)
-        params, opt_state = update(params, grads, opt_state, it)
+        with jax.named_scope("optimizer"):
+            params, opt_state = update(params, grads, opt_state, it)
         return params, new_state, opt_state, metrics
 
     return train_step
@@ -254,6 +255,12 @@ class Solver:
         from ..supervise import records
 
         records.publish_progress(self)
+        # the newest step's metrics, readable through the telemetry
+        # registry (source "train_step"); floats are made on read only
+        from ..telemetry.registry import REGISTRY, LastStep
+
+        self.last_step = LastStep()
+        REGISTRY.register_source("train_step", self.last_step)
         # per-iteration phase attribution (telemetry/timeline.py): the
         # apps swap in an enabled Timeline under --trace /
         # SPARKNET_TIMELINE=1; the default NULL costs one falsy test
@@ -354,6 +361,7 @@ class Solver:
                     )
                 if tl.fence:
                     jax.block_until_ready(metrics)
+            self.last_step.metrics = metrics
             self.iter += 1
             if log_fn and self.sp.display:
                 self._push_loss(metrics)

@@ -155,6 +155,22 @@ class Gauge:
             return {"value": self.value, "max": self.max}
 
 
+class LastStep:
+    """The newest training step's metrics as a registry *source*: the
+    solver stores the step's device scalars here (an attribute store, no
+    sync on the step path) and ``snapshot()`` turns them into floats only
+    when somebody reads — a scrape, a benchmark reader, the periodic
+    ``telemetry:`` line.  That is how a model's counters (the decoder's
+    ``moe_slots_held``, ``moe_load_max_over_mean``, ``moe_slots_dropped``)
+    reach the registry."""
+
+    def __init__(self):
+        self.metrics: Dict[str, object] = {}
+
+    def snapshot(self) -> Dict[str, float]:
+        return {name: float(v) for name, v in self.metrics.items()}
+
+
 class NamedCounters:
     """Lock-protected name -> :class:`Counter` table.
 

@@ -299,3 +299,115 @@ def test_flash_dropout_mask_identical_fwd_bwd_on_hardware():
     # would sit at (or above) the keep-all error scale
     assert err_mask < 1e-3, err_mask
     assert err_keepall > 5 * err_mask, (err_mask, err_keepall)
+
+
+# ---------------------------------------------------------------------------
+# sliding window and grouped KV heads (the banded kernels)
+# ---------------------------------------------------------------------------
+
+def _grouped_qkv(seed, b, h, hkv, sq, sk, d=32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (
+        jax.random.normal(ks[0], (b, h, sq, d)),
+        jax.random.normal(ks[1], (b, hkv, sk, d)),
+        jax.random.normal(ks[2], (b, hkv, sk, d)),
+        jax.random.normal(ks[3], (b, h, sq, d)),
+    )
+
+
+_BANDED = {
+    # b, h, hkv, sq, sk, window, block_q, block_k, q_offset, kv_offset
+    "window_grouped_blocks_skipped": (1, 4, 2, 512, 512, 100, 64, 128, 0, 0),
+    "window_plain_heads": (1, 4, 4, 512, 512, 100, 64, 128, 0, 0),
+    "grouped_causal_no_window": (2, 8, 2, 512, 512, None, 128, 128, 0, 0),
+    "window_grouped_offsets_longer_keys": (1, 6, 2, 512, 1024, 200, 128, 128, 256, 0),
+    "window_odd_offsets_and_length": (1, 4, 2, 384, 384, 130, 64, 128, 5, 3),
+    "window_of_one_block_exactly": (1, 2, 1, 512, 512, 128, 128, 128, 0, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BANDED))
+def test_window_and_grouped_kernels_match_reference_fwd_and_grads(case):
+    """Forward and all three gradients of the banded kernels (interpret
+    mode) against ``mha_reference(window=...)``, at block sizes where whole
+    key blocks lie outside the window and are never visited."""
+    b, h, hkv, sq, sk, window, bq, bk, qo, ko = _BANDED[case]
+    q, k, v, ct = _grouped_qkv(0, b, h, hkv, sq, sk)
+    kw = dict(causal=True, window=window, q_offset=qo, kv_offset=ko)
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, block_q=bq, block_k=bk, interpret=True, **kw
+    )
+    plain = lambda q, k, v: mha_reference(q, k, v, **kw)
+    np.testing.assert_allclose(flash(q, k, v), plain(q, k, v), atol=2e-5, rtol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * ct), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(plain(*a) * ct), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=5e-5, rtol=5e-5)
+
+
+def test_window_grid_walks_the_band_not_the_axis():
+    """The last grid axis of a windowed call is as long as the band of key
+    blocks a q block can touch (exact for static offsets, the worst
+    alignment for traced ones), in dkv as in the forward."""
+    from sparknet_tpu.ops.attention import _band_steps
+
+    # S=8192 in blocks of 512, window 512: two key blocks a q block
+    assert _band_steps(16, 0, 512, 512, 16, 511, 0) == 2
+    assert _band_steps(16, 0, 512, 512, 16, 0, 511) == 2  # dkv's q blocks
+    assert _band_steps(16, None, 512, 512, 16, 511, 0) == 3  # traced offsets
+    assert _band_steps(16, 0, 512, 512, 16, None, 0) == 16  # causal, no window
+    q, k, v, _ = _grouped_qkv(1, 1, 4, 2, 256, 512)
+    traced = jax.jit(lambda qo, ko: flash_attention(
+        q, k, v, causal=True, window=96, block_q=64, block_k=128,
+        interpret=True, q_offset=qo, kv_offset=ko,
+    ))
+    for qo, ko in ((200, 0), (0, 0), (300, 37)):
+        want = mha_reference(
+            q, k, v, causal=True, window=96, q_offset=qo, kv_offset=ko
+        )
+        np.testing.assert_allclose(traced(qo, ko), want, atol=2e-5, rtol=2e-5)
+
+
+def test_window_none_is_todays_call_bit_for_bit():
+    """``window=None`` with equal head counts traces the plain kernels: the
+    same jaxpr as a call that does not name the argument, the banded call
+    not in it, and the same bits out."""
+    rng = np.random.default_rng(3)
+    q, k, v = rand_qkv(rng, sq=256, sk=256)
+    named = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=None, interpret=True, block_q=64, block_k=128
+    )
+    unnamed = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, interpret=True, block_q=64, block_k=128
+    )
+    text = str(jax.make_jaxpr(jax.grad(lambda *a: named(*a).sum(), (0, 1, 2)))(q, k, v))
+    assert text == str(
+        jax.make_jaxpr(jax.grad(lambda *a: unnamed(*a).sum(), (0, 1, 2)))(q, k, v)
+    )
+    assert "_flash_banded" not in text
+    banded = str(jax.make_jaxpr(lambda *a: flash_attention(
+        *a, causal=True, window=64, interpret=True, block_q=64, block_k=128
+    ))(q, k, v))
+    assert "_flash_banded" in banded
+    np.testing.assert_array_equal(named(q, k, v), unnamed(q, k, v))
+    np.testing.assert_array_equal(
+        mha_reference(q, k, v, causal=True, window=None),
+        mha_reference(q, k, v, causal=True),
+    )
+
+
+def test_window_and_grouping_refuse_what_they_do_not_do():
+    q, k, v, _ = _grouped_qkv(2, 1, 4, 2, 128, 128)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, window=16, interpret=True)
+    with pytest.raises(ValueError, match="causal"):
+        mha_reference(q, k, v, window=16)
+    with pytest.raises(ValueError, match="H_kv"):
+        mha_reference(q, k[:, :1].repeat(3, 1), v[:, :1].repeat(3, 1))
+    with pytest.raises(ValueError, match="fold"):
+        flash_attention(q, k, v, causal=True, fold=4, interpret=True)
+    with pytest.raises(NotImplementedError, match="dropout"):
+        flash_attention(
+            q, k, v, causal=True, window=16, interpret=True,
+            dropout_rate=0.1, dropout_rng=jax.random.PRNGKey(0),
+        )

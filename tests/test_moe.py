@@ -156,3 +156,86 @@ def test_moe_rejects_indivisible_experts():
             mesh=mesh, in_specs=(moe_pspecs(), P()), out_specs=P(),
             check_vma=False,
         )(params, x)
+
+
+# ---------------------------------------------------------------------------
+# held_experts_ffn: one chip's share of the experts, no token dropped
+# ---------------------------------------------------------------------------
+
+def _held_setup(first=4, held=4, experts=16, h=32, f=16, tokens=80, std=0.3):
+    from sparknet_tpu.parallel.moe import init_held_experts_params
+
+    p = init_held_experts_params(jax.random.PRNGKey(0), h, f, experts, held, std=std)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, tokens // 2, h))
+    return p, x, dict(experts_held=(first, held), top_k=2, routed_scale=2.5)
+
+
+def _held_plain(x, p, experts_held, top_k, routed_scale):
+    """Every held expert on every token, masked by the routing weights."""
+    first, held = experts_held
+    f = p["experts_down"].shape[1]
+    xt = x.reshape(-1, x.shape[-1])
+    scores = jax.nn.sigmoid(xt @ p["router_w"])
+    top, idx = jax.lax.top_k(scores, top_k)
+    w = routed_scale * top / top.sum(-1, keepdims=True)
+    out = jnp.zeros_like(xt)
+    for e in range(held):
+        hid = xt @ p["experts_gate_up"][e]
+        y = (jax.nn.silu(hid[:, :f]) * hid[:, f:]) @ p["experts_down"][e]
+        out = out + jnp.sum(jnp.where(idx == first + e, w, 0.0), -1)[:, None] * y
+    return out.reshape(x.shape)
+
+
+@pytest.mark.parametrize("chunk_rows", [None, 8, 16, 56])
+def test_held_experts_match_the_masked_dense_computation(chunk_rows):
+    """Output and the gradient of every input, whatever the chunking: one
+    chunk, chunks that split an expert's rows, a last chunk partly empty."""
+    from sparknet_tpu.parallel.moe import held_experts_ffn
+
+    p, x, kw = _held_setup()
+    with jax.default_matmul_precision("highest"):
+        fast = lambda x, p: held_experts_ffn(x, p, chunk_rows=chunk_rows, **kw)[0]
+        plain = lambda x, p: _held_plain(x, p, **kw)
+        np.testing.assert_allclose(fast(x, p), plain(x, p), atol=2e-5)
+        got = jax.grad(lambda x, p: jnp.sum(fast(x, p) ** 2), (0, 1))(x, p)
+        want = jax.grad(lambda x, p: jnp.sum(plain(x, p) ** 2), (0, 1))(x, p)
+    for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(g, w, atol=1e-5 * float(jnp.abs(w).max()) + 1e-7)
+
+
+@pytest.mark.parametrize("chunk_rows", [None, 16])
+def test_held_experts_drop_nothing_when_one_expert_takes_every_token(chunk_rows):
+    """A router forced to send every token's first choice to ONE held expert
+    (a Switch layer at capacity 1.25 would drop most of them): every slot
+    is computed, the counters say so, and the result is the plain one."""
+    from sparknet_tpu.parallel.moe import held_experts_ffn
+
+    p, x, kw = _held_setup()
+    x = jnp.abs(x)  # so that one router column can dominate for every token
+    router = p["router_w"] * 0.01
+    p = {**p, "router_w": router.at[:, 6].set(1.0)}  # expert 6 = held index 2
+    out, counters = held_experts_ffn(x, p, chunk_rows=chunk_rows, **kw)
+    tokens = x.shape[0] * x.shape[1]
+    assert float(counters["moe_slots_dropped"]) == 0.0
+    assert float(counters["moe_slots_held"]) >= tokens  # one slot a token at least
+    # the fullest held expert holds every token: at least tokens / (held / 4)
+    assert float(counters["moe_load_max_over_mean"]) >= 4 * tokens / float(
+        counters["moe_slots_held"]
+    ) - 1e-6
+    with jax.default_matmul_precision("highest"):
+        out, _ = held_experts_ffn(x, p, chunk_rows=chunk_rows, **kw)
+        np.testing.assert_allclose(out, _held_plain(x, p, **kw), atol=2e-5)
+
+
+def test_held_experts_counters_on_an_even_split_and_bad_shares():
+    from sparknet_tpu.parallel.moe import held_experts_ffn
+
+    p, x, kw = _held_setup(first=0, held=16)  # every expert is held
+    _, counters = held_experts_ffn(x, p, **kw)
+    assert float(counters["moe_slots_held"]) == 2 * 80  # all T * top_k slots
+    assert float(counters["moe_slots_dropped"]) == 0.0
+    assert float(counters["moe_load_max_over_mean"]) >= 1.0
+    with pytest.raises(ValueError, match="experts_held"):
+        held_experts_ffn(x, p, experts_held=(8, 16), top_k=2)
+    with pytest.raises(ValueError, match="weights hold"):
+        held_experts_ffn(x, p, experts_held=(0, 4), top_k=2)

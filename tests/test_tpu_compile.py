@@ -1,0 +1,93 @@
+"""The TPU's own compiler on the main path's kernels at real widths, for a
+chip that is described and not attached: interpret mode cannot refuse a
+block the tiling does not allow or a kernel that wants too much VMEM; this
+can, and costs no chip time.  Nothing runs, so nothing here is a time or a
+result.  Every such compile of the repository lives in this one file, and
+the topology is described inside a fixture (one process at a time may load
+the TPU's library: see the on-chip-measurement guide, section 2)."""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no compiler here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described chip cannot be read back from the
+    persistent cache without one; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+_ATTENTION = {
+    # heads, KV heads, sequence, head size, window, batch
+    "laguna_sliding_layer": (64, 8, 8192, 128, 512, 2),
+    "laguna_full_layer": (48, 8, 8192, 128, None, 2),
+    "bert_base_layer": (12, 12, 512, 64, None, 64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ATTENTION))
+def test_flash_kernels_compile_for_v5e_at_real_widths(case, one_chip, no_compile_cache):
+    from sparknet_tpu.ops.attention import flash_attention
+
+    heads, kv_heads, seq, d, window, batch = _ATTENTION[case]
+    causal = case != "bert_base_layer"
+    q = jax.ShapeDtypeStruct((batch, heads, seq, d), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((batch, kv_heads, seq, d), jnp.bfloat16, sharding=one_chip)
+
+    def grads(q, k, v):
+        out = lambda *a: flash_attention(*a, causal=causal, window=window)
+        return jax.grad(lambda *a: out(*a).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
+
+    text = jax.jit(grads).lower(q, kv, kv).compile().as_text()
+    for kernel in ("flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv"):
+        assert kernel in text, kernel
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_held_experts_compile_to_the_grouped_product_for_v5e(one_chip, no_compile_cache):
+    """One sparse layer's held experts at the cell's shapes: the products
+    are XLA's grouped ones, forward and backward."""
+    from sparknet_tpu.parallel.moe import held_experts_ffn
+
+    shape = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one_chip)
+    params = {
+        "router_w": shape((2048, 256), jnp.float32),
+        "experts_gate_up": shape((32, 2048, 1024), jnp.float32),
+        "experts_down": shape((32, 512, 2048), jnp.float32),
+    }
+
+    def grads(x, params):
+        routed = lambda x, p: held_experts_ffn(
+            x, p, experts_held=(0, 32), top_k=8, routed_scale=2.5,
+            compute_dtype=jnp.bfloat16,
+        )[0]
+        return jax.grad(lambda x, p: routed(x, p).astype(jnp.float32).sum(), (0, 1))(
+            x, params
+        )
+
+    text = jax.jit(grads).lower(shape((2, 8192, 2048), jnp.bfloat16), params).compile().as_text()
+    assert text.count("ragged-dot") >= 6  # gate+up and down, and both gradients of each
