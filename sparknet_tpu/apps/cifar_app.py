@@ -11,10 +11,11 @@ evaluation and snapshotting, and prints Caffe-style progress lines.
 from __future__ import annotations
 
 import argparse
+import functools
 import os
 import sys
 import time
-from typing import Dict, Iterator
+from typing import Dict, Iterator, Optional
 
 import jax
 import jax.numpy as jnp
@@ -162,12 +163,14 @@ def print_data_cache_line(log=print) -> None:
 
 def make_native_feed(
     ds, transformer: Transformer, batch_size: int, seed: int = 0,
-    workers: int = 0,
+    workers: int = 0, threads: Optional[int] = None,
 ):
     """Feed served by the C++ prefetching loader (sparknet_tpu.native):
     shuffle + crop/mirror/mean + batch assembly in native worker threads,
     Python only wraps the ready batch's buffer (lent until the last
-    reference to it dies, never copied). Falls back to :func:`make_feed`
+    reference to it dies, never copied). ``threads`` is the app's
+    ``--data-workers`` as given: N >= 1 loader threads, else a count from
+    the cores (``native.resolve_threads``). Falls back to :func:`make_feed`
     (which honours ``workers`` — the multiprocess python pipeline) when
     the library can't be built, or when the dataset won't fit the
     loader's in-RAM cache (it materialises every partition —
@@ -202,6 +205,7 @@ def make_native_feed(
         mean_channel=transformer.mean_values,
         scale=transformer.scale,
         seed=seed,
+        num_threads=threads,
     )
 
 
@@ -355,7 +359,10 @@ def build(args) -> tuple:
     feed_fn = (
         make_feed
         if getattr(args, "native_loader", "auto") == "off"
-        else make_native_feed  # auto/on: falls back if the lib won't build
+        # auto/on: falls back if the lib won't build
+        else functools.partial(
+            make_native_feed, threads=getattr(args, "data_workers", -1)
+        )
     )
     workers = resolve_feed_workers(args, nproc)
     train_feed = feed_fn(
@@ -684,8 +691,9 @@ def arg_parser() -> argparse.ArgumentParser:
                     help="C++ prefetching data loader: auto (default — "
                          "use it when the library builds), on, or off")
     ap.add_argument("--data-workers", type=int, default=-1,
-                    help="preprocessing worker processes for the train "
-                         "feed (-1 auto: SPARKNET_DATA_WORKERS or "
+                    help="preprocessing workers for the train feed: "
+                         "threads of the native loader, processes of the "
+                         "python feed (-1 auto: SPARKNET_DATA_WORKERS or "
                          "cpu-count aware; 0 serial). The batch stream "
                          "is bit-identical for any count")
     ap.add_argument("--data-format", choices=("auto", "packed"),

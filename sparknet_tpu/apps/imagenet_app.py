@@ -14,6 +14,7 @@ or across the mesh (``--parallel sync`` gradient all-reduce, or
 from __future__ import annotations
 
 import argparse
+import functools
 import os
 from typing import Dict, Iterator
 
@@ -245,7 +246,10 @@ def build(args):
     elif getattr(args, "native_loader", "auto") == "off":
         feed_fn = make_feed
     else:
-        feed_fn = make_native_feed  # auto/on: falls back if lib won't build
+        # auto/on: falls back if lib won't build
+        feed_fn = functools.partial(
+            make_native_feed, threads=getattr(args, "data_workers", -1)
+        )
     if feed_fn is make_device_feed:
         # device augmentation already cut the host work to shuffle +
         # memcpy — worker processes would only add transport cost
@@ -298,8 +302,9 @@ def parser() -> argparse.ArgumentParser:
                     help="C++ prefetching data loader: auto (default — "
                          "use it when the library builds), on, or off")
     ap.add_argument("--data-workers", type=int, default=-1,
-                    help="preprocessing worker processes for the train "
-                         "feed (-1 auto: SPARKNET_DATA_WORKERS or "
+                    help="preprocessing workers for the train feed: "
+                         "threads of the native loader, processes of the "
+                         "python feed (-1 auto: SPARKNET_DATA_WORKERS or "
                          "cpu-count aware; 0 serial). The batch stream "
                          "is bit-identical for any count")
     ap.add_argument("--data-format", choices=("auto", "packed"),

@@ -153,6 +153,8 @@ class PipelineMetrics:
         self.prefetch_hits = 0
         self.prefetch_misses = 0
         self.buffers_allocated = None  # a feed that reuses batch buffers sets it
+        self.threads = None  # a feed whose threads share a batch sets it
+        self.build_wall = LatencyHistogram()  # and a batch's latency
         self.produce = LatencyHistogram()
         self.worker_wait = LatencyHistogram()
         self.consumer_wait = LatencyHistogram()
@@ -186,6 +188,14 @@ class PipelineMetrics:
         loader's pool); every other batch was written into a used one."""
         with self._lock:
             self.buffers_allocated = allocated
+
+    def record_build(self, threads: int, wall_s: float) -> None:
+        """One batch built by ``threads`` threads together (the native
+        loader): ``wall_s`` from its start to its last image, beside
+        ``produce``, which is the threads' time inside it added up."""
+        with self._lock:
+            self.threads = threads
+            self.build_wall.observe(wall_s)
 
     def record_respawn(self) -> None:
         with self._lock:
@@ -241,6 +251,9 @@ class PipelineMetrics:
                     "allocated": self.buffers_allocated,
                     "reused_pct": round(100.0 * reused / max(self.batches, 1), 2),
                 }
+            if self.threads is not None:
+                snap["threads"] = self.threads
+                snap["build_wall"] = self.build_wall.snapshot()
             return snap
 
     def json_line(self) -> str:

@@ -36,13 +36,39 @@ _i64p = ctypes.POINTER(ctypes.c_int64)
 _u8p = ctypes.POINTER(ctypes.c_uint8)
 
 # the newest entry point: a library on disk without it predates this
-# source whatever its mtime says
-_NEWEST_SYMBOL = b"sn_buffer_release"
+# source whatever its mtime says.  ABI 4 (all threads on one batch; the
+# counters threads and build_wall_ns) brought no call of its own, so the
+# library exports its number as a name.
+_NEWEST_SYMBOL = b"sn_abi_4"
 # sn_loader_stats' order (Loader's enum in sparknet_data.cpp)
 STATS = (
     "batches_built", "build_ns", "put_wait_ns", "batches_taken",
     "get_wait_ns", "copy_ns", "depth_on_arrival", "buffers_allocated",
+    "threads", "build_wall_ns",
 )
+
+
+def default_threads() -> int:
+    """The loader's thread count where none is asked for: half the cores
+    this process may run on, at most 8.  The other half is the step loop's,
+    the staging thread's and the runtime's transfer threads'; past 8 the
+    batch's 633 MB of writes bound the build, not the threads (PERF.md §6,
+    PR 30's sweep)."""
+    return max(1, min(8, len(os.sched_getaffinity(0)) // 2))
+
+
+def resolve_threads(requested: Optional[int] = None) -> int:
+    """``--data-workers`` on the native path: N >= 1 is N loader threads;
+    auto (None or negative) is ``SPARKNET_DATA_WORKERS`` where that names
+    one, else :func:`default_threads`.  0, the python feed's "serial", has
+    no native meaning (the loader's threads are not forks) and takes the
+    core-derived count too.  The batch stream is the same at any count."""
+    if requested is not None and requested >= 1:
+        return requested
+    env = os.environ.get("SPARKNET_DATA_WORKERS", "").strip()
+    if (requested is None or requested < 0) and env and int(env) >= 1:
+        return int(env)
+    return default_threads()
 
 
 def _is_stale() -> bool:
@@ -114,6 +140,7 @@ def _load() -> Optional[ctypes.CDLL]:
             ]
             lib.sn_buffer_release.restype = None
             lib.sn_buffer_release.argtypes = [ctypes.c_void_p, ctypes.c_void_p]
+            getattr(lib, _NEWEST_SYMBOL.decode())
         except AttributeError as e:
             _why_not = f"{_LIB_PATH} is stale: {e}"
             return None
@@ -231,7 +258,9 @@ class NativeLoader:
     Yields {"data": f32 (B, crop, crop, C), "label": int32 (B,)} batches
     indefinitely (epochs wrap with a fresh deterministic shuffle). The
     full pipeline — shuffle, crop/mirror/mean, batch assembly — runs in
-    native worker threads ahead of the consumer.
+    native worker threads ahead of the consumer, all of them on one batch
+    at a time (``num_threads``: :func:`resolve_threads`; the stream is the
+    same at any count).
 
     **A batch's memory is yours while you hold it.**  ``data`` is not a
     copy: it is the buffer a worker wrote the batch into, lent by the
@@ -247,12 +276,14 @@ class NativeLoader:
 
     ``metrics`` is a :class:`~sparknet_tpu.data.pipeline.PipelineMetrics`
     (registry source ``native_loader``) fed at each ``__next__`` from the
-    deltas of the library's counters (:meth:`stats`): ``produce`` is a
-    worker's time to build a batch, ``worker_wait`` its wait for room
-    (back-pressure), ``consumer_wait`` the caller's wait for its batch,
-    ``reorder_depth`` the batches it found ready.  The same deltas go to
-    the current timeline as ``feed.loader_blocked``, ``feed.copy_out``
-    and ``feed.produce`` (telemetry/timeline.py).
+    deltas of the library's counters (:meth:`stats`): ``produce`` is the
+    threads' time inside a batch's pixel work added up, ``build_wall`` the
+    batch's latency from its start to its last image (``produce`` over it
+    is how many of the ``threads`` really worked), ``worker_wait`` their
+    wait for room (back-pressure), ``consumer_wait`` the caller's wait for
+    its batch, ``reorder_depth`` the batches it found ready.  The same
+    deltas go to the current timeline as ``feed.loader_blocked``,
+    ``feed.copy_out`` and ``feed.produce`` (telemetry/timeline.py).
     """
 
     def __init__(
@@ -268,7 +299,7 @@ class NativeLoader:
         mean_channel: Optional[np.ndarray] = None,
         scale: float = 1.0,
         seed: int = 0,
-        num_threads: int = 2,
+        num_threads: Optional[int] = None,
         queue_cap: int = 4,
     ):
         lib = _load()
@@ -290,7 +321,7 @@ class NativeLoader:
             _as_u8p(images), labels.ctypes.data_as(_i32p), n, h, w, c,
             batch_size, crop, int(train), int(mirror), _as_f32p(mi),
             _as_f32p(mc), ctypes.c_float(scale), ctypes.c_uint64(seed),
-            num_threads, queue_cap,
+            resolve_threads(num_threads), queue_cap,
         )
         if not self._handle:
             raise ValueError("sn_loader_create failed (check batch <= n)")
@@ -327,6 +358,9 @@ class NativeLoader:
             self.metrics.record_batch(
                 self.batch_size, 1e-9 * delta["build_ns"] / built,
                 1e-9 * delta["put_wait_ns"] / built,
+            )
+            self.metrics.record_build(
+                now["threads"], 1e-9 * delta["build_wall_ns"] / built
             )
         self.metrics.record_buffers(now["buffers_allocated"])
         tl = _timeline.current()

@@ -170,9 +170,10 @@ def test_loader_stats_are_monotone_and_count_the_batches_taken():
             now = ld.stats()
             assert all(now[k] >= before[k] for k in native.STATS), (before, now)
             assert now["batches_taken"] == taken
-            # nothing is handed out before it is built, and the workers
-            # run at most the window (4) plus one batch each ahead
-            assert taken <= now["batches_built"] <= taken + 4 + 2
+            # nothing is handed out before it is built, and the threads
+            # run at most the window (4) ahead: a batch is admitted before
+            # it is started
+            assert taken <= now["batches_built"] <= taken + 4
             before = now
         assert before["build_ns"] > 0 and before["copy_ns"] > 0
     finally:
@@ -299,10 +300,12 @@ def test_a_library_without_the_newest_symbol_is_stale(tmp_path, monkeypatch):
 _WINDOW = 4  # NativeLoader's default queue_cap
 
 
-def _most_buffers(threads, lent=2):
-    """The window, one batch in each worker's hands, and what the caller
-    holds: a loop that drops a batch as it takes the next holds two."""
-    return _WINDOW + threads + lent
+def _most_buffers(lent=2):
+    """The window (batches in build and ready ones together: a batch is
+    admitted before it takes a buffer), two to spare, and what the caller
+    holds: a loop that drops a batch as it takes the next holds two.
+    Whatever the thread count: the threads share a batch."""
+    return _WINDOW + 2 + lent
 
 
 def _plain_loader(threads, train=False, seed=11):
@@ -328,21 +331,22 @@ def _same_bytes(a, b):
 
 @pytest.mark.parametrize("train", [False, True])
 def test_streams_are_byte_identical_at_any_thread_count_as_buffers_cycle(train):
-    loaders = {t: _plain_loader(t, train=train) for t in (1, 2, 4)}
+    loaders = {t: _plain_loader(t, train=train) for t in (1, 2, 3, 4, 5, 8)}
     try:
-        for _ in range(3 * _most_buffers(4)):
+        for _ in range(3 * _most_buffers()):
             # compared and dropped as it goes, so every loader's buffers
             # come back and are written again
-            one, two, four = (next(ld) for ld, _ in loaders.values())
-            for other in (two, four):
+            one, *others = (next(ld) for ld, _ in loaders.values())
+            for other in others:
                 assert _same_bytes(one["label"], other["label"])
                 assert _same_bytes(one["data"], other["data"])
             if not train:
                 assert _same_bytes(one["data"], loaders[1][1][one["label"]])
-            del one, two, four, other
+            del one, others, other
         for threads, (ld, _) in loaders.items():
+            assert ld.stats()["threads"] == threads
             allocated = ld.stats()["buffers_allocated"]
-            assert 1 <= allocated <= _most_buffers(threads, lent=1), threads
+            assert 1 <= allocated <= _most_buffers(lent=1), threads
     finally:
         for ld, _ in loaders.values():
             ld.close()
@@ -355,7 +359,7 @@ def test_a_held_batch_and_a_view_of_a_dropped_one_are_never_rewritten():
         piece = next(ld)["data"][2:5, 1]  # its parent array is gone
         assert not piece.flags.owndata
         then = held["data"].copy(), piece.copy()
-        for _ in range(3 * _most_buffers(2)):
+        for _ in range(3 * _most_buffers()):
             next(ld)
         assert _same_bytes(held["data"], then[0])
         assert _same_bytes(piece, then[1])
@@ -391,18 +395,18 @@ def test_buffers_allocated_stops_growing_and_grows_by_what_is_held():
     assert "buffers_allocated" in native.STATS
     ld, _ = _plain_loader(2)
     try:
-        for _ in range(10 * _most_buffers(2)):
+        for _ in range(10 * _most_buffers()):
             next(ld)
         settled = ld.stats()["buffers_allocated"]
-        assert 1 <= settled <= _most_buffers(2)
+        assert 1 <= settled <= _most_buffers()
         held = [next(ld) for _ in range(5)]
-        for _ in range(3 * _most_buffers(2)):
+        for _ in range(3 * _most_buffers()):
             next(ld)
         grown = ld.stats()["buffers_allocated"]
         # five are out for good, and a sixth is on loan at any time
         assert len(held) < grown <= settled + len(held)
         del held
-        for _ in range(3 * _most_buffers(2)):
+        for _ in range(3 * _most_buffers()):
             next(ld)
         assert ld.stats()["buffers_allocated"] == grown  # they came back
     finally:
@@ -429,7 +433,7 @@ def test_staged_batches_keep_their_values_where_device_put_aliases_host_memory()
     feed = prefetch_to_device(staged_ld, size=2)
     in_flight = []  # staged batches held as a step holds them, with copies
     try:
-        for _ in range(3 * _most_buffers(2, lent=2 + 1 + 3)):
+        for _ in range(3 * _most_buffers(lent=2 + 1 + 3)):
             staged, plain = next(feed), next(plain_ld)
             assert isinstance(staged["data"], jax.Array)
             in_flight.append((staged, plain["data"].copy(), plain["label"]))
@@ -496,5 +500,335 @@ def test_buffers_come_back_from_other_threads_and_none_is_rewritten_under_a_read
     assert not wrong and checked[0] == taken and taken > 100
     # at most what the queue and the checkers held at once
     assert ld.stats()["buffers_allocated"] <= (
-        _most_buffers(4, lent=1) + handed.maxsize + len(threads)
+        _most_buffers(lent=1) + handed.maxsize + len(threads)
     )
+
+
+# ------------------------------------- an independent reference for the kernel
+#
+# The loader and transform_batch share the C++ Transform, so comparing one
+# with the other passes a kernel bug.  This is the same arithmetic written
+# again: the counter RNG in Python integers, the crop by plain indexing.
+
+_M64 = (1 << 64) - 1
+
+
+def _splitmix64(x):
+    x = (x + 0x9E3779B97F4A7C15) & _M64
+    x = ((x ^ (x >> 30)) * 0xBF58476D1CE4E5B9) & _M64
+    x = ((x ^ (x >> 27)) * 0x94D049BB133111EB) & _M64
+    return x ^ (x >> 31)
+
+
+def _rng_at(seed, a, b):
+    return _splitmix64(_splitmix64(seed ^ ((a * 0x9E3779B97F4A7C15) & _M64)) ^ b)
+
+
+def _perm_index(seed, n, epoch, i):
+    """The loader's shuffle: a 4-round Feistel network, cycle-walked."""
+    width = 2
+    while (1 << width) < n:
+        width += 2
+    half = width // 2
+    mask = (1 << half) - 1
+    k = _splitmix64(seed ^ (epoch + 1))
+    x = i
+    while True:
+        for r in range(4):
+            left, right = x >> half, x & mask
+            x = (right << half) | (left ^ (_splitmix64(right ^ ((k + r) & _M64)) & mask))
+        if x < n:
+            return x
+
+
+def _reference_image(img, rseed, *, crop, train, mirror, mean_image,
+                     mean_channel, scale):
+    h, w, _ = img.shape
+    ch, cw = crop or h, crop or w
+    off_h = off_w = 0
+    if crop and (h > ch or w > cw):
+        if train:
+            off_h = _rng_at(rseed, 1, 0) % (h - ch + 1)
+            off_w = _rng_at(rseed, 2, 0) % (w - cw + 1)
+        else:
+            off_h, off_w = (h - ch) // 2, (w - cw) // 2
+    out = img[off_h:off_h + ch, off_w:off_w + cw].astype(np.float32)
+    if mean_image is not None:
+        out = out - mean_image[off_h:off_h + ch, off_w:off_w + cw]
+    if mean_channel is not None:
+        out = out - mean_channel
+    if train and mirror and _rng_at(rseed, 3, 0) & 1:
+        out = out[:, ::-1]
+    return out * np.float32(scale)
+
+
+_SIZES = {  # name -> (h, w, crop): rows that leave vector tails of every length
+    "crop5": (8, 9, 5), "crop31": (40, 37, 31), "crop227of256": (256, 256, 227),
+    "whole7x6": (7, 6, 0),
+}
+
+
+@pytest.mark.parametrize("train", [True, False], ids=["train", "test"])
+@pytest.mark.parametrize("size", sorted(_SIZES))
+@pytest.mark.parametrize("c", [1, 3, 4])
+@pytest.mark.parametrize("scale", [1.0, 1 / 256], ids=["scale1", "scale256th"])
+@pytest.mark.parametrize("means", ["none", "image", "channel", "both"])
+@pytest.mark.parametrize("mirror", [False, True], ids=["plain", "mirror"])
+def test_the_kernel_equals_an_independent_numpy_reference(
+    mirror, means, scale, c, size, train
+):
+    h, w, crop = _SIZES[size]
+    n, batch, seed = 6, 2, 0x9E3779B97F4A7C15  # a seed with its top bit set
+    rng = np.random.default_rng(h * 1000 + c)
+    images = rng.integers(0, 256, (n, h, w, c), dtype=np.uint8)
+    labels = np.arange(n, dtype=np.int32)
+    kw = dict(
+        crop=crop, train=train, mirror=mirror, scale=scale,
+        mean_image=(
+            rng.normal(110.0, 20.0, (h, w, c)).astype(np.float32)
+            if means in ("image", "both") else None
+        ),
+        mean_channel=(
+            rng.normal(100.0, 10.0, (c,)).astype(np.float32)
+            if means in ("channel", "both") else None
+        ),
+    )
+    got = native.transform_batch(images, seed=seed, num_threads=3, **kw)
+    for i in range(n):
+        want = _reference_image(images[i], _rng_at(seed, 0xA5A5, i), **kw)
+        assert _same_bytes(got[i], want), i
+    ld = native.NativeLoader(
+        images, labels, batch, seed=seed, num_threads=3, **kw
+    )
+    try:
+        for index in range(4):  # n // batch = 3 an epoch: the fourth wraps
+            epoch, off = divmod(index, n // batch)
+            got = next(ld)
+            for j in range(batch):
+                at = off * batch + j
+                src = _perm_index(seed, n, epoch, at)
+                assert got["label"][j] == src
+                want = _reference_image(
+                    images[src], _rng_at(seed, epoch + 17, at), **kw
+                )
+                assert _same_bytes(got["data"][j], want), (index, j)
+    finally:
+        ld.close()
+
+
+def _pinned_loader(threads):
+    n, h, w, c = 40, 24, 20, 3
+    images = (
+        (np.arange(n * h * w * c, dtype=np.uint64) * 2654435761 >> 7) % 256
+    ).astype(np.uint8).reshape(n, h, w, c)
+    mean = (np.arange(h * w * c, dtype=np.float32) % 97 + 0.25).reshape(h, w, c)
+    return native.NativeLoader(
+        images, np.arange(n, dtype=np.int32), 8, crop=17, train=True,
+        mirror=True, mean_image=mean,
+        mean_channel=np.array([1.5, 2.25, 3.0], np.float32),
+        scale=1 / 256, seed=30, num_threads=threads,
+    )
+
+
+@pytest.mark.parametrize("threads", [1, 2, 5])
+def test_the_stream_is_the_one_pinned_before_the_threads_shared_a_batch(threads):
+    """sha256 of the first 8 batches (data, then labels) of this loader as
+    the library of commit b274f2d built them, one whole batch a worker, a
+    scalar pass an image (PR 29's tree; computed there at 1 and 2 threads)."""
+    import hashlib
+
+    ld = _pinned_loader(threads)
+    digest = hashlib.sha256()
+    try:
+        for _ in range(8):
+            b = next(ld)
+            digest.update(b["data"].tobytes())
+            digest.update(b["label"].tobytes())
+    finally:
+        ld.close()
+    assert digest.hexdigest() == (
+        "d2bf1259dd5f0891cab4e3822b2746f2d44d049986dac6c4b623210384d8c12c"
+    )
+
+
+# ------------------------------------------ all threads build the same batch
+#
+# A loader that deadlocks blocks inside C, where the conftest's alarm (a
+# signal, handled between bytecodes) never lands: each of these runs its
+# body on a thread and gives up on it after its own limit.
+
+def _within(seconds, body):
+    import threading
+
+    out = []
+
+    def run():
+        try:
+            out.append((body(), None))
+        except BaseException as e:  # handed to the test's own thread
+            out.append((None, e))
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(seconds)
+    assert not t.is_alive(), f"still running after {seconds} s"
+    result, error = out[0]
+    if error is not None:
+        raise error
+    return result
+
+
+@pytest.mark.parametrize("threads", [2, 3, 8])
+def test_the_threads_of_a_loader_work_on_one_batch_together(threads):
+    def body():
+        ld = _counted_loader(threads, side=232)  # a batch takes milliseconds
+        try:
+            for _ in range(24):
+                next(ld)
+            return ld.stats()
+        finally:
+            ld.close()
+
+    stats = _within(60, body)
+    assert stats["threads"] == threads
+    assert stats["build_wall_ns"] > 0
+    # thread time inside the pixel work over the batches' latency: how many
+    # threads really worked on a batch.  More than one did, and no more than
+    # there are
+    shared = stats["build_ns"] / stats["build_wall_ns"]
+    assert 1.0 < shared <= threads, (shared, stats)
+
+
+def test_one_thread_reads_as_one_thread_on_the_input_pipeline_line():
+    import json
+
+    def body():
+        ld = _counted_loader(1, side=120)
+        try:
+            for _ in range(12):
+                next(ld)
+        finally:
+            ld.close()
+        return ld.stats(), json.loads(ld.metrics.json_line())
+
+    stats, line = _within(60, body)
+    assert stats["threads"] == 1 and line["threads"] == 1
+    # one thread: a batch's latency is its pixel work and a little more
+    assert stats["build_ns"] <= stats["build_wall_ns"]
+    assert line["build_wall"]["count"] == stats["batches_built"]
+    assert line["build_wall"]["count"] == line["produce"]["count"]
+
+
+@pytest.mark.parametrize("threads", [2, 5])
+def test_a_slow_consumer_parks_every_thread_and_delivery_stays_in_order(threads):
+    import time
+
+    def body():
+        slow, _ = _plain_loader(threads, train=True)
+        ref, _ = _plain_loader(1, train=True)
+        try:
+            for index in range(3 * _most_buffers()):
+                if index in (3, 9):
+                    time.sleep(0.2)  # the window fills; the threads park
+                got, want = next(slow), next(ref)
+                assert _same_bytes(got["label"], want["label"]), index
+                assert _same_bytes(got["data"], want["data"]), index
+                del got, want
+            return slow.stats()
+        finally:
+            slow.close()
+            ref.close()
+
+    stats = _within(60, body)
+    # every thread parked for most of both sleeps
+    assert stats["put_wait_ns"] > 0.3e9 * threads, stats
+    assert stats["batches_built"] <= stats["batches_taken"] + _WINDOW
+
+
+@pytest.mark.parametrize("threads", [1, 4, 8])
+def test_close_in_the_middle_of_a_batch_returns_soon_and_leaks_no_buffer(threads):
+    import time
+
+    rng = np.random.default_rng(12)
+    images = rng.integers(0, 256, (64, 200, 200, 3)).astype(np.uint8)
+    labels = np.arange(64, dtype=np.int32)
+
+    def rss():
+        with open("/proc/self/statm") as fh:
+            return int(fh.read().split()[1]) * 4096
+
+    def body():
+        slowest = 0.0
+        for cycle in range(40):
+            # a batch of 64 x 192 x 192 x 3 floats is 28 MB and takes some
+            # milliseconds: the threads are inside it when close() comes
+            ld = native.NativeLoader(
+                images, labels, 64, crop=192, train=True, mirror=True,
+                seed=cycle, num_threads=threads, queue_cap=3,
+            )
+            if cycle % 2:
+                held = next(ld)  # a lent buffer outlives the loader
+            t0 = time.monotonic()
+            ld.close()
+            slowest = max(slowest, time.monotonic() - t0)
+            if cycle == 4:
+                settled = rss()  # the allocator's own growth is behind it
+        held = None
+        return slowest, rss() - settled
+
+    slowest, grew = _within(120, body)
+    assert slowest < 1.0, slowest
+    # 35 loaders since, up to three buffers each: a loader that kept its
+    # buffers would have left a gigabyte or more behind
+    assert grew < 200e6, grew
+
+
+# --------------------------------------------------------- the thread rule
+
+@pytest.mark.parametrize("cores", [1, 2, 4, 13, 64])
+def test_the_default_thread_count_is_a_function_of_the_cores(cores, monkeypatch):
+    import os
+
+    def count(k):
+        monkeypatch.setattr(os, "sched_getaffinity", lambda _pid: set(range(k)))
+        return native.default_threads()
+
+    monkeypatch.delenv("SPARKNET_DATA_WORKERS", raising=False)
+    got = count(cores)
+    assert 1 <= got <= cores
+    assert count(max(cores - 1, 1)) <= got <= count(cores + 1)  # monotone
+    count(cores)
+    assert native.resolve_threads(None) == native.resolve_threads(-1) == got
+    assert native.resolve_threads(0) == got  # "serial" is the python feed's
+    assert native.resolve_threads(3) == 3
+    monkeypatch.setenv("SPARKNET_DATA_WORKERS", "5")
+    assert native.resolve_threads(-1) == 5 and native.resolve_threads(3) == 3
+    ld = _counted_loader(None)  # NativeLoader's own default follows it
+    try:
+        assert ld.stats()["threads"] == 5
+    finally:
+        ld.close()
+
+
+@pytest.mark.parametrize("asked, threads", [(3, 3), (1, 1), (-1, None), (0, None)])
+def test_data_workers_reaches_the_native_loader_through_the_app(
+    asked, threads, monkeypatch
+):
+    """``make_native_feed`` used to drop its ``workers``: the default feed
+    ran two threads whatever ``--data-workers`` said."""
+    from sparknet_tpu.apps import imagenet_app
+
+    monkeypatch.delenv("SPARKNET_DATA_WORKERS", raising=False)
+    _, train_feed, _ = imagenet_app.build(
+        imagenet_app.make_args(
+            synthetic=True, synthetic_n=32, synthetic_classes=10,
+            batch_size=4, max_iter=2, native_loader="on", data_workers=asked,
+        )
+    )
+    try:
+        assert isinstance(train_feed, native.NativeLoader)
+        assert train_feed.stats()["threads"] == (
+            threads or native.default_threads()
+        )
+    finally:
+        train_feed.close()
