@@ -469,16 +469,5 @@ else
   exit 1
 fi
 
-# ---- bench trajectory diff (informational): compare the two newest
-# BENCH_*.json records' phase shares / throughput / wire bytes — the
-# first reader of the records PR 5/6 started embedding.  Never gates.
-bench_pair=$(ls -t BENCH_*.json 2>/dev/null | head -2)
-if [[ $(printf '%s\n' "$bench_pair" | sed '/^$/d' | wc -l) -eq 2 ]]; then
-  newest=$(printf '%s\n' "$bench_pair" | head -1)
-  prev=$(printf '%s\n' "$bench_pair" | tail -1)
-  echo "check.sh: bench diff $prev -> $newest (informational)"
-  python scripts/bench_diff.py "$prev" "$newest" --informational || true
-fi
-
 echo "check.sh: OK — no new failures ($(printf '%s\n' "$failures" | sed '/^$/d' | wc -l) known)"
 exit 0

@@ -25,13 +25,10 @@ The whole model lifecycle in one short CPU run:
    (zero bad-generation answers after rollback).
 
 Exit 0 on success; any assertion prints the evidence and exits 1.
-``--metrics-out PATH`` writes the measured numbers as JSON (the
-``BENCH_MODEL=closed_loop`` arm reads them back).
 """
 
 from __future__ import annotations
 
-import argparse
 import json
 import os
 import signal
@@ -91,11 +88,7 @@ def wait_for(pred, timeout_s, what, debug=None):
     raise SystemExit(f"closed-loop smoke: timed out waiting for {what}")
 
 
-def main(argv=None) -> int:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--metrics-out", default=None)
-    args = ap.parse_args(argv)
-
+def main() -> int:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     tmp = tempfile.mkdtemp(prefix="closed_loop_smoke_")
     deploy_dir = os.path.join(tmp, "deploy")
@@ -329,22 +322,6 @@ def main(argv=None) -> int:
             f"no replica reports rolled_back_from: {hz.get('replicas')}"
         )
 
-        metrics = {
-            "rollback_ms": round(float(rollback_ms), 2),
-            "deploy_failed_requests": failed,
-            "bad_gen_served_after_rollback": bad_answers,
-            "requests": requests,
-            "rolls": dep.get("rolls"),
-            "rollbacks": dep.get("rollbacks"),
-            "teed_samples": teed,
-            "fired_reason": fired,
-            "served_generations": sorted(
-                g for g in stats["gens"] if g is not None
-            ),
-        }
-        if args.metrics_out:
-            with open(args.metrics_out, "w") as fh:
-                json.dump(metrics, fh)
         print(
             "closed-loop smoke: OK — 0 failed requests across "
             f"{requests} reqs, {dep.get('rolls')} gated rolls, "

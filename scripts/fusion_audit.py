@@ -18,8 +18,8 @@ spans.  The audit:
    hide);
 3. reports per-phase shares plus ranked findings with the concrete
    fix each one grounds: fold host dispatches into the compiled step
-   (``SPARKNET_FUSED_STEP=1``, the ISSUE 12 solver fix — measured in
-   ``BENCH_MODEL=fusion``), donate/prefetch buffers for ``device_put``
+   (the base Solver's one step program does; ParallelSolver's sync
+   dispatch does not yet), donate/prefetch buffers for ``device_put``
    stalls, ``jax.remat`` / more data workers where input or memory
    dominates.
 
@@ -146,9 +146,9 @@ def findings(rec: Dict[str, Any], args) -> List[Dict[str, Any]]:
                 "host work between compiled regions (per-iteration "
                 "rng-split dispatch, scalar device_put of the step "
                 "counter, python bookkeeping): fold it into the step "
-                "— SPARKNET_FUSED_STEP=1 compiles split+increment "
-                "into the train program (BENCH_MODEL=fusion measures "
-                "the cut)"
+                "— the base Solver compiles split+increment into its "
+                "one step program; ParallelSolver's sync dispatch "
+                "still pays them"
             ),
         })
     put = rec["phases"].get("device_put")

@@ -10,9 +10,9 @@ The quantization story's load-bearing guarantees in one short CPU run:
    to a newer solverstate bumps the generation, ``/healthz`` and the
    ``/classify`` response both carry ``"quant": "int8"`` next to
    ``gen`` (the machine-checkable A/B surface);
-3. assert f32-vs-int8 **top-1 agreement >= 99.5%** on a fixed batch —
-   the <0.5% disagreement bar from the BENCH gate, held by the smoke
-   on every check run;
+3. assert f32-vs-int8 **top-1 agreement >= 99.5%** on a fixed batch
+   (the <0.5% disagreement bar) and int8's resident weight bytes at
+   most two thirds of f32's, on every check run;
 4. assert the **persistent compile cache cannot alias precisions**:
    the f32 and int8 fingerprints differ, each fingerprint-keyed cache
    directory exists and holds its own entries;
@@ -51,6 +51,7 @@ def main() -> int:
         enable_persistent_cache,
     )
     from sparknet_tpu.serve.engine import InferenceEngine
+    from sparknet_tpu.serve.quantize import tree_bytes
     from sparknet_tpu.solver import snapshot as snap
 
     tmp = tempfile.mkdtemp(prefix="quant_smoke_")
@@ -93,6 +94,12 @@ def main() -> int:
         f"int8 and f32 engines share a fingerprint "
         f"({f32.fingerprint}) — precision compile caches would alias"
     )
+    # resident weights: int8 holds about a quarter of f32's bytes plus
+    # the scale vectors; 1.5x is the floor a deployment is promised
+    held = tree_bytes(f32.params) / tree_bytes(int8.params)
+    assert held >= 1.5, (
+        f"int8 weights are only {held:.2f}x smaller than f32's"
+    )
     cc8 = enable_persistent_cache(cache_root, int8.fingerprint)
     int8.warmup()
     assert cc32["dir"] != cc8["dir"], (cc32, cc8)
@@ -131,6 +138,7 @@ def main() -> int:
             "quant smoke: OK — int8 tier hot-swapped to gen "
             f"{doc['generation']} (quant tag on healthz+classify), "
             f"top-1 agreement {agree:.3f} on {len(probe)} rows, "
+            f"weights {held:.2f}x smaller, "
             f"precision-distinct cache dirs "
             f"(f32 {e32} entries, int8 {e8} entries), "
             "no new ad-hoc clocks"

@@ -43,7 +43,7 @@ Design points:
 
 Counters (hit/miss/evict/torn/put) land on the PR 5 telemetry registry
 both as labeled ``data_cache`` counters and as the ``"data_cache"``
-snapshot source, so bench records and the periodic ``telemetry:`` line
+snapshot source, so run reports and the periodic ``telemetry:`` line
 carry them without extra wiring.  Imports are numpy + stdlib only
 (pipeline workers fork with a cache attached).
 """
@@ -109,7 +109,7 @@ class CacheMetrics:
     """Hit/miss/evict/torn counters, one JSON-able snapshot (the same
     discipline as ``PipelineMetrics``); registered as the telemetry
     registry's ``"data_cache"`` source AND mirrored into labeled
-    ``data_cache`` registry counters so scrapes and bench records see
+    ``data_cache`` registry counters so scrapes and run reports see
     the cache without extra plumbing."""
 
     def __init__(self):

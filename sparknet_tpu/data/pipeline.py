@@ -60,8 +60,8 @@ the batch stream:
   gauge/histogram primitives (``telemetry/registry.py``, where the
   serving metrics' primitives now live) to expose per-stage
   wait time (worker blocked on a free slot; consumer blocked waiting
-  for the next in-order batch) and queue occupancy, so ``bench.py`` and
-  the apps can report host-bound vs device-bound directly: a consumer
+  for the next in-order batch) and queue occupancy, so the apps can
+  report host-bound vs device-bound directly: a consumer
   that never waits is device-bound; one that always waits is
   host-bound.
 
@@ -126,7 +126,7 @@ def resolve_data_workers(requested: Optional[int]) -> int:
 
 class PipelineMetrics:
     """Input-pipeline observability, one JSON line (same discipline as
-    ``serve/metrics.py`` and bench records).
+    ``serve/metrics.py``).
 
     The host-vs-device question reads directly off two histograms:
     ``consumer_wait`` is how long the training loop sat waiting for the
@@ -162,7 +162,7 @@ class PipelineMetrics:
         self.reorder_depth = Gauge()  # batches parked awaiting their turn
         self.slots_free = Gauge()
         # the telemetry registry source: the periodic telemetry: line
-        # and bench records see the live feed without extra wiring
+        # and run reports see the live feed without extra wiring
         # (weakly held — dies with the pipeline/reader)
         REGISTRY.register_source(source_name, self)
 

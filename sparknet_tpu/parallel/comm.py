@@ -190,8 +190,8 @@ def plan_buckets(
 def bucket_histogram(
     plan: Sequence[Sequence[int]], leaves: Sequence[Any]
 ) -> dict:
-    """Bucket-size distribution for bench records: how well the bound
-    packs this model's tree."""
+    """Bucket-size distribution for the ``comm:`` record: how well the
+    bound packs this model's tree."""
     sizes = [
         sum(int(leaves[i].size) * jnp.dtype(leaves[i].dtype).itemsize
             for i in bucket)

@@ -91,10 +91,6 @@ class ParallelSolver(Solver):
         self.layout: Optional[partition_mod.Layout] = layout
         self._plan: Optional[partition_mod.Plan] = None
         super().__init__(solver, input_shapes, **kw)
-        # the parallel step builders below own their dispatch shape
-        # (sharded batches, explicit reduce programs): the base
-        # solver's fused host-dispatch wrapper must never shadow them
-        self._fuse_host = False
         if mesh is None:
             mesh = layout.mesh() if layout is not None else make_mesh()
         self.mesh = mesh
@@ -337,17 +333,6 @@ class ParallelSolver(Solver):
             )
         return super()._env_drift_message(key, saved, cur)
 
-    def scan_steps(self, batch, n: int):
-        """Not supported: the base implementation scans the
-        SINGLE-DEVICE train step, which would silently bypass this
-        solver's dp/local-SGD program (and local mode's per-worker
-        opt_state layout). Local-SGD rounds are already one compiled
-        scan over tau steps — bench parallel modes through step()."""
-        raise NotImplementedError(
-            "ParallelSolver.scan_steps: use step(); local-SGD rounds "
-            "already run as one compiled scan over tau iterations"
-        )
-
     # ------------------------------------------------------------------
     def _put_batch(self, batch, train: bool = True):
         """sync mode: jit's in_shardings place single-host batches; with
@@ -486,8 +471,8 @@ class ParallelSolver(Solver):
         )
 
     def comm_report(self) -> Dict[str, Any]:
-        """Machine-readable communication record for bench records and
-        run reports: the active config, the bucket plan over THIS
+        """Machine-readable communication record for the apps' ``comm:``
+        line and run reports: the active config, the bucket plan over THIS
         model's params, and the tau controller's decision log when one
         is driving."""
         leaves = jax.tree_util.tree_leaves(self.params)
@@ -510,6 +495,28 @@ class ParallelSolver(Solver):
         if self.tau_controller is not None:
             out["tau_controller"] = self.tau_controller.snapshot()
         return out
+
+    def _dispatch(self, batch):
+        """Sync mode's iteration: the mesh program built at
+        construction (``_train_step``, which a live reshard swaps)
+        takes the step's key and the counter from the host, so it costs
+        a split and a scalar placement more than the base's one
+        dispatch.  Local mode never comes here: it has its own
+        :meth:`step`."""
+        self.rng, step_rng = jax.random.split(self.rng)
+        self.params, self.state, self.opt_state, metrics = self._train_step(
+            self.params, self.state, self.opt_state, batch,
+            jnp.asarray(self.iter, jnp.int32), step_rng,
+        )
+        return metrics
+
+    def lower_step(self, batch):
+        """As :meth:`Solver.lower_step`, of sync mode's mesh program."""
+        return self._train_step.lower(
+            self.params, self.state, self.opt_state,
+            self._put_batch(batch), jnp.asarray(self.iter, jnp.int32),
+            self.rng,
+        )
 
     def step(self, batches: Iterator[Dict[str, Any]], n: int = 1, log_fn=None):
         if self.mode == "sync":

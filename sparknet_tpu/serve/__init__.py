@@ -13,15 +13,15 @@ engine behind a batched request queue:
   backpressure, graceful drain).
 - :class:`~sparknet_tpu.serve.metrics.ServeMetrics` — per-bucket
   counters, latency histograms, queue-depth / padding-waste gauges,
-  dumpable as one JSON line (bench.py record discipline).
+  dumpable as one JSON line.
 - :class:`~sparknet_tpu.serve.server.InferenceServer` /
   :class:`~sparknet_tpu.serve.server.Client` — stdlib HTTP front end
   (``/classify``, ``/healthz``, ``/metrics``) plus the in-process
   client tests and load generators drive.
 - :func:`~sparknet_tpu.serve.loadgen.run_loadgen` /
   :func:`~sparknet_tpu.serve.loadgen.run_http_loadgen` — offline and
-  over-the-wire closed-loop load generators (``serve --bench``), the
-  requests/s and p99 records BENCH tracks alongside training img/s.
+  over-the-wire closed-loop load generators (``serve --bench``): one
+  requests/s and p99 record a run.
 - :class:`~sparknet_tpu.serve.router.Router` — the production tier: a
   stateless front load-balancing ``/classify`` over N replica
   processes (spawned via ``supervise/pool.py``), peer-retrying a

@@ -27,8 +27,8 @@ Two admission policies (``mode=``):
   saturation (backlogged queue) the admitter drains straight to
   ``max_batch`` and is batch-for-batch identical to fill-then-flush
   (tests/test_serving_tier.py pins bit-equality); under mixed load it
-  dispatches early and p99 drops at the same offered rate
-  (``BENCH_MODEL=serving_tier`` measures it).
+  dispatches early (what that does to p99 at an equal offered rate has
+  no measurement: no cell of the benchmark serves yet, PERF.md §7).
 
 A single worker thread is deliberate: the engine serializes on one
 device anyway, and one consumer keeps request ordering FIFO.

@@ -4,7 +4,7 @@ Closed-loop: ``concurrency`` worker threads each keep exactly one
 request in flight (submit, wait, repeat), the standard serving-bench
 shape — throughput is governed by service latency rather than an
 open-loop arrival rate, so the requests/s number is reproducible and
-comparable across runs (the BENCH discipline: one JSON record out).
+comparable across runs (one JSON record out).
 
 Request sizes cycle through ``sizes`` so the bucket ladder is actually
 exercised (mixed 1-row and many-row requests, padding on the odd
@@ -27,9 +27,8 @@ from .metrics import ServeMetrics
 #: Decode-heavy open-loop preset (docs/SERVING.md "Batched decode"):
 #: interactive arrivals are multi-token session steps over a Zipf-hot
 #: population, so nearly every request is decode work and the batched
-#: step executable sees sustained multi-session occupancy.  Used by
-#: ``BENCH_MODEL=session_serving``'s batched arm and reusable by the
-#: autoscale spike scenarios.  The script follows
+#: step executable sees sustained multi-session occupancy.  Reusable
+#: by the autoscale spike scenarios.  The script follows
 #: :func:`sparknet_tpu.autoscale.traffic.parse_script` grammar: a warm
 #: flat lane, a 3x decode burst, a recovery lane.
 DECODE_HEAVY_SCRIPT = (
@@ -181,8 +180,7 @@ def run_http_loadgen(
     correlatable with this record: the trace ids of every **failed**
     and every **slower-than-p99** request ride the result dict
     (``failed_request_traces`` / ``slow_request_traces``) — a
-    ``BENCH_MODEL=serving_tier`` record can name the exact slow
-    requests it measured.
+    record can name the exact slow requests it measured.
 
     **Hot-session skew mode** (``sessions > 0``): instead of stateless
     ``/classify`` rows, every request is a session step — it draws a
@@ -332,9 +330,8 @@ def run_http_loadgen(
     snap = lat.snapshot()
     total_rows = sum(int(sizes[i % len(sizes)]) for i in range(n_requests))
     # exact (not histogram-bin-resolution) percentiles from the raw
-    # latency list: the reqtrace-overhead A/B in bench.py compares
-    # p50s at equal load, where the ~1.47x log-bin ladder is far too
-    # coarse to resolve a ≤2% bar
+    # latency list: an A/B at equal load compares p50s that the
+    # ~1.47x log-bin ladder is far too coarse to tell apart
     lats = sorted(s[2] for s in samples)
     p50_exact = lats[int(0.50 * (len(lats) - 1))] if lats else None
     p99_exact = lats[int(0.99 * (len(lats) - 1))] if lats else None

@@ -1,7 +1,7 @@
 """Serving observability: the serving-specific metrics registry.
 
-Same discipline as ``bench.py`` records and ``utils/profiling``'s
-StepTimer: everything is windowed against wall-clock and dumpable as
+Same discipline as ``utils/profiling``'s StepTimer: everything is
+windowed against wall-clock and dumpable as
 ONE JSON line, so a sweep log line or a ``/metrics.json`` scrape
 carries the whole serving picture — request/error counts, per-bucket
 batch counts and padding waste, p50/p95/p99 latencies, queue depth —
@@ -54,7 +54,7 @@ class ServeMetrics:
         self.shed = 0
         self.cancelled = 0
         # weight hot-swaps (serve/engine.py swap()): count + the newest
-        # generation served, so /metrics and bench records carry the
+        # generation served, so /metrics and loadgen records carry the
         # rolling-update story next to the latency story
         self.hot_swaps = 0
         self.generation = 0

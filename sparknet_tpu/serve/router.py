@@ -129,7 +129,7 @@ class Replica:
 class RouterMetrics:
     """Router-level counters — registered as the telemetry registry's
     ``"router"`` source, so ``/metrics`` (Prometheus), ``/metrics.json``
-    and bench records all see the tier without extra plumbing."""
+    and loadgen records all see the tier without extra plumbing."""
 
     def __init__(self):
         self._lock = threading.Lock()

@@ -269,30 +269,31 @@ class Solver:
         self._timeline = _timeline.NULL
         # average_loss display smoothing; deque(maxlen) evicts itself
         self._loss_window = deque(maxlen=max(1, solver.average_loss))
+        # ONE compiled program per iteration: the train step plus the
+        # host's per-iteration work (rng split, counter increment), so
+        # an iteration is one dispatch and nothing crosses from the
+        # host but the batch.  The traced function's name is the XLA
+        # module's (``jit_fused``): the benchmark's trace reduction and
+        # the persistent compile cache both key on it.  The compiler
+        # options are read here, once.
         kw = step_compile_kw()
-        self._train_step_fn = make_train_step(
+        train_step = make_train_step(
             self.train_net, solver, self.batch_transform
         )
-        self._train_step = jax.jit(
-            self._train_step_fn, donate_argnums=(0, 1, 2), **kw,
+
+        def fused(params, state, opt_state, batch, it, rng):
+            rng, step_rng = jax.random.split(rng)
+            params, state, opt_state, metrics = train_step(
+                params, state, opt_state, batch, it, step_rng
+            )
+            return params, state, opt_state, it + 1, rng, metrics
+
+        self._step_program = jax.jit(
+            fused, donate_argnums=(0, 1, 2, 4, 5), **kw
         )
         self._eval_step = jax.jit(make_eval_step(self.test_net), **kw)
-        self._scan_step_jits: Dict[int, Callable] = {}
-        # Audit-driven dispatch fusion (scripts/fusion_audit.py,
-        # BENCH_MODEL=fusion): the legacy loop issues two extra host
-        # dispatches per iteration — ``jax.random.split`` as its own
-        # compiled program, and a scalar device_put for the iteration
-        # counter.  The fused step folds both into the one compiled
-        # program (split is a deterministic function, so the rng
-        # stream — and therefore the trained weights — stays BITWISE
-        # identical; pinned by tests/test_fusion.py) and carries the
-        # counter on device.  ``SPARKNET_FUSED_STEP=0`` keeps the
-        # legacy shape reachable as the bench A/B baseline; the
-        # parallel step builders opt out (they own their dispatch).
-        self._fuse_host = os.environ.get(
-            "SPARKNET_FUSED_STEP", "1"
-        ) not in ("", "0")
-        self._fused_step: Optional[Callable] = None
+        # the iteration counter as the program carries it on the device;
+        # None until the first dispatch and after a restore
         self._it_dev = None
 
     @property
@@ -337,28 +338,7 @@ class Solver:
             with tl.phase("device_put"):
                 batch = self._put_batch(batch)
             with tl.phase("compiled_step"):
-                if self._fuse_host:
-                    if self._it_dev is None:
-                        self._it_dev = jnp.asarray(self.iter, jnp.int32)
-                    (
-                        self.params, self.state, self.opt_state,
-                        self._it_dev, self.rng, metrics,
-                    ) = self._ensure_fused_step()(
-                        self.params, self.state, self.opt_state,
-                        batch, self._it_dev, self.rng,
-                    )
-                else:
-                    self.rng, step_rng = jax.random.split(self.rng)
-                    self.params, self.state, self.opt_state, metrics = (
-                        self._train_step(
-                            self.params,
-                            self.state,
-                            self.opt_state,
-                            batch,
-                            jnp.asarray(self.iter, jnp.int32),
-                            step_rng,
-                        )
-                    )
+                metrics = self._dispatch(batch)
                 if tl.fence:
                     jax.block_until_ready(metrics)
             self.last_step.metrics = metrics
@@ -369,27 +349,22 @@ class Solver:
                     log_fn(self.iter, self._smoothed(metrics))
         return metrics
 
-    def _ensure_fused_step(self) -> Callable:
-        """The fused one-dispatch-per-iteration program, compiled
-        lazily: the base train step plus the per-iteration host work
-        (rng split, counter increment) inside the same XLA program.
-        The rng key and counter are donated — both are replaced every
-        call."""
-        if self._fused_step is None:
-            fn = self._train_step_fn
-
-            def fused(params, state, opt_state, batch, it, rng):
-                rng, step_rng = jax.random.split(rng)
-                params, state, opt_state, metrics = fn(
-                    params, state, opt_state, batch, it, step_rng
-                )
-                return params, state, opt_state, it + 1, rng, metrics
-
-            self._fused_step = jax.jit(
-                fused, donate_argnums=(0, 1, 2, 4, 5),
-                **step_compile_kw(),
-            )
-        return self._fused_step
+    def _dispatch(self, batch):
+        """Advance the solver by one iteration on a placed ``batch``
+        and return its metrics, not waited for: the one place that
+        replaces ``params/state/opt_state/rng`` and the device's
+        counter.  ParallelSolver's sync mode overrides it with its
+        mesh program."""
+        if self._it_dev is None:
+            self._it_dev = jnp.asarray(self.iter, jnp.int32)
+        (
+            self.params, self.state, self.opt_state,
+            self._it_dev, self.rng, metrics,
+        ) = self._step_program(
+            self.params, self.state, self.opt_state,
+            batch, self._it_dev, self.rng,
+        )
+        return metrics
 
     def lower_step(self, batch):
         """``jax.stages.Lowered`` of the program :meth:`step` dispatches
@@ -397,68 +372,11 @@ class Solver:
         which kernels the compiled step holds, e.g. that attention
         lowered to the Pallas ``tpu_custom_call`` and not to the
         reference path."""
-        batch = self._put_batch(batch)
-        it = jnp.asarray(self.iter, jnp.int32)
-        if self._fuse_host:
-            return self._ensure_fused_step().lower(
-                self.params, self.state, self.opt_state, batch, it, self.rng
-            )
-        return self._train_step.lower(
-            self.params, self.state, self.opt_state, batch, it, self.rng
+        return self._step_program.lower(
+            self.params, self.state, self.opt_state,
+            self._put_batch(batch), jnp.asarray(self.iter, jnp.int32),
+            self.rng,
         )
-
-    def scan_steps(self, batch, n: int):
-        """Run ``n`` train iterations on ONE resident batch inside a
-        single compiled dispatch (``lax.scan`` over the train step).
-
-        Benchmarking primitive: one dispatch for all ``n`` iterations
-        leaves the per-iteration host work (feed, placement, dispatch)
-        out of the timing, so the number is the device's share of a
-        step — not what a training run pays (ROADMAP D2 retires it once
-        the benchmark reads device time from a trace). Identical
-        per-iteration work to :meth:`step`
-        (one rng split + the full fwd/bwd/update); the rng stream
-        differs (split on device inside the scan rather than on host),
-        so this is for timing, not for bitwise-reproducible training.
-
-        Returns the LAST iteration's metrics (data-dependent on the
-        whole chain — a ``float()`` of any value fences all ``n``)."""
-        jit = self._scan_step_jits.get(n)
-        if jit is None:
-            def scanned(params, state, opt_state, batch, it0, rng0):
-                def body(carry, i):
-                    params, state, opt_state, rng = carry
-                    rng, step_rng = jax.random.split(rng)
-                    params, state, opt_state, metrics = self._train_step_fn(
-                        params, state, opt_state, batch, it0 + i, step_rng
-                    )
-                    return (params, state, opt_state, rng), metrics
-                (params, state, opt_state, _), ms = jax.lax.scan(
-                    body, (params, state, opt_state, rng0),
-                    jnp.arange(n, dtype=jnp.int32),
-                )
-                last = jax.tree_util.tree_map(lambda x: x[-1], ms)
-                return params, state, opt_state, last
-
-            jit = jax.jit(
-                scanned, donate_argnums=(0, 1, 2), **step_compile_kw()
-            )
-            self._scan_step_jits[n] = jit
-        if self.sp.iter_size > 1:
-            # mirror step()'s micro-batch stacking with iter_size copies
-            # of the one resident batch (same per-iteration work)
-            batch = jax.tree_util.tree_map(
-                lambda x: jnp.stack([x] * self.sp.iter_size), batch
-            )
-        batch = self._put_batch(batch)
-        self.rng, scan_rng = jax.random.split(self.rng)
-        self.params, self.state, self.opt_state, metrics = jit(
-            self.params, self.state, self.opt_state, batch,
-            jnp.asarray(self.iter, jnp.int32), scan_rng,
-        )
-        self.iter += n
-        self._it_dev = None  # scan advanced iter outside the fused step
-        return metrics
 
     def _push_loss(self, metrics) -> None:
         """Record this iteration's loss for ``average_loss`` smoothing
@@ -535,7 +453,7 @@ class Solver:
                 if msg:
                     print(f"WARNING: {msg}", file=sys.stderr, flush=True)
         self.iter = int(st["it"])
-        self._it_dev = None  # re-seed the fused step's device counter
+        self._it_dev = None  # the device's counter follows self.iter
         self.rng = jnp.asarray(st["rng"])
         self._loss_window.clear()  # a restarted Caffe starts empty
         if weights_only:

@@ -308,7 +308,7 @@ _self_publisher: Optional[RankPublisher] = None
 def init_aggregator() -> ClusterAggregator:
     """Create (idempotently) the process's cluster aggregator — called
     by the heartbeat server on rank 0, or by tests directly.  Registers
-    as the registry source ``cluster`` so snapshots/bench records carry
+    as the registry source ``cluster`` so snapshots and run reports carry
     the merged view."""
     global _aggregator, _self_publisher
     with _lock:

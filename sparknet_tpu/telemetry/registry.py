@@ -4,7 +4,7 @@ This is the ONE home of the repo's counting primitives.  They began
 life in ``serve/metrics.py`` and were then imported (or re-implemented
 as little name->Counter tables) by the data pipeline, the chaos
 registry and the supervisor — four subsystems, four bolted-on JSON
-print lines, no single place a scrape or a bench record could read the
+print lines, no single place a scrape or a run report could read the
 whole process.  The move here keeps every old import working
 (``serve.metrics`` re-exports) and adds what the copies never had:
 

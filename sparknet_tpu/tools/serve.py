@@ -30,7 +30,7 @@ Weights may be a ``.caffemodel``, a ``.npz`` WeightCollection, or a
 full ``.solverstate.npz`` training snapshot (params + BN stats are
 extracted). ``--bench N`` skips the HTTP server and instead runs the
 offline closed-loop load generator for N requests, printing one
-bench.py-style JSON record — the serving twin of training img/s.
+JSON record — the serving twin of training img/s.
 """
 
 from __future__ import annotations

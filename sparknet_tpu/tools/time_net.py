@@ -34,7 +34,7 @@ def synth_batch(shapes):
 
 
 def time_solver(solver, shapes, iters: int = 50, warmup: int = 3):
-    from ..utils.profiling import compiled_flops, device_peak_flops
+    from ..utils.profiling import cost_numbers, device_peak_flops
 
     batch = synth_batch(shapes)
 
@@ -68,10 +68,7 @@ def time_solver(solver, shapes, iters: int = 50, warmup: int = 3):
         flops_batch = jax.tree_util.tree_map(
             lambda x: jnp.stack([x] * iter_size), batch
         )
-    flops = compiled_flops(
-        solver._train_step, solver.params, solver.state, solver.opt_state,
-        flops_batch, jnp.asarray(0, jnp.int32), jax.random.PRNGKey(0),
-    )
+    flops = cost_numbers(solver.lower_step(flops_batch).compile())[0]
     peak = device_peak_flops()
     items_per_step = shapes["data"][0] * iter_size
     out = {
@@ -88,38 +85,19 @@ def time_solver(solver, shapes, iters: int = 50, warmup: int = 3):
     return out
 
 
-def time_per_layer(net, params, state, batch, iters: int = 10,
-                   scan_iters: int = 0):
+def time_per_layer(net, params, state, batch, iters: int = 10):
     """Per-layer forward/backward timings, like ``caffe time``'s layer
     table: each layer's ``apply`` is jitted and timed in isolation on
     its real input blobs (captured from one full forward), and its
-    backward as the VJP w.r.t. inputs+params at the same point.
-
-    ``scan_iters > 0`` amortises per-dispatch host time: the layer runs
-    ``scan_iters`` times inside ONE jitted ``lax.scan`` dispatch, so a
-    layer shorter than one dispatch still yields a per-iteration
-    number. A tiny data-dependent
-    carry (sum(outputs) * 1e-38 added to the float inputs) threads the
-    iterations so XLA can neither hoist the layer out of the loop nor
-    dead-code-eliminate its outputs.
-
-    That harness is not free: each iteration pays a carry-add pass over
-    every float input, plus the float32 reduction over the outputs
-    (fwd) / over every gradient leaf INCLUDING the large param grads
-    (bwd) — a real bias for bandwidth-bound layers. So each scanned
-    row also measures a carry-only BASELINE scan (the same carry-add +
-    reduction passes over same-shaped arrays, with the layer itself
-    removed) and subtracts it, clamped at zero. The
-    baseline approximates the harness overhead to within a memory pass
-    (it reduces where the real body writes), so corrected ms are
-    estimates good to roughly one pass over the layer's operands; a
-    0.000 entry means the layer timed at or below the harness floor."""
+    backward as the VJP w.r.t. inputs+params at the same point.  Each
+    number is ``iters`` dispatches fenced once, so it includes the
+    host's dispatch; the device's own share of a step is in the
+    benchmark's trace (PERF.md §3)."""
     from ..nets.layers import DATA_LAYER_TYPES, LAYER_IMPLS, ApplyCtx
-    from jax import lax
+    from ..utils.profiling import cost_numbers
 
     blobs = dict(batch)
     rows = []
-    baseline_cache: dict = {}
     for li, lp in enumerate(net.layers):
         if lp.type in DATA_LAYER_TYPES:
             continue
@@ -139,98 +117,33 @@ def time_per_layer(net, params, state, batch, iters: int = 10,
             outs, _ = impl.apply(lp, p_, st, inputs_, ctx)
             return outs
 
-        fidx_all = [
-            i for i, x in enumerate(inputs)
-            if jnp.issubdtype(x.dtype, jnp.floating)
-        ]
-
-        def _scan_time(run_once, n):
-            """ms/iter for ``carry -> carry`` run inside one scanned jit
-            dispatch (n iterations, one round-trip)."""
-            def scanned(c0):
-                def body(c, _):
-                    return run_once(c), None
-                c, _ = lax.scan(body, c0, None, length=n)
-                return c
-            jf = jax.jit(scanned).lower(jnp.float32(0.0)).compile()
-            jax.block_until_ready(jf(jnp.float32(0.0)))  # warm
-            t0 = time.perf_counter()
-            jax.block_until_ready(jf(jnp.float32(0.0)))
-            return 1000 * (time.perf_counter() - t0) / n
-
-        def _harness_ms(arrays, n):
-            """ms/iter of the scan harness alone: the carry-add + f32
-            reduction pass over ``arrays`` (same shapes/dtypes the real
-            body touches) with the layer removed — subtracted from the
-            scanned measurement. The carry-add keeps every pass
-            data-dependent so XLA cannot hoist it. Cached by shape
-            signature: repeated layer geometries (ReLU/pool stacks)
-            share one baseline compile."""
-            key = (
-                n,
-                tuple(
-                    sorted(
-                        (tuple(a.shape), str(a.dtype)) for a in arrays
-                    )
-                ),
-            )
-            if key not in baseline_cache:
-                def base_once(carry, arrays=tuple(arrays)):
-                    s = jnp.float32(0.0)
-                    for a in arrays:
-                        s = s + jnp.sum(
-                            (a + carry.astype(a.dtype)).astype(jnp.float32)
-                        )
-                    return s * jnp.float32(1e-38)
-
-                baseline_cache[key] = _scan_time(base_once, n)
-            return baseline_cache[key]
-
         # compile ONCE (AOT) and use the executable for both the timing
         # loop and cost analysis
         jfwd = jax.jit(fwd).lower(p, inputs).compile()
         outs = jfwd(p, inputs)
         jax.block_until_ready(outs)
-        fwd_scanned = bool(scan_iters and fidx_all and outs)
-        if fwd_scanned:
-            def fwd_once(carry):
-                inputs_ = list(inputs)
-                for i in fidx_all:
-                    inputs_[i] = inputs[i] + carry.astype(inputs[i].dtype)
-                outs_ = fwd(p, inputs_)
-                s = sum(jnp.sum(o.astype(jnp.float32)) for o in outs_)
-                return s * jnp.float32(1e-38)
-            fwd_raw = _scan_time(fwd_once, scan_iters)
-            fwd_ms = max(
-                fwd_raw
-                - _harness_ms(
-                    [inputs[i] for i in fidx_all] + list(outs), scan_iters
-                ),
-                0.0,
-            )
-        else:
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                outs = jfwd(p, inputs)
-            jax.block_until_ready(outs)
-            fwd_ms = 1000 * (time.perf_counter() - t0) / iters
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            outs = jfwd(p, inputs)
+        jax.block_until_ready(outs)
+        fwd_ms = 1000 * (time.perf_counter() - t0) / iters
 
         # cost analysis separates compute-bound from HBM-bound layers:
         # arithmetic intensity = FLOPs / bytes accessed (a layer far
         # below the device's FLOP:byte ratio is bandwidth-limited no
         # matter how its math is written)
-        from ..utils.profiling import cost_numbers
-
         f, by = cost_numbers(jfwd)
         gflop = f / 1e9 if f else None
         gbyte = by / 1e9 if by else None
 
         bwd_ms = None
-        bwd_scanned = False
         # float outputs only: losses/metrics and feature maps; index
         # outputs (ArgMax) and no-output layers (Silence) have no VJP
         if outs and all(jnp.issubdtype(o.dtype, jnp.floating) for o in outs):
-            fidx = fidx_all
+            fidx = [
+                i for i, x in enumerate(inputs)
+                if jnp.issubdtype(x.dtype, jnp.floating)
+            ]
 
             def scalar(p_, finputs):
                 full = list(inputs)
@@ -240,46 +153,17 @@ def time_per_layer(net, params, state, batch, iters: int = 10,
                 return sum(jnp.sum(o.astype(jnp.float32)) for o in outs_)
 
             if p or fidx:
-                grad_fn = jax.grad(scalar, argnums=(0, 1))
-                bwd_scanned = bool(scan_iters and fidx)
-                if bwd_scanned:
-                    def bwd_once(carry):
-                        finputs_ = [
-                            inputs[i] + carry.astype(inputs[i].dtype)
-                            for i in fidx
-                        ]
-                        g_ = grad_fn(p, finputs_)
-                        s = sum(
-                            jnp.sum(leaf.astype(jnp.float32))
-                            for leaf in jax.tree_util.tree_leaves(g_)
-                        )
-                        return s * jnp.float32(1e-38)
-                    bwd_raw = _scan_time(bwd_once, scan_iters)
-                    # bwd grad leaves are param-shaped + input-shaped:
-                    # baseline over params + inputs matches the
-                    # reduction the real body pays over them
-                    bwd_ms = max(
-                        bwd_raw
-                        - _harness_ms(
-                            [inputs[i] for i in fidx]
-                            + list(jax.tree_util.tree_leaves(p)),
-                            scan_iters,
-                        ),
-                        0.0,
-                    )
-                else:
-                    jbwd = jax.jit(grad_fn)
-                    finputs = [inputs[i] for i in fidx]
+                jbwd = jax.jit(jax.grad(scalar, argnums=(0, 1)))
+                finputs = [inputs[i] for i in fidx]
+                g = jbwd(p, finputs)
+                jax.block_until_ready(g)
+                t0 = time.perf_counter()
+                for _ in range(iters):
                     g = jbwd(p, finputs)
-                    jax.block_until_ready(g)
-                    t0 = time.perf_counter()
-                    for _ in range(iters):
-                        g = jbwd(p, finputs)
-                    jax.block_until_ready(g)
-                    bwd_ms = 1000 * (time.perf_counter() - t0) / iters
+                jax.block_until_ready(g)
+                bwd_ms = 1000 * (time.perf_counter() - t0) / iters
 
-        rows.append((lp.name, lp.type, fwd_ms, bwd_ms, gflop, gbyte,
-                     fwd_scanned, bwd_scanned))
+        rows.append((lp.name, lp.type, fwd_ms, bwd_ms, gflop, gbyte))
         for top, out in zip(lp.top, outs):
             blobs[top] = out
     return rows
@@ -299,10 +183,6 @@ def main(argv=None):
     ap.add_argument("--per-layer", action="store_true",
                     help="also print per-layer forward/backward ms "
                          "(caffe time's layer table)")
-    ap.add_argument("--scan", type=int, default=0, metavar="N",
-                    help="per-layer mode: run each layer N times inside "
-                         "ONE scanned jit dispatch so per-dispatch "
-                         "host time amortises")
     args = ap.parse_args(argv)
 
     sp = caffe_pb.load_solver(args.solver)
@@ -336,35 +216,25 @@ def main(argv=None):
         batch = synth_batch(shapes)
         rows = time_per_layer(
             solver.train_net, solver.params, solver.state, batch,
-            iters=max(3, args.iters // 5), scan_iters=args.scan,
+            iters=max(3, args.iters // 5),
         )
         print(f"{'layer':<28}{'type':<22}{'fwd ms':>10}{'bwd ms':>10}"
               f"{'GFLOP':>9}{'GB':>8}{'F/B':>7}")
-        fell_back = False
-        for name, ltype, fwd_ms, bwd_ms, gflop, gbyte, fsc, bsc in rows:
-            # '*' marks a dispatch-per-iteration fallback row when --scan
-            # was requested (int-only inputs etc.): its ms include the
-            # remote round-trip latency the scanned rows amortise away
-            fmark = "*" if args.scan and not fsc else ""
-            f = f"{fwd_ms:.3f}{fmark}"
-            bmark = "*" if args.scan and bwd_ms is not None and not bsc else ""
-            b = f"{bwd_ms:.3f}{bmark}" if bwd_ms is not None else "-"
-            fell_back = fell_back or bool(fmark or bmark)
+        for name, ltype, fwd_ms, bwd_ms, gflop, gbyte in rows:
+            f = f"{fwd_ms:.3f}"
+            b = f"{bwd_ms:.3f}" if bwd_ms is not None else "-"
             gf = f"{gflop:.2f}" if gflop is not None else "-"
             gb = f"{gbyte:.3f}" if gbyte is not None else "-"
             ai = (f"{gflop / gbyte:.0f}"
                   if gflop is not None and gbyte else "-")
             print(f"{name:<28}{ltype:<22}{f:>10}{b:>10}"
                   f"{gf:>9}{gb:>8}{ai:>7}")
-        if fell_back:
-            print("(*) not scan-amortised — includes per-dispatch latency")
         out["per_layer"] = [
             {"layer": n, "type": t, "forward_ms": round(f, 3),
              "backward_ms": None if b is None else round(b, 3),
              "gflop": None if gf is None else round(gf, 3),
-             "gbytes": None if gb is None else round(gb, 4),
-             **({"scanned": {"fwd": fsc, "bwd": bsc}} if args.scan else {})}
-            for n, t, f, b, gf, gb, fsc, bsc in rows
+             "gbytes": None if gb is None else round(gb, 4)}
+            for n, t, f, b, gf, gb in rows
         ]
     return out
 

@@ -8,7 +8,7 @@ path made from a temporary name, a pid or the time: a cache that moves
 between runs never hits.
 
 Every entry point (the apps, ``tools/caffe``, ``tools/serve``,
-``serve/replica``, ``deploy/trainer``, ``bench.py``, ``chip_smoke.py``)
+``serve/replica``, ``deploy/trainer``, ``chip_smoke.py``)
 calls :func:`enable` first thing in ``main``; importing jax and updating
 its config touches no device, so a parent that must stay off the chip
 (the supervisor, the router) may call it too.

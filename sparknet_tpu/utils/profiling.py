@@ -12,8 +12,8 @@ exposed here:
   Chrome JSON (``telemetry.finish_run``).
 - :class:`StepTimer` — windowed step-time / items-per-second / MFU
   meter for app training loops (items = images or tokens).
-- :func:`compiled_flops` — actual per-execution FLOPs of a lowered
-  jitted function from XLA cost analysis (the bench.py MFU numerator).
+- :func:`cost_numbers` — FLOPs and bytes of a compiled program from
+  XLA cost analysis (``tools/time_net``'s MFU numerator).
 
 This module answers *op-level* questions (what XLA did inside a
 dispatch).  Host-side observability — metrics registry, span tracing,
@@ -75,16 +75,6 @@ def cost_numbers(compiled) -> tuple:
         return (f if f > 0 else None, b if b > 0 else None)
     except Exception:
         return (None, None)
-
-
-def compiled_flops(jitted, *args, **kwargs) -> Optional[float]:
-    """FLOPs per execution of ``jitted(*args)`` per XLA cost analysis;
-    None when the backend doesn't report."""
-    try:
-        compiled = jitted.lower(*args, **kwargs).compile()
-    except Exception:
-        return None
-    return cost_numbers(compiled)[0]
 
 
 def sparknet_anchor(x):

@@ -34,12 +34,12 @@ import importlib, jax
 for name in ("sparknet_tpu.apps.imagenet_app", "sparknet_tpu.apps.cifar_app",
              "sparknet_tpu.apps.bert_app", "sparknet_tpu.tools.caffe",
              "sparknet_tpu.tools.serve", "sparknet_tpu.serve.replica",
-             "sparknet_tpu.deploy.trainer", "bench"):
+             "sparknet_tpu.deploy.trainer"):
     if RESET:  # so that each entry point has to set it again
         jax.config.update("jax_compilation_cache_dir", None)
     main = importlib.import_module(name).main
     try:
-        main() if name == "bench" else main(["--help"])
+        main(["--help"])
     except SystemExit:
         pass
     print("RESULT", name, jax.config.jax_compilation_cache_dir)
@@ -67,7 +67,7 @@ def test_env_variable_places_the_cache_everywhere(tmp_path):
     lines = _results(out)
     # no entry point moves jax's directory off the variable's, and the
     # serving cache skips its per-net subdirectory as well
-    assert [l[1] for l in lines[:-1]] == [placed] * 8, lines
+    assert [l[1] for l in lines[:-1]] == [placed] * 7, lines
     assert lines[-1][1:] == [placed, placed]
 
 
@@ -80,14 +80,14 @@ def test_unset_resolves_to_the_checkout_cache(tmp_path):
     assert out.returncode == 0, out.stderr[-2000:]
     lines = _results(out)
     default = os.path.join(_ROOT, ".jax_cache")
-    assert [l[1] for l in lines[:-1]] == [default] * 8, lines
+    assert [l[1] for l in lines[:-1]] == [default] * 7, lines
     # an operator's --compile-cache root keeps its per-net subdirectory
     assert lines[-1][1:] == [os.path.join(root, "f00d")] * 2
 
 
 def test_one_place_sets_the_cache_directory():
     hits = []
-    paths = [os.path.join(_ROOT, "bench.py"), os.path.join(_ROOT, "chip_smoke.py")]
+    paths = [os.path.join(_ROOT, "chip_smoke.py")]
     for base, _dirs, files in os.walk(os.path.join(_ROOT, "sparknet_tpu")):
         paths += [os.path.join(base, f) for f in files if f.endswith(".py")]
     for path in paths:
@@ -161,15 +161,6 @@ def test_router_refuses_the_deploy_loop_on_a_tpu_host(monkeypatch, capsys):
             "--deploy-train-net", "t.prototxt",
         ])
     assert "one process per chip" in capsys.readouterr().err
-
-
-def test_bench_child_replica_arms_refuse_on_a_tpu():
-    import bench
-
-    for arm in (bench.bench_serving_tier, bench.bench_session_serving,
-                bench.bench_closed_loop):
-        with pytest.raises(RuntimeError, match="one process per chip"):
-            arm("tpu")
 
 
 # ------------------------------------------------- nothing hides the device

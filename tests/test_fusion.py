@@ -1,16 +1,25 @@
-"""Dispatch fusion (ISSUE 12): the fused train step — rng split +
-iteration counter folded into the compiled program — must be BITWISE
-identical to the legacy three-dispatch loop, survive restore, stay
-out of the parallel solvers' way, and the trace-driven audit
-(scripts/fusion_audit.py) must find the gaps that ground it."""
+"""The Solver's one step program: an iteration is one compiled
+dispatch (train step, rng split and iteration counter in one XLA
+program, ``jit_fused``), ``step(feed, n)`` is n of them, ``lower_step``
+lowers that same program, a restore re-seeds the device's counter, and
+ParallelSolver's sync mode overrides the dispatch with its mesh program
+(host split, scalar counter) — which stays the oracle for the rng
+stream.  And the trace-driven audit (scripts/fusion_audit.py) finds the
+gaps that grounded the fusion."""
 
+import collections
+import glob
+import itertools
 import json
 import os
+import re
 import subprocess
 import sys
 
 import numpy as np
+import pytest
 import jax
+import jax.numpy as jnp
 
 from sparknet_tpu.proto import caffe_pb
 from sparknet_tpu.solver.trainer import Solver
@@ -34,10 +43,10 @@ SOLVER_TXT = "base_lr: 0.1 momentum: 0.9 lr_policy: 'fixed' weight_decay: 0.001"
 SHAPES = {"data": (8, 8), "label": (8,)}
 
 
-def make_solver(seed=7):
-    return Solver(
+def make_solver(seed=7, cls=Solver, **kw):
+    return cls(
         caffe_pb.load_solver(SOLVER_TXT, is_path=False), SHAPES,
-        net_param=caffe_pb.load_net(TINY_NET, is_path=False), seed=seed,
+        net_param=caffe_pb.load_net(TINY_NET, is_path=False), seed=seed, **kw,
     )
 
 
@@ -56,49 +65,282 @@ def leaves(params):
     )]
 
 
-def test_fused_step_bitwise_equals_legacy():
-    """jax.random.split is the same deterministic function inside and
-    outside jit: folding it (and the counter) into the step changes
-    dispatch count, never the rng stream or the weights."""
-    legacy = make_solver()
-    legacy._fuse_host = False
-    fused = make_solver()
-    fused._fuse_host = True
-    legacy.step(feed(), 6)
-    fused.step(feed(), 6)
-    assert legacy.iter == fused.iter == 6
-    for a, b in zip(leaves(legacy.params), leaves(fused.params)):
-        np.testing.assert_array_equal(a, b)
-    # the rng key itself advanced identically
-    np.testing.assert_array_equal(
-        np.asarray(jax.device_get(legacy.rng)),
-        np.asarray(jax.device_get(fused.rng)),
+def assert_same_training(a, b):
+    """Two solvers hold bitwise the same weights, net state, optimizer
+    slots and rng key, at the same iteration."""
+    assert a.iter == b.iter
+    for tree in ("params", "state", "opt_state", "rng"):
+        for x, y in zip(leaves(getattr(a, tree)), leaves(getattr(b, tree)),
+                        strict=True):
+            np.testing.assert_array_equal(x, y, err_msg=tree)
+
+
+# one tiny solver per family the cells train, built by the entry points'
+# own ``build``: (solver, feed), the same from every call
+def _prototxt():
+    return make_solver(), feed()
+
+
+def _bert():
+    from sparknet_tpu.apps import bert_app
+
+    solver, batches, _ = bert_app.build(bert_app.make_args(
+        config="tiny", vocab_size=64, seq_len=32, batch_size=4,
+        synthetic_tokens=4096,
+    ))
+    return solver, batches
+
+
+def _decoder():
+    from sparknet_tpu.apps import lm_app
+
+    solver, batches, _ = lm_app.build(lm_app.parser().parse_args([
+        "--config", "tiny", "--seq-len", "32", "--batch-size", "2",
+        "--synthetic-tokens", "4096",
+    ]))
+    return solver, batches
+
+
+FAMILIES = pytest.mark.parametrize(
+    "build", [_prototxt, _bert, _decoder], ids=["prototxt", "bert", "decoder"]
+)
+
+
+def lowered_name(lowered) -> str:
+    return re.match(r"module @(\S+)", lowered.as_text()).group(1)
+
+
+def executions(log_dir):
+    """What the profiler saw the host start: (executions of a compiled
+    program, names of the jitted functions called)."""
+    from jax.profiler import ProfileData
+
+    [path] = glob.glob(os.path.join(log_dir, "plugins/profile/*/*.xplane.pb"))
+    names = collections.Counter(
+        ev.name
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines for ev in line.events
+    )
+    return names["PjRtCpuExecutable::Execute"], {
+        n for n in names if n.startswith("PjitFunction(")
+    }
+
+
+def profiled_steps(solver, batch, n, log_dir):
+    """``n`` iterations on a device-resident batch under the profiler,
+    with every implicit host-to-device transfer refused."""
+    resident = itertools.repeat(jax.device_put(batch))
+    jax.block_until_ready(solver.step(resident, 2))  # compile, seed counter
+    jax.profiler.start_trace(log_dir)
+    try:
+        with jax.transfer_guard_host_to_device("disallow"):
+            jax.block_until_ready(solver.step(resident, n))
+    finally:
+        jax.profiler.stop_trace()
+    return executions(log_dir)
+
+
+@FAMILIES
+def test_step_runs_one_program_an_iteration(build, tmp_path):
+    """Warm, with the batch on the device: an iteration starts ONE
+    compiled program, the one named ``fused``, and hands the device
+    nothing from the host — no key, no counter."""
+    solver, batches = build()
+    count, programs = profiled_steps(solver, next(batches), 3, str(tmp_path))
+    assert count == 3
+    assert programs == {"PjitFunction(fused)"}
+
+
+@FAMILIES
+def test_step_n_is_n_steps(build):
+    """``step(feed, 6)`` and six ``step(feed, 1)``: bitwise the same
+    weights, state, slots and key — the loop carries nothing between
+    iterations but what the program returns."""
+    at_once, feed_a = build()
+    one_by_one, feed_b = build()
+    at_once.step(feed_a, 6)
+    for _ in range(6):
+        one_by_one.step(feed_b, 1)
+    assert at_once.iter == 6
+    assert_same_training(at_once, one_by_one)
+
+
+@FAMILIES
+def test_lower_step_is_the_program_step_runs(build):
+    """What a caller reads kernels and memory off is what ``step``
+    dispatches: the module is ``jit_fused``, the key's split and the
+    counter's increment are inside it, and compiled and run on a copy
+    of the solver's state it leaves bitwise what one ``step`` leaves."""
+    solver, batches = build()
+    solver.step(batches, 2)  # off the initial state; counter now at 2
+    batch = next(batches)
+    lowered = solver.lower_step(batch)
+    text = lowered.as_text()
+    assert lowered_name(lowered) == "jit_fused"
+    assert "threefry" in text
+    # lowering twice gives the same text: nothing in it hangs on when
+    # it was asked for
+    assert solver.lower_step(batch).as_text() == text
+    copy = lambda tree: jax.tree_util.tree_map(jnp.copy, tree)
+    params, state, opt_state, it, rng, metrics = lowered.compile()(
+        copy(solver.params), copy(solver.state), copy(solver.opt_state),
+        batch, jnp.asarray(solver.iter, jnp.int32), copy(solver.rng),
+    )
+    stepped = solver.step(iter([batch]), 1)
+    assert int(it) == solver.iter == 3
+    for mine, theirs in (
+        (params, solver.params), (state, solver.state),
+        (opt_state, solver.opt_state), (rng, solver.rng), (metrics, stepped),
+    ):
+        for x, y in zip(leaves(mine), leaves(theirs), strict=True):
+            np.testing.assert_array_equal(x, y)
+
+
+def test_step_options_are_read_at_construction(monkeypatch):
+    """The compiler options of the step program are those of the
+    environment the Solver was BUILT in: a variable set later reaches
+    no compile, and stepping builds no further ``jax.jit``."""
+    from sparknet_tpu.solver import trainer as T
+
+    seen = []
+    real_jit = jax.jit
+
+    def spy_jit(fn, **kw):
+        seen.append((fn.__name__, kw.pop("compiler_options", None)))
+        return real_jit(fn, **kw)  # CPU jit would reject the TPU option
+
+    monkeypatch.setattr(T.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(T.jax, "jit", spy_jit)
+    monkeypatch.setenv("SPARKNET_SCOPED_VMEM_KIB", "0")
+    solver = make_solver()
+    built = list(seen)
+    assert built == [("fused", None), ("eval_step", None)]
+    monkeypatch.setenv("SPARKNET_SCOPED_VMEM_KIB", "49152")
+    solver.step(feed(), 2)
+    solver.lower_step(next(feed()))
+    assert seen == built
+    # and the default, where nothing is set
+    monkeypatch.delenv("SPARKNET_SCOPED_VMEM_KIB")
+    seen.clear()
+    make_solver()
+    assert seen[0] == ("fused", {"xla_tpu_scoped_vmem_limit_kib": "32768"})
+
+
+CONV_NET = """
+name: "tiny_conv"
+layer { name: "d" type: "Input" top: "data" top: "label" }
+layer { name: "conv1" type: "Convolution" bottom: "data" top: "conv1"
+        convolution_param { num_output: 4 kernel_size: 3
+          weight_filler { type: "xavier" } } }
+layer { name: "relu1" type: "ReLU" bottom: "conv1" top: "conv1" }
+layer { name: "norm1" type: "LRN" bottom: "conv1" top: "norm1"
+        lrn_param { local_size: 3 alpha: 0.0001 beta: 0.75 } }
+layer { name: "pool1" type: "Pooling" bottom: "norm1" top: "pool1"
+        pooling_param { pool: MAX kernel_size: 2 stride: 2 } }
+layer { name: "drop1" type: "Dropout" bottom: "pool1" top: "pool1"
+        dropout_param { dropout_ratio: 0.5 } }
+layer { name: "ip1" type: "InnerProduct" bottom: "pool1" top: "ip1"
+        inner_product_param { num_output: 4
+          weight_filler { type: "xavier" } } }
+layer { name: "loss" type: "SoftmaxWithLoss" bottom: "ip1" bottom: "label" top: "loss" }
+"""
+
+
+def test_prototxt_remat_trains_like_plain():
+    """``Solver(remat=True)`` on a prototxt net recomputes each layer in
+    the backward pass and trains to the same weights: the dropout mask
+    of the recomputed forward is the one the first forward drew."""
+    shapes = {"data": (4, 8, 8, 3), "label": (4,)}
+
+    def train(remat):
+        solver = Solver(
+            caffe_pb.load_solver(SOLVER_TXT, is_path=False), shapes,
+            net_param=caffe_pb.load_net(CONV_NET, is_path=False), seed=5,
+            remat=remat,
+        )
+        rng = np.random.default_rng(2)
+        batches = iter([
+            {"data": rng.normal(size=shapes["data"]).astype(np.float32),
+             "label": rng.integers(0, 4, size=(4,)).astype(np.int32)}
+            for _ in range(4)
+        ])
+        loss = float(solver.step(batches, 4)["loss"])
+        return solver, loss
+
+    plain, plain_loss = train(False)
+    remat, remat_loss = train(True)
+    assert remat.train_net.remat and not plain.train_net.remat
+    # the TEST net keeps no backward, so nothing to recompute
+    assert not remat.test_net.remat
+    # the recomputation is in the program, not only in a flag
+    zeros = {"data": np.zeros(shapes["data"], np.float32),
+             "label": np.zeros((4,), np.int32)}
+    assert "optimization_barrier" in remat.lower_step(zeros).as_text()
+    assert "optimization_barrier" not in plain.lower_step(zeros).as_text()
+    assert np.isfinite(remat_loss)
+    np.testing.assert_allclose(remat_loss, plain_loss, rtol=1e-5)
+    for a, b in zip(leaves(plain.params), leaves(remat.params), strict=True):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(leaves(plain.rng)[0], leaves(remat.rng)[0])
+
+
+def sync_on_one_device():
+    from sparknet_tpu.parallel import ParallelSolver, make_mesh
+
+    return make_solver(
+        cls=ParallelSolver, mesh=make_mesh(devices=jax.devices()[:1]),
+        mode="sync",
     )
 
 
-def test_fused_step_is_the_default_and_env_disables(monkeypatch):
-    assert make_solver()._fuse_host is True
-    monkeypatch.setenv("SPARKNET_FUSED_STEP", "0")
-    assert make_solver()._fuse_host is False
+def test_fused_step_bitwise_equals_host_split():
+    """jax.random.split is the same deterministic function inside and
+    outside jit.  ParallelSolver's sync mode still splits on the host
+    and places the counter every iteration; on a one-device mesh it is
+    the base Solver's oracle: the key advances identically, bitwise,
+    and so do the weights."""
+    base, par = make_solver(), sync_on_one_device()
+    base.step(feed(), 6)
+    par.step(feed(), 6)
+    assert base.iter == 6
+    assert_same_training(base, par)
+
+
+def test_parallel_solver_overrides_the_dispatch(tmp_path):
+    """Sync mode OVERRIDES the method that advances the solver, and
+    ``lower_step`` with it, instead of steering the base class: its
+    program is its own (no key or counter comes back from it), and its
+    iteration takes the key and the counter from the host."""
+    from sparknet_tpu.parallel import ParallelSolver
+
+    assert ParallelSolver._dispatch is not Solver._dispatch
+    assert ParallelSolver.lower_step is not Solver.lower_step
+    base, par = make_solver(), sync_on_one_device()
+    batch = next(feed())
+    assert lowered_name(base.lower_step(batch)) == "jit_fused"
+    assert lowered_name(par.lower_step(batch)) != "jit_fused"
+    outputs = lambda s: len(
+        jax.tree_util.tree_leaves(s.lower_step(batch).out_info)
+    )
+    assert outputs(par) == outputs(base) - 2
+    with pytest.raises(Exception, match="[Dd]isallowed host-to-device"):
+        profiled_steps(par, batch, 1, str(tmp_path))
 
 
 def test_fused_resume_reseeds_device_counter(tmp_path):
     """restore() must invalidate the on-device iteration counter, so
-    an interrupted fused run resumes bit-identically to the
-    uninterrupted one (LR schedules read the counter)."""
+    an interrupted run resumes bit-identically to the uninterrupted
+    one (LR schedules read the counter)."""
     base = make_solver()
-    base._fuse_host = True
     base.step(feed(), 8)
 
     first = make_solver()
-    first._fuse_host = True
     f = feed()
     first.step(f, 4)
     path = str(tmp_path / "mid_iter_4.solverstate.npz")
     first.save(path)
 
     resumed = make_solver()
-    resumed._fuse_host = True
     resumed.step(feed(), 2)  # park the counter somewhere wrong
     resumed.restore(path)
     assert resumed._it_dev is None
@@ -107,17 +349,6 @@ def test_fused_resume_reseeds_device_counter(tmp_path):
     assert resumed.iter == 8
     for a, b in zip(leaves(base.params), leaves(resumed.params)):
         np.testing.assert_array_equal(a, b)
-
-
-def test_parallel_solver_opts_out_of_fusion():
-    from sparknet_tpu.parallel import ParallelSolver, make_mesh
-
-    par = ParallelSolver(
-        caffe_pb.load_solver(SOLVER_TXT, is_path=False), SHAPES,
-        net_param=caffe_pb.load_net(TINY_NET, is_path=False), seed=7,
-        mesh=make_mesh(), mode="sync",
-    )
-    assert par._fuse_host is False
 
 
 # ------------------------------------------------------------ fusion audit
@@ -182,14 +413,13 @@ def test_audit_flags_device_put_stalls(tmp_path):
 
 
 def test_audit_reads_a_real_solver_trace(tmp_path):
-    """End to end: a traced legacy run's capture parses, attributes
-    the timeline phases, and counts the iterations."""
+    """End to end: a traced run's capture parses, attributes the
+    timeline phases, and counts the iterations."""
     from sparknet_tpu.telemetry import timeline as ttl
     from sparknet_tpu.telemetry import trace as tr
 
     path = str(tmp_path / "real.json")
     s = make_solver()
-    s._fuse_host = False
     tr.enable(path)
     try:
         tl = ttl.Timeline(fence=True)

@@ -12,8 +12,6 @@ the PR-5 allocation-free no-op.  CPU-only, tier-1.
 import json
 import os
 import socket
-import subprocess
-import sys
 import threading
 import time
 
@@ -29,11 +27,6 @@ from sparknet_tpu.telemetry import (
     timeline,
     trace,
 )
-
-SCRIPTS = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "scripts"
-)
-
 
 @pytest.fixture(autouse=True)
 def _isolation(monkeypatch):
@@ -537,60 +530,6 @@ def test_sidecar_merge_failures_are_counted(tmp_path):
         json.load(open(path))  # the merge itself survived
     finally:
         trace.disable()
-
-
-# ------------------------------------------------------------- bench diff
-def _bench_record(tmp_path, name, value, step_ms, compiled_share):
-    rec = {
-        "metric": "images_per_sec", "value": value, "step_ms": step_ms,
-        "telemetry": {
-            "timeline": {
-                "wall_s": 1.0,
-                "phases": {
-                    "compiled_step": {"total_s": compiled_share, "count": 5},
-                    "input_wait": {"total_s": 1.0 - compiled_share,
-                                   "count": 5},
-                },
-            },
-        },
-        "comm": {"wire_bytes_per_reduction": 1000.0},
-    }
-    p = tmp_path / name
-    p.write_text(json.dumps(rec))
-    return str(p)
-
-
-def test_bench_diff_regression_table(tmp_path):
-    old = _bench_record(tmp_path, "old.json", 100.0, 10.0, 0.8)
-    new = _bench_record(tmp_path, "new.json", 60.0, 17.0, 0.5)
-    r = subprocess.run(
-        [sys.executable, os.path.join(SCRIPTS, "bench_diff.py"), old, new],
-        capture_output=True, text=True,
-    )
-    assert r.returncode == 1, r.stdout + r.stderr
-    assert "REGRESSED" in r.stdout
-    assert "phase:input_wait" in r.stdout  # share grew 20% -> 50%
-    # informational mode prints the same table but never gates
-    r2 = subprocess.run(
-        [sys.executable, os.path.join(SCRIPTS, "bench_diff.py"), old, new,
-         "--informational"],
-        capture_output=True, text=True,
-    )
-    assert r2.returncode == 0 and "REGRESSED" in r2.stdout
-
-
-def test_bench_diff_accepts_driver_wrapper(tmp_path):
-    inner = {"metric": "m", "value": 10.0, "step_ms": 5.0}
-    p1 = tmp_path / "a.json"
-    p1.write_text(json.dumps({"n": 1, "parsed": inner}))
-    p2 = tmp_path / "b.json"
-    p2.write_text(json.dumps(inner))
-    r = subprocess.run(
-        [sys.executable, os.path.join(SCRIPTS, "bench_diff.py"),
-         str(p1), str(p2)],
-        capture_output=True, text=True,
-    )
-    assert r.returncode == 0, r.stdout + r.stderr
 
 
 # ------------------------------------------------------------------- e2e

@@ -314,22 +314,3 @@ def test_app_feed_constructor_uses_pipeline():
     finally:
         par.close()
     _assert_same_stream(a, b)
-
-
-@pytest.mark.slow
-def test_bench_input_pipeline_record(monkeypatch):
-    """The input_pipeline arm assembles the serial-vs-parallel A/B
-    record (slow: real AlexNet-shaped preprocessing).  Called as a
-    function: ``python bench.py`` itself refuses to run off a TPU."""
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    monkeypatch.syspath_prepend(here)
-    import bench
-
-    monkeypatch.setenv("BENCH_BATCH", "16")
-    monkeypatch.setenv("BENCH_ITERS", "6")
-    rec = bench.bench_input_pipeline("cpu")
-    assert rec["metric"] == "input_pipeline_images_per_sec", rec
-    assert rec["value"] > 0, rec
-    assert rec["serial_img_per_sec"] > 0
-    assert rec["input_pipeline_workers"] >= 1
-    assert "speedup_vs_serial" in rec and "pipeline_metrics" in rec

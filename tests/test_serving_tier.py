@@ -10,7 +10,6 @@ swaps, stub engines for batch-composition proofs."""
 
 import json
 import os
-import subprocess
 import sys
 import threading
 import time
@@ -714,7 +713,7 @@ def test_classify_from_decoded_batch_cache():
         writer.clear()
 
 
-# ------------------------------------------------------- dash + bench_diff
+# ------------------------------------------------------------------ dash
 def test_dash_renders_router_section():
     from sparknet_tpu.telemetry.dash import render_html
 
@@ -741,39 +740,3 @@ def test_dash_renders_router_section():
     assert "ejected" in html and "1/2" in html
     # without a router snapshot the section is absent
     assert "Serving tier" not in render_html({"uptime_s": 1.0})
-
-
-def test_bench_diff_learns_serving_fields(tmp_path):
-    old = {
-        "metric": "serving_tier_p99_ms_continuous", "value": 50.0,
-        "p50_ms": 20.0, "p99_ms": 50.0, "p99_improvement": 1.5,
-        "warm_restart_speedup": 4.0,
-        "tier": {"failed_requests": 0, "served_generations": [0, 1]},
-    }
-    good = dict(old, p99_ms=48.0,
-                tier={"failed_requests": 0,
-                      "served_generations": [0, 1]})
-    bad = dict(old, p99_ms=90.0,
-               tier={"failed_requests": 2,
-                     "served_generations": [0]})
-    pa, pb, pc = (str(tmp_path / f"{n}.json") for n in "abc")
-    for p, doc in ((pa, old), (pb, good), (pc, bad)):
-        with open(p, "w") as fh:
-            json.dump(doc, fh)
-    script = os.path.join(
-        os.path.dirname(__file__), "..", "scripts", "bench_diff.py"
-    )
-    ok = subprocess.run(
-        [sys.executable, script, pa, pb],
-        capture_output=True, text=True,
-    )
-    assert ok.returncode == 0, ok.stdout + ok.stderr
-    bad_run = subprocess.run(
-        [sys.executable, script, pa, pc],
-        capture_output=True, text=True,
-    )
-    assert bad_run.returncode == 1
-    assert "failed_requests" in bad_run.stdout
-    assert "ZERO is the bar" in bad_run.stdout
-    assert "p99_ms" in bad_run.stdout
-    assert "served_generations" in bad_run.stdout

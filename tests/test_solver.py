@@ -383,64 +383,3 @@ def test_step_compile_kw_forwards_to_jit(monkeypatch):
     )
     Solver(sp, {"data": (4, 5), "label": (4,)}, net_param=net)
     assert {"xla_tpu_scoped_vmem_limit_kib": "32768"} in seen
-
-    # bench's per-arch override relies on Solver evaluating the env AT
-    # CONSTRUCTION (eager jit in _finish_init): inside _arch_env the
-    # build must see the override, and the env must restore after. A
-    # refactor deferring jit creation would silently void ARCH_ENV —
-    # this pins the ordering contract.
-    import os
-
-    import bench
-
-    seen.clear()
-    with bench._arch_env("resnet50"):
-        Solver(sp, {"data": (4, 5), "label": (4,)}, net_param=net)
-    assert seen and all(o is None for o in seen), seen
-    assert "SPARKNET_SCOPED_VMEM_KIB" not in os.environ
-
-
-def test_scan_steps_trains_like_step_loop():
-    """scan_steps(batch, n) — the one-dispatch bench primitive — runs n
-    real iterations in one dispatch: iter advances by n, the loss
-    descends like the equivalent step() loop (rng streams differ, so
-    trajectories are compared loosely, not bitwise), and iter_size>1
-    micro-batch stacking compiles through the scan."""
-    sp = sp_from("base_lr: 0.5 momentum: 0.9 lr_policy: 'fixed'")
-    net_text = """
-    name: "tiny"
-    layer { name: "d" type: "Input" top: "data" top: "label" }
-    layer { name: "ip" type: "InnerProduct" bottom: "data" top: "ip"
-            inner_product_param { num_output: 3
-              weight_filler { type: "gaussian" std: 0.1 } } }
-    layer { name: "loss" type: "SoftmaxWithLoss" bottom: "ip" bottom: "label" top: "loss" }
-    """
-    net_param = caffe_pb.load_net(net_text, is_path=False)
-    rng = np.random.default_rng(0)
-    batch = {
-        "data": jnp.asarray(rng.normal(size=(8, 5)), jnp.float32),
-        "label": jnp.asarray(np.arange(8) % 3, jnp.int32),
-    }
-    shapes = {"data": (8, 5), "label": (8,)}
-
-    scan = Solver(sp, shapes, net_param=net_param, seed=3)
-    m = scan.scan_steps(batch, 30)
-    assert scan.iter == 30
-    scanned_loss = float(m["loss"])
-
-    loop = Solver(sp, shapes, net_param=net_param, seed=3)
-
-    def batches():
-        while True:
-            yield batch
-
-    loop_loss = float(loop.step(batches(), 30)["loss"])
-    assert scanned_loss < 0.25 and loop_loss < 0.25, (scanned_loss, loop_loss)
-    # same work per iteration: the two trainings land in the same basin
-    assert abs(scanned_loss - loop_loss) < 0.15, (scanned_loss, loop_loss)
-
-    # iter_size>1: one micro-batch stacks iter_size-fold through the scan
-    sp2 = sp_from("base_lr: 0.1 momentum: 0.9 lr_policy: 'fixed' iter_size: 2")
-    s2 = Solver(sp2, shapes, net_param=net_param, seed=3)
-    m2 = s2.scan_steps(batch, 3)
-    assert s2.iter == 3 and np.isfinite(float(m2["loss"]))

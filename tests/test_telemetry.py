@@ -305,32 +305,49 @@ def test_fork_hook_drops_inherited_spans(tmp_path):
 
 
 # ---------------------------------------------------------------- timeline
-def test_timeline_nested_phases_attribute_exclusively():
+def test_timeline_nested_phases_attribute_exclusively(monkeypatch):
+    """The arithmetic of exclusive attribution, on a clock the test
+    moves: a "sleep" advances it and nothing else does, so every share
+    is exact (a wall clock beside loaded workers overshoots a sleep by
+    more than the phase is long)."""
+    import types
+
+    now = [100.0]
+
+    def sleep(seconds):
+        now[0] += seconds
+
+    monkeypatch.setattr(timeline, "time", types.SimpleNamespace(
+        perf_counter=lambda: now[0], time_ns=time.time_ns,
+    ))
     tl = timeline.Timeline()
     tl.start()
-    began = time.perf_counter()
+    sleep(0.5)  # outside every phase
     with tl.phase("device_put"):
+        sleep(0.25)
         with tl.phase("multihost_sync"):
-            time.sleep(0.02)
-        time.sleep(0.01)
-    outer = time.perf_counter() - began
+            sleep(2.0)
+        sleep(0.75)
+    with tl.phase("multihost_sync"):  # a second, top-level entry
+        sleep(0.5)
     tl.stop()
+    sleep(8.0)  # a stopped timeline's wall does not run
     snap = tl.snapshot()
     phases = snap["phases"]
     # the inner phase owns its time; the outer keeps only its exclusive
-    # share — so the table can never double-count (held against the outer
-    # block's own clock: a sleep overshoots by 10 ms on a loaded host)
-    assert phases["multihost_sync"]["total_s"] >= 0.018
-    assert 0.008 <= phases["device_put"]["total_s"]
-    assert (
-        phases["device_put"]["total_s"] + phases["multihost_sync"]["total_s"]
-        <= outer + 1e-6
-    )
-    assert snap["attributed_s"] <= snap["wall_s"] + 1e-6
-    assert snap["attributed_frac"] > 0.9
+    # share — so the table can never double-count
+    assert phases["multihost_sync"] == {
+        "total_s": 2.5, "count": 2, "mean_ms": 1250.0,
+    }
+    assert phases["device_put"] == {
+        "total_s": 1.0, "count": 1, "mean_ms": 1000.0,
+    }
+    assert snap["wall_s"] == 4.0
+    assert snap["attributed_s"] == 3.5
+    assert snap["attributed_frac"] == 0.875
     table = tl.table()
     assert "device_put" in table and "multihost_sync" in table
-    assert re.search(r"attributed \d+(\.\d+)?% of", table)
+    assert re.search(r"attributed 87\.5% of", table)
 
 
 def test_timeline_threads_do_not_cross_nest():
