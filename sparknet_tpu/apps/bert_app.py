@@ -93,6 +93,18 @@ def make_config(args):
     return cfg, seq
 
 
+def flash_tiles_note(tiles: Dict[str, int]) -> str:
+    """The start-up line's words for ``tiles`` — the score tiles a
+    batch-head executes in each flash kernel, by whether a mask runs over
+    them (``ops.attention.flash_tile_kinds``; empty: the reference path) —
+    set once as the registry's ``flash_tiles`` gauges, one a kind."""
+    from ..telemetry.registry import REGISTRY
+
+    for kind, n in tiles.items():
+        REGISTRY.gauge("flash_tiles", kind=kind).set(n)
+    return f"flash_tiles={tiles or 'none (reference attention)'}"
+
+
 def build(args):
     cfg, seq = make_config(args)
     if args.attention in ("ring", "ulysses"):
@@ -459,6 +471,14 @@ def main(argv=None) -> Dict[str, float]:
     from ..data.prefetch import maybe_prefetch
 
     feed = maybe_prefetch(feed, args, args.parallel)
+    # every tile lies under the key mask the model passes
+    from ..ops.attention import flash_tile_kinds, uses_flash
+
+    seq = solver.train_net.seq_len
+    tiles = flash_tiles_note(dict(zip(
+        ("unmasked", "masked"),
+        flash_tile_kinds(seq, seq, causal=False, key_mask=True),
+    )) if uses_flash(args.attention or None, cfg.attention_dropout > 0) else {})
     primary = multihost.is_primary()
     if primary:
         if args.restore:
@@ -467,7 +487,8 @@ def main(argv=None) -> Dict[str, float]:
         n_params = solver.train_net.num_params(solver.params)
         print(
             f"BertApp: config={args.config} vocab={cfg.vocab_size} "
-            f"layers={cfg.num_layers} hidden={cfg.hidden_size} params={n_params}"
+            f"layers={cfg.num_layers} hidden={cfg.hidden_size} params={n_params} "
+            f"{tiles}"
         )
     from ..utils.profiling import StepTimer, trace
 

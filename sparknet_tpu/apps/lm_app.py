@@ -31,9 +31,10 @@ from typing import Dict
 import jax.numpy as jnp
 
 from ..data.text import clm_dataset, clm_feed
-from ..models.decoder import COUNTERS, DecoderConfig, DecoderLM
+from ..models.decoder import COUNTERS, SLIDING, DecoderConfig, DecoderLM
+from ..ops.attention import flash_tile_kinds, uses_flash
 from ..solver.trainer import Solver
-from .bert_app import make_solver_param
+from .bert_app import flash_tiles_note, make_solver_param
 
 
 def make_config(args) -> DecoderConfig:
@@ -60,6 +61,18 @@ def build(args):
     )
     solver = Solver(make_solver_param(args), shapes, model=model, seed=args.seed)
     return solver, clm_feed(ds, args.batch_size, seed=args.seed), cfg
+
+
+def flash_tiles(cfg: DecoderConfig, seq_len: int) -> Dict[str, int]:
+    """Score tiles a batch-head of each kind of layer executes in each flash
+    kernel, by whether a mask runs over them (``flash_tile_kinds``)."""
+    tiles = {}
+    for kind in dict.fromkeys(cfg.layer_types):
+        tiles[f"{kind}_unmasked"], tiles[f"{kind}_masked"] = flash_tile_kinds(
+            seq_len, seq_len, causal=True,
+            window=cfg.sliding_window if kind == SLIDING else None,
+        )
+    return tiles
 
 
 def parser() -> argparse.ArgumentParser:
@@ -111,11 +124,14 @@ def main(argv=None) -> Dict[str, float]:
         print(f"Restoring previous solver status from {args.restore} "
               f"(iter {solver.iter})")
     feed = maybe_prefetch(feed, args, args.parallel)  # after restore
+    tiles = flash_tiles_note(
+        flash_tiles(cfg, args.seq_len) if uses_flash(args.attention) else {}
+    )
     print(
         f"LmApp: config={args.config} vocab={cfg.vocab_size} "
         f"layers={cfg.num_layers} hidden={cfg.hidden_size} experts_held="
         f"{cfg.experts_held} of {cfg.num_experts} "
-        f"params={solver.train_net.num_params(solver.params)}"
+        f"params={solver.train_net.num_params(solver.params)} {tiles}"
     )
     timer = StepTimer(
         items_per_step=args.batch_size * args.seq_len, unit="tokens"

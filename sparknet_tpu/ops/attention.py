@@ -22,7 +22,13 @@ for the BERT family and the long-context path:
   specs (K and V are never repeated in HBM) and dk/dv summed over the
   group inside the dkv kernel. Head counts may differ from call to call.
   With ``window=None`` and equal head counts the traced kernels are the
-  plain ones, unchanged.
+  plain ones.  Per score tile the forward does its two products, the
+  masks that apply (the key mask only if one was passed or keys were
+  padded; the position mask on every tile of a causal call), one max and
+  one sum across lanes, two ``exp``, and keeps its running max and sum
+  as whole lane-replicated tiles (see ``_fwd_kernel``): at head size
+  128 it then runs its products at the pace of the two backward kernels
+  (≈ 140-150 TFLOP/s of executed work on a v5e; PERF.md §5).
 - :func:`attention` — dispatcher: flash on TPU (or ``force="flash"``),
   reference elsewhere.
 
@@ -48,6 +54,10 @@ from jax.experimental.pallas import tpu as pltpu
 # that no longer exists; not re-measured). 512x512 keeps VMEM small
 # (the f32 score tile is 1 MB) and _resolve_blocks still shrinks to the
 # largest conforming divisor for short or non-conforming sequences.
+# A 512x512 tile-head costs the forward ≈ 0.97 µs for its two products
+# and the softmax step between them, dq ≈ 1.37 for three, dkv ≈ 1.82 for
+# four (v5e, head size 128; PERF.md §5, PR 32): per-tile work that is not
+# a product is what a smaller block would multiply.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30  # large-but-finite: keeps fully-masked rows NaN-free
@@ -129,21 +139,30 @@ def _kv_group(q, k) -> int:
 
 
 def mha_reference_lse(q, k, v, **kw):
-    """Reference (out, logsumexp) — for testing flash internals."""
+    """Reference (out, logsumexp) — for testing flash internals.  The
+    kernels' contract: a query row with no valid key has ``NEG_INF``."""
     *_, d = q.shape
     scale = kw.get("scale") or 1.0 / math.sqrt(d)
     logits = jnp.einsum(
-        "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32
+        "bhqd,bhkd->bhqk", q, jnp.repeat(k, _kv_group(q, k), axis=1),
+        preferred_element_type=jnp.float32,
     ) * scale
+    valid = jnp.ones(logits.shape[-2:], bool)
     if kw.get("causal"):
         sq, sk = q.shape[2], k.shape[2]
         qi = jnp.arange(sq)[:, None] + kw.get("q_offset", 0)
         ki = jnp.arange(sk)[None, :] + kw.get("kv_offset", 0)
-        logits = jnp.where(ki <= qi, logits, NEG_INF)
+        valid &= ki <= qi
+        if kw.get("window") is not None:
+            valid &= qi - ki < kw["window"]
     kv_mask = kw.get("kv_mask")
     if kv_mask is not None:
-        logits = jnp.where(kv_mask[:, None, None, :], logits, NEG_INF)
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        valid = valid & kv_mask[:, None, None, :].astype(bool)
+    lse = jnp.where(
+        jnp.any(valid, -1),
+        jax.scipy.special.logsumexp(jnp.where(valid, logits, -jnp.inf), axis=-1),
+        NEG_INF,
+    )
     out = mha_reference(q, k, v, **kw)
     return out, lse
 
@@ -202,6 +221,67 @@ def _band_steps(ni, shift, blk_i, blk_j, nj, lo_back, hi_fwd) -> int:
     return min(nj, (blk_i + lo_back + hi_fwd - 1) // blk_j + 2)
 
 
+def flash_tile_kinds(
+    sq: int, sk: int, *, causal: bool, window: Optional[int] = None,
+    key_mask: bool = False, q_offset: int = 0, kv_offset: int = 0,
+    block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
+) -> tuple:
+    """(unmasked, masked) score tiles one batch-head of a
+    :func:`flash_attention` call of these shapes executes, in each of its
+    kernels.  A tile is masked if a ``where`` runs over its scores: every
+    executed tile of a causal call (the position mask is not told apart by
+    tile: two bodies, one for tiles wholly inside the band, measured
+    slower — PERF.md §6, PR 32) and of a call that passed a key mask or
+    padded its keys; none of any other call.  A q block that sees no key
+    at all counts one tile, which the banded kernels walk."""
+    pad_q, pad_k, blk_q, blk_k = _resolve_blocks(sq, sk, block_q, block_k)
+    nq, nk = (sq + pad_q) // blk_q, (sk + pad_k) // blk_k
+    executed = 0
+    for qi in range(nq):
+        lo, hi = _band(
+            qi, q_offset - kv_offset, blk_q, blk_k, nk,
+            None if window is None else window - 1, 0 if causal else None,
+        )
+        executed += hi - lo + 1
+    masked = causal or key_mask or pad_k > 0
+    return (0, executed) if masked else (executed, 0)
+
+
+def _position_keep(qi, kb, q_offset, kv_offset, blk_q, blk_k, window):
+    """(blk_q, blk_k) bool: the pairs of tile ``(qi, kb)`` inside the
+    causal band."""
+    q_pos = (
+        jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
+        + qi * blk_q + q_offset
+    )
+    k_pos = (
+        jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
+        + kb * blk_k + kv_offset
+    )
+    keep = k_pos <= q_pos
+    if window is not None:
+        keep &= q_pos - k_pos < window
+    return keep
+
+
+def _mask_scores(s, kmask, keep):
+    """Scores with the keys ``kmask`` (a (blk_k,) row, or None) calls
+    invalid and the pairs outside ``keep`` (or None) at ``NEG_INF``."""
+    if kmask is not None:
+        s = jnp.where(kmask[None, :] != 0, s, NEG_INF)
+    if keep is not None:
+        s = jnp.where(keep, s, NEG_INF)
+    return s
+
+
+def _lanes(x, n: int):
+    """A lane-replicated (rows, 128) array as (rows, n): whole lane
+    tiles side by side, or the first ``n`` lanes."""
+    if n <= 128:
+        return x if n == 128 else x[:, :n]
+    return jnp.concatenate([x] * (n // 128), axis=1)
+
+
 def _dropout_keep(seed, rate, head_id, qi, kb, blk_q, blk_k):
     """Deterministic per-(b,h,q-block,k-block) keep mask; forward and
     both backward kernels regenerate the identical mask from the same
@@ -232,12 +312,13 @@ def _fwd_kernel(
     k_ref,    # (1, F, blk_k, d)   — streamed over the last grid dim
     v_ref,    # (1, F, blk_k, d)
     m_ref,    # (1, 8, blk_k) int8 kv mask block (sublane-broadcast: TPU
-              # requires >=8 sublanes per block; head-independent)
+              # requires >=8 sublanes per block; head-independent), or
+              # None: the caller passed no mask and padded no key
     o_ref,    # (1, F, blk_q, d)
     lse_ref,  # (1, F, blk_q, 128) f32, lane-replicated
     acc_s,    # VMEM (F, blk_q, d) f32 — running numerator per head
     m_s,      # VMEM (F, blk_q, 128) f32 — running max (lane-replicated)
-    l_s,      # VMEM (F, blk_q, 128) f32 — running denominator
+    l_s,      # VMEM (F, blk_q, 128) f32 — running denominator (likewise)
     *,
     causal: bool,
     scale: float,
@@ -252,7 +333,16 @@ def _fwd_kernel(
     every key block; a banded call (``total_kb`` given: a window or
     grouped KV heads) walks the ``nkb`` blocks from the first one its q
     block can see, of ``total_kb``.  ``kv_fold`` KV heads arrive per
-    step (``fold`` where every query head has its own)."""
+    step (``fold`` where every query head has its own).
+
+    What a score tile costs besides its two products sets the kernel's
+    pace, and what cost most was the shape of ``m`` and ``l`` (PERF.md
+    §6, PR 32: 27.4 -> 12.7 ms a full layer of ``laguna_xs2``, to the
+    bit): they are read, updated and stored as whole lane-replicated
+    (blk_q, 128) tiles, widened to the score tile by laying lane tiles
+    side by side (``_lanes``) — never sliced to a (blk_q, 1) column and
+    broadcast back across lanes.  Those slices and broadcasts, not the two
+    reductions across lanes, were what the step waited on."""
     qi = pl.program_id(2)
     step = pl.program_id(3)
     blk_q, d = q_ref.shape[2], q_ref.shape[3]
@@ -276,19 +366,11 @@ def _fwd_kernel(
         l_s[...] = jnp.zeros_like(l_s)
 
     def compute():
-        kmask = m_ref[0, 0]  # (blk_k,) int8, shared by all heads
-        if causal:
-            q_pos = (
-                jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-                + qi * blk_q + q_offset
-            )
-            k_pos = (
-                jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-                + kb * blk_k + kv_offset
-            )
-            causal_keep = k_pos <= q_pos
-            if window is not None:
-                causal_keep &= q_pos - k_pos < window
+        # m_ref[0, 0]: (blk_k,) int8, shared by all heads
+        kmask = None if m_ref is None else m_ref[0, 0]
+        causal_keep = _position_keep(
+            qi, kb, q_offset, kv_offset, blk_q, blk_k, window
+        ) if causal else None
         for hh in range(fold):
             kv = hh * kv_fold // fold
             q = q_ref[0, hh].astype(jnp.float32) * scale  # (blk_q, d)
@@ -298,28 +380,22 @@ def _fwd_kernel(
                 q, k_blk, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # (blk_q, blk_k)
-            s = jnp.where(kmask[None, :] != 0, s, NEG_INF)
-            if causal:
-                s = jnp.where(causal_keep, s, NEG_INF)
-            m_prev = m_s[hh, :, 0:1]  # (blk_q, 1) — lanes identical
-            l_prev = l_s[hh, :, 0:1]
+            s = _mask_scores(s, kmask, causal_keep)
+            m_prev = m_s[hh]  # (blk_q, 128) — lanes identical
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
+            p = jnp.exp(s - _lanes(m_new, blk_k))
             alpha = jnp.exp(m_prev - m_new)
             # l accumulates the UNdropped mass (softmax normalises
             # before dropout); only the value accumulation is masked
-            l_s[hh] = jnp.broadcast_to(
-                alpha * l_prev + jnp.sum(p, axis=1, keepdims=True),
-                l_s.shape[1:],
-            )
-            m_s[hh] = jnp.broadcast_to(m_new, m_s.shape[1:])
+            l_s[hh] = alpha * l_s[hh] + jnp.sum(p, axis=1, keepdims=True)
+            m_s[hh] = m_new
             if dropout_rate > 0.0:
                 keep = _dropout_keep(
                     off_ref[2], dropout_rate,
                     pl.program_id(1) * fold + hh, qi, kb, blk_q, blk_k,
                 )
                 p = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
-            acc_s[hh] = acc_s[hh] * alpha + jax.lax.dot_general(
+            acc_s[hh] = acc_s[hh] * _lanes(alpha, d) + jax.lax.dot_general(
                 p, v_blk, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
@@ -393,19 +469,10 @@ def _bwd_dq_kernel(
         dq_s[...] = jnp.zeros_like(dq_s)
 
     def compute():
-        kmask = m_ref[0, 0]
-        if causal:
-            q_pos = (
-                jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-                + qi * blk_q + q_offset
-            )
-            k_pos = (
-                jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-                + kb * blk_k + kv_offset
-            )
-            causal_keep = k_pos <= q_pos
-            if window is not None:
-                causal_keep &= q_pos - k_pos < window
+        kmask = None if m_ref is None else m_ref[0, 0]
+        causal_keep = _position_keep(
+            qi, kb, q_offset, kv_offset, blk_q, blk_k, window
+        ) if causal else None
         for hh in range(fold):
             kv = hh * kv_fold // fold
             q = q_ref[0, hh].astype(jnp.float32) * scale
@@ -418,9 +485,7 @@ def _bwd_dq_kernel(
                 q, k_blk, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            s = jnp.where(kmask[None, :] != 0, s, NEG_INF)
-            if causal:
-                s = jnp.where(causal_keep, s, NEG_INF)
+            s = _mask_scores(s, kmask, causal_keep)
             # masked logits must yield p=0 even when lse is itself
             # NEG_INF (fully-padded row): exp(NEG_INF-NEG_INF) would be 1
             p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - lse))
@@ -495,19 +560,10 @@ def _bwd_dkv_kernel(
         dv_s[...] = jnp.zeros_like(dv_s)
 
     def compute():
-        kmask = m_ref[0, 0]
-        if causal:
-            q_pos = (
-                jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-                + qb * blk_q + q_offset
-            )
-            k_pos = (
-                jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-                + ki * blk_k + kv_offset
-            )
-            causal_keep = k_pos <= q_pos
-            if window is not None:
-                causal_keep &= q_pos - k_pos < window
+        kmask = None if m_ref is None else m_ref[0, 0]
+        causal_keep = _position_keep(
+            qb, ki, q_offset, kv_offset, blk_q, blk_k, window
+        ) if causal else None
         for hh in range(fold):
             kv = hh * kv_fold // fold
             k_blk = k_ref[0, kv].astype(jnp.float32)
@@ -520,9 +576,7 @@ def _bwd_dkv_kernel(
                 q, k_blk, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            s = jnp.where(kmask[None, :] != 0, s, NEG_INF)
-            if causal:
-                s = jnp.where(causal_keep, s, NEG_INF)
+            s = _mask_scores(s, kmask, causal_keep)
             # same masked-p guard as _bwd_dq_kernel (fully-padded rows)
             p = jnp.where(
                 s <= NEG_INF * 0.5, 0.0, jnp.exp(s - lse)
@@ -592,6 +646,22 @@ def _params(interpret):
             dimension_semantics=_SEMANTICS
         ),
     }
+
+
+def _without_mask(kernel, in_specs, args):
+    """(kernel, in_specs, args) of a call whose ``args[4]``, the key mask,
+    is None — nothing was passed and no key was padded: the mask's spec
+    and argument go, and the kernel finds None in its ``m_ref``'s place
+    and applies no key mask.  ``in_specs`` of a banded call start after
+    the scalar-prefetched offsets."""
+    if args[4] is not None:
+        return kernel, in_specs, args
+    at = 4 - (len(args) - len(in_specs))
+    return (
+        lambda *refs: kernel(*refs[:4], None, *refs[4:]),
+        in_specs[:at] + in_specs[at + 1:],
+        args[:4] + args[5:],
+    )
 
 
 def _fold_heads(h: int, blk_q: int, blk_k: int, d: int) -> int:
@@ -680,14 +750,18 @@ def _flash_fwd(
         if fold is None:
             fold = _fold_heads(h, blk_q, blk_k, d)
         grid = (b, h // fold, sq // blk_q, nkb)
-        kernel = functools.partial(
-            _fwd_kernel, causal=causal, scale=scale, nkb=nkb,
-            dropout_rate=dropout_rate, fold=fold,
+        kernel, in_specs, args = _without_mask(
+            functools.partial(
+                _fwd_kernel, causal=causal, scale=scale, nkb=nkb,
+                dropout_rate=dropout_rate, fold=fold,
+            ),
+            _qk_specs(blk_q, blk_k, d, fold),
+            (offsets, q, k, v, kv_mask),
         )
         out, lse = pl.pallas_call(
             kernel,
             grid=grid,
-            in_specs=_qk_specs(blk_q, blk_k, d, fold),
+            in_specs=in_specs,
             out_specs=[
                 pl.BlockSpec(
                     (1, fold, blk_q, d), lambda b_, g, i, j: (b_, g, i, 0)
@@ -704,7 +778,7 @@ def _flash_fwd(
             ],
             name="flash_attention_fwd",
             **_params(interpret),
-        )(offsets, q, k, v, kv_mask)
+        )(*args)
         return out, lse
 
     window, group = band
@@ -715,12 +789,17 @@ def _flash_fwd(
     in_specs, q_spec, lane_spec = _banded_qk_specs(
         blk_q, blk_k, d, fold, kv_fold, group, nkb, back, fwd
     )
-    out, lse = pl.pallas_call(
+    kernel, in_specs, args = _without_mask(
         functools.partial(
             _fwd_kernel, causal=causal, scale=scale, nkb=steps,
             dropout_rate=dropout_rate, fold=fold, window=window,
             kv_fold=kv_fold, total_kb=nkb,
         ),
+        in_specs,
+        (offsets, q, k, v, kv_mask),
+    )
+    out, lse = pl.pallas_call(
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, h // fold, nqb, steps),
@@ -735,7 +814,7 @@ def _flash_fwd(
         out_shape=out_shape,
         name="flash_attention_fwd",
         **_params(interpret),
-    )(offsets, q, k, v, kv_mask)
+    )(*args)
     return out, lse
 
 
@@ -849,11 +928,16 @@ def _flash_bwd(
             (1, fold, blk_q, 128), lambda b_, g, i, j: (b_, g, i, 0)
         ),  # delta
     ]
-    dq = pl.pallas_call(
+    args = (offsets, q, k, v, kv_mask, do, lse, delta)
+    kernel, dq_specs, dq_args = _without_mask(
         functools.partial(
             _bwd_dq_kernel, causal=causal, scale=scale, nkb=nkb,
             dropout_rate=dropout_rate, fold=fold,
         ),
+        dq_specs, args,
+    )
+    dq = pl.pallas_call(
+        kernel,
         grid=(b, h // fold, nqb, nkb),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec(
@@ -863,7 +947,7 @@ def _flash_bwd(
         scratch_shapes=[pltpu.VMEM((fold, blk_q, d), jnp.float32)],
         name="flash_attention_dq",
         **_params(interpret),
-    )(offsets, q, k, v, kv_mask, do, lse, delta)
+    )(*dq_args)
 
     # dkv: grid (b, h/F, nk, nq) — q/do/lse/delta streamed over q
     # blocks, dk/dv carried in scratch
@@ -889,11 +973,15 @@ def _flash_bwd(
             (1, fold, blk_q, 128), lambda b_, g, i, j: (b_, g, j, 0)
         ),  # delta
     ]
-    dk, dv = pl.pallas_call(
+    kernel, dkv_specs, dkv_args = _without_mask(
         functools.partial(
             _bwd_dkv_kernel, causal=causal, scale=scale, nqb=nqb,
             dropout_rate=dropout_rate, fold=fold,
         ),
+        dkv_specs, args,
+    )
+    dk, dv = pl.pallas_call(
+        kernel,
         grid=(b, h // fold, nkb, nqb),
         in_specs=dkv_specs,
         out_specs=[
@@ -914,7 +1002,7 @@ def _flash_bwd(
         ],
         name="flash_attention_dkv",
         **_params(interpret),
-    )(offsets, q, k, v, kv_mask, do, lse, delta)
+    )(*dkv_args)
     return dq, dk, dv
 
 
@@ -943,19 +1031,24 @@ def _flash_bwd_banded(
     in_specs, q_spec, lane_spec = _banded_qk_specs(
         blk_q, blk_k, d, fold, kv_fold, group, nkb, span, edge
     )
-    dq = pl.pallas_call(
+    args = (offsets, q, k, v, kv_mask, do, lse, delta)
+    kernel, dq_specs, dq_args = _without_mask(
         functools.partial(_bwd_dq_kernel, nkb=steps, total_kb=nkb, **static),
+        in_specs + [q_spec, lane_spec, lane_spec], args,
+    )
+    dq = pl.pallas_call(
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, h // fold, nqb, steps),
-            in_specs=in_specs + [q_spec, lane_spec, lane_spec],
+            in_specs=dq_specs,
             out_specs=q_spec,
             scratch_shapes=[pltpu.VMEM((fold, blk_q, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         name="flash_attention_dq",
         **_params(interpret),
-    )(offsets, q, k, v, kv_mask, do, lse, delta)
+    )(*dq_args)
 
     steps = _band_steps(
         nkb, None if shift is None else -shift, blk_k, blk_q, nqb, edge, span
@@ -972,21 +1065,24 @@ def _flash_bwd_banded(
     kv_spec = pl.BlockSpec(
         (1, kv_fold, blk_k, d), lambda b_, g, i, t, off: (b_, g, i, 0)
     )
-    dk, dv = pl.pallas_call(
+    kernel, dkv_specs, dkv_args = _without_mask(
         functools.partial(
             _bwd_dkv_kernel, nqb=reps * steps, total_qb=nqb, band_qb=steps,
             **static,
         ),
+        [
+            q_rows(d), kv_spec, kv_spec,
+            pl.BlockSpec((1, 8, blk_k), lambda b_, g, i, t, off: (b_, 0, i)),
+            q_rows(d), q_rows(128), q_rows(128),
+        ],
+        args,
+    )
+    dk, dv = pl.pallas_call(
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, h // (group * kv_fold), nkb, reps * steps),
-            in_specs=[
-                q_rows(d), kv_spec, kv_spec,
-                pl.BlockSpec(
-                    (1, 8, blk_k), lambda b_, g, i, t, off: (b_, 0, i)
-                ),
-                q_rows(d), q_rows(128), q_rows(128),
-            ],
+            in_specs=dkv_specs,
             out_specs=[kv_spec, kv_spec],
             scratch_shapes=[
                 pltpu.VMEM((kv_fold, blk_k, d), jnp.float32),
@@ -999,7 +1095,7 @@ def _flash_bwd_banded(
         ],
         name="flash_attention_dkv",
         **_params(interpret),
-    )(offsets, q, k, v, kv_mask, do, lse, delta)
+    )(*dkv_args)
     return dq, dk, dv
 
 
@@ -1072,9 +1168,7 @@ def _ring_conditioning(q, k, kv_mask, block_q, block_k):
         )
     blk_q = math.gcd(sq, block_q)
     blk_k = math.gcd(sk, block_k)
-    if kv_mask is None:
-        kv_mask = jnp.ones((b, sk), jnp.int8)
-    kv_mask8 = jnp.broadcast_to(
+    kv_mask8 = None if kv_mask is None else jnp.broadcast_to(
         kv_mask.astype(jnp.int8)[:, None, :], (b, 8, sk)
     )
     return kv_mask8, blk_q, blk_k
@@ -1201,10 +1295,10 @@ def flash_attention(
         sq, sk, block_q, block_k
     )
     scale = (1.0 / math.sqrt(d)) if scale is None else scale
-    if kv_mask is None:
-        kv_mask = jnp.ones((b, sk), jnp.int8)
-    else:
+    if kv_mask is not None:
         kv_mask = kv_mask.astype(jnp.int8)
+    elif pad_k:
+        kv_mask = jnp.ones((b, sk), jnp.int8)
     if pad_q:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
     if pad_k:
@@ -1215,10 +1309,12 @@ def flash_attention(
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         kv_mask = jnp.pad(kv_mask, ((0, 0), (0, pad_k)))
-    # sublane-broadcast for the (1, 8, blk_k) mask block spec
-    kv_mask = jnp.broadcast_to(
-        kv_mask[:, None, :], (b, 8, sk + pad_k)
-    )
+    if kv_mask is not None:
+        # sublane-broadcast for the (1, 8, blk_k) mask block spec; with
+        # no mask passed and no key padded the kernels take none
+        kv_mask = jnp.broadcast_to(
+            kv_mask[:, None, :], (b, 8, sk + pad_k)
+        )
     if dropout_rate > 0.0 and dropout_rng is not None:
         seed = seed_from_rng(dropout_rng)
     else:
@@ -1254,6 +1350,17 @@ def flash_attention(
     return out[:, :, :sq] if pad_q else out
 
 
+def uses_flash(force: Optional[str] = None, dropping: bool = False) -> bool:
+    """Whether :func:`attention` takes the flash kernels (its ``force``;
+    ``dropping``: attention-probability dropout is on)."""
+    flash_dropout_ok = bool(int(os.environ.get("SPARKNET_FLASH_DROPOUT", "1")))
+    return force == "flash" or (
+        force is None
+        and jax.default_backend() == "tpu"
+        and (not dropping or flash_dropout_ok)
+    )
+
+
 def attention(
     q, k, v, *, causal=False, kv_mask=None, scale=None,
     q_offset=0, kv_offset=0, dropout_rate=0.0, dropout_rng=None,
@@ -1271,16 +1378,7 @@ def attention(
     all for rate <= 0.5, drops all above): dropout statistics are only
     meaningful on hardware.
     """
-    import os
-
-    dropping = dropout_rate > 0.0 and dropout_rng is not None
-    flash_dropout_ok = bool(int(os.environ.get("SPARKNET_FLASH_DROPOUT", "1")))
-    use_flash = force == "flash" or (
-        force is None
-        and jax.default_backend() == "tpu"
-        and (not dropping or flash_dropout_ok)
-    )
-    if use_flash:
+    if uses_flash(force, dropout_rate > 0.0 and dropout_rng is not None):
         return flash_attention(
             q, k, v, causal=causal, kv_mask=kv_mask, scale=scale,
             q_offset=q_offset, kv_offset=kv_offset,

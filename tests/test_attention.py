@@ -411,3 +411,223 @@ def test_window_and_grouping_refuse_what_they_do_not_do():
             q, k, v, causal=True, window=16, interpret=True,
             dropout_rate=0.1, dropout_rng=jax.random.PRNGKey(0),
         )
+
+
+# ---------------------------------------------------------------------------
+# the kinds of score tile a call can hold: wholly inside the causal band, on
+# the diagonal, on the window's edge, dead; under a key mask (applied only if
+# one was passed or keys were padded) or under none
+# ---------------------------------------------------------------------------
+
+def _case(b=1, h=2, hkv=None, sq=256, sk=256, d=32, causal=True, window=None,
+          bq=128, bk=128, qo=0, ko=0, mask=None, traced=False):
+    return dict(b=b, h=h, hkv=hkv or h, sq=sq, sk=sk, d=d, causal=causal,
+                window=window, bq=bq, bk=bk, qo=qo, ko=ko, mask=mask,
+                traced=traced)
+
+
+_TILE_KINDS = {
+    # every q block: interior tiles, then one on the diagonal
+    "interior_and_diagonal": _case(sq=512, sk=512),
+    "interior_and_diagonal_mask_passed": _case(sq=512, sk=512, mask="tail"),
+    "not_causal_no_mask": _case(causal=False),
+    "not_causal_mask_passed": _case(causal=False, mask="tail"),
+    # window 160 over blocks of 128: diagonal, window edge, blocks skipped
+    "window_edge": _case(sq=512, sk=512, window=160),
+    "window_edge_mask_passed": _case(sq=512, sk=512, window=160, mask="tail"),
+    # window 600 over blocks of 128: interior tiles between the two edges
+    "window_interior_between_edges": _case(sq=1024, sk=1024, window=600),
+    # keys 256.. : q blocks 0 and 1 lie wholly before the band (dead rows)
+    "q_blocks_past_the_band": _case(sq=512, sk=512, ko=256),
+    "q_blocks_past_the_band_window": _case(sq=512, sk=512, ko=256, window=160),
+    "dead_rows_by_mask": _case(b=2, causal=False, mask="dead"),
+    "dead_rows_by_mask_causal": _case(b=2, mask="dead"),
+    "padded_keys": _case(sk=200, causal=False),
+    "padded_keys_causal": _case(sq=200, sk=200),
+    "grouped_heads": _case(h=4, hkv=2, sq=512, sk=512),
+    "grouped_heads_not_causal": _case(h=4, hkv=2, causal=False),
+    "grouped_heads_mask_passed": _case(h=4, hkv=2, mask="tail"),
+    "blk_q_smaller": _case(sq=512, sk=512, bq=64, bk=128),
+    "blk_q_larger": _case(sq=512, sk=512, bq=256, bk=128),
+    "blk_q_larger_window": _case(sq=512, sk=512, bq=256, bk=128, window=130),
+    "longer_keys": _case(sq=256, sk=512, qo=256),
+    "longer_queries": _case(sq=512, sk=256, ko=128),
+    # traced offsets (the ring's case): the predicate cannot fold at trace time
+    "traced_diagonal_inside_tiles": _case(qo=37, ko=0, traced=True),
+    "traced_diagonal_on_tile_edges": _case(qo=128, ko=0, traced=True),
+    "traced_diagonal_outside": _case(qo=512, ko=0, traced=True),
+    "traced_all_dead": _case(qo=0, ko=512, traced=True),
+    "traced_window_inside_tiles": _case(sq=512, sk=512, qo=37, ko=5, window=160, traced=True),
+    "traced_window_on_tile_edges": _case(sq=512, sk=512, qo=128, ko=0, window=128, traced=True),
+    "traced_grouped_diagonal_outside": _case(h=4, hkv=2, qo=512, ko=0, traced=True),
+}
+
+
+def _tile_case_inputs(c, dtype=jnp.float32):
+    q, k, v, ct = _grouped_qkv(7, c["b"], c["h"], c["hkv"], c["sq"], c["sk"], c["d"])
+    mask = None
+    if c["mask"] is not None:
+        m = np.ones((c["b"], c["sk"]), bool)
+        m[0, c["sk"] - 40:] = False
+        if c["mask"] == "dead":
+            m[1, :] = False
+        mask = jnp.asarray(m)
+    return tuple(t.astype(dtype) for t in (q, k, v, ct)) + (mask,)
+
+
+def _flash_out_and_lse(q, k, v, mask, c, qo, ko):
+    """(out, lse) of the forward kernel as ``flash_attention`` calls it."""
+    from sparknet_tpu.ops import attention as A
+
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
+    pad_q, pad_k, bq, bk = A._resolve_blocks(sq, sk, c["bq"], c["bk"])
+    if mask is None and pad_k:
+        mask = jnp.ones((b, sk), bool)
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
+    k, v = (jnp.pad(t, ((0, 0), (0, 0), (0, pad_k), (0, 0))) for t in (k, v))
+    if mask is not None:
+        mask = jnp.broadcast_to(
+            jnp.pad(mask.astype(jnp.int8), ((0, 0), (0, pad_k)))[:, None, :],
+            (b, 8, sk + pad_k),
+        )
+    offsets = jnp.stack([jnp.asarray(qo, jnp.int32), jnp.asarray(ko, jnp.int32),
+                         jnp.asarray(0, jnp.int32)])
+    banded = c["window"] is not None or c["h"] != c["hkv"]
+    out, lse = A._flash_fwd(
+        q, k, v, mask, offsets, c["causal"], 1.0 / np.sqrt(d), bq, bk, True,
+        0.0, band=(c["window"], c["h"] // c["hkv"]) if banded else None,
+        shift=None if c["traced"] or not banded else qo - ko,
+    )
+    return out[:, :, :sq], lse[:, :, :sq]
+
+
+@pytest.mark.parametrize("case", sorted(_TILE_KINDS))
+def test_tile_kinds_match_reference_fwd_lse_and_grads(case):
+    """Forward output, ``lse`` (lane-replicated, NEG_INF on dead rows) and
+    dq / dk / dv against the reference, over every kind of tile the kernels
+    tell apart."""
+    from sparknet_tpu.ops.attention import NEG_INF, mha_reference_lse
+
+    c = _TILE_KINDS[case]
+    q, k, v, ct, mask = _tile_case_inputs(c)
+    static = dict(causal=c["causal"], window=c["window"], kv_mask=mask)
+
+    def flash(q, k, v, qo, ko):
+        return flash_attention(q, k, v, block_q=c["bq"], block_k=c["bk"],
+                               interpret=True, q_offset=qo, kv_offset=ko, **static)
+
+    def both(q, k, v, qo, ko):
+        out, lse = _flash_out_and_lse(q, k, v, mask, c, qo, ko)
+        grads = jax.grad(lambda *a: jnp.sum(flash(*a, qo, ko) * ct), (0, 1, 2))(q, k, v)
+        return flash(q, k, v, qo, ko), out, lse, grads
+
+    if c["traced"]:
+        both = jax.jit(both)
+    got, out, lse, grads = both(q, k, v, c["qo"], c["ko"])
+    kw = dict(static, q_offset=c["qo"], kv_offset=c["ko"])
+    want, want_lse = mha_reference_lse(q, k, v, **kw)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(out))
+    np.testing.assert_array_equal(np.asarray(lse), np.asarray(lse[..., :1]).repeat(128, -1))
+    np.testing.assert_allclose(lse[..., 0], want_lse, atol=2e-5, rtol=2e-5)
+    dead = np.asarray(want_lse) == NEG_INF
+    if "dead" in case or "past_the_band" in case:
+        assert dead.any() and (case == "traced_all_dead" or not dead.all())
+    assert (np.asarray(lse[..., 0])[dead] == NEG_INF).all()
+    assert (np.asarray(got)[dead] == 0).all()
+    ref_grads = jax.grad(
+        lambda *a: jnp.sum(mha_reference(*a, **kw) * ct), (0, 1, 2)
+    )(q, k, v)
+    for g, w, name in zip(grads, ref_grads, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(g, w, atol=5e-5, rtol=5e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("case", [
+    "interior_and_diagonal", "window_edge", "grouped_heads",
+    "not_causal_mask_passed", "padded_keys_causal",
+])
+def test_tile_kinds_bfloat16_inputs_match_reference(case):
+    """bfloat16 in, bfloat16 out, against ``mha_reference`` on the same
+    inputs.  The kernels cast their operands to float32 (the chip's MXU
+    then rounds them to bfloat16 again, to the bit what a cast would give:
+    PERF.md §6, PR 32; the interpreter here does not), the reference hands
+    q, k, v and p to its products in bfloat16.  bfloat16's spacing is 2^-8
+    of a value, so a rounding moves it by at most 2^-9 of itself.  Outputs
+    here are below 2 in size: rounding the output costs up to 2^-8 = 3.9e-3
+    on either side, and the reference's rounded p (each probability off by
+    up to 0.2 % of itself) under 4e-3 more: 2e-2 holds the forward with
+    room.  The gradients (cotangents of size 1, sums over up to 512 keys,
+    values up to 8) are held to 6e-2, twice bfloat16's spacing there — a
+    wrong mask or a lost scale reads 0.1 and more."""
+    c = _TILE_KINDS[case]
+    q, k, v, ct, mask = _tile_case_inputs(c, jnp.bfloat16)
+    kw = dict(causal=c["causal"], window=c["window"], kv_mask=mask,
+              q_offset=c["qo"], kv_offset=c["ko"])
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, block_q=c["bq"], block_k=c["bk"], interpret=True, **kw)
+    plain = lambda q, k, v: mha_reference(q, k, v, **kw)
+    f32 = lambda t: np.asarray(t.astype(jnp.float32))
+    got = flash(q, k, v)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(f32(got), f32(plain(q, k, v)), atol=2e-2)
+    loss = lambda f: (lambda *a: jnp.sum((f(*a) * ct).astype(jnp.float32)))
+    for g, w, name in zip(jax.grad(loss(flash), (0, 1, 2))(q, k, v),
+                          jax.grad(loss(plain), (0, 1, 2))(q, k, v),
+                          ("dq", "dk", "dv")):
+        assert g.dtype == jnp.bfloat16
+        np.testing.assert_allclose(f32(g), f32(w), atol=6e-2, err_msg=name)
+
+
+def _brute_force_tile_kinds(c):
+    """(unmasked, masked) tiles by building every tile's validity: the
+    tiles with a visible pair are executed (one dead tile for a q block
+    with none), all under a mask if the call is causal, passed a key mask
+    or padded its keys."""
+    from sparknet_tpu.ops.attention import _resolve_blocks
+
+    pad_q, pad_k, bq, bk = _resolve_blocks(c["sq"], c["sk"], c["bq"], c["bk"])
+    sq, sk = c["sq"] + pad_q, c["sk"] + pad_k
+    qi = np.arange(sq)[:, None] + c["qo"]
+    ki = np.arange(sk)[None, :] + c["ko"]
+    valid = np.ones((sq, sk), bool)
+    if c["causal"]:
+        valid &= ki <= qi
+        if c["window"] is not None:
+            valid &= qi - ki < c["window"]
+    executed = 0
+    for i in range(sq // bq):
+        seen = sum(valid[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk].any()
+                   for j in range(sk // bk))
+        executed += max(seen, 1)
+    if c["causal"] or c["mask"] is not None or pad_k:
+        return 0, executed
+    return executed, 0
+
+
+@pytest.mark.parametrize(
+    "case", sorted(n for n, c in _TILE_KINDS.items() if not c["traced"])
+)
+def test_flash_tile_kinds_counts_what_a_brute_force_count_does(case):
+    from sparknet_tpu.ops.attention import flash_tile_kinds
+
+    c = _TILE_KINDS[case]
+    got = flash_tile_kinds(
+        c["sq"], c["sk"], causal=c["causal"], window=c["window"],
+        key_mask=c["mask"] is not None, q_offset=c["qo"], kv_offset=c["ko"],
+        block_q=c["bq"], block_k=c["bk"],
+    )
+    assert got == _brute_force_tile_kinds(c)
+
+
+def test_flash_tile_kinds_of_the_benchmark_cells():
+    from sparknet_tpu.ops.attention import flash_tile_kinds
+
+    # laguna_train_s8k: 16 q blocks of 512; a full layer's q block i walks
+    # the i blocks under the diagonal and the diagonal's, a window layer's two
+    assert flash_tile_kinds(8192, 8192, causal=True) == (0, 136)
+    assert flash_tile_kinds(8192, 8192, causal=True, window=512) == (0, 31)
+    # bert_mlm: one tile a batch-head, under the key mask
+    assert flash_tile_kinds(512, 512, causal=False, key_mask=True) == (0, 1)
+    assert flash_tile_kinds(512, 512, causal=False) == (1, 0)
+    assert flash_tile_kinds(512, 500, causal=False) == (0, 1)  # padded keys

@@ -215,7 +215,25 @@ def test_lm_app_main_trains_and_prints_the_counters(capsys):
     out = capsys.readouterr().out
     assert "Iteration 12, loss = " in out and "moe_slots_dropped = 0" in out
     assert "experts_held=(4, 4) of 16" in out
+    assert "flash_tiles=none (reference attention)" in out  # no TPU here
     assert np.isfinite(metrics["loss"]) and metrics["moe_slots_dropped"] == 0.0
+
+
+def test_lm_app_counts_the_flash_kernels_tiles_by_kind_of_layer():
+    """The start-up line's (and the registry's ``flash_tiles`` gauges')
+    numbers: tiles a batch-head executes, none told apart from the masked."""
+    from sparknet_tpu.apps import lm_app
+    from sparknet_tpu.telemetry.registry import REGISTRY
+
+    cfg = dataclasses.replace(DecoderConfig.tiny(), sliding_window=512)
+    tiles = lm_app.flash_tiles(cfg, 8192)
+    assert tiles == {
+        "full_attention_unmasked": 0, "full_attention_masked": 136,
+        "sliding_attention_unmasked": 0, "sliding_attention_masked": 31,
+    }
+    assert lm_app.flash_tiles_note(tiles) == f"flash_tiles={tiles}"
+    series = REGISTRY.snapshot()["metrics"]["flash_tiles"]
+    assert series["kind=full_attention_masked"]["value"] == 136
 
 
 def test_a_published_file_drives_the_app(tmp_path):

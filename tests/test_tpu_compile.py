@@ -46,6 +46,9 @@ _ATTENTION = {
     "laguna_sliding_layer": (64, 8, 8192, 128, 512, 2),
     "laguna_full_layer": (48, 8, 8192, 128, None, 2),
     "bert_base_layer": (12, 12, 512, 64, None, 64),
+    # as bert_mlm calls it: a key mask passed (else the kernels take none)
+    # and dropout's hash on every tile
+    "bert_base_layer_key_mask_dropout": (12, 12, 512, 64, None, 64),
 }
 
 
@@ -54,15 +57,19 @@ def test_flash_kernels_compile_for_v5e_at_real_widths(case, one_chip, no_compile
     from sparknet_tpu.ops.attention import flash_attention
 
     heads, kv_heads, seq, d, window, batch = _ATTENTION[case]
-    causal = case != "bert_base_layer"
+    causal = not case.startswith("bert_base_layer")
+    masked = case.endswith("key_mask_dropout")
     q = jax.ShapeDtypeStruct((batch, heads, seq, d), jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((batch, kv_heads, seq, d), jnp.bfloat16, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((batch, seq), jnp.bool_, sharding=one_chip)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
 
-    def grads(q, k, v):
-        out = lambda *a: flash_attention(*a, causal=causal, window=window)
+    def grads(q, k, v, mask, rng):
+        extra = dict(kv_mask=mask, dropout_rate=0.1, dropout_rng=rng) if masked else {}
+        out = lambda *a: flash_attention(*a, causal=causal, window=window, **extra)
         return jax.grad(lambda *a: out(*a).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
 
-    text = jax.jit(grads).lower(q, kv, kv).compile().as_text()
+    text = jax.jit(grads).lower(q, kv, kv, mask, rng).compile().as_text()
     for kernel in ("flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv"):
         assert kernel in text, kernel
     assert text.count('custom_call_target="tpu_custom_call"') == 3
