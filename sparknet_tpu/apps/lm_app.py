@@ -8,15 +8,20 @@ BertApp's schedule, batches staged ahead by ``maybe_prefetch``.
     python -m sparknet_tpu.apps.lm_app --config benchmark/configs/laguna_xs2.json \\
         --bf16 --remat --seq-len 8192 --batch-size 2 --synthetic-tokens 4194304
 
-``--config`` is ``tiny`` or the path of a JSON file holding a published
-``config.json``'s keys (``DecoderConfig.from_published``: ``layer_types``,
-``mlp_layer_types``, ``num_attention_heads_per_layer``, ``rope_parameters``
-...), as cut to this chip's share if it is (``num_experts`` held of
-``deployment.num_experts_routed``).  Token ids come from the config's
-``vocab_size``.  The progress line carries the sparse layers' counters
-(``moe_slots_held``, ``moe_load_max_over_mean``, ``moe_slots_dropped``);
-the telemetry registry has them, as every solver's newest step metrics,
-under its source ``train_step``.
+``--config`` is ``tiny``, ``tiny_hybrid`` or the path of a JSON file holding
+a published ``config.json``'s keys, as cut to this chip's share if it is
+(``num_experts`` held of ``deployment.num_experts_routed``).  Its
+``model_type`` picks the model: ``bailing_hybrid`` is
+:class:`~sparknet_tpu.models.decoder.HybridLM` (KDA and MLA layers by
+``layer_group_size``, ``HybridConfig.from_published``), anything else
+:class:`~sparknet_tpu.models.decoder.DecoderLM`
+(``DecoderConfig.from_published``: ``layer_types``, ``mlp_layer_types``,
+``num_attention_heads_per_layer``, ``rope_parameters`` ...).  Token ids come
+from the config's ``vocab_size``.  The progress line carries the model's
+counters (the sparse layers' ``moe_slots_held``, ``moe_load_max_over_mean``,
+``moe_slots_dropped``; the hybrid's ``kda_chunks`` and ``kda_decay_min``
+besides); the telemetry registry has them, as every solver's newest step
+metrics, under its source ``train_step``.
 """
 
 from __future__ import annotations
@@ -31,18 +36,24 @@ from typing import Dict
 import jax.numpy as jnp
 
 from ..data.text import clm_dataset, clm_feed
-from ..models.decoder import COUNTERS, SLIDING, DecoderConfig, DecoderLM
+from ..models.decoder import (
+    MLA, SLIDING, DecoderConfig, DecoderLM, HybridConfig, HybridLM,
+)
 from ..ops.attention import flash_tile_kinds, uses_flash
 from ..solver.trainer import Solver
 from .bert_app import flash_tiles_note, make_solver_param
 
 
-def make_config(args) -> DecoderConfig:
+def make_config(args):
     if args.config == "tiny":
         cfg = DecoderConfig.tiny()
+    elif args.config == "tiny_hybrid":
+        cfg = HybridConfig.tiny()
     else:
         with open(args.config) as fh:
-            cfg = DecoderConfig.from_published(json.load(fh))
+            published = json.load(fh)
+        hybrid = published.get("model_type") == "bailing_hybrid"
+        cfg = (HybridConfig if hybrid else DecoderConfig).from_published(published)
     return dataclasses.replace(cfg, remat=True) if args.remat else cfg
 
 
@@ -54,7 +65,7 @@ def build(args):
         seq_len=args.seq_len, seed=args.seed,
     )
     shapes = {"input_ids": (args.batch_size, args.seq_len)}
-    model = DecoderLM(
+    model = (HybridLM if isinstance(cfg, HybridConfig) else DecoderLM)(
         cfg, shapes,
         compute_dtype=jnp.bfloat16 if args.bf16 else jnp.float32,
         attention_impl=args.attention or None,
@@ -63,11 +74,14 @@ def build(args):
     return solver, clm_feed(ds, args.batch_size, seed=args.seed), cfg
 
 
-def flash_tiles(cfg: DecoderConfig, seq_len: int) -> Dict[str, int]:
+def flash_tiles(cfg, seq_len: int) -> Dict[str, int]:
     """Score tiles a batch-head of each kind of layer executes in each flash
-    kernel, by whether a mask runs over them (``flash_tile_kinds``)."""
+    kernel, by whether a mask runs over them (``flash_tile_kinds``); a
+    hybrid's KDA layers run no flash kernel."""
     tiles = {}
     for kind in dict.fromkeys(cfg.layer_types):
+        if isinstance(cfg, HybridConfig) and kind != MLA:
+            continue
         tiles[f"{kind}_unmasked"], tiles[f"{kind}_masked"] = flash_tile_kinds(
             seq_len, seq_len, causal=True,
             window=cfg.sliding_window if kind == SLIDING else None,
@@ -78,7 +92,8 @@ def flash_tiles(cfg: DecoderConfig, seq_len: int) -> Dict[str, int]:
 def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description="Causal-LM pre-training (LmApp)")
     ap.add_argument("--config", default="tiny",
-                    help="'tiny' or a JSON file of published config keys")
+                    help="'tiny', 'tiny_hybrid' or a JSON file of published "
+                         "config keys")
     ap.add_argument("--seq-len", type=int, default=64)
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--max-iter", type=int, default=1000)
@@ -164,7 +179,8 @@ def _fit(solver, feed, args, timer) -> Dict[str, float]:
         print(
             f"Iteration {it}, loss = {float(mm['loss']):.5f}, token_acc = "
             f"{float(mm['token_acc']):.4f}, " + ", ".join(
-                f"{name} = {float(mm[name]):.4g}" for name in COUNTERS
+                f"{name} = {float(mm[name]):.4g}"
+                for name in solver.train_net.counters
             )
         )
 

@@ -1,7 +1,11 @@
-"""A causal decoder LM that mixes window and full attention over sparse
-experts — the pure-JAX decoder family, beside :mod:`.bert`.
+"""Causal decoder LMs over sparse experts — the pure-JAX decoder family,
+beside :mod:`.bert`: :class:`DecoderLM` mixes window and full softmax
+attention; :class:`HybridLM` (further down, with its own header) mixes
+gated delta-rule linear attention (KDA) and latent attention (MLA) and
+shares DecoderLM's frame: the residual layout, SwiGLU, the held experts,
+the chunked loss, the counters and the Solver protocol.
 
-Built from a published ``config.json``'s own keys
+DecoderLM is built from a published ``config.json``'s own keys
 (:meth:`DecoderConfig.from_published`): ``layer_types`` (``full_attention``
 or ``sliding_attention`` per layer), ``num_attention_heads_per_layer``,
 ``mlp_layer_types`` (``dense`` or ``sparse``), ``rope_parameters`` per
@@ -40,11 +44,24 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import attention
+from ..ops.kda import KDA_MIN_LOG_DECAY, kda_chunks, kda_scan
 from ..ops.matmul import mxu_dot
-from ..parallel.moe import held_experts_ffn, init_held_experts_params
+from ..parallel.moe import (
+    held_experts_ffn, init_held_experts_params, route_grouped,
+)
 
 FULL, SLIDING = "full_attention", "sliding_attention"
+KDA, MLA = "kda", "mla"
 COUNTERS = ("moe_slots_held", "moe_load_max_over_mean", "moe_slots_dropped")
+KDA_COUNTERS = ("kda_chunks", "kda_decay_min")
+# a counter over the layers that report it: the mean of the slots held, the
+# worst load ratio, every slot dropped; the chunks a sequence (the same in
+# every layer), the smallest decay anywhere
+_REDUCE = {
+    "moe_slots_held": jnp.mean, "moe_load_max_over_mean": jnp.max,
+    "moe_slots_dropped": jnp.sum, "kda_chunks": jnp.max,
+    "kda_decay_min": jnp.min,
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -228,6 +245,8 @@ def swiglu(u, gate_w, up_w, down_w):
 class DecoderLM:
     """Functional decoder + untied head; see the module docstring."""
 
+    counters = COUNTERS
+
     def __init__(
         self,
         config: DecoderConfig,
@@ -240,11 +259,20 @@ class DecoderLM:
         self.attention_impl = attention_impl
         if "input_ids" not in input_shapes:
             raise ValueError("input_shapes must provide 'input_ids' (B, S)")
-        n = cfg.num_layers
-        if not (
-            len(cfg.mlp_layer_types) == n
-            and len(cfg.num_attention_heads_per_layer) == n
-        ):
+        if len(cfg.mlp_layer_types) != cfg.num_layers:
+            raise ValueError("the per-layer lists differ in length")
+        self._check_layers()
+        b, s = input_shapes["input_ids"]
+        self.batch, self.seq_len = b, s
+        self.input_names: List[str] = ["input_ids", "labels"]
+        self.blob_shapes: Dict[str, Tuple[int, ...]] = {
+            "input_ids": (b, s), "labels": (b, s), "loss": (),
+            "token_acc": (), **{name: () for name in self.counters},
+        }
+
+    def _check_layers(self) -> None:
+        cfg = self.cfg
+        if len(cfg.num_attention_heads_per_layer) != cfg.num_layers:
             raise ValueError("the per-layer lists differ in length")
         for kind in set(cfg.layer_types):
             if kind not in (FULL, SLIDING) or kind not in cfg.rope_parameters:
@@ -254,13 +282,6 @@ class DecoderLM:
                 raise ValueError(
                     f"{heads} heads over {cfg.num_key_value_heads} KV heads"
                 )
-        b, s = input_shapes["input_ids"]
-        self.batch, self.seq_len = b, s
-        self.input_names: List[str] = ["input_ids", "labels"]
-        self.blob_shapes: Dict[str, Tuple[int, ...]] = {
-            "input_ids": (b, s), "labels": (b, s), "loss": (),
-            "token_acc": (), **{name: () for name in COUNTERS},
-        }
 
     # -- init ----------------------------------------------------------------
     def init(self, rng: jax.Array):
@@ -287,25 +308,33 @@ class DecoderLM:
                 "o_w": trunc((heads * d, h)),
                 "ffn_norm": ones(),
             }
-            if cfg.mlp_layer_types[li] == "sparse":
-                layer.update(init_held_experts_params(
-                    next(keys), h, cfg.moe_intermediate_size, cfg.num_experts,
-                    cfg.experts_held[1], std=cfg.initializer_range,
-                ))
-                width = cfg.shared_expert_intermediate_size
-                prefix = "shared_"
-            else:
-                width, prefix = cfg.intermediate_size, ""
-            layer.update({
-                prefix + "gate_w": trunc((h, width)),
-                prefix + "up_w": trunc((h, width)),
-                prefix + "down_w": trunc((width, h)),
-            })
+            layer.update(self._init_ffn(li, trunc, keys))
             params[f"layer_{li:02d}"] = layer
         params["head"] = {
             "norm": ones(), "lm_w": trunc((h, cfg.vocab_size)),
         }
         return params, {}
+
+    def _init_ffn(self, li: int, trunc, keys):
+        """Layer ``li``'s FFN weights: the dense SwiGLU, or the held experts
+        with their router and the shared expert."""
+        cfg = self.cfg
+        h, ffn = cfg.hidden_size, {}
+        if cfg.mlp_layer_types[li] == "sparse":
+            ffn.update(init_held_experts_params(
+                next(keys), h, cfg.moe_intermediate_size, cfg.num_experts,
+                cfg.experts_held[1], std=cfg.initializer_range,
+            ))
+            width = cfg.shared_expert_intermediate_size
+            prefix = "shared_"
+        else:
+            width, prefix = cfg.intermediate_size, ""
+        ffn.update({
+            prefix + "gate_w": trunc((h, width)),
+            prefix + "up_w": trunc((h, width)),
+            prefix + "down_w": trunc((width, h)),
+        })
+        return ffn
 
     # -- layers --------------------------------------------------------------
     def _attention(self, li: int, lp, u):
@@ -334,16 +363,18 @@ class DecoderLM:
         out = out.transpose(0, 2, 1, 3).reshape(b, s, heads * d)
         return mxu_dot(out, lp["o_w"].astype(cdt))
 
+    _router = None  # route_sigmoid, held_experts_ffn's own; else (xt, lp)
+
     def _ffn(self, li: int, lp, u):
-        """(float32 FFN output, this layer's counters or None)."""
+        """(float32 FFN output, this layer's counters)."""
         cfg = self.cfg
         if cfg.mlp_layer_types[li] != "sparse":
-            return swiglu(u, lp["gate_w"], lp["up_w"], lp["down_w"]), None
+            return swiglu(u, lp["gate_w"], lp["up_w"], lp["down_w"]), {}
         routed, counters = held_experts_ffn(
             u, lp, experts_held=cfg.experts_held,
             top_k=cfg.num_experts_per_tok,
             routed_scale=cfg.moe_routed_scaling_factor,
-            compute_dtype=self.compute_dtype,
+            compute_dtype=self.compute_dtype, router=self._router,
         )
         with jax.named_scope("moe.shared"):
             shared = swiglu(
@@ -351,23 +382,30 @@ class DecoderLM:
             )
         return shared + routed.astype(jnp.float32), counters
 
-    def layer_apply(self, li: int, lp, x):
-        """One layer on ``x`` (B, S, h): (x, counters or None)."""
-        cfg, cdt = self.cfg, self.compute_dtype
-        scope = "attn.window" if cfg.layer_types[li] == SLIDING else "attn.full"
+    def _mix(self, li: int, lp, u):
+        """The layer's token mixer on the normed ``u``: (float32 output,
+        its counters), under the layer kind's scope."""
+        scope = "attn.window" if self.cfg.layer_types[li] == SLIDING else "attn.full"
         with jax.named_scope(scope):
-            attended = self._attention(
-                li, lp, rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-            )
-        x = (x.astype(jnp.float32) + attended).astype(cdt)
-        fed, counters = self._ffn(
+            return self._attention(li, lp, u), {}
+
+    def layer_apply(self, li: int, lp, x):
+        """One layer on ``x`` (B, S, h): (x, the layer's counters)."""
+        cfg, cdt = self.cfg, self.compute_dtype
+        mixed, counters = self._mix(
+            li, lp, rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        )
+        x = (x.astype(jnp.float32) + mixed).astype(cdt)
+        fed, ffn_counters = self._ffn(
             li, lp, rms_norm(x, lp["ffn_norm"], cfg.rms_norm_eps)
         )
-        return (x.astype(jnp.float32) + fed).astype(cdt), counters
+        return (x.astype(jnp.float32) + fed).astype(cdt), {
+            **counters, **ffn_counters
+        }
 
     def hidden(self, params, input_ids):
         """The final-layer hidden states (before the head's norm) and the
-        sparse layers' counters, stacked."""
+        layers' counters, one dict a layer."""
         cfg = self.cfg
         x = params["embed"]["tokens"][input_ids].astype(self.compute_dtype)
         counted = []
@@ -376,8 +414,7 @@ class DecoderLM:
             if cfg.remat:
                 fn = jax.checkpoint(fn)
             x, counters = fn(params[f"layer_{li:02d}"], x)
-            if counters is not None:
-                counted.append(counters)
+            counted.append(counters)
         return x, counted
 
     def _loss(self, head, x, labels):
@@ -416,26 +453,36 @@ class DecoderLM:
         with jax.named_scope("lm_head"):
             loss, acc = self._loss(params["head"], x, batch["labels"])
         blobs = {"loss": loss, "token_acc": acc}
-        # per sparse layer: the mean of the slots held, the worst load
-        # ratio, every slot dropped
-        reduce = dict(zip(COUNTERS, (jnp.mean, jnp.max, jnp.sum)))
-        for name in COUNTERS:
+        for name in self.counters:
+            seen = [c[name] for c in counted if name in c]
             blobs[name] = (
-                reduce[name](jnp.stack([c[name] for c in counted]))
-                if counted else jnp.zeros((), jnp.float32)
+                _REDUCE[name](jnp.stack(seen)) if seen
+                else jnp.zeros((), jnp.float32)
             )
         return blobs, state
 
     def loss_and_metrics(self, blobs):
         return blobs["loss"], {
-            k: blobs[k] for k in ("loss", "token_acc", *COUNTERS)
+            k: blobs[k] for k in ("loss", "token_acc", *self.counters)
         }
 
+    _no_decay = ()  # leaves without weight decay beside the norm scales
+    _buffers = ()  # leaves no step moves
+
     def param_specs(self):
-        """No weight decay on the norm scales (Caffe decay_mult 0)."""
+        """(lr_mult, decay_mult) a leaf: no weight decay on the vectors
+        (norm scales, Caffe decay_mult 0, and ``_no_decay``); ``_buffers``
+        do not move."""
         params, _ = jax.eval_shape(self.init, jax.random.PRNGKey(0))
+
+        def spec(name):
+            if name in self._buffers:
+                return (0.0, 0.0)
+            still = "norm" in name or name in self._no_decay
+            return (1.0, 0.0 if still else 1.0)
+
         return {
-            layer: {n: (1.0, 0.0 if "norm" in n else 1.0) for n in leaves}
+            layer: {n: spec(n) for n in leaves}
             for layer, leaves in params.items()
         }
 
@@ -445,3 +492,365 @@ class DecoderLM:
 
     def num_params(self, params) -> int:
         return sum(int(x.size) for x in jax.tree_util.tree_leaves(params))
+
+
+# ---------------------------------------------------------------------------
+# The hybrid: gated delta-rule linear attention (KDA) and latent attention
+# (MLA) mixed, over experts the router selects by groups
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class HybridConfig:
+    """A ``bailing_hybrid`` ``config.json``, as :class:`HybridLM` needs it.
+    The names DecoderConfig has mean the same here."""
+    vocab_size: int
+    hidden_size: int
+    intermediate_size: int
+    num_attention_heads: int
+    head_dim: int  # a KDA head's keys and values
+    layer_types: Tuple[str, ...]  # KDA or MLA
+    mlp_layer_types: Tuple[str, ...]
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float
+    short_conv_kernel_size: int = 4
+    kda_lower_bound: float = KDA_MIN_LOG_DECAY
+    num_experts: int = 0
+    experts_held: Tuple[int, int] = (0, 0)
+    num_experts_per_tok: int = 1
+    n_group: int = 1
+    topk_group: int = 1
+    moe_intermediate_size: int = 0
+    shared_expert_intermediate_size: int = 0
+    moe_routed_scaling_factor: float = 1.0
+    rms_norm_eps: float = 1e-6
+    initializer_range: float = 0.02
+    remat: bool = False
+    loss_chunk: int = 4096
+    kda_chunk: int = 64  # tokens a chunk of ops.kda.kda_scan
+    kda_segment: int = 1024  # tokens a checkpointed segment of a KDA layer
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    @classmethod
+    def from_published(cls, published: Mapping[str, Any], **overrides):
+        """From the published keys (or a cut: ``deployment.layers_kept``
+        lists the published indices of the ``num_hidden_layers`` layers
+        held, default the first so many; ``num_experts`` counts the experts
+        held of ``deployment.num_experts_routed``).  Published layer *i* is
+        MLA if ``(i + 1) % layer_group_size == 0``, else KDA; it has the
+        dense FFN if ``i < first_k_dense_replace``."""
+        n = published["num_hidden_layers"]
+        deployment = published.get("deployment", {})
+        kept = list(deployment.get("layers_kept", range(n)))
+        if len(kept) != n:
+            raise ValueError(f"layers_kept {kept} against {n} layers")
+        if published["kda_lower_bound"] < KDA_MIN_LOG_DECAY:
+            raise ValueError(
+                f"kda_lower_bound {published['kda_lower_bound']} is under "
+                f"what ops.kda.kda_scan keeps finite ({KDA_MIN_LOG_DECAY})"
+            )
+        group = published["layer_group_size"]
+        held = published["num_experts"]
+        fields = dict(
+            vocab_size=published["vocab_size"],
+            hidden_size=published["hidden_size"],
+            intermediate_size=published["intermediate_size"],
+            num_attention_heads=published["num_attention_heads"],
+            head_dim=published["head_dim"],
+            layer_types=tuple(
+                MLA if (i + 1) % group == 0 else KDA for i in kept
+            ),
+            mlp_layer_types=tuple(
+                "dense" if i < published["first_k_dense_replace"] else "sparse"
+                for i in kept
+            ),
+            kv_lora_rank=published["kv_lora_rank"],
+            qk_nope_head_dim=published["qk_nope_head_dim"],
+            qk_rope_head_dim=published["qk_rope_head_dim"],
+            v_head_dim=published["v_head_dim"],
+            rope_theta=published["rope_theta"],
+            short_conv_kernel_size=published["short_conv_kernel_size"],
+            kda_lower_bound=published["kda_lower_bound"],
+            num_experts=deployment.get("num_experts_routed", held),
+            experts_held=(deployment.get("experts_first", 0), held),
+            num_experts_per_tok=published["num_experts_per_tok"],
+            n_group=published["n_group"],
+            topk_group=published["topk_group"],
+            moe_intermediate_size=published["moe_intermediate_size"],
+            shared_expert_intermediate_size=(
+                published["moe_shared_expert_intermediate_size"]
+                * published["num_shared_experts"]
+            ),
+            moe_routed_scaling_factor=published["routed_scaling_factor"],
+            rms_norm_eps=published["rms_norm_eps"],
+        )
+        fields.update(overrides)
+        return cls(**fields)
+
+    @classmethod
+    def tiny(cls, **overrides) -> "HybridConfig":
+        """Both kinds of layer and both FFNs at a size for CPU tests: a
+        period of three (KDA dense, KDA, MLA), 16 experts in 4 groups with
+        4 held, 2 of the groups and 3 experts a token."""
+        fields = dict(
+            vocab_size=96, hidden_size=32, intermediate_size=64,
+            num_attention_heads=4, head_dim=8,
+            layer_types=(KDA, KDA, MLA), mlp_layer_types=("dense", "sparse", "sparse"),
+            kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+            v_head_dim=8, rope_theta=10000.0, num_experts=16,
+            experts_held=(4, 4), num_experts_per_tok=3, n_group=4,
+            topk_group=2, moe_intermediate_size=16,
+            shared_expert_intermediate_size=16,
+            moe_routed_scaling_factor=2.5, loss_chunk=32, kda_chunk=16,
+            kda_segment=32,
+        )
+        fields.update(overrides)
+        return cls(**fields)
+
+
+def causal_conv(x, w, history=None):
+    """Depthwise causal convolution: ``y_t = sum_j w[j] * x_{t-(K-1)+j}``
+    (``w[K-1]`` meets the current token).  ``x``: (B, S, C); ``w``: (K,
+    C); ``history`` (B, K-1, C): the positions before the first, zeros
+    where None."""
+    taps, s = w.shape[0], x.shape[1]
+    if history is None:
+        history = jnp.zeros((x.shape[0], taps - 1, x.shape[2]), x.dtype)
+    padded = jnp.concatenate([history, x], axis=1)
+    return sum(padded[:, j:j + s] * w[j] for j in range(taps))
+
+
+def rope_interleaved(x, positions, theta):
+    """Rotate the pairs ``(x[2i], x[2i+1])`` of the last axis of ``x``
+    (B, S, H, R) by ``position * theta ** (-2i / R)``, in float32."""
+    r = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(angles)[None, :, None, :], jnp.sin(angles)[None, :, None, :]
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], r // 2, 2)
+    a, b = pairs[..., 0], pairs[..., 1]
+    return jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1).reshape(x.shape)
+
+
+class HybridLM(DecoderLM):
+    """A decoder whose layers are KDA (Kimi Delta Attention: short causal
+    convolutions, L2-normed q and k, a bounded per-channel decay gate, the
+    gated delta-rule scan of :mod:`sparknet_tpu.ops.kda`, a gated per-head
+    RMSNorm, no rotary) or MLA (latent attention in its expanded form:
+    keys and values rebuilt from a ``kv_lora_rank``-wide latent, one rotary
+    key shared by all heads, through the flash kernels at head sizes
+    ``qk_nope + qk_rope`` for q and k and ``v_head_dim`` for v); the sparse
+    FFN's router selects by groups on biased scores
+    (:func:`sparknet_tpu.parallel.moe.route_grouped`; the bias is a buffer
+    that starts at 0 and no step moves).  The frame is DecoderLM's.
+
+    Counters beside DecoderLM's: ``kda_chunks`` (the chunks the scan walks
+    one after another for a sequence) and ``kda_decay_min`` (the smallest
+    decay ``alpha`` of the step, over every KDA layer, token and channel: a
+    gate that underflows shows here)."""
+
+    counters = COUNTERS + KDA_COUNTERS
+    _no_decay = ("A_log", "dt_bias")  # the decay gate's vectors
+    _buffers = ("router_bias",)  # the selection bias: it starts at 0 and stays
+
+    def _check_layers(self) -> None:
+        cfg = self.cfg
+        for kind in set(cfg.layer_types):
+            if kind not in (KDA, MLA):
+                raise ValueError(f"layer type {kind!r}")
+        if cfg.num_experts % max(cfg.n_group, 1):
+            raise ValueError(f"{cfg.num_experts} experts in {cfg.n_group} groups")
+
+    # -- init ----------------------------------------------------------------
+    def init(self, rng: jax.Array):
+        cfg = self.cfg
+        h, heads, d = cfg.hidden_size, cfg.num_attention_heads, cfg.head_dim
+        keys = iter(jax.random.split(rng, 4 + 20 * cfg.num_layers))
+
+        def trunc(shape):
+            return cfg.initializer_range * jax.random.truncated_normal(
+                next(keys), -2.0, 2.0, shape, jnp.float32
+            )
+
+        def uniform(shape, lo, hi):
+            return jax.random.uniform(next(keys), shape, jnp.float32, lo, hi)
+
+        ones = lambda n=h: jnp.ones((n,), jnp.float32)
+        params: Dict[str, Dict[str, jax.Array]] = {
+            "embed": {"tokens": trunc((cfg.vocab_size, h))}
+        }
+        for li in range(cfg.num_layers):
+            layer = {"attn_norm": ones(), "ffn_norm": ones()}
+            if cfg.layer_types[li] == KDA:
+                taps = cfg.short_conv_kernel_size
+                bound = taps ** -0.5
+                # the decay a token starts from: dt log-uniform in
+                # [1e-3, 1e-1], dt_bias its inverse softplus
+                dt = jnp.exp(uniform((heads * d,), math.log(1e-3), math.log(1e-1)))
+                layer.update({
+                    "q_w": trunc((h, heads * d)), "k_w": trunc((h, heads * d)),
+                    "v_w": trunc((h, heads * d)), "f_w": trunc((h, heads * d)),
+                    "g_w": trunc((h, heads * d)), "beta_w": trunc((h, heads)),
+                    "o_w": trunc((heads * d, h)),
+                    "q_conv": uniform((taps, heads * d), -bound, bound),
+                    "k_conv": uniform((taps, heads * d), -bound, bound),
+                    "v_conv": uniform((taps, heads * d), -bound, bound),
+                    "A_log": jnp.log(uniform((heads,), 1.0, 4.0)),
+                    "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                    "o_norm": ones(d),
+                })
+            else:
+                qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+                layer.update({
+                    "q_w": trunc((h, heads * qk)),
+                    "kv_a_w": trunc((h, cfg.kv_lora_rank + cfg.qk_rope_head_dim)),
+                    "kv_a_norm": ones(cfg.kv_lora_rank),
+                    "kv_b_w": trunc((
+                        cfg.kv_lora_rank,
+                        heads * (cfg.qk_nope_head_dim + cfg.v_head_dim),
+                    )),
+                    "o_w": trunc((heads * cfg.v_head_dim, h)),
+                })
+            layer.update(self._init_ffn(li, trunc, keys))
+            if cfg.mlp_layer_types[li] == "sparse":
+                layer["router_bias"] = jnp.zeros((cfg.num_experts,), jnp.float32)
+            params[f"layer_{li:02d}"] = layer
+        params["head"] = {
+            "norm": ones(), "lm_w": trunc((h, cfg.vocab_size)),
+        }
+        return params, {}
+
+    # -- layers --------------------------------------------------------------
+    def _router(self, xt, lp):
+        cfg = self.cfg
+        return route_grouped(
+            xt, lp["router_w"], lp["router_bias"], cfg.num_experts_per_tok,
+            cfg.moe_routed_scaling_factor, cfg.n_group, cfg.topk_group,
+        )
+
+    def _mix(self, li: int, lp, u):
+        if self.cfg.layer_types[li] == KDA:
+            with jax.named_scope("attn.kda"):
+                return self._kda(lp, u)
+        with jax.named_scope("attn.mla"):
+            return self._mla(lp, u), {}
+
+    def _kda(self, lp, u):
+        """The KDA mixer, ``kda_segment`` tokens at a time (the whole of a
+        sequence shorter than that): a ``lax.scan`` over segments of the
+        sequence carries the scan's state and the convolutions' last
+        inputs, and each segment is a checkpoint.  What lives at once
+        between a projection and the output product (some twenty float32
+        tensors of tokens x 4096, and ``kda_scan``'s chunk matrices) is
+        then one segment's; at 16 384 tokens the sequence's would be
+        several GB.  A longer sequence is whole segments: there is no
+        fallback that would give the bound up.  (Under ``remat`` the
+        layer's checkpoint runs the mixer a second time and a segment's a
+        third.  Leaving the layer's off a KDA layer would save that pass,
+        and the step would need 15.62 GB in place of 14.88: PERF.md
+        section 7.)"""
+        cfg, cdt = self.cfg, self.compute_dtype
+        b, s, hidden = u.shape
+        heads, d = cfg.num_attention_heads, cfg.head_dim
+        taps = cfg.short_conv_kernel_size
+        seg = min(cfg.kda_segment, s)
+        if s % seg:
+            raise ValueError(
+                f"{s} tokens a sequence are not whole KDA segments of "
+                f"{cfg.kda_segment} (HybridConfig.kda_segment)"
+            )
+        by_head = lambda x: x.reshape(b, seg, heads, d).transpose(0, 2, 1, 3)
+
+        @jax.checkpoint
+        def segment(carry, u_s):
+            state, history = carry
+            project = lambda name: mxu_dot(u_s, lp[name].astype(cdt))  # float32
+            mixed, latest = {}, {}
+            for name in ("q", "k", "v"):
+                pre = project(name + "_w")
+                mixed[name] = by_head(jax.nn.silu(
+                    causal_conv(pre, lp[name + "_conv"], history[name])
+                ))
+                latest[name] = jnp.concatenate(
+                    [history[name], pre], axis=1
+                )[:, -(taps - 1):]
+            unit = lambda x: x * jax.lax.rsqrt(
+                jnp.sum(x * x, -1, keepdims=True) + 1e-6
+            )
+            g = cfg.kda_lower_bound * jax.nn.sigmoid(
+                jnp.exp(lp["A_log"])[:, None, None]
+                * by_head(project("f_w") + lp["dt_bias"])
+            )
+            beta = jax.nn.sigmoid(project("beta_w")).transpose(0, 2, 1)
+            out, state = kda_scan(
+                (unit(mixed["q"]) * d ** -0.5).astype(cdt),
+                unit(mixed["k"]).astype(cdt), mixed["v"].astype(cdt), g, beta,
+                chunk=cfg.kda_chunk, initial_state=state, return_state=True,
+            )  # (B, H, seg, d) float32
+            out = rms_norm(out.transpose(0, 2, 1, 3), lp["o_norm"], cfg.rms_norm_eps)
+            out = out.reshape(b, seg, heads * d) * jax.nn.sigmoid(project("g_w"))
+            y = mxu_dot(out.astype(cdt), lp["o_w"].astype(cdt))
+            return (state, latest), (y, jnp.min(g))
+
+        zeros = lambda *shape: jnp.zeros(shape, jnp.float32)
+        start = (
+            zeros(b, heads, d, d),
+            {name: zeros(b, taps - 1, heads * d) for name in ("q", "k", "v")},
+        )
+        _, (y, least) = jax.lax.scan(
+            segment, start,
+            jnp.moveaxis(u.reshape(b, s // seg, seg, hidden), 1, 0),
+        )
+        counters = {
+            "kda_chunks": jnp.asarray(
+                s // seg * kda_chunks(seg, cfg.kda_chunk), jnp.float32
+            ),
+            "kda_decay_min": jnp.exp(jnp.min(least)),
+        }
+        return jnp.moveaxis(y, 0, 1).reshape(b, s, hidden), counters
+
+    def _mla(self, lp, u):
+        cfg, cdt = self.cfg, self.compute_dtype
+        b, s, _ = u.shape
+        heads, rank = cfg.num_attention_heads, cfg.kv_lora_rank
+        nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        positions = jnp.arange(s)
+        by_head = lambda x: x.astype(cdt).transpose(0, 2, 1, 3)
+
+        # checkpoints, as in _kda: the float32 tensors between a projection
+        # and what the kernels take are made again in the backward pass
+        @jax.checkpoint
+        def queries(w):
+            q = mxu_dot(u, w.astype(cdt)).reshape(b, s, heads, nope + rope)
+            return by_head(jnp.concatenate([
+                q[..., :nope],
+                rope_interleaved(q[..., nope:], positions, cfg.rope_theta),
+            ], axis=-1))
+
+        @jax.checkpoint
+        def keys_values(kv_a_w, kv_a_norm, kv_b_w):
+            kv_a = mxu_dot(u, kv_a_w.astype(cdt))  # latent | rotary key
+            latent = rms_norm(kv_a[..., :rank], kv_a_norm, cfg.rms_norm_eps)
+            kv = mxu_dot(latent.astype(cdt), kv_b_w.astype(cdt)).reshape(
+                b, s, heads, nope + cfg.v_head_dim
+            )
+            k_rot = rope_interleaved(
+                kv_a[..., None, rank:], positions, cfg.rope_theta
+            )
+            k = jnp.concatenate([
+                kv[..., :nope], jnp.broadcast_to(k_rot, (b, s, heads, rope)),
+            ], axis=-1)
+            return by_head(k), by_head(kv[..., nope:])
+
+        out = attention(
+            queries(lp["q_w"]),
+            *keys_values(lp["kv_a_w"], lp["kv_a_norm"], lp["kv_b_w"]),
+            causal=True, scale=(nope + rope) ** -0.5, force=self.attention_impl,
+        )
+        out = out.transpose(0, 2, 1, 3).reshape(b, s, heads * cfg.v_head_dim)
+        return mxu_dot(out, lp["o_w"].astype(cdt))

@@ -21,6 +21,10 @@ for the BERT family and the long-context path:
   reads KV head ``h // (H_q / H_kv)``, addressed by group in the block
   specs (K and V are never repeated in HBM) and dk/dv summed over the
   group inside the dkv kernel. Head counts may differ from call to call.
+  ``v`` may be narrower or wider than ``q`` and ``k`` (latent attention's
+  expanded form: q and k of 192 = 128 + 64 rotary, v of 128): every block,
+  scratch and output that holds values, the output or their gradients
+  takes v's head size, the rest q's, and no product is padded.
   With ``window=None`` and equal head counts the traced kernels are the
   plain ones.  Per score tile the forward does its two products, the
   masks that apply (the key mask only if one was passed or keys were
@@ -345,7 +349,7 @@ def _fwd_kernel(
     reductions across lanes, were what the step waited on."""
     qi = pl.program_id(2)
     step = pl.program_id(3)
-    blk_q, d = q_ref.shape[2], q_ref.shape[3]
+    blk_q, dv = q_ref.shape[2], v_ref.shape[3]
     blk_k = k_ref.shape[2]
     q_offset = off_ref[0]
     kv_offset = off_ref[1]
@@ -395,7 +399,7 @@ def _fwd_kernel(
                     pl.program_id(1) * fold + hh, qi, kb, blk_q, blk_k,
                 )
                 p = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
-            acc_s[hh] = acc_s[hh] * _lanes(alpha, d) + jax.lax.dot_general(
+            acc_s[hh] = acc_s[hh] * _lanes(alpha, dv) + jax.lax.dot_general(
                 p, v_blk, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
@@ -451,7 +455,7 @@ def _bwd_dq_kernel(
     step.  ``window``/``kv_fold``/``total_kb`` as in the forward."""
     qi = pl.program_id(2)
     step = pl.program_id(3)
-    blk_q, d = q_ref.shape[2], q_ref.shape[3]
+    blk_q = q_ref.shape[2]
     blk_k = k_ref.shape[2]
     q_offset, kv_offset = off_ref[0], off_ref[1]
     kv_fold = fold if kv_fold is None else kv_fold
@@ -541,7 +545,7 @@ def _bwd_dkv_kernel(
     dk/dv come out summed over the group; ``nqb`` is that axis' length."""
     ki = pl.program_id(2)
     step = pl.program_id(3)
-    blk_k, d = k_ref.shape[2], k_ref.shape[3]
+    blk_k = k_ref.shape[2]
     blk_q = q_ref.shape[2]
     q_offset, kv_offset = off_ref[0], off_ref[1]
     kv_fold = fold if kv_fold is None else kv_fold
@@ -694,11 +698,12 @@ def _fold_heads(h: int, blk_q: int, blk_k: int, d: int) -> int:
     return f
 
 
-def _qk_specs(blk_q, blk_k, d, fold):
+def _qk_specs(blk_q, blk_k, d, dv, fold):
     """in_specs for (offsets, q, k, v, mask) on a (b, h/F, nq, nk)
     grid: q indexed by the q-block dim, k/v/mask streamed over the
-    k-block dim, F heads per step. The kv mask arrives
-    sublane-broadcast as (b, 8, sk) and is head-independent."""
+    k-block dim, F heads per step; q and k are ``d`` wide, v ``dv``. The
+    kv mask arrives sublane-broadcast as (b, 8, sk) and is
+    head-independent."""
     return [
         pl.BlockSpec(memory_space=pltpu.SMEM),  # offsets (3,)
         pl.BlockSpec(
@@ -708,7 +713,7 @@ def _qk_specs(blk_q, blk_k, d, fold):
             (1, fold, blk_k, d), lambda b_, g, i, j: (b_, g, j, 0)
         ),
         pl.BlockSpec(
-            (1, fold, blk_k, d), lambda b_, g, i, j: (b_, g, j, 0)
+            (1, fold, blk_k, dv), lambda b_, g, i, j: (b_, g, j, 0)
         ),
         pl.BlockSpec((1, 8, blk_k), lambda b_, g, i, j: (b_, 0, j)),
     ]
@@ -739,23 +744,23 @@ def _flash_fwd(
     key blocks, K/V addressed by group.  ``shift`` is ``q_offset -
     kv_offset`` where both are Python ints (an exact band), else None."""
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     nkb = sk // blk_k
     out_shape = [
-        jax.ShapeDtypeStruct(q.shape, q.dtype),
+        jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
         # lane-replicated: TPU blocks need a 128-lane trailing dim
         jax.ShapeDtypeStruct((b, h, sq, 128), jnp.float32),
     ]
     if band is None:
         if fold is None:
-            fold = _fold_heads(h, blk_q, blk_k, d)
+            fold = _fold_heads(h, blk_q, blk_k, max(d, dv))
         grid = (b, h // fold, sq // blk_q, nkb)
         kernel, in_specs, args = _without_mask(
             functools.partial(
                 _fwd_kernel, causal=causal, scale=scale, nkb=nkb,
                 dropout_rate=dropout_rate, fold=fold,
             ),
-            _qk_specs(blk_q, blk_k, d, fold),
+            _qk_specs(blk_q, blk_k, d, dv, fold),
             (offsets, q, k, v, kv_mask),
         )
         out, lse = pl.pallas_call(
@@ -764,7 +769,7 @@ def _flash_fwd(
             in_specs=in_specs,
             out_specs=[
                 pl.BlockSpec(
-                    (1, fold, blk_q, d), lambda b_, g, i, j: (b_, g, i, 0)
+                    (1, fold, blk_q, dv), lambda b_, g, i, j: (b_, g, i, 0)
                 ),
                 pl.BlockSpec(
                     (1, fold, blk_q, 128), lambda b_, g, i, j: (b_, g, i, 0)
@@ -772,7 +777,7 @@ def _flash_fwd(
             ],
             out_shape=out_shape,
             scratch_shapes=[
-                pltpu.VMEM((fold, blk_q, d), jnp.float32),
+                pltpu.VMEM((fold, blk_q, dv), jnp.float32),
                 pltpu.VMEM((fold, blk_q, 128), jnp.float32),
                 pltpu.VMEM((fold, blk_q, 128), jnp.float32),
             ],
@@ -782,12 +787,12 @@ def _flash_fwd(
         return out, lse
 
     window, group = band
-    fold, kv_fold, _ = _banded_fold(fold, h, group, blk_q, blk_k, d)
+    fold, kv_fold, _ = _banded_fold(fold, h, group, blk_q, blk_k, max(d, dv))
     nqb = sq // blk_q
     back, fwd = (None if window is None else window - 1), (0 if causal else None)
     steps = _band_steps(nqb, shift, blk_q, blk_k, nkb, back, fwd)
-    in_specs, q_spec, lane_spec = _banded_qk_specs(
-        blk_q, blk_k, d, fold, kv_fold, group, nkb, back, fwd
+    in_specs, _, o_spec, lane_spec = _banded_qk_specs(
+        blk_q, blk_k, d, dv, fold, kv_fold, group, nkb, back, fwd
     )
     kernel, in_specs, args = _without_mask(
         functools.partial(
@@ -804,9 +809,9 @@ def _flash_fwd(
             num_scalar_prefetch=1,
             grid=(b, h // fold, nqb, steps),
             in_specs=in_specs,
-            out_specs=[q_spec, lane_spec],
+            out_specs=[o_spec, lane_spec],
             scratch_shapes=[
-                pltpu.VMEM((fold, blk_q, d), jnp.float32),
+                pltpu.VMEM((fold, blk_q, dv), jnp.float32),
                 pltpu.VMEM((fold, blk_q, 128), jnp.float32),
                 pltpu.VMEM((fold, blk_q, 128), jnp.float32),
             ],
@@ -818,24 +823,22 @@ def _flash_fwd(
     return out, lse
 
 
-def _banded_qk_specs(blk_q, blk_k, d, fold, kv_fold, group, nkb, back, fwd):
-    """(in_specs for (q, k, v, mask), the q-shaped spec, the lane-
-    replicated spec) of a banded call on a (b, h/F, nq, band) grid; the
-    offsets are scalar-prefetched and arrive last in every index map.
-    Step j of q block i addresses key block ``min(first + j, last)``."""
+def _banded_qk_specs(blk_q, blk_k, d, dv, fold, kv_fold, group, nkb, back, fwd):
+    """(in_specs for (q, k, v, mask), the q-shaped spec, the o-shaped
+    spec, the lane-replicated spec) of a banded call on a (b, h/F, nq,
+    band) grid; the offsets are scalar-prefetched and arrive last in
+    every index map.  Step j of q block i addresses key block
+    ``min(first + j, last)``."""
 
     def kv_block(i, j, off):
         first, last = _band(i, off[0] - off[1], blk_q, blk_k, nkb, back, fwd)
         return jnp.minimum(first + j, last)
 
-    q_spec = pl.BlockSpec(
-        (1, fold, blk_q, d), lambda b_, g, i, j, off: (b_, g, i, 0)
+    q_rows = lambda width: pl.BlockSpec(
+        (1, fold, blk_q, width), lambda b_, g, i, j, off: (b_, g, i, 0)
     )
-    lane_spec = pl.BlockSpec(
-        (1, fold, blk_q, 128), lambda b_, g, i, j, off: (b_, g, i, 0)
-    )
-    kv_spec = pl.BlockSpec(
-        (1, kv_fold, blk_k, d),
+    kv_rows = lambda width: pl.BlockSpec(
+        (1, kv_fold, blk_k, width),
         lambda b_, g, i, j, off: (
             b_, g * fold // (group * kv_fold), kv_block(i, j, off), 0
         ),
@@ -843,7 +846,11 @@ def _banded_qk_specs(blk_q, blk_k, d, fold, kv_fold, group, nkb, back, fwd):
     mask_spec = pl.BlockSpec(
         (1, 8, blk_k), lambda b_, g, i, j, off: (b_, 0, kv_block(i, j, off))
     )
-    return [q_spec, kv_spec, kv_spec, mask_spec], q_spec, lane_spec
+    q_spec = q_rows(d)
+    return (
+        [q_spec, kv_rows(d), kv_rows(dv), mask_spec], q_spec, q_rows(dv),
+        q_rows(128),
+    )
 
 
 @functools.partial(
@@ -905,7 +912,7 @@ def _flash_bwd(
     exact global softmax probabilities for this kv block, which is what
     makes flash-per-block ring backward exact."""
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     nqb, nkb = sq // blk_q, sk // blk_k
     if band is not None:
         return _flash_bwd_banded(
@@ -914,12 +921,12 @@ def _flash_bwd(
             dropout_rate=dropout_rate, fold=fold, band=band, shift=shift,
         )
     if fold is None:
-        fold = _fold_heads(h, blk_q, blk_k, d)
+        fold = _fold_heads(h, blk_q, blk_k, max(d, dv))
 
     # dq: grid (b, h/F, nq, nk) — K/V streamed, dq carried in scratch
-    dq_specs = _qk_specs(blk_q, blk_k, d, fold) + [
+    dq_specs = _qk_specs(blk_q, blk_k, d, dv, fold) + [
         pl.BlockSpec(
-            (1, fold, blk_q, d), lambda b_, g, i, j: (b_, g, i, 0)
+            (1, fold, blk_q, dv), lambda b_, g, i, j: (b_, g, i, 0)
         ),  # do
         pl.BlockSpec(
             (1, fold, blk_q, 128), lambda b_, g, i, j: (b_, g, i, 0)
@@ -960,11 +967,11 @@ def _flash_bwd(
             (1, fold, blk_k, d), lambda b_, g, i, j: (b_, g, i, 0)
         ),  # k
         pl.BlockSpec(
-            (1, fold, blk_k, d), lambda b_, g, i, j: (b_, g, i, 0)
+            (1, fold, blk_k, dv), lambda b_, g, i, j: (b_, g, i, 0)
         ),  # v
         pl.BlockSpec((1, 8, blk_k), lambda b_, g, i, j: (b_, 0, i)),  # mask
         pl.BlockSpec(
-            (1, fold, blk_q, d), lambda b_, g, i, j: (b_, g, j, 0)
+            (1, fold, blk_q, dv), lambda b_, g, i, j: (b_, g, j, 0)
         ),  # do
         pl.BlockSpec(
             (1, fold, blk_q, 128), lambda b_, g, i, j: (b_, g, j, 0)
@@ -989,7 +996,7 @@ def _flash_bwd(
                 (1, fold, blk_k, d), lambda b_, g, i, j: (b_, g, i, 0)
             ),
             pl.BlockSpec(
-                (1, fold, blk_k, d), lambda b_, g, i, j: (b_, g, i, 0)
+                (1, fold, blk_k, dv), lambda b_, g, i, j: (b_, g, i, 0)
             ),
         ],
         out_shape=[
@@ -998,7 +1005,7 @@ def _flash_bwd(
         ],
         scratch_shapes=[
             pltpu.VMEM((fold, blk_k, d), jnp.float32),
-            pltpu.VMEM((fold, blk_k, d), jnp.float32),
+            pltpu.VMEM((fold, blk_k, dv), jnp.float32),
         ],
         name="flash_attention_dkv",
         **_params(interpret),
@@ -1016,10 +1023,10 @@ def _flash_bwd_banded(
     blocks of query heads that read a block of KV heads, the band of q
     blocks that can see the key block; dk/dv leave summed over them."""
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     nqb, nkb = sq // blk_q, sk // blk_k
     window, group = band
-    fold, kv_fold, reps = _banded_fold(fold, h, group, blk_q, blk_k, d)
+    fold, kv_fold, reps = _banded_fold(fold, h, group, blk_q, blk_k, max(d, dv))
     span = None if window is None else window - 1
     edge = 0 if causal else None
     static = dict(
@@ -1028,13 +1035,13 @@ def _flash_bwd_banded(
     )
 
     steps = _band_steps(nqb, shift, blk_q, blk_k, nkb, span, edge)
-    in_specs, q_spec, lane_spec = _banded_qk_specs(
-        blk_q, blk_k, d, fold, kv_fold, group, nkb, span, edge
+    in_specs, q_spec, o_spec, lane_spec = _banded_qk_specs(
+        blk_q, blk_k, d, dv, fold, kv_fold, group, nkb, span, edge
     )
     args = (offsets, q, k, v, kv_mask, do, lse, delta)
     kernel, dq_specs, dq_args = _without_mask(
         functools.partial(_bwd_dq_kernel, nkb=steps, total_kb=nkb, **static),
-        in_specs + [q_spec, lane_spec, lane_spec], args,
+        in_specs + [o_spec, lane_spec, lane_spec], args,
     )
     dq = pl.pallas_call(
         kernel,
@@ -1062,8 +1069,8 @@ def _flash_bwd_banded(
         (1, fold, blk_q, width),
         lambda b_, g, i, t, off: (b_, g * reps + t // steps, q_block(i, t, off), 0),
     )
-    kv_spec = pl.BlockSpec(
-        (1, kv_fold, blk_k, d), lambda b_, g, i, t, off: (b_, g, i, 0)
+    kv_rows = lambda width: pl.BlockSpec(
+        (1, kv_fold, blk_k, width), lambda b_, g, i, t, off: (b_, g, i, 0)
     )
     kernel, dkv_specs, dkv_args = _without_mask(
         functools.partial(
@@ -1071,9 +1078,9 @@ def _flash_bwd_banded(
             **static,
         ),
         [
-            q_rows(d), kv_spec, kv_spec,
+            q_rows(d), kv_rows(d), kv_rows(dv),
             pl.BlockSpec((1, 8, blk_k), lambda b_, g, i, t, off: (b_, 0, i)),
-            q_rows(d), q_rows(128), q_rows(128),
+            q_rows(dv), q_rows(128), q_rows(128),
         ],
         args,
     )
@@ -1083,10 +1090,10 @@ def _flash_bwd_banded(
             num_scalar_prefetch=1,
             grid=(b, h // (group * kv_fold), nkb, reps * steps),
             in_specs=dkv_specs,
-            out_specs=[kv_spec, kv_spec],
+            out_specs=[kv_rows(d), kv_rows(dv)],
             scratch_shapes=[
                 pltpu.VMEM((kv_fold, blk_k, d), jnp.float32),
-                pltpu.VMEM((kv_fold, blk_k, d), jnp.float32),
+                pltpu.VMEM((kv_fold, blk_k, dv), jnp.float32),
             ],
         ),
         out_shape=[
@@ -1274,7 +1281,8 @@ def flash_attention(
     window: Optional[int] = None,
 ) -> jax.Array:
     """Flash attention on (B,H,S,D); ``k``/``v`` may be (B,H_kv,S,D) with
-    ``H % H_kv == 0`` (grouped-query), and ``window`` (with ``causal``)
+    ``H % H_kv == 0`` (grouped-query), ``v`` (B,H_kv,S,D_v) with a head
+    size of its own (the output has it), and ``window`` (with ``causal``)
     restricts query i to keys ``i - window < j <= i`` — both described in
     the module header; neither takes dropout. Any sequence length works:
     non-conforming lengths are zero-padded up to Mosaic's block

@@ -9,9 +9,11 @@ to a token when an expert is full.
 - :func:`held_experts_ffn` — **drops nothing.** One chip's share of an
   expert-parallel layer: it is told which experts it holds
   (``experts_held = (first, count)`` of the router's ``num_experts``),
-  routes over all of them (sigmoid scores, the ``top_k`` largest,
-  weights normalised over every chosen expert, held here or not, times
-  ``routed_scale``), and computes the part of the result its own
+  routes over all of them (:func:`route_sigmoid`: sigmoid scores, the
+  ``top_k`` largest, weights normalised over every chosen expert, held
+  here or not, times ``routed_scale``; or the ``router`` it is given,
+  such as :func:`route_grouped`, which chooses by groups of experts on
+  biased scores), and computes the part of the result its own
   SwiGLU experts give, for every slot routed to them whatever the
   imbalance: slots sorted by expert, the held ones gathered, grouped
   matrix products (``jax.lax.ragged_dot``) over the experts held,
@@ -52,7 +54,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -293,6 +295,36 @@ def route_sigmoid(
     return routed_scale * top / jnp.sum(top, axis=-1, keepdims=True), idx
 
 
+def route_grouped(
+    xt: jax.Array, router_w: jax.Array, bias: jax.Array, top_k: int,
+    routed_scale: float, n_group: int, topk_group: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """(weights, experts), both (T, K), of a router that selects by groups:
+    sigmoid scores ``s`` in float32; on ``s + bias`` the experts in
+    ``n_group`` equal groups, the ``topk_group`` groups with the largest
+    sum of their two best, and the ``top_k`` best experts inside those
+    groups; weights ``routed_scale * s / sum(s)`` over the chosen, from
+    the scores without the bias.  ``bias`` is a buffer that steers the
+    selection only: no gradient reaches it.  Ties go to the lower index."""
+    scores = jax.nn.sigmoid(
+        jnp.dot(
+            xt.astype(jnp.float32), router_w,
+            preferred_element_type=jnp.float32,
+        )
+    )
+    biased = scores + lax.stop_gradient(bias)
+    t, e = biased.shape
+    grouped = biased.reshape(t, n_group, e // n_group)
+    group_score = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)
+    _, groups = lax.top_k(group_score, topk_group)
+    kept = jnp.any(groups[:, :, None] == jnp.arange(n_group), axis=1)
+    _, idx = lax.top_k(
+        jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(t, e), top_k
+    )
+    top = jnp.take_along_axis(scores, idx, axis=-1)
+    return routed_scale * top / jnp.sum(top, axis=-1, keepdims=True), idx
+
+
 def _swiglu_rows(xg, gate_up, down, sizes, cdt):
     """SwiGLU experts on rows grouped by expert: (R, h) -> (R, h) f32.
     Rows past ``sum(sizes)`` belong to no expert; the caller masks them."""
@@ -425,11 +457,14 @@ def held_experts_ffn(
     routed_scale: float = 1.0,
     chunk_rows: Optional[int] = None,
     compute_dtype=jnp.float32,
+    router: Optional[Callable] = None,
 ):
     """The held experts' part of a sparse FFN (module header). ``x``:
     (..., h), flattened to T tokens.  Returns ``(out, counters)``:
     ``out`` has x's shape — add the shared expert and the residual
     outside — and the counters are scalars of this call:
+    ``router(xt, params) -> (weights, experts)``, both (T, top_k), takes
+    the place of :func:`route_sigmoid` where given.  The counters:
     ``moe_slots_held`` (slots routed to held experts; T * top_k * held /
     num_experts if the router is even), ``moe_load_max_over_mean`` (the
     fullest held expert over their mean) and ``moe_slots_dropped``
@@ -453,7 +488,7 @@ def held_experts_ffn(
     slots = t * top_k
 
     with jax.named_scope("moe.route"):
-        weights, experts = route_sigmoid(
+        weights, experts = router(xt, params) if router else route_sigmoid(
             xt, params["router_w"], top_k, routed_scale
         )
         local = experts.reshape(-1) - first  # (S,) token-major slots

@@ -301,6 +301,39 @@ def test_flash_dropout_mask_identical_fwd_bwd_on_hardware():
     assert err_keepall > 5 * err_mask, (err_mask, err_keepall)
 
 
+@pytest.mark.skipif(
+    jax.default_backend() != "tpu",
+    reason="holds the compiled kernels, not the interpreter, to the reference",
+)
+@pytest.mark.parametrize("window", [None, 600], ids=["plain", "banded"])
+def test_flash_value_head_narrower_than_keys_on_hardware(window):
+    """q and k of 192, v of 128 (MLA's expanded form) in bfloat16 through
+    the kernels the chip compiles, at their default blocks: forward, dq, dk
+    and dv against ``mha_reference`` in float32 on the same rounded inputs.
+    bfloat16's spacing is 2^-8 of a value: each is held to 2e-2 of the
+    largest; a wrong head size, mask or scale reads 0.1 of it and more."""
+    ks = jax.random.split(jax.random.PRNGKey(192), 4)
+    q = jax.random.normal(ks[0], (1, 8, 2048, 192)).astype(jnp.bfloat16)
+    k = jax.random.normal(ks[1], (1, 8, 2048, 192)).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, 8, 2048, 128)).astype(jnp.bfloat16)
+    ct = jax.random.normal(ks[3], (1, 8, 2048, 128))
+    kw = dict(causal=True, window=window, scale=192 ** -0.5)
+    total = lambda f: (lambda *a: jnp.sum(f(*a, **kw).astype(jnp.float32) * ct))
+    f32 = lambda t: np.asarray(t.astype(jnp.float32))
+    got = (flash_attention(q, k, v, **kw),
+           *jax.grad(total(flash_attention), (0, 1, 2))(q, k, v))
+    with jax.default_matmul_precision("highest"):
+        exact = [x.astype(jnp.float32) for x in (q, k, v)]
+        want = (mha_reference(*exact, **kw),
+                *jax.grad(total(mha_reference), (0, 1, 2))(*exact))
+    assert got[0].shape == (1, 8, 2048, 128) and got[0].dtype == jnp.bfloat16
+    for g, w, name in zip(got, want, ("o", "dq", "dk", "dv")):
+        assert g.shape == w.shape, name
+        np.testing.assert_allclose(
+            f32(g), f32(w), atol=2e-2 * float(jnp.abs(w).max()), err_msg=name
+        )
+
+
 # ---------------------------------------------------------------------------
 # sliding window and grouped KV heads (the banded kernels)
 # ---------------------------------------------------------------------------

@@ -42,7 +42,8 @@ def no_compile_cache():
 
 
 _ATTENTION = {
-    # heads, KV heads, sequence, head size, window, batch
+    # heads, KV heads, sequence, head size (of q and k, of v), window, batch
+    "ling_mla_layer": (32, 32, 16384, (192, 128), None, 1),
     "laguna_sliding_layer": (64, 8, 8192, 128, 512, 2),
     "laguna_full_layer": (48, 8, 8192, 128, None, 2),
     "bert_base_layer": (12, 12, 512, 64, None, 64),
@@ -59,8 +60,10 @@ def test_flash_kernels_compile_for_v5e_at_real_widths(case, one_chip, no_compile
     heads, kv_heads, seq, d, window, batch = _ATTENTION[case]
     causal = not case.startswith("bert_base_layer")
     masked = case.endswith("key_mask_dropout")
+    d, dv = d if isinstance(d, tuple) else (d, d)
     q = jax.ShapeDtypeStruct((batch, heads, seq, d), jnp.bfloat16, sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((batch, kv_heads, seq, d), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((batch, kv_heads, seq, d), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((batch, kv_heads, seq, dv), jnp.bfloat16, sharding=one_chip)
     mask = jax.ShapeDtypeStruct((batch, seq), jnp.bool_, sharding=one_chip)
     rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
 
@@ -69,7 +72,7 @@ def test_flash_kernels_compile_for_v5e_at_real_widths(case, one_chip, no_compile
         out = lambda *a: flash_attention(*a, causal=causal, window=window, **extra)
         return jax.grad(lambda *a: out(*a).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
 
-    text = jax.jit(grads).lower(q, kv, kv, mask, rng).compile().as_text()
+    text = jax.jit(grads).lower(q, k, v, mask, rng).compile().as_text()
     for kernel in ("flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv"):
         assert kernel in text, kernel
     assert text.count('custom_call_target="tpu_custom_call"') == 3
@@ -98,3 +101,27 @@ def test_held_experts_compile_to_the_grouped_product_for_v5e(one_chip, no_compil
 
     text = jax.jit(grads).lower(shape((2, 8192, 2048), jnp.bfloat16), params).compile().as_text()
     assert text.count("ragged-dot") >= 6  # gate+up and down, and both gradients of each
+
+
+def test_kda_scan_compiles_for_v5e_at_real_widths(one_chip, no_compile_cache):
+    """One segment of a KDA layer of ``ling3_flash`` (1024 tokens, 32 heads
+    of 128, chunks of 64), forward and backward: the chunk matrices are
+    matrix products and the recurrence a loop, with no kernel of ours."""
+    from sparknet_tpu.ops.kda import kda_scan
+
+    shape = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one_chip)
+    wide = lambda t: shape((1, 32, 1024, 128), t)
+
+    def grads(q, k, v, g, beta, state):
+        def total(*a):
+            out, end = kda_scan(*a, chunk=64, initial_state=state, return_state=True)
+            return out.sum() + end.sum()
+
+        return jax.grad(total, range(5))(q, k, v, g, beta)
+
+    text = jax.jit(grads).lower(
+        wide(jnp.bfloat16), wide(jnp.bfloat16), wide(jnp.bfloat16), wide(jnp.float32),
+        shape((1, 32, 1024), jnp.float32), shape((1, 32, 128, 128), jnp.float32),
+    ).compile().as_text()
+    assert " while(" in text and "tpu_custom_call" not in text
+    assert "kda.scan" in text  # the scope reaches the compiled program's metadata
