@@ -19,9 +19,9 @@ a published ``config.json``'s keys, as cut to this chip's share if it is
 ``num_attention_heads_per_layer``, ``rope_parameters`` ...).  Token ids come
 from the config's ``vocab_size``.  The progress line carries the model's
 counters (the sparse layers' ``moe_slots_held``, ``moe_load_max_over_mean``,
-``moe_slots_dropped``; the hybrid's ``kda_chunks`` and ``kda_decay_min``
-besides); the telemetry registry has them, as every solver's newest step
-metrics, under its source ``train_step``.
+``moe_slots_dropped``; the hybrid's ``kda_chunks``, ``kda_chunks_in_kernel``
+and ``kda_decay_min`` besides); the telemetry registry has them, as every
+solver's newest step metrics, under its source ``train_step``.
 """
 
 from __future__ import annotations

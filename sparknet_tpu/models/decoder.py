@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import attention
-from ..ops.kda import KDA_MIN_LOG_DECAY, kda_chunks, kda_scan
+from ..ops.kda import KDA_MIN_LOG_DECAY, kda_chunks, kda_scan, uses_kernels
 from ..ops.matmul import mxu_dot
 from ..parallel.moe import (
     held_experts_ffn, init_held_experts_params, route_grouped,
@@ -53,14 +53,14 @@ from ..parallel.moe import (
 FULL, SLIDING = "full_attention", "sliding_attention"
 KDA, MLA = "kda", "mla"
 COUNTERS = ("moe_slots_held", "moe_load_max_over_mean", "moe_slots_dropped")
-KDA_COUNTERS = ("kda_chunks", "kda_decay_min")
+KDA_COUNTERS = ("kda_chunks", "kda_chunks_in_kernel", "kda_decay_min")
 # a counter over the layers that report it: the mean of the slots held, the
 # worst load ratio, every slot dropped; the chunks a sequence (the same in
 # every layer), the smallest decay anywhere
 _REDUCE = {
     "moe_slots_held": jnp.mean, "moe_load_max_over_mean": jnp.max,
     "moe_slots_dropped": jnp.sum, "kda_chunks": jnp.max,
-    "kda_decay_min": jnp.min,
+    "kda_chunks_in_kernel": jnp.max, "kda_decay_min": jnp.min,
 }
 
 
@@ -650,7 +650,10 @@ class HybridLM(DecoderLM):
     that starts at 0 and no step moves).  The frame is DecoderLM's.
 
     Counters beside DecoderLM's: ``kda_chunks`` (the chunks the scan walks
-    one after another for a sequence) and ``kda_decay_min`` (the smallest
+    one after another for a sequence), ``kda_chunks_in_kernel`` (those of
+    them walked inside the Pallas kernels of ``ops/kda.py``: all where
+    ``kda_scan`` takes the kernels, 0 on its ``jax.numpy`` path; decided
+    with the scan's own rule) and ``kda_decay_min`` (the smallest
     decay ``alpha`` of the step, over every KDA layer, token and channel: a
     gate that underflows shows here)."""
 
@@ -746,10 +749,14 @@ class HybridLM(DecoderLM):
         sequence carries the scan's state and the convolutions' last
         inputs, and each segment is a checkpoint.  What lives at once
         between a projection and the output product (some twenty float32
-        tensors of tokens x 4096, and ``kda_scan``'s chunk matrices) is
-        then one segment's; at 16 384 tokens the sequence's would be
-        several GB.  A longer sequence is whole segments: there is no
-        fallback that would give the bound up.  (Under ``remat`` the
+        tensors of tokens x 4096, and what ``kda_scan`` keeps for its
+        backward pass: every chunk's entering state and ``T`` where it
+        takes the Pallas kernels, 40 MB a segment of 1024 tokens and 32
+        heads; its chunk matrices on the ``jax.numpy`` path) is then one
+        segment's; at 16 384 tokens the sequence's would be several GB.
+        A longer sequence is whole segments: there is no fallback that
+        would give the bound up.  ``attention_impl`` governs the scan's
+        kernels as it governs the flash kernels.  (Under ``remat`` the
         layer's checkpoint runs the mixer a second time and a segment's a
         third.  Leaving the layer's off a KDA layer would save that pass,
         and the step would need 15.62 GB in place of 14.88: PERF.md
@@ -791,6 +798,7 @@ class HybridLM(DecoderLM):
                 (unit(mixed["q"]) * d ** -0.5).astype(cdt),
                 unit(mixed["k"]).astype(cdt), mixed["v"].astype(cdt), g, beta,
                 chunk=cfg.kda_chunk, initial_state=state, return_state=True,
+                force=self.attention_impl,
             )  # (B, H, seg, d) float32
             out = rms_norm(out.transpose(0, 2, 1, 3), lp["o_norm"], cfg.rms_norm_eps)
             out = out.reshape(b, seg, heads * d) * jax.nn.sigmoid(project("g_w"))
@@ -806,9 +814,15 @@ class HybridLM(DecoderLM):
             segment, start,
             jnp.moveaxis(u.reshape(b, s // seg, seg, hidden), 1, 0),
         )
+        chunks = s // seg * kda_chunks(seg, cfg.kda_chunk)
+        in_kernel = uses_kernels(
+            (b, heads, seg, d), (b, heads, seg, d), cfg.kda_chunk,
+            self.attention_impl,
+        )
         counters = {
-            "kda_chunks": jnp.asarray(
-                s // seg * kda_chunks(seg, cfg.kda_chunk), jnp.float32
+            "kda_chunks": jnp.asarray(chunks, jnp.float32),
+            "kda_chunks_in_kernel": jnp.asarray(
+                chunks if in_kernel else 0, jnp.float32
             ),
             "kda_decay_min": jnp.exp(jnp.min(least)),
         }

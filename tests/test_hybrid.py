@@ -429,7 +429,7 @@ def test_lm_app_main_trains_the_hybrid_and_prints_the_counters(capsys):
     )
     out = capsys.readouterr().out
     assert "Iteration 12, loss = " in out and "moe_slots_dropped = 0" in out
-    assert "kda_chunks = 2, kda_decay_min = 0." in out
+    assert "kda_chunks = 2, kda_chunks_in_kernel = 0, kda_decay_min = 0." in out
     assert "experts_held=(4, 4) of 16" in out
     assert np.isfinite(metrics["loss"]) and metrics["kda_chunks"] == 2.0
 

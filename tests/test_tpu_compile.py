@@ -103,10 +103,13 @@ def test_held_experts_compile_to_the_grouped_product_for_v5e(one_chip, no_compil
     assert text.count("ragged-dot") >= 6  # gate+up and down, and both gradients of each
 
 
-def test_kda_scan_compiles_for_v5e_at_real_widths(one_chip, no_compile_cache):
+@pytest.mark.parametrize("force", ["flash", "reference"], ids=["kernels", "jax_numpy"])
+def test_kda_scan_compiles_for_v5e_at_real_widths(force, one_chip, no_compile_cache):
     """One segment of a KDA layer of ``ling3_flash`` (1024 tokens, 32 heads
-    of 128, chunks of 64), forward and backward: the chunk matrices are
-    matrix products and the recurrence a loop, with no kernel of ours."""
+    of 128, chunks of 64), forward and backward, in both forms: the Pallas
+    kernels, each with the state ``f32[1,32,128,128]`` among its operands
+    or results (what the benchmark's reader of ``kda_scan_ms`` matches),
+    and ``jax.numpy``, matrix products and a loop with no kernel of ours."""
     from sparknet_tpu.ops.kda import kda_scan
 
     shape = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one_chip)
@@ -114,14 +117,22 @@ def test_kda_scan_compiles_for_v5e_at_real_widths(one_chip, no_compile_cache):
 
     def grads(q, k, v, g, beta, state):
         def total(*a):
-            out, end = kda_scan(*a, chunk=64, initial_state=state, return_state=True)
+            out, end = kda_scan(
+                *a[:5], chunk=64, initial_state=a[5], return_state=True, force=force
+            )
             return out.sum() + end.sum()
 
-        return jax.grad(total, range(5))(q, k, v, g, beta)
+        return jax.grad(total, range(6))(q, k, v, g, beta, state)
 
     text = jax.jit(grads).lower(
         wide(jnp.bfloat16), wide(jnp.bfloat16), wide(jnp.bfloat16), wide(jnp.float32),
         shape((1, 32, 1024), jnp.float32), shape((1, 32, 128, 128), jnp.float32),
     ).compile().as_text()
-    assert " while(" in text and "tpu_custom_call" not in text
     assert "kda.scan" in text  # the scope reaches the compiled program's metadata
+    if force == "reference":
+        assert " while(" in text and "tpu_custom_call" not in text
+        return
+    calls = [line for line in text.splitlines() if " custom-call(" in line and "kda_scan" in line]
+    for name in ("kda_scan_fwd", "kda_scan_bwd"):
+        mine = [line for line in calls if name in line.split(" = ")[0]]
+        assert mine and all("f32[1,32,128,128]" in line for line in mine), name
