@@ -7,6 +7,19 @@ BertApp's schedule, batches staged ahead by ``maybe_prefetch``.
     python -m sparknet_tpu.apps.lm_app --config tiny --max-iter 20
     python -m sparknet_tpu.apps.lm_app --config benchmark/configs/laguna_xs2.json \\
         --bf16 --remat --seq-len 8192 --batch-size 2 --synthetic-tokens 4194304
+    python -m sparknet_tpu.apps.lm_app --config benchmark/configs/mellum2.json \\
+        --bf16 --remat --seq-len 8192 --batch-size 4 --pack-documents
+
+``--pack-documents`` trains on packed documents (``data.text.packed_feed``:
+lengths ``clip(lognormal(--doc-median, --doc-sigma), --doc-min, --doc-max)``
+tokens, concatenated and cut every ``--seq-len``): attention, rotary
+positions and the loss keep inside each document, and the progress line
+gains ``doc_count``, ``loss_positions``, ``attn_pairs_full``,
+``attn_pairs_window`` and ``flash_tiles_docs_full`` / ``_window`` (the score
+tiles the documents leave of the start-up line's ``flash_tiles``, which is
+then the most a batch-head walks).  The ``train feed:`` line says what
+traffic the run had, with the pool's mean pairs a sequence (the registry's
+``attn_pairs_pool`` gauges: what a mean step's attention costs).
 
 ``--config`` is ``tiny``, ``tiny_hybrid`` or the path of a JSON file holding
 a published ``config.json``'s keys, as cut to this chip's share if it is
@@ -35,7 +48,9 @@ from typing import Dict
 
 import jax.numpy as jnp
 
-from ..data.text import clm_dataset, clm_feed
+from ..data.text import (
+    clm_dataset, clm_feed, packed_dataset, packed_feed, pool_pairs,
+)
 from ..models.decoder import (
     MLA, SLIDING, DecoderConfig, DecoderLM, HybridConfig, HybridLM,
 )
@@ -60,18 +75,60 @@ def make_config(args):
 def build(args):
     """(solver, feed, cfg): one chip through the Solver."""
     cfg = make_config(args)
-    ds = clm_dataset(
-        vocab_size=cfg.vocab_size, n_tokens=args.synthetic_tokens,
-        seq_len=args.seq_len, seed=args.seed,
-    )
     shapes = {"input_ids": (args.batch_size, args.seq_len)}
+    if args.pack_documents:
+        if args.seq_len < args.doc_min:
+            raise SystemExit(
+                f"--pack-documents: --seq-len {args.seq_len} is shorter than "
+                f"the shortest document (--doc-min {args.doc_min})"
+            )
+        ds = packed_dataset(
+            vocab_size=cfg.vocab_size, n_tokens=args.synthetic_tokens,
+            seq_len=args.seq_len, median_len=args.doc_median,
+            sigma=args.doc_sigma, min_len=args.doc_min, max_len=args.doc_max,
+            seed=args.seed,
+        )
+        make_feed = packed_feed
+        shapes.update(segment_ids=shapes["input_ids"], positions=shapes["input_ids"])
+    else:
+        ds = clm_dataset(
+            vocab_size=cfg.vocab_size, n_tokens=args.synthetic_tokens,
+            seq_len=args.seq_len, seed=args.seed,
+        )
+        make_feed = clm_feed
     model = (HybridLM if isinstance(cfg, HybridConfig) else DecoderLM)(
         cfg, shapes,
         compute_dtype=jnp.bfloat16 if args.bf16 else jnp.float32,
         attention_impl=args.attention or None,
     )
+    if args.pack_documents:
+        print(packing_note(args, cfg, ds))
     solver = Solver(make_solver_param(args), shapes, model=model, seed=args.seed)
-    return solver, clm_feed(ds, args.batch_size, seed=args.seed), cfg
+    return solver, make_feed(ds, args.batch_size, seed=args.seed), cfg
+
+
+def packing_note(args, cfg, ds) -> str:
+    """The ``train feed:`` line of a run on packed documents: the packing's
+    parameters, the first batch's documents, and what a sequence of this
+    traffic costs an attention layer — the mean over the pool, steady where
+    a batch's own pairs (the progress line's ``attn_pairs_*``) are 0.45-1.75
+    x it; set once as the registry's ``attn_pairs_pool`` gauges, one a
+    layer kind."""
+    from ..telemetry.registry import REGISTRY
+
+    first = next(iter(packed_feed(ds, args.batch_size, seed=args.seed)))
+    pairs = {"full": pool_pairs(ds), "window": pool_pairs(ds, cfg.sliding_window)}
+    for kind, mean in pairs.items():
+        REGISTRY.gauge("attn_pairs_pool", kind=kind).set(mean)
+    return (
+        f"train feed: packed documents, lengths clip(lognormal(median "
+        f"{args.doc_median:g}, sigma {args.doc_sigma:g}), {args.doc_min}, "
+        f"{args.doc_max}) tokens, cut every {args.seq_len}; first batch "
+        f"doc_count={int((first['positions'] == 0).sum())} "
+        f"loss_positions={int((first['labels'] >= 0).sum())}; the pool's "
+        f"mean attn_pairs a sequence full={pairs['full']:.0f} "
+        f"window={pairs['window']:.0f}"
+    )
 
 
 def flash_tiles(cfg, seq_len: int) -> Dict[str, int]:
@@ -100,6 +157,14 @@ def parser() -> argparse.ArgumentParser:
     ap.add_argument("--lr", type=float, default=1e-4)
     ap.add_argument("--display", type=int, default=20)
     ap.add_argument("--synthetic-tokens", type=int, default=1 << 16)
+    ap.add_argument("--pack-documents", action="store_true",
+                    help="train on packed documents: attention, positions "
+                         "and the loss keep inside each (data.text.packed_feed)")
+    ap.add_argument("--doc-median", type=float, default=1024.0,
+                    help="median document length, tokens (lognormal)")
+    ap.add_argument("--doc-sigma", type=float, default=1.0)
+    ap.add_argument("--doc-min", type=int, default=32)
+    ap.add_argument("--doc-max", type=int, default=8192)
     # one choice, but not a knob of this app's: every app's args carry
     # ``parallel`` for ``maybe_prefetch``, and benchmark/run.py reads it
     ap.add_argument("--parallel", choices=("none",), default="none",
@@ -142,6 +207,10 @@ def main(argv=None) -> Dict[str, float]:
     tiles = flash_tiles_note(
         flash_tiles(cfg, args.seq_len) if uses_flash(args.attention) else {}
     )
+    if args.pack_documents:
+        # the band's tiles; what a batch's documents leave of them is the
+        # progress line's flash_tiles_docs_full / _window
+        tiles = tiles.replace("flash_tiles=", "flash_tiles at most ", 1)
     print(
         f"LmApp: config={args.config} vocab={cfg.vocab_size} "
         f"layers={cfg.num_layers} hidden={cfg.hidden_size} experts_held="

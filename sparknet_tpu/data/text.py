@@ -1,5 +1,6 @@
 """Text data layer for the BERT family and the decoder: corpus -> MLM
-batches (:func:`mlm_feed`) or next-token batches (:func:`clm_feed`).
+batches (:func:`mlm_feed`), next-token batches over one unbroken stream
+(:func:`clm_feed`) or over packed documents (:func:`packed_feed`).
 
 No reference counterpart (SparkNet has no text path — SURVEY.md §2);
 follows the framework's RDD-style contract: partitions are pure
@@ -244,3 +245,100 @@ def clm_feed(
         }
 
     return ds.batches(batch_size, shuffle=True, seed=seed, transform=transform)
+
+
+# a position of a packed batch that bears no loss: the last token of a
+# document (and of a sequence), whose next token is another document's
+NO_LABEL = -100
+
+
+def packed_dataset(
+    *,
+    vocab_size: int,
+    n_tokens: int = 1 << 16,
+    seq_len: int = 128,
+    median_len: float = 1024.0,
+    sigma: float = 1.0,
+    min_len: int = 32,
+    max_len: int = 8192,
+    num_partitions: int = 8,
+    seed: int = 0,
+) -> ShardedDataset:
+    """Documents packed into sequences of ``seq_len`` tokens, as a code or
+    chat corpus is: lengths ``clip(lognormal(median_len, sigma), min_len,
+    max_len)`` from ``seed``, concatenated and cut every ``seq_len`` tokens;
+    a document cut by a sequence's end goes on in the next sequence as a
+    document of its own.  No padding: every position is a token.  Rows:
+    ``input_ids``; ``labels`` (the next token, ``NO_LABEL`` where it is
+    another document's or the sequence ends); ``segment_ids`` (0, 1, ... along
+    a sequence); ``positions`` (inside the document), all (seq_len,) int32.
+
+    Token ids are a chain over ``[NUM_SPECIAL, vocab_size)`` that restarts
+    at a random id with every document and, inside one, with probability
+    0.2 a token: each token otherwise predicts ``previous + 17``, so there
+    is structure to learn.  Built with array operations only (the pool is
+    made once, in set-up)."""
+    real = vocab_size - NUM_SPECIAL
+    assert real >= 2, "vocab too small"
+    if seq_len < min_len:
+        raise ValueError(
+            f"sequences of {seq_len} tokens are shorter than the shortest "
+            f"document ({min_len}): nothing to pack"
+        )
+    n_seq = n_tokens // seq_len
+    if n_seq == 0:
+        raise ValueError(f"{n_tokens} tokens hold no sequence of {seq_len}")
+    total = n_seq * seq_len
+    rng = np.random.default_rng(seed)
+    lengths = np.clip(
+        np.rint(rng.lognormal(np.log(median_len), sigma, total // min_len + 1)),
+        min_len, max_len,
+    ).astype(np.int64)
+    starts = np.concatenate([[0], np.cumsum(lengths)])
+    at = np.arange(total, dtype=np.int32)
+    opens = np.zeros(total, bool)  # a document begins here
+    opens[starts[starts < total]] = True
+    opens[::seq_len] = True  # a sequence begins with (the rest of) one
+    since_last = lambda flags: at - np.maximum.accumulate(
+        np.where(flags, at, np.int32(0))
+    )
+    # the chain: restarts where a document begins, and at random inside
+    since = since_last(opens | (rng.random(total, dtype=np.float32) < 0.2))
+    base = rng.integers(0, real, total, dtype=np.int32)[at - since]
+    tokens = (base + 17 * since) % np.int32(real) + np.int32(NUM_SPECIAL)
+    labels = np.empty_like(tokens)  # the next token, unless it opens another
+    labels[:-1] = np.where(opens[1:], np.int32(NO_LABEL), tokens[1:])
+    labels[-1] = NO_LABEL
+    rows = lambda x: x.reshape(n_seq, seq_len)
+    segment = np.cumsum(rows(opens), axis=1, dtype=np.int32) - np.int32(1)
+    position = since_last(opens)
+    return ShardedDataset.from_arrays(
+        {
+            "input_ids": rows(tokens), "labels": rows(labels),
+            "segment_ids": segment, "positions": rows(position),
+        },
+        min(num_partitions, n_seq),
+    )
+
+
+def packed_feed(
+    ds: ShardedDataset, batch_size: int, seed: int = 0
+) -> Iterator[Dict[str, np.ndarray]]:
+    """Batches of :func:`packed_dataset`'s rows in the DecoderLM blob
+    layout (host numpy): ``input_ids``, ``labels``, ``segment_ids`` and
+    ``positions``, (B, S) int32, through the same iterator as
+    :func:`clm_feed`."""
+    return ds.batches(batch_size, shuffle=True, seed=seed)
+
+
+def pool_pairs(ds: ShardedDataset, window: Optional[int] = None) -> float:
+    """The (query, key) pairs a causal attention layer computes on one
+    sequence of :func:`packed_dataset`'s pool, the mean over the pool: each
+    token with the keys of its own document at or before it, the nearest
+    ``window`` of them where given."""
+    seen = np.concatenate([
+        ds.collect_partition(i)["positions"] for i in range(ds.num_partitions)
+    ]).astype(np.int64) + 1
+    if window is not None:
+        seen = np.minimum(seen, window)
+    return float(seen.sum() / len(seen))

@@ -11,7 +11,9 @@ or ``sliding_attention`` per layer), ``num_attention_heads_per_layer``,
 ``mlp_layer_types`` (``dense`` or ``sparse``), ``rope_parameters`` per
 layer type.  Per layer, pre-norm: ``h = x + Attn(RMSNorm(x))``, ``y = h +
 FFN(RMSNorm(h))``; a final RMSNorm and an untied head; the loss is the mean
-next-token cross-entropy over every position.
+next-token cross-entropy over every position — or, on a batch of packed
+documents (below), over the positions whose next token belongs to the same
+document.
 
 - **Attention**: grouped-query (``num_key_value_heads`` under a per-layer
   head count), no bias, rotary embeddings by layer type — ``default``
@@ -24,14 +26,23 @@ next-token cross-entropy over every position.
 - **FFN**: SwiGLU. Dense layers at ``intermediate_size``; sparse layers
   are :func:`sparknet_tpu.parallel.moe.held_experts_ffn` — this chip's
   ``experts_held`` of the router's ``num_experts``, no token dropped —
-  plus a shared expert with weight 1.
+  plus a shared expert with weight 1 where the file gives one a width.
+  The router is the configuration's ``scoring_func``: ``sigmoid``
+  (``route_sigmoid``, with ``moe_routed_scaling_factor``) or ``softmax``
+  (``route_softmax``; a file that says ``norm_topk_prob: false`` is refused).
+- **Packed documents**: a batch that also carries ``segment_ids`` and
+  ``positions`` (B, S) (``data.text.packed_feed``) rotates by the position
+  inside the document, attends inside documents only (the flash kernels
+  skip key blocks that hold only other documents), and counts the loss
+  where ``labels >= 0``.  Its counters beside the expert layer's:
+  ``DOC_COUNTERS``.
 - A published ``gating`` flag is not modelled: no equation comes with it.
 
 It satisfies the :class:`~sparknet_tpu.solver.trainer.Solver` net protocol
 as :class:`~.bert.BertMLM` does: float32 weights in the two-level layout,
 ``compute_dtype`` activations and matmul inputs, float32 norms, softmax,
 router and loss.  Batch blobs: ``input_ids`` (B, S) and ``labels`` (B, S)
-int32, the next token at every position.
+int32, the next token at every position (-100 where a packed batch has none).
 """
 
 from __future__ import annotations
@@ -43,17 +54,27 @@ from typing import Any, Dict, List, Mapping, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import attention
+from ..ops.attention import attention, document_spans, flash_tiles_documents
 from ..ops.kda import KDA_MIN_LOG_DECAY, kda_chunks, kda_scan, uses_kernels
 from ..ops.matmul import mxu_dot
 from ..parallel.moe import (
-    held_experts_ffn, init_held_experts_params, route_grouped,
+    held_experts_ffn, init_held_experts_params, route_grouped, route_sigmoid,
+    route_softmax,
 )
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 KDA, MLA = "kda", "mla"
 COUNTERS = ("moe_slots_held", "moe_load_max_over_mean", "moe_slots_dropped")
 KDA_COUNTERS = ("kda_chunks", "kda_chunks_in_kernel", "kda_decay_min")
+# of a batch of packed documents, newest step: the documents in it; the
+# positions that bear a loss; the keys every token sees, summed over the
+# batch, in one layer of a kind (times heads and head size: a product's
+# multiply-adds); the score tiles a batch-head of such a layer executes in
+# the flash forward and dq kernels (ops.attention.flash_tiles_documents)
+DOC_COUNTERS = (
+    "doc_count", "loss_positions", "attn_pairs_full", "attn_pairs_window",
+    "flash_tiles_docs_full", "flash_tiles_docs_window",
+)
 # a counter over the layers that report it: the mean of the slots held, the
 # worst load ratio, every slot dropped; the chunks a sequence (the same in
 # every layer), the smallest decay anywhere
@@ -61,6 +82,7 @@ _REDUCE = {
     "moe_slots_held": jnp.mean, "moe_load_max_over_mean": jnp.max,
     "moe_slots_dropped": jnp.sum, "kda_chunks": jnp.max,
     "kda_chunks_in_kernel": jnp.max, "kda_decay_min": jnp.min,
+    **dict.fromkeys(DOC_COUNTERS, jnp.max),  # of the batch: reported once
 }
 
 
@@ -83,6 +105,9 @@ class DecoderConfig:
     moe_intermediate_size: int = 0
     shared_expert_intermediate_size: int = 0
     moe_routed_scaling_factor: float = 1.0
+    # the router: "sigmoid" scores times the factor above, or "softmax"
+    # probabilities; both normalised over the chosen
+    scoring_func: str = "sigmoid"
     rms_norm_eps: float = 1e-6
     initializer_range: float = 0.02
     # recompute each layer in the backward pass; tokens per chunk of the
@@ -101,6 +126,10 @@ class DecoderConfig:
         ``num_experts`` counts the experts held here, from
         ``deployment.experts_first`` on, of the router's
         ``deployment.num_experts_routed`` — both default to all)."""
+        if not published.get("norm_topk_prob", True):
+            raise ValueError(
+                "norm_topk_prob false: both routers normalise over the chosen"
+            )
         n = published["num_hidden_layers"]
         heads = published.get("num_attention_heads_per_layer") or (
             [published["num_attention_heads"]] * n
@@ -128,6 +157,7 @@ class DecoderConfig:
             moe_routed_scaling_factor=published.get(
                 "moe_routed_scaling_factor", 1.0
             ),
+            scoring_func=published.get("scoring_func", "sigmoid"),
             rms_norm_eps=published["rms_norm_eps"],
         )
         fields.update(overrides)
@@ -216,12 +246,13 @@ def rope_inv_freq(rope: Mapping[str, Any], head_dim: int) -> Tuple[jax.Array, fl
 
 def apply_rope(x, positions, inv_freq, scale):
     """Rotate the first ``2 * len(inv_freq)`` dims of each head of ``x``
-    (B, S, H, D), in float32, by the rotate-half convention."""
+    (B, S, H, D), in float32, by the rotate-half convention; ``positions``
+    (S,), or (B, S) where they differ by sequence (packed documents)."""
     rot = 2 * inv_freq.shape[0]
-    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
-    angles = jnp.concatenate([angles, angles], axis=-1)  # (S, rot)
-    cos = (jnp.cos(angles) * scale)[None, :, None, :]
-    sin = (jnp.sin(angles) * scale)[None, :, None, :]
+    angles = jnp.atleast_2d(positions).astype(jnp.float32)[:, :, None] * inv_freq
+    angles = jnp.concatenate([angles, angles], axis=-1)  # (B or 1, S, rot)
+    cos = (jnp.cos(angles) * scale)[:, :, None, :]
+    sin = (jnp.sin(angles) * scale)[:, :, None, :]
     xf = x.astype(jnp.float32)
     xr, rest = xf[..., :rot], xf[..., rot:]
     half = jnp.concatenate([-xr[..., rot // 2:], xr[..., : rot // 2]], axis=-1)
@@ -265,10 +296,19 @@ class DecoderLM:
         b, s = input_shapes["input_ids"]
         self.batch, self.seq_len = b, s
         self.input_names: List[str] = ["input_ids", "labels"]
+        # packed documents: the batch carries these two blobs as well
+        self.packed = "segment_ids" in input_shapes
+        if self.packed:
+            self._check_packed()
+            self.input_names += ["segment_ids", "positions"]
+            self.counters = self.counters + DOC_COUNTERS
         self.blob_shapes: Dict[str, Tuple[int, ...]] = {
-            "input_ids": (b, s), "labels": (b, s), "loss": (),
+            **{name: (b, s) for name in self.input_names}, "loss": (),
             "token_acc": (), **{name: () for name in self.counters},
         }
+
+    def _check_packed(self) -> None:
+        pass  # every layer of this model keeps to a document's bounds
 
     def _check_layers(self) -> None:
         cfg = self.cfg
@@ -282,6 +322,8 @@ class DecoderLM:
                 raise ValueError(
                     f"{heads} heads over {cfg.num_key_value_heads} KV heads"
                 )
+        if cfg.scoring_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring_func {cfg.scoring_func!r}")
 
     # -- init ----------------------------------------------------------------
     def init(self, rng: jax.Array):
@@ -317,7 +359,8 @@ class DecoderLM:
 
     def _init_ffn(self, li: int, trunc, keys):
         """Layer ``li``'s FFN weights: the dense SwiGLU, or the held experts
-        with their router and the shared expert."""
+        with their router and, where the configuration gives it a width,
+        the shared expert."""
         cfg = self.cfg
         h, ffn = cfg.hidden_size, {}
         if cfg.mlp_layer_types[li] == "sparse":
@@ -327,6 +370,8 @@ class DecoderLM:
             ))
             width = cfg.shared_expert_intermediate_size
             prefix = "shared_"
+            if not width:
+                return ffn
         else:
             width, prefix = cfg.intermediate_size, ""
         ffn.update({
@@ -337,14 +382,16 @@ class DecoderLM:
         return ffn
 
     # -- layers --------------------------------------------------------------
-    def _attention(self, li: int, lp, u):
+    def _attention(self, li: int, lp, u, docs=None):
+        """``docs``: (segment_ids, positions) of a packed batch, else the
+        sequence is one document."""
         cfg, cdt = self.cfg, self.compute_dtype
         b, s, _ = u.shape
         kind = cfg.layer_types[li]
         heads = cfg.num_attention_heads_per_layer[li]
         kv, d = cfg.num_key_value_heads, cfg.head_dim
         inv_freq, factor = rope_inv_freq(cfg.rope_parameters[kind], d)
-        positions = jnp.arange(s)
+        segment_ids, positions = docs or (None, jnp.arange(s))
 
         def project(w, n, rotate):
             t = mxu_dot(u, w.astype(cdt)).reshape(b, s, n, d)
@@ -358,12 +405,20 @@ class DecoderLM:
             project(lp["v_w"], kv, False),
             causal=True,
             window=cfg.sliding_window if kind == SLIDING else None,
-            force=self.attention_impl,
+            segment_ids=segment_ids, force=self.attention_impl,
         )
         out = out.transpose(0, 2, 1, 3).reshape(b, s, heads * d)
         return mxu_dot(out, lp["o_w"].astype(cdt))
 
-    _router = None  # route_sigmoid, held_experts_ffn's own; else (xt, lp)
+    def _router(self, xt, lp):
+        """(weights, experts) a token, by the configuration's scoring."""
+        cfg = self.cfg
+        if cfg.scoring_func == "softmax":
+            return route_softmax(xt, lp["router_w"], cfg.num_experts_per_tok)
+        return route_sigmoid(
+            xt, lp["router_w"], cfg.num_experts_per_tok,
+            cfg.moe_routed_scaling_factor,
+        )
 
     def _ffn(self, li: int, lp, u):
         """(float32 FFN output, this layer's counters)."""
@@ -373,27 +428,28 @@ class DecoderLM:
         routed, counters = held_experts_ffn(
             u, lp, experts_held=cfg.experts_held,
             top_k=cfg.num_experts_per_tok,
-            routed_scale=cfg.moe_routed_scaling_factor,
             compute_dtype=self.compute_dtype, router=self._router,
         )
+        if not cfg.shared_expert_intermediate_size:
+            return routed.astype(jnp.float32), counters
         with jax.named_scope("moe.shared"):
             shared = swiglu(
                 u, lp["shared_gate_w"], lp["shared_up_w"], lp["shared_down_w"]
             )
         return shared + routed.astype(jnp.float32), counters
 
-    def _mix(self, li: int, lp, u):
+    def _mix(self, li: int, lp, u, docs=None):
         """The layer's token mixer on the normed ``u``: (float32 output,
         its counters), under the layer kind's scope."""
         scope = "attn.window" if self.cfg.layer_types[li] == SLIDING else "attn.full"
         with jax.named_scope(scope):
-            return self._attention(li, lp, u), {}
+            return self._attention(li, lp, u, docs), {}
 
-    def layer_apply(self, li: int, lp, x):
+    def layer_apply(self, li: int, lp, x, docs=None):
         """One layer on ``x`` (B, S, h): (x, the layer's counters)."""
         cfg, cdt = self.cfg, self.compute_dtype
         mixed, counters = self._mix(
-            li, lp, rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+            li, lp, rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps), docs
         )
         x = (x.astype(jnp.float32) + mixed).astype(cdt)
         fed, ffn_counters = self._ffn(
@@ -403,14 +459,14 @@ class DecoderLM:
             **counters, **ffn_counters
         }
 
-    def hidden(self, params, input_ids):
+    def hidden(self, params, input_ids, docs=None):
         """The final-layer hidden states (before the head's norm) and the
         layers' counters, one dict a layer."""
         cfg = self.cfg
         x = params["embed"]["tokens"][input_ids].astype(self.compute_dtype)
         counted = []
         for li in range(cfg.num_layers):
-            fn = lambda lp, x, li=li: self.layer_apply(li, lp, x)
+            fn = lambda lp, x, li=li: self.layer_apply(li, lp, x, docs)
             if cfg.remat:
                 fn = jax.checkpoint(fn)
             x, counters = fn(params[f"layer_{li:02d}"], x)
@@ -418,8 +474,10 @@ class DecoderLM:
         return x, counted
 
     def _loss(self, head, x, labels):
-        """(mean next-token NLL, accuracy) over every position, the
-        logits made ``loss_chunk`` tokens at a time and not kept."""
+        """(mean next-token NLL, accuracy) over every position — of a
+        packed batch, over the positions that bear a label (``labels >=
+        0``) — the logits made ``loss_chunk`` tokens at a time and not
+        kept."""
         cfg, cdt = self.cfg, self.compute_dtype
         x = rms_norm(x, head["norm"], cfg.rms_norm_eps)
         tokens = labels.size
@@ -436,6 +494,16 @@ class DecoderLM:
         def one(xc, yc):
             logits = mxu_dot(xc, lm_w)  # (chunk, V) f32
             lse = jax.scipy.special.logsumexp(logits, axis=-1)
+            if self.packed:
+                borne = yc >= 0
+                picked = jnp.take_along_axis(
+                    logits, jnp.maximum(yc, 0)[:, None], axis=-1
+                )[:, 0]
+                hit = borne & (jnp.argmax(logits, -1) == yc)
+                return (
+                    jnp.sum(jnp.where(borne, lse - picked, 0.0)),
+                    jnp.sum(hit.astype(jnp.float32)),
+                )
             picked = jnp.take_along_axis(logits, yc[:, None], axis=-1)[:, 0]
             hit = jnp.argmax(logits, -1) == yc
             return jnp.sum(lse - picked), jnp.sum(hit.astype(jnp.float32))
@@ -445,14 +513,39 @@ class DecoderLM:
             return (carry[0] + nll, carry[1] + hit), None
 
         (nll, hit), _ = jax.lax.scan(body, (0.0, 0.0), (xs, ys))
+        if self.packed:
+            tokens = jnp.maximum(jnp.sum(labels >= 0), 1).astype(jnp.float32)
         return nll / tokens, hit / tokens
+
+    def _doc_counters(self, batch):
+        """DOC_COUNTERS of a packed batch: a few vector operations on its
+        ``segment_ids`` and ``labels``."""
+        cfg = self.cfg
+        seg = batch["segment_ids"]
+        at = jnp.arange(seg.shape[1], dtype=jnp.int32)
+        start, _ = document_spans(seg)
+        seen = at - start + 1  # the keys a token sees in a full layer
+        total = lambda x: jnp.sum(x).astype(jnp.float32)
+        return {
+            "doc_count": total(at == start),
+            "loss_positions": total(batch["labels"] >= 0),
+            "attn_pairs_full": total(seen),
+            "attn_pairs_window": total(jnp.minimum(seen, cfg.sliding_window)),
+            "flash_tiles_docs_full": flash_tiles_documents(seg),
+            "flash_tiles_docs_window": flash_tiles_documents(
+                seg, window=cfg.sliding_window
+            ),
+        }
 
     # -- Solver protocol -----------------------------------------------------
     def apply(self, params, state, batch, *, train=None, rng=None):
-        x, counted = self.hidden(params, batch["input_ids"])
+        docs = (batch["segment_ids"], batch["positions"]) if self.packed else None
+        x, counted = self.hidden(params, batch["input_ids"], docs)
         with jax.named_scope("lm_head"):
             loss, acc = self._loss(params["head"], x, batch["labels"])
         blobs = {"loss": loss, "token_acc": acc}
+        if self.packed:
+            counted = counted + [self._doc_counters(batch)]
         for name in self.counters:
             seen = [c[name] for c in counted if name in c]
             blobs[name] = (
@@ -488,7 +581,7 @@ class DecoderLM:
 
     def dummy_batch(self):
         zeros = jnp.zeros((self.batch, self.seq_len), jnp.int32)
-        return {"input_ids": zeros, "labels": zeros}
+        return {name: zeros for name in self.input_names}
 
     def num_params(self, params) -> int:
         return sum(int(x.size) for x in jax.tree_util.tree_leaves(params))
@@ -661,6 +754,12 @@ class HybridLM(DecoderLM):
     _no_decay = ("A_log", "dt_bias")  # the decay gate's vectors
     _buffers = ("router_bias",)  # the selection bias: it starts at 0 and stays
 
+    def _check_packed(self) -> None:
+        raise NotImplementedError(
+            "packed documents through KDA layers: the scan's state and the "
+            "convolutions' history would have to reset at a boundary"
+        )
+
     def _check_layers(self) -> None:
         cfg = self.cfg
         for kind in set(cfg.layer_types):
@@ -736,7 +835,7 @@ class HybridLM(DecoderLM):
             cfg.moe_routed_scaling_factor, cfg.n_group, cfg.topk_group,
         )
 
-    def _mix(self, li: int, lp, u):
+    def _mix(self, li: int, lp, u, docs=None):
         if self.cfg.layer_types[li] == KDA:
             with jax.named_scope("attn.kda"):
                 return self._kda(lp, u)

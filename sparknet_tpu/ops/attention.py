@@ -25,8 +25,15 @@ for the BERT family and the long-context path:
   expanded form: q and k of 192 = 128 + 64 rotary, v of 128): every block,
   scratch and output that holds values, the output or their gradients
   takes v's head size, the rest q's, and no product is padded.
-  With ``window=None`` and equal head counts the traced kernels are the
-  plain ones.  Per score tile the forward does its two products, the
+  ``segment_ids`` (with ``causal``) keeps attention inside packed
+  documents: the band of blocks a q block walks starts at the block
+  holding its first row's document start (and, in dkv, a key block's ends
+  at the block holding its last row's document end), from two small
+  tables a call; inside a tile a key is seen while the query is not past
+  the end of the key's document.
+  With ``window=None``, equal head counts and no ``segment_ids`` the
+  traced kernels are the plain ones.  Per score tile the forward does
+  its two products, the
   masks that apply (the key mask only if one was passed or keys were
   padded; the position mask on every tile of a causal call), one max and
   one sum across lanes, two ``exp``, and keeps its running max and sum
@@ -84,10 +91,13 @@ def mha_reference(
     dropout_rate: float = 0.0,
     dropout_rng: Optional[jax.Array] = None,
     window: Optional[int] = None,
+    segment_ids: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Plain attention. q,k,v: (B,H,S,D); kv_mask: (B,Sk) True=valid.
     ``window`` (needs ``causal``): query i sees keys i - window < j <= i.
     ``k``/``v`` may have fewer heads than ``q`` (grouped-query).
+    ``segment_ids`` (B, S), for q and k alike: query i sees key j only if
+    both carry the same id (packed documents).
 
     A query row with *no* valid key (fully padded) outputs exactly zero
     and propagates zero gradients — same contract as the flash kernel.
@@ -112,6 +122,10 @@ def mha_reference(
             valid = valid & (qi - ki < window)[None, None]
     if kv_mask is not None:
         valid = valid & kv_mask[:, None, None, :].astype(bool)
+    if segment_ids is not None:
+        valid = valid & (
+            segment_ids[:, None, :, None] == segment_ids[:, None, None, :]
+        )
     logits = jnp.where(valid, logits, NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
     p = jnp.where(jnp.any(valid, -1, keepdims=True), p, 0.0)
@@ -182,7 +196,7 @@ def mha_reference_lse(q, k, v, **kw):
 # ---------------------------------------------------------------------------
 
 
-def _band(i, shift, blk_i, blk_j, nj, lo_back, hi_fwd):
+def _band(i, shift, blk_i, blk_j, nj, lo_back, hi_fwd, doc_lo=None, doc_hi=None):
     """(first, last) block along the other axis that block ``i`` of this
     axis can touch.  Block ``i`` covers local positions ``i*blk_i ..
     i*blk_i + blk_i - 1``; a position p touches ``p + shift - lo_back ..
@@ -191,10 +205,16 @@ def _band(i, shift, blk_i, blk_j, nj, lo_back, hi_fwd):
     kv_offset``, ``lo_back = window - 1`` (None without a window),
     ``hi_fwd = 0`` if causal.  dkv walks q blocks from a key block:
     ``shift = kv_offset - q_offset``, ``lo_back = 0`` if causal,
-    ``hi_fwd = window - 1``.  Works on Python ints and on traced scalars
-    (index maps and kernels call it with the same arguments, so the block
-    a kernel computes on is the block that was fetched)."""
-    static = isinstance(i, int) and isinstance(shift, int)
+    ``hi_fwd = window - 1``.  Packed documents tighten one end: ``doc_lo``
+    is the first key block a q block's documents reach back to (forward,
+    dq), ``doc_hi`` the last q block a key block's documents reach (dkv),
+    from ``_document_tables``; the band is the tighter of the two.  Works
+    on Python ints and on traced scalars (index maps and kernels call it
+    with the same arguments, so the block a kernel computes on is the block
+    that was fetched)."""
+    static = all(
+        isinstance(x, int) for x in (i, shift, doc_lo, doc_hi) if x is not None
+    )
     most, least = (max, min) if static else (jnp.maximum, jnp.minimum)
     lo = 0 if lo_back is None else (
         most(i * blk_i + shift - lo_back, 0) // blk_j
@@ -202,6 +222,10 @@ def _band(i, shift, blk_i, blk_j, nj, lo_back, hi_fwd):
     hi = nj - 1 if hi_fwd is None else least(
         most(i * blk_i + blk_i - 1 + shift + hi_fwd, 0) // blk_j, nj - 1
     )
+    if doc_lo is not None:
+        lo = most(lo, doc_lo)
+    if doc_hi is not None:
+        hi = least(hi, doc_hi)
     return lo, hi
 
 
@@ -237,7 +261,9 @@ def flash_tile_kinds(
     tile: two bodies, one for tiles wholly inside the band, measured
     slower — PERF.md §6, PR 32) and of a call that passed a key mask or
     padded its keys; none of any other call.  A q block that sees no key
-    at all counts one tile, which the banded kernels walk."""
+    at all counts one tile, which the banded kernels walk.  For a call
+    with ``segment_ids`` this is the most it executes: what the documents
+    of a batch leave of it is :func:`flash_tiles_documents`."""
     pad_q, pad_k, blk_q, blk_k = _resolve_blocks(sq, sk, block_q, block_k)
     nq, nk = (sq + pad_q) // blk_q, (sk + pad_k) // blk_k
     executed = 0
@@ -251,9 +277,86 @@ def flash_tile_kinds(
     return (0, executed) if masked else (executed, 0)
 
 
-def _position_keep(qi, kb, q_offset, kv_offset, blk_q, blk_k, window):
+def document_spans(segment_ids: jax.Array):
+    """(start, end), both (B, S) int32: the positions of the first and the
+    last token of each token's document, for ids that do not decrease
+    along a sequence."""
+    b, n = segment_ids.shape
+    at = jnp.arange(n, dtype=jnp.int32)
+    edge = segment_ids[:, 1:] != segment_ids[:, :-1]
+    one = jnp.ones((b, 1), bool)
+    start = jax.lax.cummax(
+        jnp.where(jnp.concatenate([one, edge], axis=1), at, 0), axis=1
+    )
+    end = jax.lax.cummin(
+        jnp.where(jnp.concatenate([edge, one], axis=1), at, n - 1),
+        axis=1, reverse=True,
+    )
+    return start, end
+
+
+def _document_tables(segment_ids, rows_q, rows_k, blk_q, blk_k):
+    """What the banded kernels take for packed documents, from
+    ``segment_ids`` (B, S) with S <= the padded lengths ``rows_q``,
+    ``rows_k`` (padding is a document of its own): the keys' document
+    ends, sublane-broadcast (B, 8, rows_k) as the key mask is; the first
+    key block of each q block — the block holding the first token of the
+    document of the q block's FIRST row, since ids do not decrease — flat
+    (B * nq,); and the last q block of each key block — the block holding
+    the last token of the document of the key block's LAST row — flat
+    (B * nk,).  ``_band``'s lower (forward, dq) and upper (dkv) block
+    become the tighter of the window's and these."""
+    b, s = segment_ids.shape
+    n = max(rows_q, rows_k)
+    ids = jnp.pad(
+        segment_ids.astype(jnp.int32), ((0, 0), (0, n - s)),
+        constant_values=jnp.iinfo(jnp.int32).max,
+    )
+    start, end = document_spans(ids)
+    first_kb = start[:, 0:rows_q:blk_q] // blk_k
+    last_qb = jnp.minimum(
+        end[:, blk_k - 1:rows_k:blk_k] // blk_q, rows_q // blk_q - 1
+    )
+    doc_end = jnp.broadcast_to(end[:, None, :rows_k], (b, 8, rows_k))
+    return doc_end, first_kb.reshape(-1), last_qb.reshape(-1)
+
+
+def flash_tiles_documents(
+    segment_ids: jax.Array, *, window: Optional[int] = None,
+    block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
+) -> jax.Array:
+    """Score tiles a batch-head of a causal :func:`flash_attention` call
+    with these ``segment_ids`` (B, S) executes in its forward and dq
+    kernels (the mean over the batch's rows, float32; traceable): the
+    tiles of :func:`flash_tile_kinds` that the documents' first key blocks
+    leave, from the same table the kernels get.  dkv walks the transposed
+    band, cut at each key block's last q block: as many tiles or a few
+    more or fewer."""
+    b, s = segment_ids.shape
+    pad_q, pad_k, blk_q, blk_k = _resolve_blocks(s, s, block_q, block_k)
+    nq, nk = (s + pad_q) // blk_q, (s + pad_k) // blk_k
+    _, first_kb, _ = _document_tables(
+        segment_ids, s + pad_q, s + pad_k, blk_q, blk_k
+    )
+    band = [
+        _band(i, 0, blk_q, blk_k, nk, None if window is None else window - 1, 0)
+        for i in range(nq)
+    ]
+    lo = jnp.maximum(
+        first_kb.reshape(b, nq), jnp.asarray([lo for lo, _ in band], jnp.int32)
+    )
+    hi = jnp.asarray([hi for _, hi in band], jnp.int32)
+    return jnp.mean(jnp.sum(jnp.maximum(hi - lo + 1, 1), axis=1).astype(jnp.float32))
+
+
+def _position_keep(
+    qi, kb, q_offset, kv_offset, blk_q, blk_k, window, doc_end=None
+):
     """(blk_q, blk_k) bool: the pairs of tile ``(qi, kb)`` inside the
-    causal band."""
+    causal band and, given ``doc_end`` (a (blk_k,) row: the position of the
+    last token of each key's document), inside one document: ids do not
+    decrease along a sequence, so a key at or before the query shares its
+    document iff the query is not past that document's end."""
     q_pos = (
         jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
         + qi * blk_q + q_offset
@@ -265,7 +368,17 @@ def _position_keep(qi, kb, q_offset, kv_offset, blk_q, blk_k, window):
     keep = k_pos <= q_pos
     if window is not None:
         keep &= q_pos - k_pos < window
+    if doc_end is not None:
+        keep &= q_pos <= doc_end[None, :]
     return keep
+
+
+def _table_entry(doc_refs, block):
+    """This grid step's entry of the documents' flat (batch row, block)
+    table — the kernel's batch row and third grid axis — or None."""
+    if doc_refs is None:
+        return None
+    return doc_refs[0][pl.program_id(0) * pl.num_programs(2) + block]
 
 
 def _mask_scores(s, kmask, keep):
@@ -332,12 +445,17 @@ def _fwd_kernel(
     window: Optional[int] = None,
     kv_fold: Optional[int] = None,
     total_kb: Optional[int] = None,
+    doc_refs=None,
 ):
     """``nkb`` is the length of the last grid axis.  Plain calls walk
     every key block; a banded call (``total_kb`` given: a window or
     grouped KV heads) walks the ``nkb`` blocks from the first one its q
     block can see, of ``total_kb``.  ``kv_fold`` KV heads arrive per
-    step (``fold`` where every query head has its own).
+    step (``fold`` where every query head has its own).  ``doc_refs``
+    (packed documents; ``_document_tables``): the scalar-prefetched first
+    key block of each (batch row, q block), which tightens the band, and
+    this step's (1, 8, blk_k) block of the keys' document ends, which
+    masks inside a tile.
 
     What a score tile costs besides its two products sets the kernel's
     pace, and what cost most was the shape of ``m`` and ``l`` (PERF.md
@@ -360,6 +478,7 @@ def _fwd_kernel(
         first_kb, last_kb = _band(
             qi, q_offset - kv_offset, blk_q, blk_k, total_kb,
             None if window is None else window - 1, 0 if causal else None,
+            doc_lo=_table_entry(doc_refs, qi),
         )
         kb = first_kb + step
 
@@ -373,7 +492,8 @@ def _fwd_kernel(
         # m_ref[0, 0]: (blk_k,) int8, shared by all heads
         kmask = None if m_ref is None else m_ref[0, 0]
         causal_keep = _position_keep(
-            qi, kb, q_offset, kv_offset, blk_q, blk_k, window
+            qi, kb, q_offset, kv_offset, blk_q, blk_k, window,
+            None if doc_refs is None else doc_refs[1][0, 0],
         ) if causal else None
         for hh in range(fold):
             kv = hh * kv_fold // fold
@@ -449,10 +569,12 @@ def _bwd_dq_kernel(
     dq_ref, dq_s, *, causal: bool, scale: float, nkb: int,
     dropout_rate: float, fold: int, window: Optional[int] = None,
     kv_fold: Optional[int] = None, total_kb: Optional[int] = None,
+    doc_refs=None,
 ):
     """Grid (b, h/F, nq, nk): K/V stream over the last dim, dq (per
     folded head) accumulates in VMEM scratch, written on the final k
-    step.  ``window``/``kv_fold``/``total_kb`` as in the forward."""
+    step.  ``window``/``kv_fold``/``total_kb``/``doc_refs`` as in the
+    forward."""
     qi = pl.program_id(2)
     step = pl.program_id(3)
     blk_q = q_ref.shape[2]
@@ -465,6 +587,7 @@ def _bwd_dq_kernel(
         first_kb, last_kb = _band(
             qi, q_offset - kv_offset, blk_q, blk_k, total_kb,
             None if window is None else window - 1, 0 if causal else None,
+            doc_lo=_table_entry(doc_refs, qi),
         )
         kb = first_kb + step
 
@@ -475,7 +598,8 @@ def _bwd_dq_kernel(
     def compute():
         kmask = None if m_ref is None else m_ref[0, 0]
         causal_keep = _position_keep(
-            qi, kb, q_offset, kv_offset, blk_q, blk_k, window
+            qi, kb, q_offset, kv_offset, blk_q, blk_k, window,
+            None if doc_refs is None else doc_refs[1][0, 0],
         ) if causal else None
         for hh in range(fold):
             kv = hh * kv_fold // fold
@@ -534,7 +658,7 @@ def _bwd_dkv_kernel(
     dk_ref, dv_ref, dk_s, dv_s, *, causal: bool, scale: float, nqb: int,
     dropout_rate: float, fold: int, window: Optional[int] = None,
     kv_fold: Optional[int] = None, total_qb: Optional[int] = None,
-    band_qb: Optional[int] = None,
+    band_qb: Optional[int] = None, doc_refs=None,
 ):
     """Grid (b, h/F, nk, nq): Q/dO/lse/delta stream over the last dim,
     dk/dv (per folded head) accumulate in VMEM scratch, written once on
@@ -542,7 +666,9 @@ def _bwd_dkv_kernel(
     (b, H_kv/kv_fold, nk, R * band_qb): the last axis walks, for each of
     the R blocks of ``fold`` query heads that read these KV heads, the
     ``band_qb`` q blocks from the first that can see this key block, so
-    dk/dv come out summed over the group; ``nqb`` is that axis' length."""
+    dk/dv come out summed over the group; ``nqb`` is that axis' length.
+    ``doc_refs``: the scalar-prefetched last q block of each (batch row,
+    key block) and this key block's document ends."""
     ki = pl.program_id(2)
     step = pl.program_id(3)
     blk_k = k_ref.shape[2]
@@ -555,6 +681,7 @@ def _bwd_dkv_kernel(
         first_qb, last_qb = _band(
             ki, kv_offset - q_offset, blk_k, blk_q, total_qb,
             0 if causal else None, None if window is None else window - 1,
+            doc_hi=_table_entry(doc_refs, ki),
         )
         qb = first_qb + step % band_qb
 
@@ -566,7 +693,8 @@ def _bwd_dkv_kernel(
     def compute():
         kmask = None if m_ref is None else m_ref[0, 0]
         causal_keep = _position_keep(
-            qb, ki, q_offset, kv_offset, blk_q, blk_k, window
+            qb, ki, q_offset, kv_offset, blk_q, blk_k, window,
+            None if doc_refs is None else doc_refs[1][0, 0],
         ) if causal else None
         for hh in range(fold):
             kv = hh * kv_fold // fold
@@ -662,9 +790,27 @@ def _without_mask(kernel, in_specs, args):
         return kernel, in_specs, args
     at = 4 - (len(args) - len(in_specs))
     return (
-        lambda *refs: kernel(*refs[:4], None, *refs[4:]),
+        lambda *refs, **kw: kernel(*refs[:4], None, *refs[4:], **kw),
         in_specs[:at] + in_specs[at + 1:],
         args[:4] + args[5:],
+    )
+
+
+def _with_docs(kernel, in_specs, args, table, doc_end, end_spec):
+    """(kernel, in_specs, args, scalar prefetches) of a banded call: as
+    given and 1 without documents; with them ``table`` (a flat int32 bound
+    per batch row and block) is prefetched beside the offsets, ``doc_end``
+    is the last input, and the kernel finds both in its ``doc_refs``."""
+    if table is None:
+        return kernel, in_specs, args, 1
+    n = len(args) - 1  # the inputs after the offsets
+    return (
+        lambda off, tab, *refs: kernel(
+            off, *refs[:n], *refs[n + 1:], doc_refs=(tab, refs[n])
+        ),
+        in_specs + [end_spec],
+        (args[0], table, *args[1:], doc_end),
+        2,
     )
 
 
@@ -737,12 +883,13 @@ def _banded_fold(fold, h, group, blk_q, blk_k, d):
 
 def _flash_fwd(
     q, k, v, kv_mask, offsets, causal, scale, blk_q, blk_k, interpret,
-    dropout_rate, fold=None, band=None, shift=None,
+    dropout_rate, fold=None, band=None, shift=None, docs=None,
 ):
     """``band`` = (window, group) makes the call banded (see the module
     header): scalar-prefetched offsets, a last grid axis over the band of
     key blocks, K/V addressed by group.  ``shift`` is ``q_offset -
-    kv_offset`` where both are Python ints (an exact band), else None."""
+    kv_offset`` where both are Python ints (an exact band), else None.
+    ``docs`` (``_document_tables``, banded calls only): packed documents."""
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
     nkb = sk // blk_k
@@ -792,8 +939,9 @@ def _flash_fwd(
     back, fwd = (None if window is None else window - 1), (0 if causal else None)
     steps = _band_steps(nqb, shift, blk_q, blk_k, nkb, back, fwd)
     in_specs, _, o_spec, lane_spec = _banded_qk_specs(
-        blk_q, blk_k, d, dv, fold, kv_fold, group, nkb, back, fwd
+        blk_q, blk_k, d, dv, fold, kv_fold, group, nkb, back, fwd, nqb
     )
+    end_spec = in_specs[3]  # a row a key block, as the key mask
     kernel, in_specs, args = _without_mask(
         functools.partial(
             _fwd_kernel, causal=causal, scale=scale, nkb=steps,
@@ -803,10 +951,14 @@ def _flash_fwd(
         in_specs,
         (offsets, q, k, v, kv_mask),
     )
+    doc_end, first_kb, _ = docs or (None, None, None)
+    kernel, in_specs, args, prefetched = _with_docs(
+        kernel, in_specs, args, first_kb, doc_end, end_spec
+    )
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=prefetched,
             grid=(b, h // fold, nqb, steps),
             in_specs=in_specs,
             out_specs=[o_spec, lane_spec],
@@ -823,28 +975,36 @@ def _flash_fwd(
     return out, lse
 
 
-def _banded_qk_specs(blk_q, blk_k, d, dv, fold, kv_fold, group, nkb, back, fwd):
+def _banded_qk_specs(
+    blk_q, blk_k, d, dv, fold, kv_fold, group, nkb, back, fwd, nqb
+):
     """(in_specs for (q, k, v, mask), the q-shaped spec, the o-shaped
     spec, the lane-replicated spec) of a banded call on a (b, h/F, nq,
-    band) grid; the offsets are scalar-prefetched and arrive last in
-    every index map.  Step j of q block i addresses key block
+    band) grid; what is scalar-prefetched (``pre``: the offsets and, with
+    documents, the flat (b, ``nqb``) table of first key blocks) arrives
+    last in every index map.  Step j of q block i addresses key block
     ``min(first + j, last)``."""
 
-    def kv_block(i, j, off):
-        first, last = _band(i, off[0] - off[1], blk_q, blk_k, nkb, back, fwd)
+    def kv_block(b_, i, j, pre):
+        off = pre[0]
+        first, last = _band(
+            i, off[0] - off[1], blk_q, blk_k, nkb, back, fwd,
+            doc_lo=pre[1][b_ * nqb + i] if len(pre) > 1 else None,
+        )
         return jnp.minimum(first + j, last)
 
     q_rows = lambda width: pl.BlockSpec(
-        (1, fold, blk_q, width), lambda b_, g, i, j, off: (b_, g, i, 0)
+        (1, fold, blk_q, width), lambda b_, g, i, j, *pre: (b_, g, i, 0)
     )
     kv_rows = lambda width: pl.BlockSpec(
         (1, kv_fold, blk_k, width),
-        lambda b_, g, i, j, off: (
-            b_, g * fold // (group * kv_fold), kv_block(i, j, off), 0
+        lambda b_, g, i, j, *pre: (
+            b_, g * fold // (group * kv_fold), kv_block(b_, i, j, pre), 0
         ),
     )
     mask_spec = pl.BlockSpec(
-        (1, 8, blk_k), lambda b_, g, i, j, off: (b_, 0, kv_block(i, j, off))
+        (1, 8, blk_k),
+        lambda b_, g, i, j, *pre: (b_, 0, kv_block(b_, i, j, pre)),
     )
     q_spec = q_rows(d)
     return (
@@ -882,7 +1042,7 @@ def _flash_vjp_fwd(
 
 def _flash_vjp_bwd(
     causal, scale, blk_q, blk_k, interpret, dropout_rate, fold, res, do,
-    band=None, shift=None,
+    band=None, shift=None, docs=None,
 ):
     q, k, v, kv_mask, offsets, out, lse = res
     b, h, sq, _ = q.shape
@@ -898,6 +1058,7 @@ def _flash_vjp_bwd(
         q, k, v, kv_mask, offsets, do, lse, delta, causal=causal,
         scale=scale, blk_q=blk_q, blk_k=blk_k, interpret=interpret,
         dropout_rate=dropout_rate, fold=fold, band=band, shift=shift,
+        docs=docs,
     )
     return dq, dk, dv, None, None
 
@@ -905,6 +1066,7 @@ def _flash_vjp_bwd(
 def _flash_bwd(
     q, k, v, kv_mask, offsets, do, lse, delta, *, causal, scale,
     blk_q, blk_k, interpret, dropout_rate, fold=None, band=None, shift=None,
+    docs=None,
 ):
     """The two backward pallas calls, reusable per ring block: ``lse``
     and ``delta`` arrive lane-replicated (b, h, sq, 128) and may be the
@@ -919,6 +1081,7 @@ def _flash_bwd(
             q, k, v, kv_mask, offsets, do, lse, delta, causal=causal,
             scale=scale, blk_q=blk_q, blk_k=blk_k, interpret=interpret,
             dropout_rate=dropout_rate, fold=fold, band=band, shift=shift,
+            docs=docs,
         )
     if fold is None:
         fold = _fold_heads(h, blk_q, blk_k, max(d, dv))
@@ -1015,13 +1178,15 @@ def _flash_bwd(
 
 def _flash_bwd_banded(
     q, k, v, kv_mask, offsets, do, lse, delta, *, causal, scale,
-    blk_q, blk_k, interpret, dropout_rate, fold, band, shift,
+    blk_q, blk_k, interpret, dropout_rate, fold, band, shift, docs=None,
 ):
     """The two backward calls of a banded forward (``_flash_fwd``'s
     ``band``).  dq walks the same band of key blocks as the forward.
     dkv's grid is (b, H_kv/kv_fold, nk, R * band): for each of the R
     blocks of query heads that read a block of KV heads, the band of q
-    blocks that can see the key block; dk/dv leave summed over them."""
+    blocks that can see the key block; dk/dv leave summed over them.
+    With ``docs`` the forward's band starts, and dkv's ends, where the
+    documents' bounds say."""
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
     nqb, nkb = sq // blk_q, sk // blk_k
@@ -1036,17 +1201,21 @@ def _flash_bwd_banded(
 
     steps = _band_steps(nqb, shift, blk_q, blk_k, nkb, span, edge)
     in_specs, q_spec, o_spec, lane_spec = _banded_qk_specs(
-        blk_q, blk_k, d, dv, fold, kv_fold, group, nkb, span, edge
+        blk_q, blk_k, d, dv, fold, kv_fold, group, nkb, span, edge, nqb
     )
+    doc_end, first_kb, last_qb = docs or (None, None, None)
     args = (offsets, q, k, v, kv_mask, do, lse, delta)
     kernel, dq_specs, dq_args = _without_mask(
         functools.partial(_bwd_dq_kernel, nkb=steps, total_kb=nkb, **static),
         in_specs + [o_spec, lane_spec, lane_spec], args,
     )
+    kernel, dq_specs, dq_args, prefetched = _with_docs(
+        kernel, dq_specs, dq_args, first_kb, doc_end, in_specs[3]
+    )
     dq = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=prefetched,
             grid=(b, h // fold, nqb, steps),
             in_specs=dq_specs,
             out_specs=q_spec,
@@ -1061,33 +1230,42 @@ def _flash_bwd_banded(
         nkb, None if shift is None else -shift, blk_k, blk_q, nqb, edge, span
     )
 
-    def q_block(i, t, off):
-        first, last = _band(i, off[1] - off[0], blk_k, blk_q, nqb, edge, span)
+    def q_block(b_, i, t, pre):
+        off = pre[0]
+        first, last = _band(
+            i, off[1] - off[0], blk_k, blk_q, nqb, edge, span,
+            doc_hi=pre[1][b_ * nkb + i] if len(pre) > 1 else None,
+        )
         return jnp.minimum(first + t % steps, last)
 
     q_rows = lambda width: pl.BlockSpec(
         (1, fold, blk_q, width),
-        lambda b_, g, i, t, off: (b_, g * reps + t // steps, q_block(i, t, off), 0),
+        lambda b_, g, i, t, *pre: (
+            b_, g * reps + t // steps, q_block(b_, i, t, pre), 0
+        ),
     )
     kv_rows = lambda width: pl.BlockSpec(
-        (1, kv_fold, blk_k, width), lambda b_, g, i, t, off: (b_, g, i, 0)
+        (1, kv_fold, blk_k, width), lambda b_, g, i, t, *pre: (b_, g, i, 0)
     )
+    key_row = pl.BlockSpec((1, 8, blk_k), lambda b_, g, i, t, *pre: (b_, 0, i))
     kernel, dkv_specs, dkv_args = _without_mask(
         functools.partial(
             _bwd_dkv_kernel, nqb=reps * steps, total_qb=nqb, band_qb=steps,
             **static,
         ),
         [
-            q_rows(d), kv_rows(d), kv_rows(dv),
-            pl.BlockSpec((1, 8, blk_k), lambda b_, g, i, t, off: (b_, 0, i)),
+            q_rows(d), kv_rows(d), kv_rows(dv), key_row,
             q_rows(dv), q_rows(128), q_rows(128),
         ],
         args,
     )
+    kernel, dkv_specs, dkv_args, prefetched = _with_docs(
+        kernel, dkv_specs, dkv_args, last_qb, doc_end, key_row
+    )
     dk, dv = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=prefetched,
             grid=(b, h // (group * kv_fold), nkb, reps * steps),
             in_specs=dkv_specs,
             out_specs=[kv_rows(d), kv_rows(dv)],
@@ -1110,36 +1288,41 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12)
+    jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11, 12, 13)
 )
 def _flash_banded(
-    q, k, v, kv_mask, offsets, causal, scale, blk_q, blk_k, interpret,
+    q, k, v, kv_mask, offsets, docs, causal, scale, blk_q, blk_k, interpret,
     fold, band, shift,
 ):
-    """``_flash`` for a window and/or grouped KV heads (no dropout)."""
+    """``_flash`` for a window, grouped KV heads and/or packed documents
+    (``docs``: ``_document_tables``' arrays, or None); no dropout."""
     return _flash_fwd(
         q, k, v, kv_mask, offsets, causal, scale, blk_q, blk_k, interpret,
-        0.0, fold=fold, band=band, shift=shift,
+        0.0, fold=fold, band=band, shift=shift, docs=docs,
     )[0]
 
 
 def _flash_banded_vjp_fwd(
-    q, k, v, kv_mask, offsets, causal, scale, blk_q, blk_k, interpret,
+    q, k, v, kv_mask, offsets, docs, causal, scale, blk_q, blk_k, interpret,
     fold, band, shift,
 ):
     out, lse = _flash_fwd(
         q, k, v, kv_mask, offsets, causal, scale, blk_q, blk_k, interpret,
-        0.0, fold=fold, band=band, shift=shift,
+        0.0, fold=fold, band=band, shift=shift, docs=docs,
     )
-    return out, (q, k, v, kv_mask, offsets, out, lse[..., 0])
+    return out, ((q, k, v, kv_mask, offsets, out, lse[..., 0]), docs)
 
 
 def _flash_banded_vjp_bwd(
     causal, scale, blk_q, blk_k, interpret, fold, band, shift, res, do
 ):
-    return _flash_vjp_bwd(
-        causal, scale, blk_q, blk_k, interpret, 0.0, fold, res, do,
-        band=band, shift=shift,
+    res, docs = res
+    return (
+        *_flash_vjp_bwd(
+            causal, scale, blk_q, blk_k, interpret, 0.0, fold, res, do,
+            band=band, shift=shift, docs=docs,
+        ),
+        None,
     )
 
 
@@ -1279,6 +1462,7 @@ def flash_attention(
     interpret: bool = False,
     fold: Optional[int] = None,
     window: Optional[int] = None,
+    segment_ids: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Flash attention on (B,H,S,D); ``k``/``v`` may be (B,H_kv,S,D) with
     ``H % H_kv == 0`` (grouped-query), ``v`` (B,H_kv,S,D_v) with a head
@@ -1291,6 +1475,15 @@ def flash_attention(
     kernel always streams in O(block) VMEM — 128-multiples get
     full-size MXU blocks with no padding; prefer those. Offsets may be
     traced scalars — ring attention passes per-step shard offsets.
+
+    ``segment_ids`` (B, S) int32, non-decreasing along a sequence, for q
+    and k alike (causal self-attention, no offsets, no dropout): packed
+    documents.  Query i sees key j only inside its own document; key
+    blocks that hold only other documents are neither computed nor
+    fetched, in all three kernels, as a window's are (two small int32
+    tables a call, scalar-prefetched beside the offsets:
+    ``_document_tables``).  Such a call takes the banded kernels whatever
+    its group and window.
 
     Attention-probability dropout runs inside the kernels via the TPU
     PRNG, seeded per (batch, head, q-block, k-block) so forward and both
@@ -1339,7 +1532,21 @@ def flash_attention(
     # Passing it here (rather than flipping SPARKNET_FLASH_FOLD after a
     # trace) keys the jit cache honestly — a different fold is a
     # different traced argument, so an A/B actually recompiles.
-    if window is None and group == 1:
+    static = isinstance(q_offset, int) and isinstance(kv_offset, int)
+    docs = None
+    if segment_ids is not None:
+        if not (causal and sq == sk and static and q_offset == kv_offset == 0):
+            raise ValueError(
+                "segment_ids are for causal self-attention without offsets"
+            )
+        if segment_ids.shape != (b, sq):
+            raise ValueError(
+                f"segment_ids {segment_ids.shape} for q {q.shape}"
+            )
+        docs = _document_tables(
+            segment_ids, sq + pad_q, sk + pad_k, block_q, block_k
+        )
+    if window is None and group == 1 and docs is None:
         out = _flash(
             q, k, v, kv_mask, offsets, causal, scale, block_q, block_k,
             interpret, float(dropout_rate), fold,
@@ -1347,11 +1554,11 @@ def flash_attention(
     else:
         if dropout_rate > 0.0:
             raise NotImplementedError(
-                "attention dropout with a window or grouped KV heads"
+                "attention dropout with a window, grouped KV heads or "
+                "segment_ids"
             )
-        static = isinstance(q_offset, int) and isinstance(kv_offset, int)
         out = _flash_banded(
-            q, k, v, kv_mask, offsets, causal, scale, block_q, block_k,
+            q, k, v, kv_mask, offsets, docs, causal, scale, block_q, block_k,
             interpret, fold, (window, group),
             q_offset - kv_offset if static else None,
         )
@@ -1372,7 +1579,8 @@ def uses_flash(force: Optional[str] = None, dropping: bool = False) -> bool:
 def attention(
     q, k, v, *, causal=False, kv_mask=None, scale=None,
     q_offset=0, kv_offset=0, dropout_rate=0.0, dropout_rng=None,
-    window: Optional[int] = None, force: Optional[str] = None, **flash_kw
+    window: Optional[int] = None, segment_ids=None,
+    force: Optional[str] = None, **flash_kw
 ):
     """Dispatch: Pallas flash on TPU, reference elsewhere.
 
@@ -1391,10 +1599,11 @@ def attention(
             q, k, v, causal=causal, kv_mask=kv_mask, scale=scale,
             q_offset=q_offset, kv_offset=kv_offset,
             dropout_rate=dropout_rate, dropout_rng=dropout_rng,
-            window=window, **flash_kw
+            window=window, segment_ids=segment_ids, **flash_kw
         )
     return mha_reference(
         q, k, v, causal=causal, kv_mask=kv_mask, scale=scale,
         q_offset=q_offset, kv_offset=kv_offset,
         dropout_rate=dropout_rate, dropout_rng=dropout_rng, window=window,
+        segment_ids=segment_ids,
     )

@@ -9,11 +9,12 @@ to a token when an expert is full.
 - :func:`held_experts_ffn` — **drops nothing.** One chip's share of an
   expert-parallel layer: it is told which experts it holds
   (``experts_held = (first, count)`` of the router's ``num_experts``),
-  routes over all of them (:func:`route_sigmoid`: sigmoid scores, the
-  ``top_k`` largest, weights normalised over every chosen expert, held
-  here or not, times ``routed_scale``; or the ``router`` it is given,
-  such as :func:`route_grouped`, which chooses by groups of experts on
-  biased scores), and computes the part of the result its own
+  routes over all of them by the ``router`` it is given
+  (:func:`route_sigmoid`: sigmoid scores, the ``top_k`` largest, weights
+  normalised over every chosen expert, held here or not, times
+  ``routed_scale``; :func:`route_softmax`: softmax probabilities
+  normalised over the chosen; :func:`route_grouped`, which chooses by
+  groups of experts on biased scores), and computes the part of the result its own
   SwiGLU experts give, for every slot routed to them whatever the
   imbalance: slots sorted by expert, the held ones gathered, grouped
   matrix products (``jax.lax.ragged_dot``) over the experts held,
@@ -295,6 +296,24 @@ def route_sigmoid(
     return routed_scale * top / jnp.sum(top, axis=-1, keepdims=True), idx
 
 
+def route_softmax(
+    xt: jax.Array, router_w: jax.Array, top_k: int
+) -> Tuple[jax.Array, jax.Array]:
+    """(weights, experts), both (T, K): ``p = softmax(xt W_r)`` over all
+    the experts in float32, the ``top_k`` largest (ties to the lower
+    index), ``p / sum(p)`` over the chosen (a published ``norm_topk_prob:
+    true``).  No scaling factor."""
+    probs = jax.nn.softmax(
+        jnp.dot(
+            xt.astype(jnp.float32), router_w,
+            preferred_element_type=jnp.float32,
+        ),
+        axis=-1,
+    )
+    top, idx = lax.top_k(probs, top_k)
+    return top / jnp.sum(top, axis=-1, keepdims=True), idx
+
+
 def route_grouped(
     xt: jax.Array, router_w: jax.Array, bias: jax.Array, top_k: int,
     routed_scale: float, n_group: int, topk_group: int,
@@ -454,17 +473,15 @@ def held_experts_ffn(
     *,
     experts_held: Tuple[int, int],
     top_k: int,
-    routed_scale: float = 1.0,
+    router: Callable,
     chunk_rows: Optional[int] = None,
     compute_dtype=jnp.float32,
-    router: Optional[Callable] = None,
 ):
     """The held experts' part of a sparse FFN (module header). ``x``:
-    (..., h), flattened to T tokens.  Returns ``(out, counters)``:
-    ``out`` has x's shape — add the shared expert and the residual
-    outside — and the counters are scalars of this call:
-    ``router(xt, params) -> (weights, experts)``, both (T, top_k), takes
-    the place of :func:`route_sigmoid` where given.  The counters:
+    (..., h), flattened to T tokens; ``router(xt, params) -> (weights,
+    experts)``, both (T, top_k), over all the experts.  Returns ``(out,
+    counters)``: ``out`` has x's shape — add the shared expert and the
+    residual outside — and the counters are scalars of this call:
     ``moe_slots_held`` (slots routed to held experts; T * top_k * held /
     num_experts if the router is even), ``moe_load_max_over_mean`` (the
     fullest held expert over their mean) and ``moe_slots_dropped``
@@ -488,9 +505,7 @@ def held_experts_ffn(
     slots = t * top_k
 
     with jax.named_scope("moe.route"):
-        weights, experts = router(xt, params) if router else route_sigmoid(
-            xt, params["router_w"], top_k, routed_scale
-        )
+        weights, experts = router(xt, params)
         local = experts.reshape(-1) - first  # (S,) token-major slots
         key = jnp.where((local >= 0) & (local < held), local, held)
         order = jnp.argsort(key, stable=True)  # held slots first, by expert

@@ -664,3 +664,225 @@ def test_flash_tile_kinds_of_the_benchmark_cells():
     assert flash_tile_kinds(512, 512, causal=False, key_mask=True) == (0, 1)
     assert flash_tile_kinds(512, 512, causal=False) == (1, 0)
     assert flash_tile_kinds(512, 500, causal=False) == (0, 1)  # padded keys
+
+
+# ---------------------------------------------------------------------------
+# packed documents: segment ids in the banded kernels
+# ---------------------------------------------------------------------------
+
+def _segments(*rows, total=None):
+    """(B, S) int32 ids from each row's document lengths."""
+    ids = [np.repeat(np.arange(len(r)), r) for r in rows]
+    assert len({len(x) for x in ids}) == 1 and (total is None or len(ids[0]) == total)
+    return jnp.asarray(np.stack(ids), jnp.int32)
+
+
+_PACKED = {
+    # heads, KV heads, window, blocks, the rows' document lengths (S = 512)
+    "full_documents_start_mid_block": (4, 4, None, 128, [(100, 156, 256), (300, 112, 100)]),
+    "full_grouped": (4, 2, None, 128, [(100, 156, 256), (300, 112, 100)]),
+    "window_grouped": (4, 2, 96, 128, [(100, 156, 256), (300, 112, 100)]),
+    "window_plain_heads": (2, 2, 200, 128, [(100, 156, 256), (300, 112, 100)]),
+    "a_document_of_one_token": (4, 2, None, 128, [(127, 1, 1, 383), (1, 510, 1)]),
+    "window_and_a_document_of_one_token": (4, 2, 64, 128, [(127, 1, 1, 383), (1, 510, 1)]),
+    "a_document_a_block_and_longer_ones": (4, 1, None, 128, [(128, 128, 256), (256, 255, 1)]),
+    "q_blocks_other_than_key_blocks": (4, 2, None, (64, 256), [(100, 156, 256), (300, 112, 100)]),
+    "unequal_blocks_and_a_window": (4, 2, 150, (256, 128), [(100, 156, 256), (300, 112, 100)]),
+}
+
+
+def _packed_call(case, **over):
+    h, hkv, window, blocks, rows = _PACKED[case]
+    bq, bk = blocks if isinstance(blocks, tuple) else (blocks, blocks)
+    seg = _segments(*rows, total=512)
+    q, k, v, ct = _grouped_qkv(35, seg.shape[0], h, hkv, 512, 512)
+    kw = dict(causal=True, window=window, segment_ids=seg)
+    kw.update(over)
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, block_q=bq, block_k=bk, interpret=True, **kw)
+    return (q, k, v, ct), flash, (lambda q, k, v: mha_reference(q, k, v, **kw)), seg
+
+
+@pytest.mark.parametrize("case", sorted(_PACKED))
+def test_segment_ids_through_the_flash_kernels_match_reference_fwd_and_grads(case):
+    """Documents that start mid-block, that are one token, under a window,
+    over grouped KV heads: output, dq, dk and dv of the three kernels in
+    interpret mode against ``mha_reference``'s dense ``seg_q == seg_k``."""
+    (q, k, v, ct), flash, plain, seg = _packed_call(case)
+    np.testing.assert_allclose(flash(q, k, v), plain(q, k, v), atol=2e-5, rtol=2e-5)
+    total = lambda f: (lambda *a: jnp.sum(f(*a) * ct))
+    for g, w, name in zip(jax.grad(total(flash), (0, 1, 2))(q, k, v),
+                          jax.grad(total(plain), (0, 1, 2))(q, k, v),
+                          ("dq", "dk", "dv")):
+        np.testing.assert_allclose(g, w, atol=5e-5, rtol=5e-5, err_msg=name)
+    # and the ids did something: without them the output is another
+    assert float(jnp.abs(plain(q, k, v) - mha_reference(
+        q, k, v, causal=True, window=_PACKED[case][2])).max()) > 1e-2
+
+
+@pytest.mark.parametrize("window", [None, 96])
+@pytest.mark.parametrize("hkv", [4, 2])
+def test_one_document_a_sequence_is_the_call_without_ids(window, hkv):
+    q, k, v, ct = _grouped_qkv(36, 2, 4, hkv, 512, 512)
+    seg = jnp.zeros((2, 512), jnp.int32) + jnp.asarray([[3], [7]])
+    call = lambda **kw: flash_attention(
+        q, k, v, causal=True, window=window, block_q=128, block_k=128,
+        interpret=True, **kw)
+    np.testing.assert_allclose(call(segment_ids=seg), call(), atol=1e-6)
+    total = lambda **kw: (lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True, window=window, block_q=128, block_k=128,
+        interpret=True, **kw) * ct))
+    for g, w in zip(jax.grad(total(segment_ids=seg), (0, 1, 2))(q, k, v),
+                    jax.grad(total(), (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(g, w, atol=2e-6)
+
+
+def test_segment_ids_with_a_length_the_blocks_do_not_divide():
+    """200 tokens: keys padded to 256 and masked, the padding a document of
+    its own in the tables."""
+    seg = _segments((70, 130), (1, 199))
+    q, k, v, ct = _grouped_qkv(37, 2, 4, 2, 200, 200)
+    kw = dict(causal=True, segment_ids=seg)
+    got = flash_attention(q, k, v, interpret=True, **kw)
+    np.testing.assert_allclose(got, mha_reference(q, k, v, **kw), atol=2e-5, rtol=2e-5)
+    total = lambda f, **e: (lambda *a: jnp.sum(f(*a, **kw, **e) * ct))
+    for g, w in zip(jax.grad(total(flash_attention, interpret=True), (0, 1, 2))(q, k, v),
+                    jax.grad(total(mha_reference), (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(g, w, atol=5e-5, rtol=5e-5)
+
+
+def test_segment_ids_refuse_what_they_do_not_do():
+    q, k, v, _ = _grouped_qkv(38, 1, 2, 2, 256, 256)
+    seg = jnp.zeros((1, 256), jnp.int32)
+    with pytest.raises(ValueError, match="causal self-attention without offsets"):
+        flash_attention(q, k, v, segment_ids=seg, interpret=True)
+    with pytest.raises(ValueError, match="causal self-attention without offsets"):
+        flash_attention(q, k, v, causal=True, q_offset=256, segment_ids=seg, interpret=True)
+    with pytest.raises(ValueError, match="segment_ids"):
+        flash_attention(q, k, v, causal=True, segment_ids=seg[:, :128], interpret=True)
+    with pytest.raises(NotImplementedError, match="dropout"):
+        flash_attention(q, k, v, causal=True, segment_ids=seg, dropout_rate=0.1,
+                        dropout_rng=jax.random.PRNGKey(0), interpret=True)
+
+
+def _random_rows(rng, s, n):
+    cuts = np.sort(rng.choice(np.arange(1, s), size=n - 1, replace=False))
+    return tuple(np.diff(np.concatenate([[0], cuts, [s]])))
+
+
+@pytest.mark.parametrize("window", [None, 100, 300])
+@pytest.mark.parametrize("blocks", [(128, 128), (64, 256), (256, 128)])
+def test_bands_document_bounds_hold_every_seen_pair_and_no_block_more(window, blocks):
+    """``_band`` on Python ints with the documents' bounds from
+    ``_document_tables``, against a brute-force mask: every pair the three
+    masks leave lies in a tile the forward (and dq) band of its q block
+    walks and in one the dkv band of its key block walks; the band's
+    tightened end holds a seen pair (no block is walked for nothing there);
+    and ``flash_tiles_documents`` counts the forward band's tiles."""
+    from sparknet_tpu.ops.attention import (
+        _band, _document_tables, document_spans, flash_tiles_documents,
+    )
+
+    s, (bq, bk) = 1024, blocks  # blocks the kernels would keep as given
+    rng = np.random.default_rng(5)
+    seg = _segments(_random_rows(rng, s, 5), _random_rows(rng, s, 2),
+                    (1,) * 3 + (s - 3,), (s,))
+    start, end = (np.asarray(x) for x in document_spans(seg))
+    doc_end, first_kb, last_qb = (
+        np.asarray(x) for x in _document_tables(seg, s, s, bq, bk))
+    nq, nk = s // bq, s // bk
+    first_kb, last_qb = first_kb.reshape(-1, nq), last_qb.reshape(-1, nk)
+    back = None if window is None else window - 1
+    i, j = np.arange(s)[:, None], np.arange(s)[None, :]
+    tiles = 0
+    for b, ids in enumerate(np.asarray(seg)):
+        np.testing.assert_array_equal(doc_end[b, 0], end[b])
+        assert (ids[start[b]] == ids).all() and (ids[end[b]] == ids).all()
+        seen = (j <= i) & (ids[:, None] == ids[None, :])
+        if window is not None:
+            seen &= i - j < window
+        tile = seen.reshape(nq, bq, nk, bk).any(axis=(1, 3))  # (nq, nk)
+        for qb in range(nq):
+            lo, hi = _band(qb, 0, bq, bk, nk, back, 0, doc_lo=int(first_kb[b, qb]))
+            assert isinstance(lo, int) and lo <= hi
+            walked = np.zeros(nk, bool)
+            walked[lo:hi + 1] = True
+            assert not (tile[qb] & ~walked).any(), (b, qb)
+            assert tile[qb, lo] and tile[qb, hi], (b, qb, lo, hi)
+            tiles += hi - lo + 1
+        for kb in range(nk):
+            lo, hi = _band(kb, 0, bk, bq, nq, 0, back, doc_hi=int(last_qb[b, kb]))
+            walked = np.zeros(nq, bool)
+            walked[lo:hi + 1] = True
+            assert not (tile[:, kb] & ~walked).any(), (b, kb)
+            assert tile[lo, kb] and tile[hi, kb], (b, kb, lo, hi)
+    counted = flash_tiles_documents(seg, window=window, block_q=bq, block_k=bk)
+    assert float(counted) == tiles / seg.shape[0]
+    # the last row is one document: the causal band's own count
+    from sparknet_tpu.ops.attention import flash_tile_kinds
+    whole = flash_tiles_documents(seg[-1:], window=window, block_q=bq, block_k=bk)
+    assert float(whole) == flash_tile_kinds(
+        s, s, causal=True, window=window, block_q=bq, block_k=bk)[1]
+
+
+def test_flash_tiles_documents_of_the_packed_cell():
+    """8192 tokens in 512-blocks: sixteen documents of one block each walk
+    the diagonal alone; a batch's counter is the mean over its rows."""
+    from sparknet_tpu.ops.attention import flash_tiles_documents
+
+    blocks = _segments((512,) * 16, (8192,))
+    assert float(flash_tiles_documents(blocks[:1])) == 16
+    assert float(flash_tiles_documents(blocks[1:])) == 136
+    assert float(flash_tiles_documents(blocks)) == (16 + 136) / 2
+    assert float(flash_tiles_documents(blocks, window=1024)) == (16 + 45) / 2
+
+
+@pytest.mark.skipif(
+    jax.default_backend() != "tpu",
+    reason="holds the compiled kernels, not the interpreter, to the reference",
+)
+@pytest.mark.parametrize("window", [None, 1024], ids=["full", "window"])
+def test_segment_ids_at_the_packed_cells_shapes_on_hardware(window):
+    """``mellum_train_packed8k``'s call — bfloat16 ``(4, 32, 8192, 128)``
+    over 4 KV heads, the packing feed's own documents — through the kernels
+    the chip compiles: output, dq, dk and dv against ``mha_reference`` in
+    float32 on the same rounded inputs, a batch row and a query head at a
+    time (a head's dense scores are 268 MB), each held to 2e-2 of the
+    largest value; a wrong bound or mask reads 0.1 of it and more."""
+    from sparknet_tpu.data.text import packed_dataset, packed_feed
+
+    b, h, hkv, s, d = 4, 32, 4, 8192, 128
+    ds = packed_dataset(vocab_size=24576, n_tokens=1 << 18, seq_len=s, seed=35)
+    seg = jnp.asarray(next(iter(packed_feed(ds, b, seed=35)))["segment_ids"])
+    ks = jax.random.split(jax.random.PRNGKey(35), 4)
+    q = jax.random.normal(ks[0], (b, h, s, d)).astype(jnp.bfloat16)
+    k = jax.random.normal(ks[1], (b, hkv, s, d)).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (b, hkv, s, d)).astype(jnp.bfloat16)
+    ct = jax.random.normal(ks[3], (b, h, s, d))
+    kw = dict(causal=True, window=window)
+    total = lambda f: (lambda q, k, v, seg, ct: jnp.sum(
+        f(q, k, v, segment_ids=seg, **kw).astype(jnp.float32) * ct))
+    f32 = lambda t: np.asarray(t.astype(jnp.float32))
+    got = [f32(flash_attention(q, k, v, segment_ids=seg, **kw)),
+           *map(f32, jax.grad(total(flash_attention), (0, 1, 2))(q, k, v, seg, ct))]
+
+    @jax.jit
+    def one_head(q1, k1, v1, seg1, ct1):
+        with jax.default_matmul_precision("highest"):
+            exact = [x.astype(jnp.float32) for x in (q1, k1, v1)]
+            out = mha_reference(*exact, segment_ids=seg1, **kw)
+            return (out, *jax.grad(total(mha_reference), (0, 1, 2))(*exact, seg1, ct1))
+
+    want = [np.zeros(x.shape, np.float32) for x in got]
+    for row in range(b):
+        for head in range(h):
+            kv = head // (h // hkv)
+            at = np.s_[row:row + 1, head:head + 1]
+            kv_at = np.s_[row:row + 1, kv:kv + 1]
+            o, dq, dk, dv = one_head(q[at], k[kv_at], v[kv_at], seg[row:row + 1], ct[at])
+            want[0][at], want[1][at] = np.asarray(o), np.asarray(dq)
+            want[2][kv_at] += np.asarray(dk)
+            want[3][kv_at] += np.asarray(dv)
+    for g, w, name in zip(got, want, ("o", "dq", "dk", "dv")):
+        np.testing.assert_allclose(
+            g, w, atol=2e-2 * float(np.abs(w).max()), err_msg=name)

@@ -342,7 +342,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
 
 def _no_mla(model_cls):
     class SkipsMla(model_cls):
-        def _mix(self, li, lp, u):
+        def _mix(self, li, lp, u, docs=None):
             if self.cfg.layer_types[li] == MLA:
                 return jnp.zeros(u.shape, jnp.float32), {}
             return super()._mix(li, lp, u)

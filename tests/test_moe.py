@@ -163,15 +163,17 @@ def test_moe_rejects_indivisible_experts():
 # ---------------------------------------------------------------------------
 
 def _held_setup(first=4, held=4, experts=16, h=32, f=16, tokens=80, std=0.3):
-    from sparknet_tpu.parallel.moe import init_held_experts_params
+    from sparknet_tpu.parallel.moe import init_held_experts_params, route_sigmoid
 
     p = init_held_experts_params(jax.random.PRNGKey(0), h, f, experts, held, std=std)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, tokens // 2, h))
-    return p, x, dict(experts_held=(first, held), top_k=2, routed_scale=2.5)
+    router = lambda xt, p: route_sigmoid(xt, p["router_w"], 2, 2.5)
+    return p, x, dict(experts_held=(first, held), top_k=2, router=router)
 
 
-def _held_plain(x, p, experts_held, top_k, routed_scale):
-    """Every held expert on every token, masked by the routing weights."""
+def _held_plain(x, p, experts_held, top_k, router=None, routed_scale=2.5):
+    """Every held expert on every token, masked by the routing weights
+    (``_held_setup``'s router, written out)."""
     first, held = experts_held
     f = p["experts_down"].shape[1]
     xt = x.reshape(-1, x.shape[-1])
@@ -236,6 +238,6 @@ def test_held_experts_counters_on_an_even_split_and_bad_shares():
     assert float(counters["moe_slots_dropped"]) == 0.0
     assert float(counters["moe_load_max_over_mean"]) >= 1.0
     with pytest.raises(ValueError, match="experts_held"):
-        held_experts_ffn(x, p, experts_held=(8, 16), top_k=2)
+        held_experts_ffn(x, p, **dict(kw, experts_held=(8, 16)))
     with pytest.raises(ValueError, match="weights hold"):
-        held_experts_ffn(x, p, experts_held=(0, 4), top_k=2)
+        held_experts_ffn(x, p, **dict(kw, experts_held=(0, 4)))

@@ -50,6 +50,10 @@ _ATTENTION = {
     # as bert_mlm calls it: a key mask passed (else the kernels take none)
     # and dropout's hash on every tile
     "bert_base_layer_key_mask_dropout": (12, 12, 512, 64, None, 64),
+    # as mellum_train_packed8k calls them: segment ids, so two scalar
+    # prefetches and the keys' document ends beside q, k, v
+    "mellum_full_layer_documents": (32, 4, 8192, 128, None, 4),
+    "mellum_sliding_layer_documents": (32, 4, 8192, 128, 1024, 4),
 }
 
 
@@ -60,19 +64,23 @@ def test_flash_kernels_compile_for_v5e_at_real_widths(case, one_chip, no_compile
     heads, kv_heads, seq, d, window, batch = _ATTENTION[case]
     causal = not case.startswith("bert_base_layer")
     masked = case.endswith("key_mask_dropout")
+    packed = case.endswith("documents")
     d, dv = d if isinstance(d, tuple) else (d, d)
     q = jax.ShapeDtypeStruct((batch, heads, seq, d), jnp.bfloat16, sharding=one_chip)
     k = jax.ShapeDtypeStruct((batch, kv_heads, seq, d), jnp.bfloat16, sharding=one_chip)
     v = jax.ShapeDtypeStruct((batch, kv_heads, seq, dv), jnp.bfloat16, sharding=one_chip)
     mask = jax.ShapeDtypeStruct((batch, seq), jnp.bool_, sharding=one_chip)
     rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one_chip)
 
-    def grads(q, k, v, mask, rng):
+    def grads(q, k, v, mask, rng, ids):
         extra = dict(kv_mask=mask, dropout_rate=0.1, dropout_rng=rng) if masked else {}
+        if packed:
+            extra["segment_ids"] = ids
         out = lambda *a: flash_attention(*a, causal=causal, window=window, **extra)
         return jax.grad(lambda *a: out(*a).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
 
-    text = jax.jit(grads).lower(q, k, v, mask, rng).compile().as_text()
+    text = jax.jit(grads).lower(q, k, v, mask, rng, ids).compile().as_text()
     for kernel in ("flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv"):
         assert kernel in text, kernel
     assert text.count('custom_call_target="tpu_custom_call"') == 3
@@ -81,7 +89,7 @@ def test_flash_kernels_compile_for_v5e_at_real_widths(case, one_chip, no_compile
 def test_held_experts_compile_to_the_grouped_product_for_v5e(one_chip, no_compile_cache):
     """One sparse layer's held experts at the cell's shapes: the products
     are XLA's grouped ones, forward and backward."""
-    from sparknet_tpu.parallel.moe import held_experts_ffn
+    from sparknet_tpu.parallel.moe import held_experts_ffn, route_sigmoid
 
     shape = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one_chip)
     params = {
@@ -92,8 +100,8 @@ def test_held_experts_compile_to_the_grouped_product_for_v5e(one_chip, no_compil
 
     def grads(x, params):
         routed = lambda x, p: held_experts_ffn(
-            x, p, experts_held=(0, 32), top_k=8, routed_scale=2.5,
-            compute_dtype=jnp.bfloat16,
+            x, p, experts_held=(0, 32), top_k=8, compute_dtype=jnp.bfloat16,
+            router=lambda xt, p: route_sigmoid(xt, p["router_w"], 8, 2.5),
         )[0]
         return jax.grad(lambda x, p: routed(x, p).astype(jnp.float32).sum(), (0, 1))(
             x, params
