@@ -31,7 +31,9 @@ a published ``config.json``'s keys, as cut to this chip's share if it is
 (``DecoderConfig.from_published``: ``layer_types``, ``mlp_layer_types``,
 ``num_attention_heads_per_layer``, ``rope_parameters`` ...).  Token ids come
 from the config's ``vocab_size``.  The progress line carries the model's
-counters (the sparse layers' ``moe_slots_held``, ``moe_load_max_over_mean``,
+counters (the sparse layers' ``moe_slots_held``, ``moe_slots_in_kernel``
+(as many where the ``moe_combine`` kernel sums the experts' rows into their
+tokens, 0 where a scatter-add does), ``moe_load_max_over_mean``,
 ``moe_slots_dropped``; the hybrid's ``kda_chunks``, ``kda_chunks_in_kernel``
 and ``kda_decay_min`` besides); the telemetry registry has them, as every
 solver's newest step metrics, under its source ``train_step``.

@@ -64,7 +64,10 @@ from ..parallel.moe import (
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 KDA, MLA = "kda", "mla"
-COUNTERS = ("moe_slots_held", "moe_load_max_over_mean", "moe_slots_dropped")
+COUNTERS = (
+    "moe_slots_held", "moe_slots_in_kernel", "moe_load_max_over_mean",
+    "moe_slots_dropped",
+)
 KDA_COUNTERS = ("kda_chunks", "kda_chunks_in_kernel", "kda_decay_min")
 # of a batch of packed documents, newest step: the documents in it; the
 # positions that bear a loss; the keys every token sees, summed over the
@@ -75,12 +78,14 @@ DOC_COUNTERS = (
     "doc_count", "loss_positions", "attn_pairs_full", "attn_pairs_window",
     "flash_tiles_docs_full", "flash_tiles_docs_window",
 )
-# a counter over the layers that report it: the mean of the slots held, the
+# a counter over the layers that report it: the mean of the slots held (and
+# of those whose rows the moe_combine kernel read: as many, or none), the
 # worst load ratio, every slot dropped; the chunks a sequence (the same in
 # every layer), the smallest decay anywhere
 _REDUCE = {
-    "moe_slots_held": jnp.mean, "moe_load_max_over_mean": jnp.max,
-    "moe_slots_dropped": jnp.sum, "kda_chunks": jnp.max,
+    "moe_slots_held": jnp.mean, "moe_slots_in_kernel": jnp.mean,
+    "moe_load_max_over_mean": jnp.max, "moe_slots_dropped": jnp.sum,
+    "kda_chunks": jnp.max,
     "kda_chunks_in_kernel": jnp.max, "kda_decay_min": jnp.min,
     **dict.fromkeys(DOC_COUNTERS, jnp.max),  # of the batch: reported once
 }
@@ -429,6 +434,7 @@ class DecoderLM:
             u, lp, experts_held=cfg.experts_held,
             top_k=cfg.num_experts_per_tok,
             compute_dtype=self.compute_dtype, router=self._router,
+            force=self.attention_impl,
         )
         if not cfg.shared_expert_intermediate_size:
             return routed.astype(jnp.float32), counters

@@ -14,13 +14,24 @@ to a token when an expert is full.
   normalised over every chosen expert, held here or not, times
   ``routed_scale``; :func:`route_softmax`: softmax probabilities
   normalised over the chosen; :func:`route_grouped`, which chooses by
-  groups of experts on biased scores), and computes the part of the result its own
-  SwiGLU experts give, for every slot routed to them whatever the
-  imbalance: slots sorted by expert, the held ones gathered, grouped
-  matrix products (``jax.lax.ragged_dot``) over the experts held,
-  combined by scatter-add. Slots of absent experts cost nothing, and
-  nothing stands in for the absent chips or their traffic. The decoder
-  (``models/decoder.py``) uses it.
+  groups of experts on biased scores), and computes the part of the
+  result its own SwiGLU experts give, for every slot routed to them
+  whatever the imbalance.  Nothing of it moves one index at a time
+  (XLA's ``gather`` and ``scatter`` on a TPU do, at 7-9 ns an element of
+  a vector and 40-90 ns a row): the routers read the chosen scores by
+  comparison; the slots are put in the order of their experts, held
+  ones first, by one sort that carries slot ids and weights, and their
+  inverse permutation by a second (:func:`slot_tables`); the held ones'
+  tokens are gathered a chunk at a time, grouped matrix products
+  (``jax.lax.ragged_dot``) run over the experts held, and in the forward
+  pass a chunk's rows are summed into token order by the Pallas kernel
+  :func:`moe_combine`, which copies each tile of tokens the runs of rows
+  it sent to each expert — off a TPU, or where the shapes do not fit the
+  kernel (:func:`uses_combine_kernel`), and in the backward pass for the
+  tokens' gradient (``_held_chunks_bwd`` says why), by a scatter-add.
+  Slots of absent experts cost nothing, and nothing stands in for the
+  absent chips or their traffic.  The decoder (``models/decoder.py``)
+  uses it.
 
 No reference counterpart (SURVEY.md §2: data parallelism only; EP is a
 task-spec obligation). :func:`moe_ffn` in detail:
@@ -61,6 +72,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..ops.attention import uses_flash
 from ..ops.matmul import mxu_bmm
 
 
@@ -281,6 +296,24 @@ def init_held_experts_params(
     }
 
 
+def _chosen(scores: jax.Array, idx: jax.Array) -> jax.Array:
+    """``scores[t, idx[t, j]]``, (T, K), read by comparison: of each sum
+    over the experts one term is not zero, so these are
+    ``take_along_axis``'s values to the bit, and their gradient is its
+    transpose's, but neither a gather forward nor a scatter backward (on a
+    TPU both walk one index at a time): one fused pass over T x K x E."""
+    hit = idx[..., None] == jnp.arange(scores.shape[-1])
+    return jnp.sum(jnp.where(hit, scores[:, None, :], 0.0), axis=-1)
+
+
+def _top_k(scores: jax.Array, chosen_on: jax.Array, top_k: int):
+    """(scores of the chosen, their indices): the ``top_k`` largest of
+    ``chosen_on`` a row, ties to the lower index.  The choice bears no
+    gradient; the scores are read where it points (:func:`_chosen`)."""
+    _, idx = lax.top_k(lax.stop_gradient(chosen_on), top_k)
+    return _chosen(scores, idx), idx
+
+
 def route_sigmoid(
     xt: jax.Array, router_w: jax.Array, top_k: int, routed_scale: float
 ) -> Tuple[jax.Array, jax.Array]:
@@ -292,7 +325,7 @@ def route_sigmoid(
             preferred_element_type=jnp.float32,
         )
     )
-    top, idx = lax.top_k(scores, top_k)
+    top, idx = _top_k(scores, scores, top_k)
     return routed_scale * top / jnp.sum(top, axis=-1, keepdims=True), idx
 
 
@@ -310,7 +343,7 @@ def route_softmax(
         ),
         axis=-1,
     )
-    top, idx = lax.top_k(probs, top_k)
+    top, idx = _top_k(probs, probs, top_k)
     return top / jnp.sum(top, axis=-1, keepdims=True), idx
 
 
@@ -331,22 +364,23 @@ def route_grouped(
             preferred_element_type=jnp.float32,
         )
     )
-    biased = scores + lax.stop_gradient(bias)
+    biased = lax.stop_gradient(scores + bias)  # the whole selection
     t, e = biased.shape
     grouped = biased.reshape(t, n_group, e // n_group)
     group_score = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)
     _, groups = lax.top_k(group_score, topk_group)
     kept = jnp.any(groups[:, :, None] == jnp.arange(n_group), axis=1)
-    _, idx = lax.top_k(
-        jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(t, e), top_k
+    top, idx = _top_k(
+        scores, jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(t, e),
+        top_k,
     )
-    top = jnp.take_along_axis(scores, idx, axis=-1)
     return routed_scale * top / jnp.sum(top, axis=-1, keepdims=True), idx
 
 
 def _swiglu_rows(xg, gate_up, down, sizes, cdt):
     """SwiGLU experts on rows grouped by expert: (R, h) -> (R, h) f32.
-    Rows past ``sum(sizes)`` belong to no expert; the caller masks them."""
+    Rows past ``sum(sizes)`` belong to no expert and hold whatever the
+    grouped product left there: nothing may read them unmasked."""
     ffn = down.shape[1]
     hid = lax.ragged_dot(
         xg.astype(cdt), gate_up.astype(cdt), sizes,
@@ -362,10 +396,219 @@ def _swiglu_rows(xg, gate_up, down, sizes, cdt):
 def _chunk_rows(xg, gate_up, down, wgt, live, sizes, cdt):
     """One chunk of sorted held slots, from their gathered tokens ``xg``:
     run their experts and weight the rows.  ``live`` marks rows that are
-    slots (the last chunk's tail is not): a row outside every group is
-    whatever the grouped product left there, so it is zeroed."""
+    slots (the last chunk's tail is not), the others are zeroed."""
     y = _swiglu_rows(xg, gate_up, down, sizes, cdt)
     return jnp.where(live[:, None], y * wgt[:, None], 0.0)
+
+
+# ---------------------------------------------------------------------------
+# The slots' permutation: sorts that carry their payloads, no gather or
+# scatter over a vector of slots
+# ---------------------------------------------------------------------------
+
+
+def _placed(order, values):
+    """``out[order[i]] = values[i]`` for a permutation ``order``: one sort
+    keyed by it (a scatter, one index at a time, costs ten of them)."""
+    return lax.sort((order, values), num_keys=1)[1]
+
+
+@jax.custom_vjp
+def _sort_slots(key, weights):
+    """``(key[order], order, weights[order])`` with ``order =
+    argsort(key, stable)``, from one sort that carries the slot ids and
+    the weights beside the key.  The weights' gradient goes back by the
+    inverse permutation (:func:`_placed`); JAX's own rule for a sort's
+    payload is a ``take_along_axis``, whose transpose is a scatter."""
+    return _sort_slots_fwd(key, weights)[0]
+
+
+def _sort_slots_fwd(key, weights):
+    slot = lax.iota(jnp.int32, key.shape[0])
+    sorted_ = lax.sort((key, slot, weights), num_keys=1, is_stable=True)
+    return sorted_, sorted_[1]
+
+
+def _sort_slots_bwd(order, cts):
+    return None, _placed(order, cts[2])
+
+
+_sort_slots.defvjp(_sort_slots_fwd, _sort_slots_bwd)
+
+
+def slot_tables(key, weights, held: int, top_k: int):
+    """The held slots' permutation, from each token-major slot's ``key``
+    (its expert's index among the ``held``, or ``held`` for an expert held
+    elsewhere) and weight, both (S,): ``tok`` and ``wgt`` (S,), the token
+    and the weight of the r-th slot in the order of the experts, held
+    slots first and a token's order kept inside an expert; ``offsets``
+    (held + 1,), where each expert's slots start, the last the number
+    held (a binary search of the sorted keys, which the sort gives: held +
+    1 elements read a step; counted by comparison, ``sum(key[:, None] <
+    arange(held + 1))``, the same numbers cost the compiled step a padded
+    (S, 128) tensor, 0.15 GB at 131 072 slots); ``pos`` (S,), where in that
+    order each token-major slot lies (the inverse permutation).  Two sorts
+    and a search."""
+    sorted_key, order, wgt = _sort_slots(key, weights)
+    offsets = jnp.searchsorted(
+        sorted_key, jnp.arange(held + 1), side="left"
+    ).astype(jnp.int32)
+    pos = _placed(order, lax.iota(jnp.int32, key.shape[0]))
+    return order // top_k, wgt, offsets, pos
+
+
+# ---------------------------------------------------------------------------
+# Combining a chunk's rows in token order
+# ---------------------------------------------------------------------------
+
+_COMBINE_SLOTS = 1024  # slots of one tile of tokens: a 1-D SMEM block
+
+
+def uses_combine_kernel(
+    tokens: int, hidden: int, top_k: int, rows: int,
+    force: Optional[str] = None,
+) -> bool:
+    """Whether a chunk's rows are combined by the Pallas kernel
+    :func:`moe_combine` (``force`` as
+    :func:`sparknet_tpu.ops.attention.attention` has it: "flash" the kernel
+    where the shapes fit it, "reference" never, None the kernel on a TPU):
+    rows of whole 128-lane tiles, chunks of whole 8-row tiles, tokens in
+    whole tiles of ``_COMBINE_SLOTS`` slots."""
+    fits = (
+        hidden % 128 == 0 and rows % 8 == 0 and _COMBINE_SLOTS % top_k == 0
+        and (tokens * top_k) % _COMBINE_SLOTS == 0
+    )
+    return fits and uses_flash(force)
+
+
+def tile_runs(key, offsets, slots: int):
+    """Where each tile of tokens, ``slots`` token-major slots, finds its
+    rows among the sorted slots: ``(start, count)``, both (tiles, held).  A
+    token's order is kept inside an expert, so the slots that the tokens of
+    one tile send to one held expert are consecutive rows: ``count`` of
+    them from ``start``.  ``key`` (S,) as :func:`slot_tables` takes it.
+    (Experts lead the comparison: with them last the compiled step pads a
+    tensor of slots x held to 128 lanes.)"""
+    held = offsets.shape[0] - 1
+    sent = jnp.arange(held)[:, None, None] == key.reshape(1, -1, slots)
+    count = jnp.sum(sent, axis=2, dtype=jnp.int32).T
+    return offsets[:-1] + jnp.cumsum(count, axis=0) - count, count
+
+
+def _combine_plan(pos, runs, n_held, c, rows: int):
+    """What :func:`moe_combine` needs of chunk ``c``: the row copies of each
+    tile and each slot's row in the chunk.  A tile's run in an expert's
+    rows (:func:`tile_runs`), cut to the chunk's live rows, is copied in
+    whole 8-row tiles (a single row of a tiled array is no copy's to take),
+    from tile ``a`` of the chunk ``n`` of them, to tile ``b`` of the
+    kernel's buffer, one run after another: ``plan`` is ``a``, ``n``,
+    ``b``, each (tiles * held,), in one vector.  ``rel`` (S,) is the row
+    of each token-major slot in the chunk, -1 for a slot that is not held
+    or not in it.  (Vectors of slots stay flat: as (T, K) the compiled step
+    pads each to 128 lanes, sixteen times its size at K = 8.)"""
+    lo = c * rows
+    hi = jnp.minimum(lo + rows, n_held)
+    first = jnp.clip(runs[0], lo, hi) - lo
+    last = jnp.clip(runs[0] + runs[1], lo, hi) - lo
+    a = first // 8
+    n = jnp.where(last > first, (last + 7) // 8 - a, 0)
+    b = jnp.cumsum(n, axis=1) - n
+    rel = jnp.where((pos >= lo) & (pos < hi), pos - lo, -1)
+    return jnp.concatenate([a, n, b]).reshape(-1), rel
+
+
+def _combine_kernel(
+    plan_ref, rel_ref, key_ref, w_ref, prev_ref, y_ref, out_ref, buf, sem, *,
+    top_k, held,
+):
+    """One tile of tokens.  Its runs' rows from ``y`` in HBM to ``buf`` as
+    the plan says, an 8-row tile a copy; then a token at a time ``prev +
+    sum_j w[j] * buf[row j]`` in float32 over the slots with ``rel >= 0``,
+    a slot's row in ``buf`` being its row in the chunk moved as its run's
+    copies were (8 * (b - a) of the slot's expert)."""
+    tile = prev_ref.shape[0]
+    runs, mine = pl.num_programs(0) * held, pl.program_id(0) * held
+
+    def row_tile(src, dst):
+        return pltpu.make_async_copy(
+            y_ref.at[pl.ds(pl.multiple_of(8 * src, 8), 8), :],
+            buf.at[pl.ds(pl.multiple_of(8 * dst, 8), 8), :], sem,
+        )
+
+    def run(e, started):
+        a, n, b = (plan_ref[k * runs + mine + e] for k in range(3))
+        lax.fori_loop(
+            0, n, lambda u, c: (row_tile(a + u, b + u).start(), c)[1], 0
+        )
+        return started + n
+
+    started = lax.fori_loop(0, held, run, 0)
+    lax.fori_loop(0, started, lambda _, c: (row_tile(0, 0).wait(), c)[1], 0)
+
+    def token(i, carry):
+        acc = prev_ref[pl.ds(i, 1), :]
+        for j in range(top_k):
+            slot = i * top_k + j
+            rel, w = rel_ref[slot], w_ref[slot]
+            e = mine + jnp.minimum(key_ref[slot], held - 1)
+            at = rel + 8 * (plan_ref[2 * runs + e] - plan_ref[e])
+            # the row is read wherever the branch goes (at -1 the chip
+            # hung), so from inside the buffer
+            row = buf[pl.ds(jnp.clip(at, 0, buf.shape[0] - 1), 1), :]
+            acc = lax.cond(
+                rel >= 0, lambda acc: acc + w * row, lambda acc: acc, acc
+            )
+        out_ref[pl.ds(i, 1), :] = acc
+        return carry
+
+    lax.fori_loop(0, tile, token, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def moe_combine(prev, y, plan, rel, key, w, *, interpret: bool = False):
+    """``prev[t] + sum_j w[t, j] * y[rel[t, j]]`` over the slots with
+    ``rel >= 0``: a chunk's rows ``y`` (R, h) float32, in the order of the
+    experts, summed into token order.  ``plan`` and ``rel`` are
+    :func:`_combine_plan`'s, ``key`` each slot's expert among the held (as
+    :func:`slot_tables` takes it), ``w`` its float32 weight, all three
+    (T * K,) token-major, and ``prev`` (T, h) float32 what earlier chunks
+    summed.  Reads the rows of slots (and the rest of their 8-row tiles),
+    never a row past the held ones, and sums a token's slots in the order
+    of its choices.  A Pallas TPU kernel (``interpret`` for tests off a
+    TPU); the ``jax.numpy`` form of the same sum is the scatter-add of
+    :func:`_held_chunks`."""
+    t, hidden = prev.shape
+    top_k = rel.shape[0] // t
+    tile = _COMBINE_SLOTS // top_k
+    held = plan.shape[0] // (3 * (t // tile))
+    slots = pl.BlockSpec(
+        (_COMBINE_SLOTS,), lambda i, plan: (i,), memory_space=pltpu.SMEM
+    )
+    tokens = pl.BlockSpec((tile, hidden), lambda i, plan: (i, 0))
+    return pl.pallas_call(
+        functools.partial(_combine_kernel, top_k=top_k, held=held),
+        name="moe_combine",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(t // tile,),
+            in_specs=[
+                slots, slots, slots, tokens, pl.BlockSpec(memory_space=pl.ANY)
+            ],
+            out_specs=tokens,
+            scratch_shapes=[
+                pltpu.VMEM((_COMBINE_SLOTS + 16 * held, hidden), jnp.float32),
+                pltpu.SemaphoreType.DMA(()),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(prev.shape, jnp.float32),
+        input_output_aliases={4: 0},
+        # buf is 12-13 MB at the cells' widths, beside two tiles of tokens
+        # in and out, double-buffered: over the default 16 MB
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=32 * 1024 * 1024
+        ),
+        interpret=interpret,
+    )(plan, rel, key, w, prev, y)
 
 
 def _chunk_of(tok, wgt, offsets, n_held, c, rows):
@@ -380,28 +623,52 @@ def _chunk_of(tok, wgt, offsets, n_held, c, rows):
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _held_chunks(xt, gate_up, down, tok, wgt, offsets, n_held, rows, cdt):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def _held_chunks(
+    xt, gate_up, down, tok, wgt, offsets, n_held, tokens_side, rows, cdt,
+    kernel,
+):
     """Sum over the held slots, ``rows`` at a time: ``out[tok[r]] +=
     wgt[r] * E(xt[tok[r]])`` for the sorted slots r < n_held, whose
     expert boundaries are ``offsets`` (held + 1,).  The static number of
     chunks covers every slot being held; a chunk past ``n_held`` is
     skipped (``lax.cond``), so time follows the slots there are and
-    memory one chunk.  Returns (out (T, h) f32, rows computed)."""
+    memory one chunk.  ``kernel`` (None, or moe_combine's ``interpret``)
+    says how a chunk's rows reach their tokens in the forward pass: by
+    :func:`moe_combine` from the tokens' side (``tokens_side``: the
+    weights in token order, ``key`` and ``pos``, all three (T * K,), and
+    :func:`tile_runs`), or by a scatter-add from the rows' side (``tok``,
+    ``wgt``).  The weights' gradient comes back in ``wgt``'s order.
+    Returns (out (T, h) f32, rows computed)."""
     return _held_chunks_fwd(
-        xt, gate_up, down, tok, wgt, offsets, n_held, rows, cdt
+        xt, gate_up, down, tok, wgt, offsets, n_held, tokens_side, rows, cdt,
+        kernel,
     )[0]
 
 
-def _held_chunks_fwd(xt, gate_up, down, tok, wgt, offsets, n_held, rows, cdt):
+def _combine(prev, y, tokens_side, n_held, c, rows, kernel):
+    """:func:`moe_combine` of chunk ``c``'s rows ``y``."""
+    w, key, pos, *runs = tokens_side
+    plan, rel = _combine_plan(pos, runs, n_held, c, rows)
+    return moe_combine(prev, y, plan, rel, key, w, interpret=kernel)
+
+
+def _held_chunks_fwd(
+    xt, gate_up, down, tok, wgt, offsets, n_held, tokens_side, rows, cdt,
+    kernel,
+):
     def chunk(carry, c):
         def run(carry):
             out, done = carry
             tok_c, wgt_c, live, sizes = _chunk_of(
                 tok, wgt, offsets, n_held, c, rows
             )
-            y = _chunk_rows(xt[tok_c], gate_up, down, wgt_c, live, sizes, cdt)
-            return out.at[tok_c].add(y), done + jnp.sum(sizes)
+            if kernel is None:
+                y = _chunk_rows(xt[tok_c], gate_up, down, wgt_c, live, sizes, cdt)
+                return out.at[tok_c].add(y), done + jnp.sum(sizes)
+            y = _swiglu_rows(xt[tok_c], gate_up, down, sizes, cdt)
+            out = _combine(out, y, tokens_side, n_held, c, rows, kernel)
+            return out, done + jnp.sum(sizes)
 
         return lax.cond(c * rows < n_held, run, lambda carry: carry, carry), None
 
@@ -410,10 +677,15 @@ def _held_chunks_fwd(xt, gate_up, down, tok, wgt, offsets, n_held, rows, cdt):
     return carry, (xt, gate_up, down, tok, wgt, offsets, n_held)
 
 
-def _held_chunks_bwd(rows, cdt, res, cts):
+def _held_chunks_bwd(rows, cdt, kernel, res, cts):
     """The same walk backwards: a live chunk recomputes its rows and adds
-    its share to dxt, to the weights' gradients and to its slots'
-    weights; a skipped one passes the sums on untouched."""
+    its share to dxt, to the weights' gradients and to its slots' weights;
+    a skipped one passes the sums on untouched.  dxt takes XLA's
+    scatter-add on every path: with :func:`moe_combine` in this pass as
+    well (weights of 1) the compiled steps of two of the three
+    configurations kept 0.24 and 0.34 GB more, copies of prefetched
+    weights that the compiler then writes back (PERF.md section 6, PR 36),
+    over the bound of their memory."""
     xt, gate_up, down, tok, wgt, offsets, n_held = res
     dout = cts[0]
 
@@ -431,8 +703,9 @@ def _held_chunks_bwd(rows, cdt, res, cts):
             )
             dxg, dgu_c, ddown_c, dw_c = vjp(dout[tok_c])
             dxg = jnp.where(live[:, None], dxg, 0).astype(jnp.float32)
+            dxt = dxt.at[tok_c].add(dxg)
             return (
-                dxt.at[tok_c].add(dxg), dgu + dgu_c, ddown + ddown_c,
+                dxt, dgu + dgu_c, ddown + ddown_c,
                 lax.dynamic_update_slice_in_dim(
                     dwgt, jnp.where(live, dw_c, 0.0), c * rows, 0
                 ),
@@ -447,7 +720,7 @@ def _held_chunks_bwd(rows, cdt, res, cts):
     (dxt, dgu, ddown, dwgt), _ = lax.scan(
         chunk, init, jnp.arange(tok.shape[0] // rows)
     )
-    return dxt.astype(xt.dtype), dgu, ddown, None, dwgt, None, None
+    return dxt.astype(xt.dtype), dgu, ddown, None, dwgt, None, None, None
 
 
 _held_chunks.defvjp(_held_chunks_fwd, _held_chunks_bwd)
@@ -476,6 +749,8 @@ def held_experts_ffn(
     router: Callable,
     chunk_rows: Optional[int] = None,
     compute_dtype=jnp.float32,
+    force: Optional[str] = None,
+    interpret: bool = False,
 ):
     """The held experts' part of a sparse FFN (module header). ``x``:
     (..., h), flattened to T tokens; ``router(xt, params) -> (weights,
@@ -483,13 +758,19 @@ def held_experts_ffn(
     counters)``: ``out`` has x's shape — add the shared expert and the
     residual outside — and the counters are scalars of this call:
     ``moe_slots_held`` (slots routed to held experts; T * top_k * held /
-    num_experts if the router is even), ``moe_load_max_over_mean`` (the
-    fullest held expert over their mean) and ``moe_slots_dropped``
-    (held slots that were not computed: 0, by construction).
+    num_experts if the router is even), ``moe_slots_in_kernel`` (as many
+    where :func:`moe_combine` sums their rows into ``out``, 0 where the
+    scatter-add does), ``moe_load_max_over_mean`` (the fullest held expert
+    over their mean) and ``moe_slots_dropped`` (held slots that were not
+    computed: 0, by construction).
 
-    Rows are processed ``chunk_rows`` at a time
+    The slots are put in the order of their experts by
+    :func:`slot_tables`; rows are processed ``chunk_rows`` at a time
     (:func:`held_chunk_rows` where not given; tests pass small ones), in
-    as many chunks as hold all T * top_k slots."""
+    as many chunks as hold all T * top_k slots, and each chunk's rows are
+    summed into their tokens by :func:`moe_combine` or, where
+    :func:`uses_combine_kernel` says no (``force``; ``interpret`` runs the
+    kernel in Pallas's interpreter), by a scatter-add."""
     first, held = experts_held
     num_experts = params["router_w"].shape[-1]
     if held != params["experts_down"].shape[0]:
@@ -508,27 +789,35 @@ def held_experts_ffn(
         weights, experts = router(xt, params)
         local = experts.reshape(-1) - first  # (S,) token-major slots
         key = jnp.where((local >= 0) & (local < held), local, held)
-        order = jnp.argsort(key, stable=True)  # held slots first, by expert
-        offsets = jnp.searchsorted(
-            key[order], jnp.arange(held + 1), side="left"
-        ).astype(jnp.int32)
+        tok, wgt, offsets, pos = slot_tables(
+            key, weights.reshape(-1), held, top_k
+        )
         n_held = offsets[-1]
-        tok = (order // top_k).astype(jnp.int32)
-        wgt = weights.reshape(-1)[order]
 
     rows = chunk_rows or held_chunk_rows(slots, held, num_experts)
     pad = -slots % rows
     if pad:
         tok = jnp.pad(tok, (0, pad))
         wgt = jnp.pad(wgt, (0, pad))
+    kernel = tokens_side = None
+    if uses_combine_kernel(t, xt.shape[1], top_k, rows, force):
+        kernel = interpret
+        tokens_side = (
+            lax.stop_gradient(weights).reshape(-1), key, pos,
+            *tile_runs(key, offsets, _COMBINE_SLOTS),
+        )
     with jax.named_scope("moe.experts"):
         out, done = _held_chunks(
             xt, params["experts_gate_up"], params["experts_down"], tok, wgt,
-            offsets, n_held, rows, compute_dtype,
+            offsets, n_held, tokens_side, rows, compute_dtype, kernel,
         )
     loads = (offsets[1:] - offsets[:-1]).astype(jnp.float32)
+    held_f = n_held.astype(jnp.float32)
     counters = {
-        "moe_slots_held": n_held.astype(jnp.float32),
+        "moe_slots_held": held_f,
+        "moe_slots_in_kernel": (
+            jnp.zeros_like(held_f) if kernel is None else held_f
+        ),
         "moe_load_max_over_mean": jnp.max(loads) / jnp.maximum(
             jnp.mean(loads), 1.0 / held
         ),

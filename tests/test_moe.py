@@ -1,5 +1,6 @@
 """MoE FFN with expert parallelism: sharded == single-device oracle."""
 
+import re
 from functools import partial
 
 import numpy as np
@@ -241,3 +242,427 @@ def test_held_experts_counters_on_an_even_split_and_bad_shares():
         held_experts_ffn(x, p, **dict(kw, experts_held=(8, 16)))
     with pytest.raises(ValueError, match="weights hold"):
         held_experts_ffn(x, p, **dict(kw, experts_held=(0, 4)))
+
+
+# ---------------------------------------------------------------------------
+# the held slots' permutation: sorts with payloads, rows combined in token
+# order (PR 36)
+# ---------------------------------------------------------------------------
+
+ROUTERS = ("sigmoid", "softmax", "grouped")
+
+
+def _router(kind, top_k=2, impl="new"):
+    """``router(xt, p)`` of one of the three kinds, over 16 experts; ``impl``
+    "old" is the same router written with ``top_k``'s own values and
+    ``take_along_axis``, as the program had it before."""
+    from sparknet_tpu.parallel import moe
+
+    if impl == "new":
+        return {
+            "sigmoid": lambda xt, p: moe.route_sigmoid(xt, p["router_w"], top_k, 2.5),
+            "softmax": lambda xt, p: moe.route_softmax(xt, p["router_w"], top_k),
+            "grouped": lambda xt, p: moe.route_grouped(
+                xt, p["router_w"], p["router_bias"], top_k, 2.5, 4, 2
+            ),
+        }[kind]
+
+    def old(xt, p):
+        logits = xt.astype(jnp.float32) @ p["router_w"]
+        if kind == "softmax":
+            top, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+            return top / top.sum(-1, keepdims=True), idx
+        scores = jax.nn.sigmoid(logits)
+        if kind == "sigmoid":
+            top, idx = jax.lax.top_k(scores, top_k)
+        else:
+            t, e = scores.shape
+            grouped = (scores + jax.lax.stop_gradient(p["router_bias"])).reshape(t, 4, e // 4)
+            _, groups = jax.lax.top_k(jnp.sum(jax.lax.top_k(grouped, 2)[0], -1), 2)
+            kept = jnp.any(groups[:, :, None] == jnp.arange(4), axis=1)
+            _, idx = jax.lax.top_k(
+                jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(t, e), top_k
+            )
+            top = jnp.take_along_axis(scores, idx, axis=-1)
+        return 2.5 * top / top.sum(-1, keepdims=True), idx
+
+    return old
+
+
+def _plain(x, p, experts_held, top_k, router):
+    """Every held expert on every token, masked by the router's weights."""
+    first, held = experts_held
+    f = p["experts_down"].shape[1]
+    xt = x.reshape(-1, x.shape[-1])
+    w, idx = router(xt, p)
+    out = jnp.zeros_like(xt)
+    for e in range(held):
+        hid = xt @ p["experts_gate_up"][e]
+        y = (jax.nn.silu(hid[:, :f]) * hid[:, f:]) @ p["experts_down"][e]
+        out = out + jnp.sum(jnp.where(idx == first + e, w, 0.0), -1)[:, None] * y
+    return out.reshape(x.shape)
+
+
+# name: (setup overrides, chunk_rows, what it is)
+CASES = {
+    "one_chunk": (dict(), None),
+    "two_chunks": (dict(), 80),
+    "five_chunks_and_a_pad": (dict(), 36),  # 160 slots in 5 x 36: a pad of 20
+    "ties": (dict(ties=True), 16),
+    "an_expert_with_no_slot": (dict(starve=5), 16),
+    "every_first_choice_on_one_expert": (dict(crowd=6), 16),
+    "all_experts_held": (dict(first=0, held=16), 56),
+}
+
+
+def _case(name, kind):
+    """(params, x, kwargs of held_experts_ffn) of a case and a router."""
+    overrides, chunk_rows = CASES[name]
+    overrides = dict(overrides)
+    ties, starve, crowd = (overrides.pop(k, None) for k in ("ties", "starve", "crowd"))
+    p, x, kw = _held_setup(**overrides)
+    p["router_bias"] = 0.1 * jax.random.normal(jax.random.PRNGKey(3), (16,))
+    if ties:  # half the tokens' scores are all equal: ties go to the lower index
+        x = x.at[0].set(0.0)
+    if starve is not None:  # a held expert nobody chooses: the lowest score of every token
+        x = jnp.abs(x)
+        p["router_w"] = jnp.abs(p["router_w"]).at[:, starve].set(-1.0)
+        p["router_bias"] = p["router_bias"].at[starve].set(-10.0)
+    if crowd is not None:  # a held expert everybody chooses first
+        x = jnp.abs(x)
+        p["router_w"] = (p["router_w"] * 0.01).at[:, crowd].set(1.0)
+    kw = dict(kw, router=_router(kind), chunk_rows=chunk_rows)
+    return p, x, kw
+
+
+def _grads(fn, x, p):
+    return jax.grad(lambda x, p: jnp.sum(fn(x, p) ** 2), (0, 1))(x, p)
+
+
+@pytest.mark.parametrize("kind", ROUTERS)
+@pytest.mark.parametrize("name", list(CASES))
+def test_held_experts_and_their_gradients_match_the_plain_form(name, kind):
+    """(a) Output and the gradients of ``x``, the router, both expert
+    stacks (the grouped router's bias: none) against every held expert on
+    every token, to the tolerances of the tests above."""
+    from sparknet_tpu.parallel.moe import held_experts_ffn
+
+    p, x, kw = _case(name, kind)
+    with jax.default_matmul_precision("highest"):
+        fast = lambda x, p: held_experts_ffn(x, p, **kw)[0]
+        plain = lambda x, p: _plain(x, p, kw["experts_held"], kw["top_k"], kw["router"])
+        np.testing.assert_allclose(fast(x, p), plain(x, p), atol=2e-5)
+        got, want = _grads(fast, x, p), _grads(plain, x, p)
+    assert not np.asarray(got[1]["router_bias"]).any()
+    for key in ("router_w", "experts_gate_up", "experts_down"):
+        w = want[1][key]
+        np.testing.assert_allclose(
+            got[1][key], w, atol=1e-5 * float(jnp.abs(w).max()) + 1e-7, err_msg=key
+        )
+    np.testing.assert_allclose(
+        got[0], want[0], atol=1e-5 * float(jnp.abs(want[0]).max()) + 1e-7
+    )
+    counters = held_experts_ffn(x, p, **kw)[1]
+    assert float(counters["moe_slots_dropped"]) == 0.0
+    assert float(counters["moe_slots_in_kernel"]) == 0.0  # (e) the CPU path
+    if name == "an_expert_with_no_slot":
+        _, idx = kw["router"](x.reshape(-1, x.shape[-1]), p)
+        assert not np.any(np.asarray(idx) == 5)
+    if name == "every_first_choice_on_one_expert":
+        _, idx = kw["router"](x.reshape(-1, x.shape[-1]), p)
+        assert np.all(np.asarray(idx)[:, 0] == 6) or kind == "grouped"
+
+
+@pytest.mark.parametrize("kind", ROUTERS)
+@pytest.mark.parametrize("name", ["one_chunk", "ties", "an_expert_with_no_slot", "all_experts_held"])
+def test_slot_tables_are_the_argsort_s_element_for_element(name, kind):
+    """(b) ``tok``, the sorted weights, ``offsets`` and ``pos`` against
+    ``argsort`` / ``searchsorted`` / an inverse by scatter; and the sorted
+    weights' gradient against the gather's own."""
+    from sparknet_tpu.parallel.moe import slot_tables
+
+    p, x, kw = _case(name, kind)
+    first, held = kw["experts_held"]
+    weights, experts = kw["router"](x.reshape(-1, x.shape[-1]), p)
+    local = experts.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    tok, wgt, offsets, pos = jax.jit(slot_tables, static_argnums=(2, 3))(
+        key, weights.reshape(-1), held, 2
+    )
+    order = jnp.argsort(key, stable=True)
+    np.testing.assert_array_equal(tok, order // 2)
+    np.testing.assert_array_equal(wgt, weights.reshape(-1)[order])
+    np.testing.assert_array_equal(
+        offsets, jnp.searchsorted(key[order], jnp.arange(held + 1), side="left")
+    )
+    np.testing.assert_array_equal(
+        pos, jnp.zeros_like(order).at[order].set(jnp.arange(order.size))
+    )
+    probe = jnp.cos(jnp.arange(order.size, dtype=jnp.float32))
+    got = jax.grad(lambda w: jnp.sum(probe * slot_tables(key, w, held, 2)[1]))(weights.reshape(-1))
+    want = jax.grad(lambda w: jnp.sum(probe * w[order]))(weights.reshape(-1))
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("kind", ROUTERS)
+@pytest.mark.parametrize("ties", [False, True], ids=["plain", "ties"])
+def test_routers_read_the_chosen_scores_bit_for_bit(kind, ties):
+    """(b) ``(weights, experts)`` and the gradients of the router's matrix
+    and of the tokens equal the ``top_k`` + ``take_along_axis`` form to the
+    bit; the grouped router's bias gets none."""
+    p, x, _ = _case("ties" if ties else "one_chunk", kind)
+    xt = x.reshape(-1, x.shape[-1])
+    new, old = _router(kind, impl="new"), _router(kind, impl="old")
+    for got, want in zip(new(xt, p), old(xt, p)):
+        np.testing.assert_array_equal(got, want)
+    probe = jnp.sin(jnp.arange(xt.shape[0] * 2, dtype=jnp.float32)).reshape(-1, 2)
+    loss = lambda router: lambda xt, p: jnp.sum(probe * router(xt, p)[0])
+    got = jax.grad(loss(new), (0, 1))(xt, p)
+    want = jax.grad(loss(old), (0, 1))(xt, p)
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[1]["router_w"], want[1]["router_w"])
+    assert not np.asarray(got[1]["router_bias"]).any()
+
+
+@pytest.mark.parametrize("family", ["decoder", "hybrid"])
+def test_the_lowered_step_holds_no_permutation_of_slots_one_at_a_time(family):
+    """(d) In the lowered gradient step of a small sparse decoder no
+    ``gather`` or ``scatter`` moves T * top_k elements (a vector of slots,
+    or the (T, top_k) scores of the chosen): neither its indices, its
+    updates nor its result have that many, and none scatters into a vector
+    of slots.  The slots are moved by sorts and read by comparisons.  What
+    stays, by name: the offsets' binary search, which reads held + 1
+    elements of the sorted keys a step; the row gathers ``xt[tok_c]`` and
+    ``dout[tok_c]`` of ``rows`` rows of (T, h); and off a TPU the rows'
+    scatter-add into (T, h)."""
+    from sparknet_tpu.models.decoder import DecoderConfig, DecoderLM, HybridConfig, HybridLM
+    from sparknet_tpu.parallel.moe import held_chunk_rows
+
+    b, s = 2, 160
+    if family == "decoder":
+        cfg = DecoderConfig.tiny(remat=True)
+        model = DecoderLM(cfg, {"input_ids": (b, s)})
+    else:
+        cfg = HybridConfig.tiny(remat=True, kda_segment=32)
+        model = HybridLM(cfg, {"input_ids": (b, s)})
+    tokens, k = b * s, cfg.num_experts_per_tok
+    slots = tokens * k
+    rows = held_chunk_rows(slots, cfg.experts_held[1], cfg.num_experts)
+    assert rows < slots  # so that a chunk's rows are told from the slots
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))[0])
+    ids = jax.ShapeDtypeStruct((b, s), jnp.int32)
+    batch = {"input_ids": ids, "labels": ids}
+
+    def step(p, batch):
+        return jax.grad(
+            lambda p_: model.apply(p_, {}, batch, train=True, rng=jax.random.PRNGKey(0))[0]["loss"]
+        )(p)
+
+    text = jax.jit(step).lower(params, batch).as_text()
+    moved = re.findall(r'"stablehlo\.(gather)"\([^\n]*? : (\([^\n]*)', text) + re.findall(
+        r'"stablehlo\.(scatter)"\(.*?\}\) : (\([^\n]*)', text, flags=re.DOTALL
+    )
+    assert moved  # the embedding's rows at least
+    elements = lambda dims: int(np.prod([int(d) for d in dims.split("x")[:-1]]))
+    held = cfg.experts_held[1]
+    searches = 0
+    for op, types in moved:
+        operand, *moving = [elements(t) for t in re.findall(r"tensor<([^>]*)>", types)]
+        if op == "gather" and operand == slots and set(moving) == {held + 1}:
+            searches += 1  # a step of the offsets' binary search
+            continue
+        assert slots not in moving, (op, types)  # indices, updates, result
+        assert op == "gather" or operand != slots, (op, types)
+    assert searches
+    h = cfg.hidden_size
+    rows_of = lambda op: [
+        types for o, types in moved
+        if o == op and f"tensor<{tokens}x{h}x" in types and f"tensor<{rows}x1xi32>" in types
+    ]
+    assert rows_of("gather")  # xt[tok_c], dout[tok_c]
+    assert rows_of("scatter")  # the CPU path's scatter-add
+
+
+def _combine_case(tokens=512, top_k=2, held=4, experts=16, seed=0):
+    """The tables of a seeded softmax router at a size ``moe_combine``
+    takes, and a key for rows to combine."""
+    from sparknet_tpu.parallel import moe
+
+    k = jax.random.split(jax.random.PRNGKey(seed), 3)
+    xt = jax.random.normal(k[0], (tokens, 32))
+    weights, idx = moe.route_softmax(xt, 0.5 * jax.random.normal(k[1], (32, experts)), top_k)
+    key = jnp.where(idx.reshape(-1) < held, idx.reshape(-1), held)
+    tok, wgt, offsets, pos = moe.slot_tables(key, weights.reshape(-1), held, top_k)
+    weights = weights.reshape(-1)
+    side = (weights, key, pos, *moe.tile_runs(key, offsets, moe._COMBINE_SLOTS))
+    return tok, wgt, offsets, side, weights, k[2]
+
+
+@pytest.mark.parametrize("rows", [512, 48], ids=["one_chunk", "seven_chunks"])
+def test_moe_combine_equals_the_masked_scatter_add(rows):
+    """(c) The kernel in Pallas's interpreter against the scatter-add it
+    stands for, every chunk, to 1e-6 of the largest value.  Rows that are
+    no slot hold NaN: a kernel that summed one fails.  (The backward pass's
+    ``dxt`` keeps the scatter-add: ``_held_chunks_bwd`` says why.)"""
+    from sparknet_tpu.parallel import moe
+
+    tok, wgt, offsets, side, weights, rng = _combine_case()
+    tokens, h, n_held = 512, 128, int(offsets[-1])
+    chunks = -(-n_held // rows)
+    assert chunks == (1 if rows == 512 else 7)
+    got = want = jax.random.normal(rng, (tokens, h))
+    for c in range(chunks):
+        live = c * rows + jnp.arange(rows) < n_held
+        y = jnp.where(
+            live[:, None], jax.random.normal(jax.random.fold_in(rng, c), (rows, h)), jnp.nan
+        )
+        tok_c = jnp.pad(tok, (0, rows))[c * rows:(c + 1) * rows]
+        wgt_c = jnp.pad(wgt, (0, rows))[c * rows:(c + 1) * rows]
+        want = want.at[tok_c].add(jnp.where(live[:, None], y * wgt_c[:, None], 0.0))
+        got = moe._combine(got, y, side, offsets[-1], c, rows, True)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, want, atol=1e-6 * float(jnp.abs(want).max()))
+
+
+def test_combine_plan_copies_whole_row_tiles_that_cover_each_run():
+    """The plan of a chunk: every tile's run in every expert's rows lies
+    inside the 8-row tiles the plan copies, the copies fill the buffer one
+    after another without overlap, and ``rel`` names the row of ``y`` that
+    the sorted order gives the slot: moved as its run's copies were, it
+    lies inside them."""
+    from sparknet_tpu.parallel import moe
+
+    tok, wgt, offsets, side, weights, _ = _combine_case(tokens=1024, seed=3)  # two tiles
+    _, key, pos, start, count = side
+    held, rows, n_held = 4, 64, int(offsets[-1])
+    for c in range(-(-n_held // rows)):
+        plan, rel = moe._combine_plan(pos, (start, count), offsets[-1], c, rows)
+        a, n, b = np.asarray(plan).reshape(3, -1, held)
+        assert (n >= 0).all() and ((a + n) * 8 <= rows).all()
+        np.testing.assert_array_equal(b, np.cumsum(n, axis=1) - n)
+        assert (b[:, -1] + n[:, -1]).max() * 8 <= moe._COMBINE_SLOTS + 16 * held
+        rel, pos_, key_ = np.asarray(rel), np.asarray(pos), np.asarray(key)
+        lo, hi = c * rows, min((c + 1) * rows, n_held)
+        inside = (pos_ >= lo) & (pos_ < hi)
+        np.testing.assert_array_equal(rel >= 0, inside)
+        np.testing.assert_array_equal(rel[inside], pos_[inside] - lo)
+        t, e = (np.arange(rel.shape[0]) // moe._COMBINE_SLOTS)[inside], key_[inside]
+        at = rel[inside] + 8 * (b[t, e] - a[t, e])  # the buffer row, as the kernel reckons it
+        assert ((at >= 8 * b[t, e]) & (at < 8 * (b[t, e] + n[t, e]))).all()
+
+
+def test_the_kernel_is_taken_by_what_the_call_shows():
+    """Off a TPU never, unless forced; forced, where rows are whole lane
+    tiles, chunks whole row tiles and the slots whole SMEM blocks."""
+    from sparknet_tpu.parallel.moe import uses_combine_kernel
+
+    assert not uses_combine_kernel(32768, 2304, 8, 81920)  # no TPU here
+    assert uses_combine_kernel(32768, 2304, 8, 81920, "flash")
+    assert uses_combine_kernel(16384, 2048, 8, 20480, "flash")
+    assert uses_combine_kernel(16384, 2560, 8, 2560, "flash")
+    assert not uses_combine_kernel(32768, 2304, 8, 81920, "reference")
+    assert not uses_combine_kernel(32768, 2300, 8, 81920, "flash")  # lanes
+    assert not uses_combine_kernel(32768, 2304, 8, 81916, "flash")  # row tiles
+    assert not uses_combine_kernel(1000, 2304, 8, 8000, "flash")  # slots
+    assert not uses_combine_kernel(1024, 2304, 3, 3072, "flash")  # 1024 % 3
+
+
+def _step(model, params, batch):
+    def loss(p):
+        out, _ = model.apply(p, {}, batch, train=True, rng=jax.random.PRNGKey(0))
+        return out["loss"], out
+    (value, out), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+    return value, out, grads
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_a_decoder_step_through_the_kernel_agrees_and_counts_its_slots(monkeypatch, remat):
+    """(e) A sparse decoder's loss and every gradient with the kernel
+    (interpreted) against the scatter-add; ``moe_slots_in_kernel`` equals
+    ``moe_slots_held`` there and is 0 on the CPU's own path."""
+    from sparknet_tpu.models.decoder import DecoderConfig, DecoderLM
+
+    cfg = DecoderConfig.tiny(
+        hidden_size=128, remat=remat, layer_types=("full_attention", "sliding_attention"),
+        mlp_layer_types=("dense", "sparse"), num_attention_heads_per_layer=(4, 6),
+        loss_chunk=128,
+    )
+    model = DecoderLM(cfg, {"input_ids": (2, 256)})
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 257), 0, cfg.vocab_size)
+    batch = {"input_ids": ids[:, :-1], "labels": ids[:, 1:]}
+    params, _ = model.init(jax.random.PRNGKey(2))
+    loss_p, out_p, grads_p = _step(model, params, batch)
+    assert float(out_p["moe_slots_held"]) > 0 and float(out_p["moe_slots_in_kernel"]) == 0.0
+    from sparknet_tpu.models import decoder
+    from sparknet_tpu.parallel.moe import held_experts_ffn
+
+    # the kernel forced, in Pallas's interpreter: what a test off the chip can run
+    monkeypatch.setattr(
+        decoder, "held_experts_ffn",
+        lambda *a, **kw: held_experts_ffn(*a, **{**kw, "force": "flash", "interpret": True}),
+    )
+    loss_k, out_k, grads_k = _step(model, params, batch)
+    assert float(out_k["moe_slots_in_kernel"]) == float(out_k["moe_slots_held"]) == float(out_p["moe_slots_held"])
+    assert float(out_k["moe_slots_dropped"]) == 0.0
+    np.testing.assert_allclose(loss_k, loss_p, rtol=2e-6)
+    for layer in grads_p:
+        for name, w in grads_p[layer].items():
+            np.testing.assert_allclose(
+                grads_k[layer][name], w, atol=2e-5 * max(float(jnp.abs(w).max()), 1e-12),
+                err_msg=f"{layer}.{name}",
+            )
+
+
+def test_the_counter_reaches_the_progress_line_and_the_registry(capsys):
+    """``moe_slots_in_kernel`` beside ``moe_slots_held`` on the app's
+    progress line and in the registry: 0 off a TPU."""
+    from sparknet_tpu.apps import lm_app
+    from sparknet_tpu.telemetry.registry import REGISTRY
+
+    from sparknet_tpu.utils.profiling import StepTimer
+
+    args = lm_app.parser().parse_args(
+        ["--config", "tiny", "--seq-len", "32", "--batch-size", "4", "--max-iter", "2",
+         "--display", "2", "--synthetic-tokens", "4096"]
+    )
+    solver, feed, _ = lm_app.build(args)
+    metrics = lm_app._fit(solver, iter(feed), args, StepTimer(items_per_step=128, unit="tokens"))
+    out = capsys.readouterr().out
+    assert re.search(r"moe_slots_held = [\d.]+, moe_slots_in_kernel = 0, moe_load_max_over_mean = ", out)
+    assert metrics["moe_slots_in_kernel"] == 0.0 < metrics["moe_slots_held"]
+    read = REGISTRY.sources()["train_step"].snapshot()
+    assert read["moe_slots_in_kernel"] == 0.0 and read["moe_slots_held"] == metrics["moe_slots_held"]
+
+
+# ------------------------------------------------------------- on the chip
+
+@pytest.mark.skipif(
+    jax.default_backend() != "tpu", reason="the compiled kernel needs a TPU"
+)
+@pytest.mark.parametrize(
+    "tokens,h,experts,held", [(32768, 2304, 64, 16), (16384, 2048, 256, 32)],
+    ids=["mellum2", "laguna_xs2"],
+)
+def test_compiled_moe_combine_at_the_cells_shapes_on_hardware(tokens, h, experts, held):
+    """The compiled kernel at a cell's (T, h, rows, top_k), under a seeded
+    softmax router, against the scatter-add, within 1e-5 of the largest
+    value; rows that are no slot hold NaN."""
+    from sparknet_tpu.parallel import moe
+
+    top_k = 8
+    rows = moe.held_chunk_rows(tokens * top_k, held, experts)
+    assert (tokens, h, rows) in ((32768, 2304, 81920), (16384, 2048, 20480))
+    tok, wgt, offsets, side, weights, rng = _combine_case(tokens, top_k, held, experts)
+    live = jnp.arange(rows) < offsets[-1]
+    y = jnp.where(live[:, None], jax.random.normal(rng, (rows, h)), jnp.nan)
+    want = jax.jit(
+        lambda y: jnp.zeros((tokens, h)).at[tok[:rows]].add(
+            jnp.where(live[:, None], y * wgt[:rows, None], 0.0)
+        )
+    )(y)
+    got = jax.jit(
+        lambda y: moe._combine(
+            jnp.zeros((tokens, h)), y, side, offsets[-1], 0, rows, False
+        )
+    )(y)
+    np.testing.assert_allclose(got, want, atol=1e-5 * float(jnp.abs(want).max()))

@@ -86,29 +86,46 @@ def test_flash_kernels_compile_for_v5e_at_real_widths(case, one_chip, no_compile
     assert text.count('custom_call_target="tpu_custom_call"') == 3
 
 
-def test_held_experts_compile_to_the_grouped_product_for_v5e(one_chip, no_compile_cache):
-    """One sparse layer's held experts at the cell's shapes: the products
-    are XLA's grouped ones, forward and backward."""
+_HELD = {
+    # tokens (batch, sequence), hidden, expert width, experts, held
+    "laguna": ((2, 8192), 2048, 512, 256, 32),
+    "mellum": ((4, 8192), 2304, 896, 64, 16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_HELD))
+def test_held_experts_compile_to_the_grouped_product_for_v5e(case, one_chip, no_compile_cache):
+    """One sparse layer's held experts at a cell's shapes, the kernel
+    forced as a TPU takes it: the products are XLA's grouped ones, forward
+    and backward; a chunk's rows reach their tokens through ``moe_combine``
+    in the forward pass (the backward pass's scatter-add stays)."""
     from sparknet_tpu.parallel.moe import held_experts_ffn, route_sigmoid
 
+    tokens, h, f, experts, held = _HELD[case]
     shape = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one_chip)
     params = {
-        "router_w": shape((2048, 256), jnp.float32),
-        "experts_gate_up": shape((32, 2048, 1024), jnp.float32),
-        "experts_down": shape((32, 512, 2048), jnp.float32),
+        "router_w": shape((h, experts), jnp.float32),
+        "experts_gate_up": shape((held, h, 2 * f), jnp.float32),
+        "experts_down": shape((held, f, h), jnp.float32),
     }
 
     def grads(x, params):
         routed = lambda x, p: held_experts_ffn(
-            x, p, experts_held=(0, 32), top_k=8, compute_dtype=jnp.bfloat16,
+            x, p, experts_held=(0, held), top_k=8, compute_dtype=jnp.bfloat16,
             router=lambda xt, p: route_sigmoid(xt, p["router_w"], 8, 2.5),
+            force="flash",
         )[0]
-        return jax.grad(lambda x, p: routed(x, p).astype(jnp.float32).sum(), (0, 1))(
-            x, params
-        )
+        return jax.value_and_grad(  # the value keeps the forward pass's sum
+            lambda x, p: routed(x, p).astype(jnp.float32).sum(), (0, 1)
+        )(x, params)
 
-    text = jax.jit(grads).lower(shape((2, 8192, 2048), jnp.bfloat16), params).compile().as_text()
+    text = jax.jit(grads).lower(shape((*tokens, h), jnp.bfloat16), params).compile().as_text()
     assert text.count("ragged-dot") >= 6  # gate+up and down, and both gradients of each
+    calls = [
+        ln for ln in text.splitlines()
+        if " custom-call(" in ln and "moe_combine" in ln.split(" = ")[0]
+    ]
+    assert len(calls) == 1  # the forward loop's
 
 
 @pytest.mark.parametrize("force", ["flash", "reference"], ids=["kernels", "jax_numpy"])
