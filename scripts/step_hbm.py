@@ -29,6 +29,7 @@ def main(argv) -> int:
     from sparknet_tpu.apps import lm_app
     from sparknet_tpu.models.decoder import DecoderLM, HybridConfig, HybridLM
     from sparknet_tpu.solver.trainer import init_opt_state, make_train_step
+    from sparknet_tpu.utils import profiling
 
     jax.config.update("jax_enable_compilation_cache", False)
     cell = run.load_cell(argv[0])
@@ -47,7 +48,8 @@ def main(argv) -> int:
     train_step = make_train_step(model, solver, None)
 
     def fused(params, state, opt_state, batch, it, rng):  # Solver._finish_init's
-        rng, step_rng = jax.random.split(rng)
+        with profiling.scope("rng"):
+            rng, step_rng = jax.random.split(rng)
         params, state, opt_state, metrics = train_step(
             params, state, opt_state, batch, it, step_rng
         )

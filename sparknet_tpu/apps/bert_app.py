@@ -509,7 +509,11 @@ def main(argv=None) -> Dict[str, float]:
         with trace(args.profile_dir), telemetry.training_loop(
             solver.timeline, emit=print
         ):
-            metrics = _fit(solver, feed, args, timer, primary)
+            metrics = _fit(
+                solver,
+                telemetry.first_batch_lowered(feed, solver, args.profile_dir),
+                args, timer, primary,
+            )
     finally:
         telemetry.finish_run()
     dt = time.time() - t0

@@ -425,7 +425,13 @@ def main(argv=None):
     telemetry.install_for_training(solver, args.trace, args.profile_dir)
     try:
         with trace(args.profile_dir):
-            result = train_loop(solver, train_feed, test_feed)
+            result = train_loop(
+                solver,
+                telemetry.first_batch_lowered(
+                    train_feed, solver, args.profile_dir
+                ),
+                test_feed,
+            )
     except BaseException as e:
         # supervised runs leave a machine-readable failure record for
         # the supervisor's attribution (see cifar_app.main)

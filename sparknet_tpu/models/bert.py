@@ -31,6 +31,7 @@ import jax.numpy as jnp
 
 from ..ops.attention import attention
 from ..ops.matmul import mxu_dot
+from ..utils.profiling import scope
 
 
 @dataclasses.dataclass
@@ -75,11 +76,12 @@ class BertConfig:
 
 
 def _layer_norm(x, scale, bias, eps):
-    xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, -1, keepdims=True)
-    var = jnp.var(xf, -1, keepdims=True)
-    y = (xf - mu) * jax.lax.rsqrt(var + eps)
-    return (y * scale + bias).astype(x.dtype)
+    with scope("norm"):
+        xf = x.astype(jnp.float32)
+        mu = jnp.mean(xf, -1, keepdims=True)
+        var = jnp.var(xf, -1, keepdims=True)
+        y = (xf - mu) * jax.lax.rsqrt(var + eps)
+        return (y * scale + bias).astype(x.dtype)
 
 
 import functools
@@ -271,17 +273,18 @@ class BertMLM:
         # position_ids lets sequence-sharded callers pass each shard's
         # global positions (they shard along S with the rest of the batch)
         pos_ids = batch.get("position_ids")
-        pos_emb = (
-            emb["position"][jnp.arange(s)][None, :, :]
-            if pos_ids is None
-            else emb["position"][pos_ids]
-        )
-        x = emb["word"][ids] + pos_emb + emb["token_type"][batch["token_type_ids"]]
-        x = _layer_norm(x, emb["ln_scale"], emb["ln_bias"], cfg.layer_norm_eps)
-        if rng is not None:
-            rng_emb, rng = jax.random.split(rng)
-            x = _dropout(x, cfg.hidden_dropout, rng_emb, train)
-        x = x.astype(self.compute_dtype)
+        with scope("embed"):
+            pos_emb = (
+                emb["position"][jnp.arange(s)][None, :, :]
+                if pos_ids is None
+                else emb["position"][pos_ids]
+            )
+            x = emb["word"][ids] + pos_emb + emb["token_type"][batch["token_type_ids"]]
+            x = _layer_norm(x, emb["ln_scale"], emb["ln_bias"], cfg.layer_norm_eps)
+            if rng is not None:
+                rng_emb, rng = jax.random.split(rng)
+                x = _dropout(x, cfg.hidden_dropout, rng_emb, train)
+            x = x.astype(self.compute_dtype)
         kv_mask = batch["attention_mask"].astype(jnp.int32)
         return x, kv_mask, rng
 
@@ -305,7 +308,8 @@ class BertMLM:
         aux_total = jnp.asarray(0.0, jnp.float32)
         for li in range(cfg.num_layers):
             lp = params[f"layer_{li:02d}"]
-            lrng = jax.random.fold_in(rng, li) if rng is not None else None
+            with scope("rng"):
+                lrng = jax.random.fold_in(rng, li) if rng is not None else None
             x, aux = apply_one(lp, x, kv_mask, lrng)
             aux_total = aux_total + aux
         return x, aux_total
@@ -335,56 +339,60 @@ class BertMLM:
                 y = _tp_reduce(y, tp)
             return (y + b_).astype(cdt)
 
-        # column-parallel under tp: q_w is (h, h/ntp), so the local
-        # head count falls out of the weight shape
-        nh = lp["q_w"].shape[-1] // hd
-        x_in = _tp_copy(x, tp) if tp is not None else x
-        # one fused (h, 3h) matmul instead of three: a bigger MXU op
-        # with identical math — y = x@[q|k|v] column-blocks exactly
-        # equals the three separate products (params stay separate, so
-        # checkpoints and tp sharding are unchanged)
-        qkv = proj(
-            jnp.concatenate([lp["q_w"], lp["k_w"], lp["v_w"]], axis=1),
-            jnp.concatenate([lp["q_b"], lp["k_b"], lp["v_b"]]),
-            x_in,
-        )
-        local_h = nh * hd
-        q, k, v = (
-            t.reshape(b, s, nh, hd)
-            for t in jnp.split(qkv, (local_h, 2 * local_h), axis=-1)
-        )
-        # (B,S,H,D) -> (B,H,S,D)
-        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        if rng is not None and train and cfg.attention_dropout > 0:
-            rng, attn_rng = jax.random.split(rng)
-        else:
-            attn_rng = None
-        impl = self.attention_impl
-        if impl in ("ring", "ulysses"):
-            from ..parallel.sequence import ring_attention, ulysses_attention
+        with scope("attn"):
+            # column-parallel under tp: q_w is (h, h/ntp), so the local
+            # head count falls out of the weight shape
+            nh = lp["q_w"].shape[-1] // hd
+            x_in = _tp_copy(x, tp) if tp is not None else x
+            # one fused (h, 3h) matmul instead of three: a bigger MXU op
+            # with identical math — y = x@[q|k|v] column-blocks exactly
+            # equals the three separate products (params stay separate, so
+            # checkpoints and tp sharding are unchanged)
+            with scope("attn.proj"):
+                qkv = proj(
+                    jnp.concatenate([lp["q_w"], lp["k_w"], lp["v_w"]], axis=1),
+                    jnp.concatenate([lp["q_b"], lp["k_b"], lp["v_b"]]),
+                    x_in,
+                )
+            local_h = nh * hd
+            q, k, v = (
+                t.reshape(b, s, nh, hd)
+                for t in jnp.split(qkv, (local_h, 2 * local_h), axis=-1)
+            )
+            # (B,S,H,D) -> (B,H,S,D)
+            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+            if rng is not None and train and cfg.attention_dropout > 0:
+                rng, attn_rng = jax.random.split(rng)
+            else:
+                attn_rng = None
+            impl = self.attention_impl
+            if impl in ("ring", "ulysses"):
+                from ..parallel.sequence import ring_attention, ulysses_attention
 
-            sp_fn = ring_attention if impl == "ring" else ulysses_attention
-            ctx = sp_fn(
-                q, k, v, axis_name=self.sp_axis, kv_mask=kv_mask,
-                dropout_rate=cfg.attention_dropout if train else 0.0,
-                dropout_rng=attn_rng,
-            )
-        else:
-            ctx = attention(
-                q, k, v, kv_mask=kv_mask, force=impl,
-                dropout_rate=cfg.attention_dropout if train else 0.0,
-                dropout_rng=attn_rng,
-            )
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, nh * hd)
-        attn_out = row_proj(lp["out_w"], lp["out_b"], ctx)
-        if rng is not None:
-            k1, k2 = jax.random.split(rng)
-            attn_out = _dropout(attn_out, cfg.hidden_dropout, k1, train)
-        else:
-            k2 = None
+                sp_fn = ring_attention if impl == "ring" else ulysses_attention
+                ctx = sp_fn(
+                    q, k, v, axis_name=self.sp_axis, kv_mask=kv_mask,
+                    dropout_rate=cfg.attention_dropout if train else 0.0,
+                    dropout_rng=attn_rng,
+                )
+            else:
+                ctx = attention(
+                    q, k, v, kv_mask=kv_mask, force=impl,
+                    dropout_rate=cfg.attention_dropout if train else 0.0,
+                    dropout_rng=attn_rng,
+                )
+            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, nh * hd)
+            with scope("attn.proj"):
+                attn_out = row_proj(lp["out_w"], lp["out_b"], ctx)
+            if rng is not None:
+                k1, k2 = jax.random.split(rng)
+                attn_out = _dropout(attn_out, cfg.hidden_dropout, k1, train)
+            else:
+                k2 = None
+        with scope("residual"):
+            x = x + attn_out
         x = _layer_norm(
-            x + attn_out, lp["attn_ln_scale"], lp["attn_ln_bias"],
-            cfg.layer_norm_eps,
+            x, lp["attn_ln_scale"], lp["attn_ln_bias"], cfg.layer_norm_eps
         ).astype(cdt)
         aux = jnp.asarray(0.0, jnp.float32)
         if "router_w" in lp:  # MoE FFN (dropped tokens ride the residual)
@@ -400,16 +408,20 @@ class BertMLM:
                 top_k=cfg.moe_top_k, z_loss_weight=cfg.moe_z_loss,
                 dispatch=cfg.moe_dispatch, compute_dtype=cdt,
             )
+            ff = _dropout(ff, cfg.hidden_dropout, k2, train)
         else:
-            ff_in = _tp_copy(x, tp) if tp is not None else x
-            ff = jax.nn.gelu(
-                proj(lp["ffn_in_w"], lp["ffn_in_b"], ff_in), approximate=True
-            )
-            ff = row_proj(lp["ffn_out_w"], lp["ffn_out_b"], ff)
-        ff = _dropout(ff, cfg.hidden_dropout, k2, train)
+            with scope("mlp.dense"):
+                ff_in = _tp_copy(x, tp) if tp is not None else x
+                ff = jax.nn.gelu(
+                    proj(lp["ffn_in_w"], lp["ffn_in_b"], ff_in),
+                    approximate=True,
+                )
+                ff = row_proj(lp["ffn_out_w"], lp["ffn_out_b"], ff)
+                ff = _dropout(ff, cfg.hidden_dropout, k2, train)
+        with scope("residual"):
+            out = x + ff
         out = _layer_norm(
-            x + ff, lp["ffn_ln_scale"], lp["ffn_ln_bias"],
-            cfg.layer_norm_eps,
+            out, lp["ffn_ln_scale"], lp["ffn_ln_bias"], cfg.layer_norm_eps
         ).astype(cdt)
         return out, aux
 
@@ -421,33 +433,34 @@ class BertMLM:
             params, batch, train=train, rng=rng if train else None
         )
         b, s, h = x.shape
-        pos = batch["mlm_positions"]  # (B, M)
-        gathered = jnp.take_along_axis(x, pos[:, :, None], axis=1)  # (B,M,H)
-        head = params["mlm_head"]
-        t = jax.nn.gelu(
-            mxu_dot(gathered, head["dense_w"].astype(x.dtype))
-            + head["dense_b"],
-            approximate=True,
-        )
-        t = _layer_norm(t, head["ln_scale"], head["ln_bias"], cfg.layer_norm_eps)
-        logits = (
-            mxu_dot(
-                t.astype(self.compute_dtype),
-                params["embeddings"]["word"].T.astype(self.compute_dtype),
+        with scope("loss"):  # the MLM head and the cross-entropy
+            pos = batch["mlm_positions"]  # (B, M)
+            gathered = jnp.take_along_axis(x, pos[:, :, None], axis=1)  # (B,M,H)
+            head = params["mlm_head"]
+            t = jax.nn.gelu(
+                mxu_dot(gathered, head["dense_w"].astype(x.dtype))
+                + head["dense_b"],
+                approximate=True,
             )
-            + head["output_bias"]
-        )  # (B, M, V) f32
-        labels = batch["mlm_labels"]
-        weights = batch["mlm_weights"].astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, labels[:, :, None], axis=-1)[..., 0]
-        denom = jnp.maximum(jnp.sum(weights), 1.0)
-        loss = jnp.sum(nll * weights) / denom
-        if cfg.moe_num_experts > 0:
-            loss = loss + cfg.moe_aux_weight * moe_aux
-        acc = jnp.sum(
-            (jnp.argmax(logits, -1) == labels).astype(jnp.float32) * weights
-        ) / denom
+            t = _layer_norm(t, head["ln_scale"], head["ln_bias"], cfg.layer_norm_eps)
+            logits = (
+                mxu_dot(
+                    t.astype(self.compute_dtype),
+                    params["embeddings"]["word"].T.astype(self.compute_dtype),
+                )
+                + head["output_bias"]
+            )  # (B, M, V) f32
+            labels = batch["mlm_labels"]
+            weights = batch["mlm_weights"].astype(jnp.float32)
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, labels[:, :, None], axis=-1)[..., 0]
+            denom = jnp.maximum(jnp.sum(weights), 1.0)
+            loss = jnp.sum(nll * weights) / denom
+            if cfg.moe_num_experts > 0:
+                loss = loss + cfg.moe_aux_weight * moe_aux
+            acc = jnp.sum(
+                (jnp.argmax(logits, -1) == labels).astype(jnp.float32) * weights
+            ) / denom
         return {"loss": loss, "mlm_acc": acc}, state
 
     def token_loss_sums(self, params, state, batch, *, train=False, rng=None):
@@ -484,28 +497,29 @@ class BertMLM:
         """MLM head + per-token NLL over hidden states ``x`` (B, S, H).
         Returns local partial sums (nll_sum, weight_sum, correct_sum)."""
         cfg = self.cfg
-        head = params["mlm_head"]
-        t = jax.nn.gelu(
-            mxu_dot(x, head["dense_w"].astype(x.dtype)) + head["dense_b"],
-            approximate=True,
-        )
-        t = _layer_norm(t, head["ln_scale"], head["ln_bias"], cfg.layer_norm_eps)
-        logits = (
-            mxu_dot(
-                t.astype(self.compute_dtype),
-                params["embeddings"]["word"].T.astype(self.compute_dtype),
+        with scope("loss"):
+            head = params["mlm_head"]
+            t = jax.nn.gelu(
+                mxu_dot(x, head["dense_w"].astype(x.dtype)) + head["dense_b"],
+                approximate=True,
             )
-            + head["output_bias"]
-        )  # (B, S_local, V)
-        weights = weights.astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-        correct = (jnp.argmax(logits, -1) == labels).astype(jnp.float32)
-        return (
-            jnp.sum(nll * weights),
-            jnp.sum(weights),
-            jnp.sum(correct * weights),
-        )
+            t = _layer_norm(t, head["ln_scale"], head["ln_bias"], cfg.layer_norm_eps)
+            logits = (
+                mxu_dot(
+                    t.astype(self.compute_dtype),
+                    params["embeddings"]["word"].T.astype(self.compute_dtype),
+                )
+                + head["output_bias"]
+            )  # (B, S_local, V)
+            weights = weights.astype(jnp.float32)
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+            correct = (jnp.argmax(logits, -1) == labels).astype(jnp.float32)
+            return (
+                jnp.sum(nll * weights),
+                jnp.sum(weights),
+                jnp.sum(correct * weights),
+            )
 
     def loss_and_metrics(self, blobs):
         return blobs["loss"], {"loss": blobs["loss"], "mlm_acc": blobs["mlm_acc"]}

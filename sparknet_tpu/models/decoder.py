@@ -57,6 +57,7 @@ import jax.numpy as jnp
 from ..ops.attention import attention, document_spans, flash_tiles_documents
 from ..ops.kda import KDA_MIN_LOG_DECAY, kda_chunks, kda_scan, uses_kernels
 from ..ops.matmul import mxu_dot
+from ..utils.profiling import scope
 from ..parallel.moe import (
     held_experts_ffn, init_held_experts_params, route_grouped, route_sigmoid,
     route_softmax,
@@ -205,9 +206,10 @@ class DecoderConfig:
 # ---------------------------------------------------------------------------
 
 def rms_norm(x, scale, eps):
-    xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
-    return (y * scale).astype(x.dtype)
+    with scope("norm"):
+        xf = x.astype(jnp.float32)
+        y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+        return (y * scale).astype(x.dtype)
 
 
 def rope_inv_freq(rope: Mapping[str, Any], head_dim: int) -> Tuple[jax.Array, float]:
@@ -399,9 +401,11 @@ class DecoderLM:
         segment_ids, positions = docs or (None, jnp.arange(s))
 
         def project(w, n, rotate):
-            t = mxu_dot(u, w.astype(cdt)).reshape(b, s, n, d)
+            with scope("attn.proj"):
+                t = mxu_dot(u, w.astype(cdt)).reshape(b, s, n, d)
             if rotate:
-                t = apply_rope(t, positions, inv_freq, factor)
+                with scope("attn.rope"):
+                    t = apply_rope(t, positions, inv_freq, factor)
             return t.astype(cdt).transpose(0, 2, 1, 3)  # (B, n, S, D)
 
         out = attention(
@@ -413,7 +417,8 @@ class DecoderLM:
             segment_ids=segment_ids, force=self.attention_impl,
         )
         out = out.transpose(0, 2, 1, 3).reshape(b, s, heads * d)
-        return mxu_dot(out, lp["o_w"].astype(cdt))
+        with scope("attn.proj"):
+            return mxu_dot(out, lp["o_w"].astype(cdt))
 
     def _router(self, xt, lp):
         """(weights, experts) a token, by the configuration's scoring."""
@@ -429,7 +434,8 @@ class DecoderLM:
         """(float32 FFN output, this layer's counters)."""
         cfg = self.cfg
         if cfg.mlp_layer_types[li] != "sparse":
-            return swiglu(u, lp["gate_w"], lp["up_w"], lp["down_w"]), {}
+            with scope("mlp.dense"):
+                return swiglu(u, lp["gate_w"], lp["up_w"], lp["down_w"]), {}
         routed, counters = held_experts_ffn(
             u, lp, experts_held=cfg.experts_held,
             top_k=cfg.num_experts_per_tok,
@@ -438,7 +444,7 @@ class DecoderLM:
         )
         if not cfg.shared_expert_intermediate_size:
             return routed.astype(jnp.float32), counters
-        with jax.named_scope("moe.shared"):
+        with scope("moe.shared"):
             shared = swiglu(
                 u, lp["shared_gate_w"], lp["shared_up_w"], lp["shared_down_w"]
             )
@@ -447,8 +453,8 @@ class DecoderLM:
     def _mix(self, li: int, lp, u, docs=None):
         """The layer's token mixer on the normed ``u``: (float32 output,
         its counters), under the layer kind's scope."""
-        scope = "attn.window" if self.cfg.layer_types[li] == SLIDING else "attn.full"
-        with jax.named_scope(scope):
+        kind = "attn.window" if self.cfg.layer_types[li] == SLIDING else "attn.full"
+        with scope(kind):
             return self._attention(li, lp, u, docs), {}
 
     def layer_apply(self, li: int, lp, x, docs=None):
@@ -457,19 +463,21 @@ class DecoderLM:
         mixed, counters = self._mix(
             li, lp, rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps), docs
         )
-        x = (x.astype(jnp.float32) + mixed).astype(cdt)
+        with scope("residual"):
+            x = (x.astype(jnp.float32) + mixed).astype(cdt)
         fed, ffn_counters = self._ffn(
             li, lp, rms_norm(x, lp["ffn_norm"], cfg.rms_norm_eps)
         )
-        return (x.astype(jnp.float32) + fed).astype(cdt), {
-            **counters, **ffn_counters
-        }
+        with scope("residual"):
+            x = (x.astype(jnp.float32) + fed).astype(cdt)
+        return x, {**counters, **ffn_counters}
 
     def hidden(self, params, input_ids, docs=None):
         """The final-layer hidden states (before the head's norm) and the
         layers' counters, one dict a layer."""
         cfg = self.cfg
-        x = params["embed"]["tokens"][input_ids].astype(self.compute_dtype)
+        with scope("embed"):
+            x = params["embed"]["tokens"][input_ids].astype(self.compute_dtype)
         counted = []
         for li in range(cfg.num_layers):
             fn = lambda lp, x, li=li: self.layer_apply(li, lp, x, docs)
@@ -499,20 +507,21 @@ class DecoderLM:
         @jax.checkpoint
         def one(xc, yc):
             logits = mxu_dot(xc, lm_w)  # (chunk, V) f32
-            lse = jax.scipy.special.logsumexp(logits, axis=-1)
-            if self.packed:
-                borne = yc >= 0
-                picked = jnp.take_along_axis(
-                    logits, jnp.maximum(yc, 0)[:, None], axis=-1
-                )[:, 0]
-                hit = borne & (jnp.argmax(logits, -1) == yc)
-                return (
-                    jnp.sum(jnp.where(borne, lse - picked, 0.0)),
-                    jnp.sum(hit.astype(jnp.float32)),
-                )
-            picked = jnp.take_along_axis(logits, yc[:, None], axis=-1)[:, 0]
-            hit = jnp.argmax(logits, -1) == yc
-            return jnp.sum(lse - picked), jnp.sum(hit.astype(jnp.float32))
+            with scope("loss"):  # inside lm_head: the head's product is not
+                lse = jax.scipy.special.logsumexp(logits, axis=-1)
+                if self.packed:
+                    borne = yc >= 0
+                    picked = jnp.take_along_axis(
+                        logits, jnp.maximum(yc, 0)[:, None], axis=-1
+                    )[:, 0]
+                    hit = borne & (jnp.argmax(logits, -1) == yc)
+                    return (
+                        jnp.sum(jnp.where(borne, lse - picked, 0.0)),
+                        jnp.sum(hit.astype(jnp.float32)),
+                    )
+                picked = jnp.take_along_axis(logits, yc[:, None], axis=-1)[:, 0]
+                hit = jnp.argmax(logits, -1) == yc
+                return jnp.sum(lse - picked), jnp.sum(hit.astype(jnp.float32))
 
         def body(carry, xy):
             nll, hit = one(*xy)
@@ -547,17 +556,18 @@ class DecoderLM:
     def apply(self, params, state, batch, *, train=None, rng=None):
         docs = (batch["segment_ids"], batch["positions"]) if self.packed else None
         x, counted = self.hidden(params, batch["input_ids"], docs)
-        with jax.named_scope("lm_head"):
+        with scope("lm_head"):
             loss, acc = self._loss(params["head"], x, batch["labels"])
         blobs = {"loss": loss, "token_acc": acc}
-        if self.packed:
-            counted = counted + [self._doc_counters(batch)]
-        for name in self.counters:
-            seen = [c[name] for c in counted if name in c]
-            blobs[name] = (
-                _REDUCE[name](jnp.stack(seen)) if seen
-                else jnp.zeros((), jnp.float32)
-            )
+        with scope("counters"):
+            if self.packed:
+                counted = counted + [self._doc_counters(batch)]
+            for name in self.counters:
+                seen = [c[name] for c in counted if name in c]
+                blobs[name] = (
+                    _REDUCE[name](jnp.stack(seen)) if seen
+                    else jnp.zeros((), jnp.float32)
+                )
         return blobs, state
 
     def loss_and_metrics(self, blobs):
@@ -843,9 +853,9 @@ class HybridLM(DecoderLM):
 
     def _mix(self, li: int, lp, u, docs=None):
         if self.cfg.layer_types[li] == KDA:
-            with jax.named_scope("attn.kda"):
+            with scope("attn.kda"):
                 return self._kda(lp, u)
-        with jax.named_scope("attn.mla"):
+        with scope("attn.mla"):
             return self._mla(lp, u), {}
 
     def _kda(self, lp, u):
@@ -881,7 +891,10 @@ class HybridLM(DecoderLM):
         @jax.checkpoint
         def segment(carry, u_s):
             state, history = carry
-            project = lambda name: mxu_dot(u_s, lp[name].astype(cdt))  # float32
+            def project(name):  # float32
+                with scope("attn.proj"):
+                    return mxu_dot(u_s, lp[name].astype(cdt))
+
             mixed, latest = {}, {}
             for name in ("q", "k", "v"):
                 pre = project(name + "_w")
@@ -907,7 +920,8 @@ class HybridLM(DecoderLM):
             )  # (B, H, seg, d) float32
             out = rms_norm(out.transpose(0, 2, 1, 3), lp["o_norm"], cfg.rms_norm_eps)
             out = out.reshape(b, seg, heads * d) * jax.nn.sigmoid(project("g_w"))
-            y = mxu_dot(out.astype(cdt), lp["o_w"].astype(cdt))
+            with scope("attn.proj"):
+                y = mxu_dot(out.astype(cdt), lp["o_w"].astype(cdt))
             return (state, latest), (y, jnp.min(g))
 
         zeros = lambda *shape: jnp.zeros(shape, jnp.float32)
@@ -945,22 +959,25 @@ class HybridLM(DecoderLM):
         # and what the kernels take are made again in the backward pass
         @jax.checkpoint
         def queries(w):
-            q = mxu_dot(u, w.astype(cdt)).reshape(b, s, heads, nope + rope)
-            return by_head(jnp.concatenate([
-                q[..., :nope],
-                rope_interleaved(q[..., nope:], positions, cfg.rope_theta),
-            ], axis=-1))
+            with scope("attn.proj"):
+                q = mxu_dot(u, w.astype(cdt)).reshape(b, s, heads, nope + rope)
+            with scope("attn.rope"):
+                rotated = rope_interleaved(q[..., nope:], positions, cfg.rope_theta)
+            return by_head(jnp.concatenate([q[..., :nope], rotated], axis=-1))
 
         @jax.checkpoint
         def keys_values(kv_a_w, kv_a_norm, kv_b_w):
-            kv_a = mxu_dot(u, kv_a_w.astype(cdt))  # latent | rotary key
+            with scope("attn.proj"):
+                kv_a = mxu_dot(u, kv_a_w.astype(cdt))  # latent | rotary key
             latent = rms_norm(kv_a[..., :rank], kv_a_norm, cfg.rms_norm_eps)
-            kv = mxu_dot(latent.astype(cdt), kv_b_w.astype(cdt)).reshape(
-                b, s, heads, nope + cfg.v_head_dim
-            )
-            k_rot = rope_interleaved(
-                kv_a[..., None, rank:], positions, cfg.rope_theta
-            )
+            with scope("attn.proj"):
+                kv = mxu_dot(latent.astype(cdt), kv_b_w.astype(cdt)).reshape(
+                    b, s, heads, nope + cfg.v_head_dim
+                )
+            with scope("attn.rope"):
+                k_rot = rope_interleaved(
+                    kv_a[..., None, rank:], positions, cfg.rope_theta
+                )
             k = jnp.concatenate([
                 kv[..., :nope], jnp.broadcast_to(k_rot, (b, s, heads, rope)),
             ], axis=-1)
@@ -972,4 +989,5 @@ class HybridLM(DecoderLM):
             causal=True, scale=(nope + rope) ** -0.5, force=self.attention_impl,
         )
         out = out.transpose(0, 2, 1, 3).reshape(b, s, heads * cfg.v_head_dim)
-        return mxu_dot(out, lp["o_w"].astype(cdt))
+        with scope("attn.proj"):
+            return mxu_dot(out, lp["o_w"].astype(cdt))

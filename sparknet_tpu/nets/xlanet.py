@@ -21,12 +21,14 @@ array, e.g. ``{"data": (N,H,W,C) float, "label": (N,) int}``.
 
 from __future__ import annotations
 
+import re
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..proto.caffe_pb import NetParameter
+from ..utils.profiling import scope
 from .layers import (
     ApplyCtx,
     DATA_LAYER_TYPES,
@@ -35,6 +37,14 @@ from .layers import (
     Shape,
 )
 from .weights import WeightCollection
+
+
+def layer_scope(lp) -> str:
+    """A prototxt layer's scope, from its own type and name:
+    ``convolution.conv1``, ``lrn.norm1``, ``pooling.inception_3a_pool``
+    (a ``/`` or a parenthesis of the name reads ``_``: the scope is one
+    element of an operation's path)."""
+    return f"{lp.type.lower()}.{re.sub(r'[^\w.-]', '_', lp.name)}"
 
 
 class XLANet:
@@ -134,7 +144,10 @@ class XLANet:
             if lp.type in DATA_LAYER_TYPES:
                 continue
             impl = LAYER_IMPLS[lp.type]
-            layer_rng = jax.random.fold_in(rng, i) if rng is not None else None
+            with scope("rng"):
+                layer_rng = (
+                    jax.random.fold_in(rng, i) if rng is not None else None
+                )
             inputs = [blobs[b] for b in lp.bottom]
 
             def run_layer(p, st_in, inputs_, rng_, lp=lp, impl=impl):
@@ -146,10 +159,13 @@ class XLANet:
 
             if self.remat and train:
                 run_layer = jax.checkpoint(run_layer)
-            outputs, st = run_layer(
-                params.get(lp.name, {}), state.get(lp.name), inputs,
-                layer_rng,
-            )
+            # every layer is a scope of the device's time by its own
+            # type and name (utils/profiling.scope): nobody writes one
+            with scope(layer_scope(lp)):
+                outputs, st = run_layer(
+                    params.get(lp.name, {}), state.get(lp.name), inputs,
+                    layer_rng,
+                )
             for top, out in zip(lp.top, outputs):
                 blobs[top] = out
             if st is not None:

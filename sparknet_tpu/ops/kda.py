@@ -79,6 +79,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils.profiling import scope
 from .attention import uses_flash
 
 SUB = 16
@@ -586,7 +587,7 @@ def kda_scan(
     if uses_kernels(q.shape, v.shape, chunk, force):
         if initial_state is None:
             initial_state = jnp.zeros((b, h, dk, dv), f32)
-        with jax.named_scope("kda.scan"):
+        with scope("kda.scan"):
             out, state = _scan_kernels(
                 q, k.astype(mmt), v.astype(mmt), g.astype(f32),
                 beta.astype(f32), initial_state.astype(f32), interpret,
@@ -610,7 +611,7 @@ def kda_scan(
         state = decay_n * state + dot("bhtk,bhtv->bhkv", k_n, u)
         return state, out
 
-    with jax.named_scope("kda.scan"):
+    with scope("kda.scan"):
         q, k, v = cut(q), cut(k), cut(v)
         cum = jnp.cumsum(cut(g.astype(f32)), axis=3)  # G, inclusive
         beta = cut(beta.astype(f32))[..., None]

@@ -77,6 +77,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..ops.attention import uses_flash
 from ..ops.matmul import mxu_bmm
+from ..utils.profiling import scope
 
 
 def init_moe_params(
@@ -398,7 +399,8 @@ def _chunk_rows(xg, gate_up, down, wgt, live, sizes, cdt):
     run their experts and weight the rows.  ``live`` marks rows that are
     slots (the last chunk's tail is not), the others are zeroed."""
     y = _swiglu_rows(xg, gate_up, down, sizes, cdt)
-    return jnp.where(live[:, None], y * wgt[:, None], 0.0)
+    with scope("moe.rows"):
+        return jnp.where(live[:, None], y * wgt[:, None], 0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -649,8 +651,9 @@ def _held_chunks(
 def _combine(prev, y, tokens_side, n_held, c, rows, kernel):
     """:func:`moe_combine` of chunk ``c``'s rows ``y``."""
     w, key, pos, *runs = tokens_side
-    plan, rel = _combine_plan(pos, runs, n_held, c, rows)
-    return moe_combine(prev, y, plan, rel, key, w, interpret=kernel)
+    with scope("moe.rows"):
+        plan, rel = _combine_plan(pos, runs, n_held, c, rows)
+        return moe_combine(prev, y, plan, rel, key, w, interpret=kernel)
 
 
 def _held_chunks_fwd(
@@ -663,10 +666,13 @@ def _held_chunks_fwd(
             tok_c, wgt_c, live, sizes = _chunk_of(
                 tok, wgt, offsets, n_held, c, rows
             )
+            with scope("moe.rows"):
+                xg = xt[tok_c]
             if kernel is None:
-                y = _chunk_rows(xt[tok_c], gate_up, down, wgt_c, live, sizes, cdt)
-                return out.at[tok_c].add(y), done + jnp.sum(sizes)
-            y = _swiglu_rows(xt[tok_c], gate_up, down, sizes, cdt)
+                y = _chunk_rows(xg, gate_up, down, wgt_c, live, sizes, cdt)
+                with scope("moe.rows"):
+                    return out.at[tok_c].add(y), done + jnp.sum(sizes)
+            y = _swiglu_rows(xg, gate_up, down, sizes, cdt)
             out = _combine(out, y, tokens_side, n_held, c, rows, kernel)
             return out, done + jnp.sum(sizes)
 
@@ -695,15 +701,18 @@ def _held_chunks_bwd(rows, cdt, kernel, res, cts):
             tok_c, wgt_c, live, sizes = _chunk_of(
                 tok, wgt, offsets, n_held, c, rows
             )
+            with scope("moe.rows"):
+                xg, dy = xt[tok_c], dout[tok_c]
             _, vjp = jax.vjp(
                 lambda xg, gu, dn, w: _chunk_rows(
                     xg, gu, dn, w, live, sizes, cdt
                 ),
-                xt[tok_c], gate_up, down, wgt_c,
+                xg, gate_up, down, wgt_c,
             )
-            dxg, dgu_c, ddown_c, dw_c = vjp(dout[tok_c])
-            dxg = jnp.where(live[:, None], dxg, 0).astype(jnp.float32)
-            dxt = dxt.at[tok_c].add(dxg)
+            dxg, dgu_c, ddown_c, dw_c = vjp(dy)
+            with scope("moe.rows"):
+                dxg = jnp.where(live[:, None], dxg, 0).astype(jnp.float32)
+                dxt = dxt.at[tok_c].add(dxg)
             return (
                 dxt, dgu + dgu_c, ddown + ddown_c,
                 lax.dynamic_update_slice_in_dim(
@@ -781,11 +790,11 @@ def held_experts_ffn(
     if not 0 <= first <= first + held <= num_experts:
         raise ValueError(f"experts_held {experts_held} of {num_experts}")
     orig_shape = x.shape
-    xt = x.reshape(-1, orig_shape[-1])
-    t = xt.shape[0]
+    t = math.prod(orig_shape[:-1])
     slots = t * top_k
 
-    with jax.named_scope("moe.route"):
+    with scope("moe.route"):
+        xt = x.reshape(-1, orig_shape[-1])
         weights, experts = router(xt, params)
         local = experts.reshape(-1) - first  # (S,) token-major slots
         key = jnp.where((local >= 0) & (local < held), local, held)
@@ -793,34 +802,35 @@ def held_experts_ffn(
             key, weights.reshape(-1), held, top_k
         )
         n_held = offsets[-1]
-
-    rows = chunk_rows or held_chunk_rows(slots, held, num_experts)
-    pad = -slots % rows
-    if pad:
-        tok = jnp.pad(tok, (0, pad))
-        wgt = jnp.pad(wgt, (0, pad))
-    kernel = tokens_side = None
-    if uses_combine_kernel(t, xt.shape[1], top_k, rows, force):
-        kernel = interpret
-        tokens_side = (
-            lax.stop_gradient(weights).reshape(-1), key, pos,
-            *tile_runs(key, offsets, _COMBINE_SLOTS),
-        )
-    with jax.named_scope("moe.experts"):
+        rows = chunk_rows or held_chunk_rows(slots, held, num_experts)
+        pad = -slots % rows
+        if pad:
+            tok = jnp.pad(tok, (0, pad))
+            wgt = jnp.pad(wgt, (0, pad))
+        kernel = tokens_side = None
+        if uses_combine_kernel(t, xt.shape[1], top_k, rows, force):
+            kernel = interpret
+            tokens_side = (
+                lax.stop_gradient(weights).reshape(-1), key, pos,
+                *tile_runs(key, offsets, _COMBINE_SLOTS),
+            )
+    with scope("moe.experts"):
         out, done = _held_chunks(
             xt, params["experts_gate_up"], params["experts_down"], tok, wgt,
             offsets, n_held, tokens_side, rows, compute_dtype, kernel,
         )
-    loads = (offsets[1:] - offsets[:-1]).astype(jnp.float32)
-    held_f = n_held.astype(jnp.float32)
-    counters = {
-        "moe_slots_held": held_f,
-        "moe_slots_in_kernel": (
-            jnp.zeros_like(held_f) if kernel is None else held_f
-        ),
-        "moe_load_max_over_mean": jnp.max(loads) / jnp.maximum(
-            jnp.mean(loads), 1.0 / held
-        ),
-        "moe_slots_dropped": (n_held - done).astype(jnp.float32),
-    }
-    return out.reshape(orig_shape).astype(x.dtype), counters
+        out = out.reshape(orig_shape).astype(x.dtype)
+    with scope("counters"):
+        loads = (offsets[1:] - offsets[:-1]).astype(jnp.float32)
+        held_f = n_held.astype(jnp.float32)
+        counters = {
+            "moe_slots_held": held_f,
+            "moe_slots_in_kernel": (
+                jnp.zeros_like(held_f) if kernel is None else held_f
+            ),
+            "moe_load_max_over_mean": jnp.max(loads) / jnp.maximum(
+                jnp.mean(loads), 1.0 / held
+            ),
+            "moe_slots_dropped": (n_held - done).astype(jnp.float32),
+        }
+    return out, counters

@@ -511,12 +511,21 @@ class ParallelSolver(Solver):
         return metrics
 
     def lower_step(self, batch):
-        """As :meth:`Solver.lower_step`, of sync mode's mesh program."""
-        return self._train_step.lower(
+        """As :meth:`Solver.lower_step`, of sync mode's mesh program
+        (kept for :meth:`Solver.step_scopes` as the base keeps its own:
+        the partitioned module's instructions are one chip's).  Local
+        mode has no program of one step, it dispatches rounds of tau:
+        nothing is lowered, None comes back and ``step_scopes`` stays
+        None."""
+        if self.mode != "sync":
+            self._lowered = None
+            return None
+        self._lowered = self._train_step.lower(
             self.params, self.state, self.opt_state,
             self._put_batch(batch), jnp.asarray(self.iter, jnp.int32),
             self.rng,
         )
+        return self._lowered
 
     def step(self, batches: Iterator[Dict[str, Any]], n: int = 1, log_fn=None):
         if self.mode == "sync":

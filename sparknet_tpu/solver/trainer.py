@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from ..proto import caffe_pb
 from ..nets.xlanet import XLANet
 from ..telemetry import timeline as _timeline
+from ..utils import profiling
 from .caffe_solver import init_opt_state, make_update_fn, mults_for_params
 
 
@@ -144,7 +145,7 @@ def make_train_step(
         specs = net.param_specs()
         lr_m, dec_m = mults_for_params(params, specs)
         update = make_update_fn(sp, lr_m, dec_m)
-        with jax.named_scope("optimizer"):
+        with profiling.scope("optimizer"):
             params, opt_state = update(params, grads, opt_state, it)
         return params, new_state, opt_state, metrics
 
@@ -282,7 +283,8 @@ class Solver:
         )
 
         def fused(params, state, opt_state, batch, it, rng):
-            rng, step_rng = jax.random.split(rng)
+            with profiling.scope("rng"):
+                rng, step_rng = jax.random.split(rng)
             params, state, opt_state, metrics = train_step(
                 params, state, opt_state, batch, it, step_rng
             )
@@ -295,6 +297,11 @@ class Solver:
         # the iteration counter as the program carries it on the device;
         # None until the first dispatch and after a restore
         self._it_dev = None
+        # what lower_step last made, and its scope table once asked for
+        # (step_scopes); a caller without a solver means the newest one's
+        self._lowered = None
+        self._scopes = None
+        profiling.publish_step_source(self)
 
     @property
     def timeline(self):
@@ -371,12 +378,32 @@ class Solver:
         for ``batch`` — what a caller reads (``.as_text()``) to check
         which kernels the compiled step holds, e.g. that attention
         lowered to the Pallas ``tpu_custom_call`` and not to the
-        reference path."""
-        return self._step_program.lower(
+        reference path.  The newest one is kept: it is the program
+        :meth:`step_scopes` speaks of."""
+        self._lowered = self._step_program.lower(
             self.params, self.state, self.opt_state,
             self._put_batch(batch), jnp.asarray(self.iter, jnp.int32),
             self.rng,
         )
+        return self._lowered
+
+    def step_scopes(self):
+        """``{instruction name: profiling.Scoped}`` of the step program
+        :meth:`lower_step` last lowered: which of the program's own
+        scopes (``profiling.scope``) and which pass each instruction of
+        the *compiled* step belongs to, for a device trace's operations
+        to be summed by (``profiling.by_scope``).  Compiles the kept
+        ``Lowered`` (it keeps its executable: a second ``compile()`` is
+        free, a first one finds the step in the compile cache) and is
+        memoised for it.  None before any ``lower_step``."""
+        lowered = self._lowered
+        if lowered is None:
+            return None
+        if self._scopes is None or self._scopes[0] is not lowered:
+            self._scopes = (lowered, profiling.scope_table(
+                lowered.compile().as_text(), profiling.declared_scopes()
+            ))
+        return self._scopes[1]
 
     def _push_loss(self, metrics) -> None:
         """Record this iteration's loss for ``average_loss`` smoothing

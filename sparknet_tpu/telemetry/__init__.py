@@ -89,6 +89,7 @@ __all__ = [
     "dash",
     "exporter",
     "finish_run",
+    "first_batch_lowered",
     "flight",
     "install_for_training",
     "reqtrace",
@@ -135,6 +136,33 @@ def install_for_training(
     # the allocation-free no-op
     flight.configure_from_env()
     return path
+
+
+def first_batch_lowered(feed, solver, profile_dir: Optional[str]):
+    """With ``--profile-dir``, ``feed`` with the step program of its
+    first batch lowered on its way to the loop (``Solver.lower_step``:
+    the program that batch's steps dispatch, which :func:`finish_run`
+    lays the device's time on, scope by scope); without, ``feed``
+    itself.  One lowering before the first step, nothing a step after.
+    A step that does not lower from one batch of the feed (``iter_size``
+    micro-batches stacked by the loop) costs the run one line, not its
+    life."""
+    if not profile_dir:
+        return feed
+
+    def noting():
+        batches = iter(feed)
+        first = next(batches)
+        try:
+            solver.lower_step(first)
+        except Exception as e:  # diagnostics must not end a training run
+            print(f"trace: the step of the feed's first batch does not "
+                  f"lower ({type(e).__name__}: {e}); no time by scope",
+                  flush=True)
+        yield first
+        yield from batches
+
+    return noting()
 
 
 @contextlib.contextmanager
@@ -199,15 +227,60 @@ def _device_track(profile_dir: str, emit=print) -> list:
     return events
 
 
+def _device_scopes(profile_dir: str, emit=print) -> None:
+    """On ``emit``, the device time of the profiled steps by the program's
+    own scopes (``utils/profiling.scope``): outermost scope x forward /
+    backward / recompute, ms a step and share of the step, then what
+    joined no scope, what joined nothing, what lies in fusions over
+    several scopes, and the coverage.  From the ``XLA Ops`` of the plane
+    that ran the step program and the scope table of the newest Solver's
+    lowered step; one line saying why where either is missing."""
+    import time
+
+    from ..utils import profiling  # jax; only under --profile-dir
+
+    t0 = time.perf_counter()
+    table = profiling.step_scopes()
+    if table is None:
+        emit(
+            "trace: no step program lowered (Solver.lower_step): no scope "
+            "table to lay the device's operations on"
+        )
+        return
+    for plane, events in sorted(profiling.device_modules(profile_dir).items()):
+        try:
+            program = trace.step_program(events, but=profiling.ANCHOR_PROGRAM)
+            break
+        except ValueError:  # nothing but the anchor ran on this plane
+            continue
+    else:
+        emit(f"trace: no device plane under {profile_dir} ran a program; "
+             f"no time by scope")
+        return
+    seconds, steps = profiling.step_op_seconds(
+        events, profiling.device_ops(profile_dir).get(plane, ()), program
+    )
+    reduced = profiling.by_scope(seconds, table, steps)
+    emit(
+        f"trace: device time by scope, ms a step over {steps} executions "
+        f"of {program} on {plane} (table and sums made in "
+        f"{time.perf_counter() - t0:.2f} s):\n  "
+        + "\n  ".join(profiling.scope_lines(reduced))
+    )
+
+
 def finish_run() -> None:
     """End-of-run hook (apps' ``finally``): write the merged Chrome
     trace when this process owns one (with the device's track when
-    ``--profile-dir`` was on too), then reset tracer + current
+    ``--profile-dir`` was on too; with ``--profile-dir``, traced or not,
+    the device time by scope is printed first), then reset tracer + current
     timeline (and the SPARKNET_TRACE export) so an in-process rerun
     (tests driving ``main()`` twice) starts clean.  Safe to call when
     telemetry was never enabled."""
     global _saved_trace_env, _profile_dir
     profile_dir, _profile_dir = _profile_dir, None
+    if profile_dir:
+        _device_scopes(profile_dir)  # needs no --trace
     if trace.enabled():
         try:
             trace.write(

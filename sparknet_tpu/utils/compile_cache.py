@@ -45,6 +45,14 @@ def enable(root: Optional[str] = None, subdir: Optional[str] = None) -> str:
 
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # jax leaves an instruction's metadata (op_name, source line) out of
+    # the cache's key by default, so a step that differs from a cached one
+    # in its scopes alone is handed that executable, with the older
+    # op_names in its text: Solver.step_scopes would read the scopes of a
+    # program that is not this one (seen on the CPU and on the chip,
+    # PERF.md section 6, PR 37).  With the metadata in the key an edit that
+    # moves a traced line compiles once more.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = resolve(root, subdir)
     if not os.environ.get(ENV) and jax.config.jax_compilation_cache_dir != path:
         jax.config.update("jax_compilation_cache_dir", path)

@@ -14,6 +14,11 @@ exposed here:
   meter for app training loops (items = images or tokens).
 - :func:`cost_numbers` — FLOPs and bytes of a compiled program from
   XLA cost analysis (``tools/time_net``'s MFU numerator).
+- :func:`scope` / :func:`scope_table` / :func:`by_scope` — device time
+  by the program's own scopes: the step names its parts as jax traces
+  it, the compiled step's text says which instruction belongs to which
+  (and to which pass), and a trace's seconds per operation are summed
+  by them (docs/OBSERVABILITY.md, "Device time by scope").
 
 This module answers *op-level* questions (what XLA did inside a
 dispatch).  Host-side observability — metrics registry, span tracing,
@@ -23,11 +28,17 @@ per-step phase attribution, Prometheus export — lives in
 
 from __future__ import annotations
 
+import bisect
 import contextlib
 import glob
 import os
+import re
 import time
-from typing import Dict, List, Optional, Tuple
+import weakref
+from collections import Counter, defaultdict
+from typing import (
+    Dict, Iterable, List, Mapping, NamedTuple, Optional, Sequence, Tuple,
+)
 
 import jax
 
@@ -131,11 +142,12 @@ def trace(log_dir: Optional[str]):
         yield
 
 
-def device_modules(log_dir: str) -> Dict[str, List[Tuple[str, int, int]]]:
-    """``{device plane: [(name, start_ns, duration_ns), ...]}``: the
-    ``XLA Modules`` line (one event per execution of a program) of every
-    device plane in the newest ``.xplane.pb`` under ``log_dir``.  Empty
-    where no device was traced (a CPU run)."""
+def _device_lines(
+    log_dir: str, line_name: str
+) -> Dict[str, List[Tuple[str, int, int]]]:
+    """``{device plane: [(name, start_ns, duration_ns), ...]}``: the line
+    ``line_name`` of every device plane in the newest ``.xplane.pb``
+    under ``log_dir``.  Empty where no device was traced (a CPU run)."""
     from jax.profiler import ProfileData
 
     found = glob.glob(
@@ -148,12 +160,380 @@ def device_modules(log_dir: str) -> Dict[str, List[Tuple[str, int, int]]]:
         if not plane.name.startswith("/device:"):
             continue
         for line in plane.lines:
-            if line.name == "XLA Modules":
+            if line.name == line_name:
                 out[plane.name] = [
                     (ev.name, int(ev.start_ns), int(ev.duration_ns))
                     for ev in line.events
                 ]
     return out
+
+
+def device_modules(log_dir: str) -> Dict[str, List[Tuple[str, int, int]]]:
+    """The ``XLA Modules`` line (one event per execution of a program) of
+    every device plane of the newest trace under ``log_dir``."""
+    return _device_lines(log_dir, "XLA Modules")
+
+
+def device_ops(log_dir: str) -> Dict[str, List[Tuple[str, int, int]]]:
+    """The ``XLA Ops`` line of the same planes: the operations the core
+    ran, each named by its HLO text (``%fusion.261 = ...``), whose first
+    token is the instruction's name in the compiled module."""
+    return _device_lines(log_dir, "XLA Ops")
+
+
+# ---------------------------------------------------------------------------
+# Device time by the program's own scopes: scope -> instruction -> seconds
+# ---------------------------------------------------------------------------
+
+_declared: set = set()
+
+
+def scope(name: str):
+    """``jax.named_scope(name)``, with the name remembered as one of the
+    program's own scopes (:func:`declared_scopes`).  It runs while jax
+    traces the Python, once a compile: a step pays nothing for it.  The
+    name becomes one element of every operation's ``op_name`` path under
+    it, so it holds no ``/`` and no parenthesis."""
+    if not name or any(c in name for c in "/()"):
+        raise ValueError(f"scope name {name!r}: one path element, please")
+    _declared.add(name)
+    return jax.named_scope(name)
+
+
+def declared_scopes() -> frozenset:
+    """Every name :func:`scope` was given so far in this process."""
+    return frozenset(_declared)
+
+
+class Scoped(NamedTuple):
+    """One instruction of a compiled step, as :func:`scope_table` reads it."""
+    chain: Tuple[str, ...]  # its declared scopes, outermost first
+    pass_: str  # "forward", "backward" or "recompute"
+    kernel: bool  # a Pallas kernel (custom call to tpu_custom_call)
+    mixed: bool  # a fusion whose body spans several outermost scopes
+    lent: bool  # the compiler's own instruction, placed by its neighbours
+
+
+CONTAINERS = frozenset({"while", "conditional", "call"})
+_PLUMBING = frozenset(
+    {"parameter", "constant", "tuple", "get-tuple-element", "bitcast"}
+)
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = (.*)$")
+_OPCODE = re.compile(r"\s([a-z][a-z0-9\-]*)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+# the computations a container runs: their instructions are device
+# operations of their own; a fusion's, a reduction's or a sort's are not
+_RUNS = re.compile(
+    r"\b(?:body|condition|to_apply|true_computation|false_computation)"
+    r"=%?([\w.\-]+)|\bbranch_computations=\{([^}]*)\}"
+)
+# jvp(x), transpose(jvp(x)), vmap(x): a transformation around a path
+# element; jit(x) is a function of that name, not a scope
+_WRAPPED = re.compile(r"^(?!p?jit\()\w+\((.*)\)$")
+
+
+def instruction_name(text: str) -> str:
+    """``fusion.261`` of ``%fusion.261 = bf16[...] fusion(...)``: what a
+    trace's operation and the compiled module's instruction share."""
+    return text.split(" ", 1)[0].lstrip("%")
+
+
+def opcode_of(text: str) -> Optional[str]:
+    """The opcode in an instruction's HLO text (the first lower-case word
+    before a parenthesis after the result's type), None where there is no
+    text beyond a name."""
+    m = _OPCODE.search(text.partition(" = ")[2])
+    return m.group(1) if m else None
+
+
+def scope_chain(op_name: str, declared: Iterable[str]) -> Tuple[str, ...]:
+    """The declared scopes on an ``op_name`` path, outermost first; jax's
+    own elements (``while``, ``body``, ``closed_call``, ``checkpoint``,
+    ``cond``, ``pjit``, primitive names) are skipped and ``jvp(x)`` /
+    ``transpose(jvp(x))`` read as ``x``.  Where XLA merged operations it
+    joins their paths with ``;``: the first one speaks."""
+    chain = []
+    for part in op_name.split(";", 1)[0].split("/"):
+        m = _WRAPPED.match(part)
+        while m:
+            part = m.group(1)
+            m = _WRAPPED.match(part)
+        # a scope closed over by a checkpoint inside it comes twice
+        if part in declared and part not in chain[-1:]:
+            chain.append(part)
+    return tuple(chain)
+
+
+def pass_of(op_name: str) -> str:
+    if "rematted_computation" in op_name:
+        return "recompute"
+    return "backward" if "transpose(" in op_name else "forward"
+
+
+def _computations(hlo_text: str):
+    """``({computation: [(instruction, opcode, rest of its line)]}, the
+    entry computation's name)`` of a module's text."""
+    found: Dict[str, List[Tuple[str, str, str]]] = {}
+    entry, body = None, None
+    for line in hlo_text.splitlines():
+        if body is not None and line.startswith(" "):
+            m = _INSTRUCTION.match(line)
+            if m:
+                op = _OPCODE.search(" " + m.group(2))
+                body.append((m.group(1), op.group(1) if op else "", m.group(2)))
+        elif line.endswith("{") and "->" in line and not line.startswith(" "):
+            head = line.split("(", 1)[0].split()
+            body = found.setdefault(head[-1].lstrip("%"), [])
+            if head[0] == "ENTRY":
+                entry = head[-1].lstrip("%")
+        elif line.startswith("}"):
+            body = None
+    return found, entry
+
+
+def _operands(rest: str) -> List[str]:
+    """The operands' names in an instruction's text after `` = ``."""
+    m = _OPCODE.search(" " + rest)
+    if not m:
+        return []
+    listed = rest[m.end() - 1:].split(")", 1)[0]
+    return [
+        word.split()[-1].lstrip("%")
+        for word in re.sub(r"/\*.*?\*/", "", listed).split(",") if word.strip()
+    ]
+
+
+def scope_table(hlo_text: str, declared: Iterable[str]) -> Dict[str, Scoped]:
+    """``{instruction name: Scoped}`` of a *compiled* module's text
+    (``compiled.as_text()``): every instruction the device runs as an
+    operation of its own — those of the entry computation and of the
+    bodies, conditions and branches of its containers — with the chain of
+    ``declared`` scopes on its ``op_name`` path and the pass the path
+    says.  A fusion takes its own ``op_name``; where it has none, what
+    most of its fused computation's instructions carry; and it is marked
+    ``mixed`` when those span more than one outermost scope: the size of
+    the attribution's error.  What the compiler made itself and gave no
+    path of the program's (the grouped products' own kernels, which XLA
+    names ``ragged-dot-none``; prefetches and copies between memory
+    spaces; buffer allocations) is ``lent`` the scopes that its nearest
+    neighbours share — the instructions that use it and those it uses,
+    through tuple plumbing and other such instructions — or, where they
+    share none, those of what uses it, else of what it uses: a prefetched
+    weight belongs to the product it is fetched for.  Containers (``while``,
+    ``conditional``, ``call``) get no entry, their children are operations
+    of their own; nor do parameters, constants and tuple plumbing, which
+    take no time.  A pure function over text."""
+    declared = frozenset(declared)
+    computations, entry = _computations(hlo_text)
+    read = lambda op_name: (scope_chain(op_name, declared), pass_of(op_name))
+    table: Dict[str, Scoped] = {}
+    uses: Dict[str, List[str]] = {}  # instruction -> its operands
+    used_by: Dict[str, List[str]] = defaultdict(list)
+    made, passing = set(), set()  # the compiler's own; plumbing to walk through
+    seen, queue = set(), [entry]
+    while queue:
+        name = queue.pop()
+        if name in seen or name not in computations:
+            continue
+        seen.add(name)
+        for instruction, opcode, rest in computations[name]:
+            if opcode in CONTAINERS:
+                for one, several in _RUNS.findall(rest):
+                    queue.extend(
+                        c.strip().lstrip("%")
+                        for c in (several.split(",") if several else [one])
+                    )
+                continue
+            uses[instruction] = _operands(rest)
+            for operand in uses[instruction]:
+                used_by[operand].append(instruction)
+            if opcode in _PLUMBING:
+                if opcode not in ("parameter", "constant"):
+                    passing.add(instruction)
+                continue
+            named = _OP_NAME.search(rest)
+            path = named.group(1) if named else ""
+            chain, pass_ = read(path)
+            ours, mixed = "jit(" in path, False  # a path of the program's
+            if opcode == "fusion":
+                fused = _CALLS.search(rest)
+                inner = [
+                    read(m.group(1))
+                    for _i, op, text in computations.get(
+                        fused.group(1) if fused else "", ()
+                    )
+                    if op not in _PLUMBING
+                    for m in [_OP_NAME.search(text)] if m
+                ]
+                mixed = len({c[0] for c, _p in inner if c}) > 1
+                if not path and inner:
+                    (chain, pass_), ours = Counter(inner).most_common(1)[0][0], True
+            if not ours:
+                made.add(instruction)
+            table[instruction] = Scoped(
+                chain, pass_,
+                opcode == "custom-call"
+                and 'custom_call_target="tpu_custom_call"' in rest,
+                mixed, False,
+            )
+
+    def lenders(start: str, edges: Mapping[str, List[str]]) -> List[Tuple]:
+        """(chain, pass) of the nearest scoped instructions of the
+        program's own along ``edges`` from ``start``."""
+        reached, front = {start}, [start]
+        for _hop in range(8):
+            found = [
+                (table[n].chain, table[n].pass_)
+                for n in front if n != start and n in table
+                and n not in made and table[n].chain
+            ]
+            if found:
+                return found
+            front = [
+                n for at in front if at == start or at in made or at in passing
+                for n in edges.get(at, ()) if n not in reached
+            ]
+            reached.update(front)
+        return []
+
+    for instruction in made:
+        after, before = lenders(instruction, used_by), lenders(instruction, uses)
+        near = after + before
+        if not near:
+            continue
+        # the scopes all its neighbours share (a product between the
+        # activation under moe.experts and the weighting under
+        # moe.experts/moe.rows is moe.experts'); where they share none,
+        # those of what uses it, or else of what it uses
+        shared = os.path.commonprefix([chain for chain, _pass in near])
+        chain, pass_ = Counter(after or before).most_common(1)[0][0]
+        table[instruction] = table[instruction]._replace(
+            chain=shared or chain, pass_=pass_, lent=True
+        )
+    return table
+
+
+def by_scope(
+    op_seconds: Mapping[str, float], table: Mapping[str, Scoped], steps: int
+) -> Dict:
+    """A trace's seconds per operation (named by HLO text or by the
+    instruction's name alone), summed by the table's scopes, ms a step:
+    ``rows`` ``{(chain, pass, kernel): ms}``; ``unscoped``, joined and
+    under no declared scope; ``unjoined``, a name the table lacks;
+    ``mixed``, the part of the joined time in fusions over several
+    outermost scopes, and ``lent``, the part in instructions the compiler
+    made, placed by their neighbours: the two sizes of the attribution's
+    error; ``containers``, the ``while`` / ``conditional`` /
+    ``call`` operations, whose time is their children's over again and is
+    in nothing else here; ``total`` = rows + unscoped + unjoined, the
+    device time of a step; ``coverage``, the rows' share of it in %."""
+    rows: Dict[Tuple, float] = defaultdict(float)
+    sums = dict.fromkeys(
+        ("unscoped", "unjoined", "mixed", "lent", "containers"), 0.0
+    )
+    for text, seconds in op_seconds.items():
+        ms = 1e3 * seconds / steps
+        entry = table.get(instruction_name(text))
+        if entry is None:
+            container = opcode_of(text) in CONTAINERS
+            sums["containers" if container else "unjoined"] += ms
+            continue
+        if entry.mixed:
+            sums["mixed"] += ms
+        if entry.lent:
+            sums["lent"] += ms
+        if entry.chain:
+            rows[(entry.chain, entry.pass_, entry.kernel)] += ms
+        else:
+            sums["unscoped"] += ms
+    scoped = sum(rows.values())
+    total = scoped + sums["unscoped"] + sums["unjoined"]
+    return {
+        "rows": dict(rows), **sums, "total": total,
+        "coverage": 100.0 * scoped / total if total else 0.0,
+    }
+
+
+PASSES = ("forward", "backward", "recompute")
+
+
+def scope_lines(reduced: Dict, depth: Optional[int] = 1) -> List[str]:
+    """:func:`by_scope`'s result as a table: a row for each chain cut to
+    ``depth`` scopes (whole chains where None), ms a step forward,
+    backward and recomputed, their sum, its share of the step and the
+    part of it in Pallas kernels; then unscoped, unjoined, mixed and the
+    coverage."""
+    grouped: Dict[str, Dict[str, float]] = defaultdict(lambda: defaultdict(float))
+    for (chain, pass_, kernel), ms in reduced["rows"].items():
+        row = grouped["/".join(chain[:depth])]
+        row[pass_] += ms
+        row["kernels"] += ms if kernel else 0.0
+    total = reduced["total"] or 1.0
+    width = max([len(k) for k in grouped] + [8])
+    lines = [
+        f"{'scope':<{width}} {'forward':>9} {'backward':>9} {'recompute':>9} "
+        f"{'ms a step':>9} {'share':>7} {'kernels':>9}"
+    ]
+    ranked = sorted(
+        grouped.items(), key=lambda kv: -sum(kv[1][p] for p in PASSES)
+    )
+    for name, row in ranked:
+        ms = sum(row[p] for p in PASSES)
+        lines.append(
+            f"{name:<{width}} {row['forward']:>9.3f} {row['backward']:>9.3f} "
+            f"{row['recompute']:>9.3f} {ms:>9.3f} {100 * ms / total:>6.2f}% "
+            f"{row['kernels']:>9.3f}"
+        )
+    for name in ("unscoped", "unjoined"):
+        lines.append(
+            f"{name:<{width}} {'':>29} {reduced[name]:>9.3f} "
+            f"{100 * reduced[name] / total:>6.2f}%"
+        )
+    lines.append(
+        f"a step {reduced['total']:.3f} ms on the device; under a declared "
+        f"scope {reduced['coverage']:.2f}%; in fusions over more than one "
+        f"outermost scope (mixed) {reduced['mixed']:.3f} ms, "
+        f"{100 * reduced['mixed'] / total:.2f}%; in instructions of the "
+        f"compiler's own, placed by their neighbours (lent) "
+        f"{reduced['lent']:.3f} ms, {100 * reduced['lent'] / total:.2f}%"
+    )
+    return lines
+
+
+def step_op_seconds(
+    modules: Sequence[Tuple[str, int, int]],
+    ops: Sequence[Tuple[str, int, int]],
+    program: str,
+) -> Tuple[Dict[str, float], int]:
+    """(seconds per operation inside the executions of ``program``, their
+    count) from one device plane's two lines."""
+    runs = sorted((s, s + d) for name, s, d in modules if name == program)
+    starts = [s for s, _e in runs]
+    seconds: Dict[str, float] = defaultdict(float)
+    for name, start, duration in ops:
+        i = bisect.bisect_right(starts, start) - 1
+        if i >= 0 and start < runs[i][1]:
+            seconds[name] += duration / 1e9
+    return dict(seconds), len(runs)
+
+
+# the newest Solver: the one whose step program a caller without a solver
+# means (a weak reference; None until one is built)
+_step_source: Optional[weakref.ref] = None
+
+
+def publish_step_source(solver) -> None:
+    global _step_source
+    _step_source = weakref.ref(solver)
+
+
+def step_scopes() -> Optional[Dict[str, Scoped]]:
+    """``Solver.step_scopes()`` of the newest Solver: the scope table of
+    the step program it last lowered (``lower_step``).  None where there
+    is no solver, or it has lowered nothing yet."""
+    solver = _step_source() if _step_source is not None else None
+    return solver.step_scopes() if solver is not None else None
 
 
 class StepTimer:
