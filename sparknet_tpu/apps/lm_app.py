@@ -34,8 +34,10 @@ from the config's ``vocab_size``.  The progress line carries the model's
 counters (the sparse layers' ``moe_slots_held``, ``moe_slots_in_kernel``
 (as many where the ``moe_combine`` kernel sums the experts' rows into their
 tokens, 0 where a scatter-add does), ``moe_load_max_over_mean``,
-``moe_slots_dropped``; the hybrid's ``kda_chunks``, ``kda_chunks_in_kernel``
-and ``kda_decay_min`` besides); the telemetry registry has them, as every
+``moe_slots_dropped``; ``rope_rows_in_kernel``, the rows of q and k that the
+``rope_to_heads`` kernel rotated, 0 where ``apply_rope`` did; the hybrid's
+``kda_chunks``, ``kda_chunks_in_kernel`` and ``kda_decay_min`` in its place);
+the telemetry registry has them, as every
 solver's newest step metrics, under its source ``train_step``.
 """
 

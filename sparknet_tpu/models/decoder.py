@@ -57,6 +57,7 @@ import jax.numpy as jnp
 from ..ops.attention import attention, document_spans, flash_tiles_documents
 from ..ops.kda import KDA_MIN_LOG_DECAY, kda_chunks, kda_scan, uses_kernels
 from ..ops.matmul import mxu_dot
+from ..ops.rope import rope_tables, rope_to_heads, uses_rope_kernel
 from ..utils.profiling import scope
 from ..parallel.moe import (
     held_experts_ffn, init_held_experts_params, route_grouped, route_sigmoid,
@@ -69,6 +70,10 @@ COUNTERS = (
     "moe_slots_held", "moe_slots_in_kernel", "moe_load_max_over_mean",
     "moe_slots_dropped",
 )
+# rows of q and k (B * S * (heads + KV heads), summed over the layers) that
+# ops.rope.rope_to_heads rotated in the newest step: all of them where the
+# kernel runs, 0 on apply_rope's path
+ROPE_COUNTERS = ("rope_rows_in_kernel",)
 KDA_COUNTERS = ("kda_chunks", "kda_chunks_in_kernel", "kda_decay_min")
 # of a batch of packed documents, newest step: the documents in it; the
 # positions that bear a loss; the keys every token sees, summed over the
@@ -86,7 +91,7 @@ DOC_COUNTERS = (
 _REDUCE = {
     "moe_slots_held": jnp.mean, "moe_slots_in_kernel": jnp.mean,
     "moe_load_max_over_mean": jnp.max, "moe_slots_dropped": jnp.sum,
-    "kda_chunks": jnp.max,
+    "rope_rows_in_kernel": jnp.sum, "kda_chunks": jnp.max,
     "kda_chunks_in_kernel": jnp.max, "kda_decay_min": jnp.min,
     **dict.fromkeys(DOC_COUNTERS, jnp.max),  # of the batch: reported once
 }
@@ -283,7 +288,7 @@ def swiglu(u, gate_w, up_w, down_w):
 class DecoderLM:
     """Functional decoder + untied head; see the module docstring."""
 
-    counters = COUNTERS
+    counters = COUNTERS + ROPE_COUNTERS
 
     def __init__(
         self,
@@ -390,8 +395,13 @@ class DecoderLM:
 
     # -- layers --------------------------------------------------------------
     def _attention(self, li: int, lp, u, docs=None):
-        """``docs``: (segment_ids, positions) of a packed batch, else the
-        sequence is one document."""
+        """(float32 output, the layer's rotary counter).  ``docs``:
+        (segment_ids, positions) of a packed batch, else the sequence is
+        one document.  q and k go from their projection's float32 product
+        to the flash kernels' head-major layout through
+        :func:`sparknet_tpu.ops.rope.rope_to_heads` where
+        :func:`~sparknet_tpu.ops.rope.uses_rope_kernel` says so, else
+        through :func:`apply_rope`, a cast and a transpose."""
         cfg, cdt = self.cfg, self.compute_dtype
         b, s, _ = u.shape
         kind = cfg.layer_types[li]
@@ -399,10 +409,19 @@ class DecoderLM:
         kv, d = cfg.num_key_value_heads, cfg.head_dim
         inv_freq, factor = rope_inv_freq(cfg.rope_parameters[kind], d)
         segment_ids, positions = docs or (None, jnp.arange(s))
+        rot = 2 * inv_freq.shape[0]
+        kernel = uses_rope_kernel(s, d, rot, self.attention_impl)
+        if kernel:
+            with scope("attn.rope"):
+                tables = rope_tables(positions, inv_freq, factor, d)
 
         def project(w, n, rotate):
             with scope("attn.proj"):
-                t = mxu_dot(u, w.astype(cdt)).reshape(b, s, n, d)
+                t = mxu_dot(u, w.astype(cdt))
+            if rotate and kernel:
+                with scope("attn.rope"):
+                    return rope_to_heads(t, *tables, rot, cdt)
+            t = t.reshape(b, s, n, d)
             if rotate:
                 with scope("attn.rope"):
                     t = apply_rope(t, positions, inv_freq, factor)
@@ -418,7 +437,9 @@ class DecoderLM:
         )
         out = out.transpose(0, 2, 1, 3).reshape(b, s, heads * d)
         with scope("attn.proj"):
-            return mxu_dot(out, lp["o_w"].astype(cdt))
+            out = mxu_dot(out, lp["o_w"].astype(cdt))
+        rows = b * s * (heads + kv) if kernel else 0
+        return out, {"rope_rows_in_kernel": jnp.asarray(rows, jnp.float32)}
 
     def _router(self, xt, lp):
         """(weights, experts) a token, by the configuration's scoring."""
@@ -455,7 +476,7 @@ class DecoderLM:
         its counters), under the layer kind's scope."""
         kind = "attn.window" if self.cfg.layer_types[li] == SLIDING else "attn.full"
         with scope(kind):
-            return self._attention(li, lp, u, docs), {}
+            return self._attention(li, lp, u, docs)
 
     def layer_apply(self, li: int, lp, x, docs=None):
         """One layer on ``x`` (B, S, h): (x, the layer's counters)."""

@@ -21,7 +21,7 @@ from benchmark.configs import laguna_xs2_reference as plain  # noqa: E402
 from benchmark.reference import shaken  # noqa: E402
 from sparknet_tpu.models import decoder  # noqa: E402
 from sparknet_tpu.models.decoder import (  # noqa: E402
-    COUNTERS, DecoderConfig, DecoderLM, swiglu,
+    COUNTERS, ROPE_COUNTERS, DecoderConfig, DecoderLM, swiglu,
 )
 from sparknet_tpu.parallel.moe import held_experts_ffn  # noqa: E402
 
@@ -189,6 +189,7 @@ def test_counters_reach_the_blobs_and_the_registry(tiny):
     assert float(metrics["moe_load_max_over_mean"]) >= 1.0
     read = REGISTRY.sources()["train_step"].snapshot()
     assert {k: read[k] for k in COUNTERS} == {k: float(metrics[k]) for k in COUNTERS}
+    assert read["rope_rows_in_kernel"] == 0.0  # heads of 16: apply_rope's path
     assert read["loss"] == float(metrics["loss"])
 
 
@@ -351,7 +352,8 @@ def test_mellum_form_round_trips_and_has_no_shared_expert(packed):
         "attn_norm", "q_w", "k_w", "v_w", "o_w", "ffn_norm", "router_w",
         "experts_gate_up", "experts_down"}
     assert model.input_names == ["input_ids", "labels", "segment_ids", "positions"]
-    assert model.counters == COUNTERS + DOC_COUNTERS and DecoderLM.counters == COUNTERS
+    assert DecoderLM.counters == COUNTERS + ROPE_COUNTERS
+    assert model.counters == DecoderLM.counters + DOC_COUNTERS
     assert set(model.dummy_batch()) == set(model.input_names)
     with pytest.raises(ValueError, match="scoring_func 'tanh'"):
         DecoderLM(tiny_mellum(scoring_func="tanh"), _PACKED_SHAPES)
@@ -514,10 +516,10 @@ def test_a_batch_without_the_blobs_is_the_unpacked_program(tiny):
     """The unpacked model has no document counter, takes positions from
     ``arange`` and the loss over every position, as before this feed."""
     cfg, model, params = tiny
-    assert not model.packed and model.counters == COUNTERS
+    assert not model.packed and model.counters == COUNTERS + ROPE_COUNTERS
     assert model.input_names == ["input_ids", "labels"]
     blobs, _ = model.apply(params, {}, _batch(cfg))
-    assert set(blobs) == {"loss", "token_acc", *COUNTERS}
+    assert set(blobs) == {"loss", "token_acc", *COUNTERS, *ROPE_COUNTERS}
 
 
 def test_one_document_a_sequence_reads_what_the_unpacked_program_reads(tiny):

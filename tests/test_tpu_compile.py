@@ -161,3 +161,74 @@ def test_kda_scan_compiles_for_v5e_at_real_widths(force, one_chip, no_compile_ca
     for name in ("kda_scan_fwd", "kda_scan_bwd"):
         mine = [line for line in calls if name in line.split(" = ")[0]]
         assert mine and all("f32[1,32,128,128]" in line for line in mine), name
+
+
+_ROTARY = {
+    # batch, heads, lanes of a head that rotate, positions by sequence
+    "laguna_sliding_q": (2, 64, 128, False),
+    "laguna_full_q": (2, 48, 64, False),
+    "laguna_k": (2, 8, 128, False),
+    "mellum_q_documents": (4, 32, 128, True),
+    "mellum_k_documents": (4, 4, 128, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ROTARY))
+def test_rope_kernels_compile_for_v5e_at_real_widths(case, one_chip, no_compile_cache):
+    """``ops.rope.rope_to_heads`` at a cell's q or k (8192 tokens, heads of
+    128), forward and backward: two kernels, neither under a name the
+    attention readers match, the gradient back in bfloat16."""
+    from sparknet_tpu.ops.rope import rope_to_heads, uses_rope_kernel
+
+    batch, heads, rot, packed = _ROTARY[case]
+    assert uses_rope_kernel(8192, 128, rot, "flash")
+    shape = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one_chip)
+    x = shape((batch, 8192, heads * 128), jnp.float32)
+    table = shape((batch if packed else 1, 8192, 128), jnp.float32)
+
+    def grads(x, cos, sin):
+        total = lambda x: (rope_to_heads(x, cos, sin, rot, jnp.bfloat16).astype(jnp.float32) ** 2).sum()
+        return jax.grad(total)(x).astype(jnp.bfloat16)
+
+    text = jax.jit(grads).lower(x, table, table).compile().as_text()
+    calls = [ln.split(" = ")[0] for ln in text.splitlines() if " custom-call(" in ln]
+    assert len(calls) == 2 and "flash_attention" not in text
+    assert any("rope_to_heads" in c for c in calls) and any("rope_from_heads" in c for c in calls)
+
+
+def test_the_rope_kernels_sit_under_attn_rope_in_all_three_passes(one_chip, no_compile_cache):
+    """A small ``DecoderLM`` step with the layers checkpointed, compiled
+    for v5e as a TPU takes it: ``utils.profiling.scope_table`` (what
+    ``Solver.step_scopes()`` reads the compiled step with; on this backend
+    its own program holds no custom call) finds the rotary kernels as
+    kernels under ``attn.rope`` inside a mixer's scope, forward, recomputed
+    and backward, apart from the flash kernels by name."""
+    from sparknet_tpu.models.decoder import DecoderConfig, DecoderLM
+    from sparknet_tpu.utils import profiling
+
+    cfg = DecoderConfig.tiny(head_dim=128, remat=True)
+    model = DecoderLM(cfg, {"input_ids": (2, 128)}, attention_impl="flash")
+    put = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    params = put(jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))[0]))
+    ids = jax.ShapeDtypeStruct((2, 128), jnp.int32, sharding=one_chip)
+
+    def step(p, ids):
+        return jax.grad(
+            lambda p_: model.apply(p_, {}, {"input_ids": ids, "labels": ids}, train=True)[0]["loss"]
+        )(p)
+
+    text = jax.jit(step).lower(params, ids).compile().as_text()
+    table = profiling.scope_table(text, profiling.declared_scopes())
+    rotary = {name: e for name, e in table.items() if name.startswith("rope_")}
+    assert rotary and all(e.kernel for e in rotary.values())
+    for e in rotary.values():
+        assert e.chain[0] in ("attn.full", "attn.window") and e.chain[-1] == "attn.rope", e
+    assert {e.pass_ for n, e in rotary.items() if n.startswith("rope_to_heads")} == {"forward", "recompute"}
+    assert {e.pass_ for n, e in rotary.items() if n.startswith("rope_from_heads")} == {"backward"}
+    flash = [name for name, e in table.items() if e.kernel and "flash_attention" in name]
+    assert flash and not any("flash_attention" in name for name in rotary)
+    # every kernel under a mixer is one or the other: mixer_ms - mixer_glue_ms
+    kernels = {name for name, e in table.items() if e.kernel and e.chain[:1] and e.chain[0].startswith("attn")}
+    assert kernels == set(rotary) | set(flash)
