@@ -27,7 +27,6 @@ def main(argv) -> int:
 
     from benchmark import run
     from sparknet_tpu.apps import lm_app
-    from sparknet_tpu.models.decoder import DecoderLM, HybridConfig, HybridLM
     from sparknet_tpu.solver.trainer import init_opt_state, make_train_step
     from sparknet_tpu.utils import profiling
 
@@ -40,7 +39,7 @@ def main(argv) -> int:
     shapes = {"input_ids": (args.batch_size, args.seq_len)}
     if args.pack_documents:
         shapes.update(segment_ids=shapes["input_ids"], positions=shapes["input_ids"])
-    model = (HybridLM if isinstance(cfg, HybridConfig) else DecoderLM)(
+    model = lm_app.model_class(cfg)(
         cfg, shapes, compute_dtype=jnp.bfloat16 if args.bf16 else jnp.float32,
         attention_impl="flash",  # as a TPU takes them: the backend here is the CPU
     )

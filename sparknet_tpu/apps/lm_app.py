@@ -21,13 +21,15 @@ then the most a batch-head walks).  The ``train feed:`` line says what
 traffic the run had, with the pool's mean pairs a sequence (the registry's
 ``attn_pairs_pool`` gauges: what a mean step's attention costs).
 
-``--config`` is ``tiny``, ``tiny_hybrid`` or the path of a JSON file holding
-a published ``config.json``'s keys, as cut to this chip's share if it is
-(``num_experts`` held of ``deployment.num_experts_routed``).  Its
-``model_type`` picks the model: ``bailing_hybrid`` is
+``--config`` is ``tiny``, ``tiny_hybrid``, ``tiny_mamba`` or the path of a
+JSON file holding a published ``config.json``'s keys, as cut to this chip's
+share if it is (``num_experts`` held of ``deployment.num_experts_routed``).
+Its ``model_type`` picks the model: ``bailing_hybrid`` is
 :class:`~sparknet_tpu.models.decoder.HybridLM` (KDA and MLA layers by
-``layer_group_size``, ``HybridConfig.from_published``), anything else
-:class:`~sparknet_tpu.models.decoder.DecoderLM`
+``layer_group_size``, ``HybridConfig.from_published``), ``granitemoehybrid``
+:class:`~sparknet_tpu.models.decoder.MambaHybridLM` (Mamba-2 and attention
+layers by ``layer_types``, ``MambaHybridConfig.from_published``), anything
+else :class:`~sparknet_tpu.models.decoder.DecoderLM`
 (``DecoderConfig.from_published``: ``layer_types``, ``mlp_layer_types``,
 ``num_attention_heads_per_layer``, ``rope_parameters`` ...).  Token ids come
 from the config's ``vocab_size``.  The progress line carries the model's
@@ -36,7 +38,9 @@ counters (the sparse layers' ``moe_slots_held``, ``moe_slots_in_kernel``
 tokens, 0 where a scatter-add does), ``moe_load_max_over_mean``,
 ``moe_slots_dropped``; ``rope_rows_in_kernel``, the rows of q and k that the
 ``rope_to_heads`` kernel rotated, 0 where ``apply_rope`` did; the hybrid's
-``kda_chunks``, ``kda_chunks_in_kernel`` and ``kda_decay_min`` in its place);
+``kda_chunks``, ``kda_chunks_in_kernel`` and ``kda_decay_min`` in its place;
+the Mamba hybrid's ``ssd_chunks``, ``ssd_chunks_in_kernel``,
+``ssd_state_resets`` and ``ssd_decay_min`` alone);
 the telemetry registry has them, as every
 solver's newest step metrics, under its source ``train_step``.
 """
@@ -56,24 +60,40 @@ from ..data.text import (
     clm_dataset, clm_feed, packed_dataset, packed_feed, pool_pairs,
 )
 from ..models.decoder import (
-    MLA, SLIDING, DecoderConfig, DecoderLM, HybridConfig, HybridLM,
+    ATTENTION, MLA, SLIDING, DecoderConfig, DecoderLM, HybridConfig, HybridLM,
+    MambaHybridConfig, MambaHybridLM,
 )
 from ..ops.attention import flash_tile_kinds, uses_flash
 from ..solver.trainer import Solver
 from .bert_app import flash_tiles_note, make_solver_param
 
+# a published file's model_type -> its configuration; DecoderConfig otherwise
+_CONFIGS = {"bailing_hybrid": HybridConfig, "granitemoehybrid": MambaHybridConfig}
+_TINY = {
+    "tiny": DecoderConfig, "tiny_hybrid": HybridConfig,
+    "tiny_mamba": MambaHybridConfig,
+}
+
 
 def make_config(args):
-    if args.config == "tiny":
-        cfg = DecoderConfig.tiny()
-    elif args.config == "tiny_hybrid":
-        cfg = HybridConfig.tiny()
+    if args.config in _TINY:
+        cfg = _TINY[args.config].tiny()
     else:
         with open(args.config) as fh:
             published = json.load(fh)
-        hybrid = published.get("model_type") == "bailing_hybrid"
-        cfg = (HybridConfig if hybrid else DecoderConfig).from_published(published)
+        kind = _CONFIGS.get(published.get("model_type"), DecoderConfig)
+        cfg = kind.from_published(published)
     return dataclasses.replace(cfg, remat=True) if args.remat else cfg
+
+
+def model_class(cfg):
+    """The model a configuration's class builds (by this module's names, so
+    that a test can plant a subclass)."""
+    if isinstance(cfg, HybridConfig):
+        return HybridLM
+    if isinstance(cfg, MambaHybridConfig):
+        return MambaHybridLM
+    return DecoderLM
 
 
 def build(args):
@@ -100,7 +120,7 @@ def build(args):
             seq_len=args.seq_len, seed=args.seed,
         )
         make_feed = clm_feed
-    model = (HybridLM if isinstance(cfg, HybridConfig) else DecoderLM)(
+    model = model_class(cfg)(
         cfg, shapes,
         compute_dtype=jnp.bfloat16 if args.bf16 else jnp.float32,
         attention_impl=args.attention or None,
@@ -121,7 +141,10 @@ def packing_note(args, cfg, ds) -> str:
     from ..telemetry.registry import REGISTRY
 
     first = next(iter(packed_feed(ds, args.batch_size, seed=args.seed)))
-    pairs = {"full": pool_pairs(ds), "window": pool_pairs(ds, cfg.sliding_window)}
+    pairs = {"full": pool_pairs(ds)}
+    window = getattr(cfg, "sliding_window", None)  # None: no window layers
+    if window is not None:
+        pairs["window"] = pool_pairs(ds, window)
     for kind, mean in pairs.items():
         REGISTRY.gauge("attn_pairs_pool", kind=kind).set(mean)
     return (
@@ -130,18 +153,21 @@ def packing_note(args, cfg, ds) -> str:
         f"{args.doc_max}) tokens, cut every {args.seq_len}; first batch "
         f"doc_count={int((first['positions'] == 0).sum())} "
         f"loss_positions={int((first['labels'] >= 0).sum())}; the pool's "
-        f"mean attn_pairs a sequence full={pairs['full']:.0f} "
-        f"window={pairs['window']:.0f}"
+        f"mean attn_pairs a sequence "
+        + " ".join(f"{kind}={mean:.0f}" for kind, mean in pairs.items())
     )
 
 
 def flash_tiles(cfg, seq_len: int) -> Dict[str, int]:
     """Score tiles a batch-head of each kind of layer executes in each flash
     kernel, by whether a mask runs over them (``flash_tile_kinds``); a
-    hybrid's KDA layers run no flash kernel."""
+    hybrid's KDA layers and the Mamba hybrid's Mamba layers run no flash
+    kernel."""
     tiles = {}
     for kind in dict.fromkeys(cfg.layer_types):
         if isinstance(cfg, HybridConfig) and kind != MLA:
+            continue
+        if isinstance(cfg, MambaHybridConfig) and kind != ATTENTION:
             continue
         tiles[f"{kind}_unmasked"], tiles[f"{kind}_masked"] = flash_tile_kinds(
             seq_len, seq_len, causal=True,
@@ -153,8 +179,8 @@ def flash_tiles(cfg, seq_len: int) -> Dict[str, int]:
 def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description="Causal-LM pre-training (LmApp)")
     ap.add_argument("--config", default="tiny",
-                    help="'tiny', 'tiny_hybrid' or a JSON file of published "
-                         "config keys")
+                    help="'tiny', 'tiny_hybrid', 'tiny_mamba' or a JSON "
+                         "file of published config keys")
     ap.add_argument("--seq-len", type=int, default=64)
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--max-iter", type=int, default=1000)
@@ -215,10 +241,13 @@ def main(argv=None) -> Dict[str, float]:
         # the band's tiles; what a batch's documents leave of them is the
         # progress line's flash_tiles_docs_full / _window
         tiles = tiles.replace("flash_tiles=", "flash_tiles at most ", 1)
+    experts = (
+        f"experts_held={cfg.experts_held} of {cfg.num_experts} "
+        if hasattr(cfg, "experts_held") else ""
+    )
     print(
         f"LmApp: config={args.config} vocab={cfg.vocab_size} "
-        f"layers={cfg.num_layers} hidden={cfg.hidden_size} experts_held="
-        f"{cfg.experts_held} of {cfg.num_experts} "
+        f"layers={cfg.num_layers} hidden={cfg.hidden_size} {experts}"
         f"params={solver.train_net.num_params(solver.params)} {tiles}"
     )
     timer = StepTimer(

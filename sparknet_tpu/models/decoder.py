@@ -1,8 +1,10 @@
-"""Causal decoder LMs over sparse experts — the pure-JAX decoder family,
-beside :mod:`.bert`: :class:`DecoderLM` mixes window and full softmax
-attention; :class:`HybridLM` (further down, with its own header) mixes
-gated delta-rule linear attention (KDA) and latent attention (MLA) and
-shares DecoderLM's frame: the residual layout, SwiGLU, the held experts,
+"""Causal decoder LMs — the pure-JAX decoder family, beside :mod:`.bert`:
+:class:`DecoderLM` mixes window and full softmax attention over sparse
+experts; :class:`HybridLM` (further down, with its own header) mixes gated
+delta-rule linear attention (KDA) and latent attention (MLA);
+:class:`MambaHybridLM` (last, with its own header) mixes Mamba-2 state-space
+mixers and attention without positions over dense layers, on packed
+documents.  The two share DecoderLM's frame: the residual layout, SwiGLU,
 the chunked loss, the counters and the Solver protocol.
 
 DecoderLM is built from a published ``config.json``'s own keys
@@ -58,6 +60,7 @@ from ..ops.attention import attention, document_spans, flash_tiles_documents
 from ..ops.kda import KDA_MIN_LOG_DECAY, kda_chunks, kda_scan, uses_kernels
 from ..ops.matmul import mxu_dot
 from ..ops.rope import rope_tables, rope_to_heads, uses_rope_kernel
+from ..ops.ssd import document_starts, ssd_chunks, ssd_scan
 from ..utils.profiling import scope
 from ..parallel.moe import (
     held_experts_ffn, init_held_experts_params, route_grouped, route_sigmoid,
@@ -66,6 +69,7 @@ from ..parallel.moe import (
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 KDA, MLA = "kda", "mla"
+MAMBA, ATTENTION = "mamba", "attention"
 COUNTERS = (
     "moe_slots_held", "moe_slots_in_kernel", "moe_load_max_over_mean",
     "moe_slots_dropped",
@@ -75,6 +79,13 @@ COUNTERS = (
 # kernel runs, 0 on apply_rope's path
 ROPE_COUNTERS = ("rope_rows_in_kernel",)
 KDA_COUNTERS = ("kda_chunks", "kda_chunks_in_kernel", "kda_decay_min")
+# the Mamba-2 scan's: the chunks it walks one after another for a sequence;
+# of them those walked inside a Pallas kernel (none: every call is
+# jax.numpy); the document starts inside a sequence at which the carried
+# state was dropped; the smallest decay exp(delta A) of the step
+SSD_COUNTERS = (
+    "ssd_chunks", "ssd_chunks_in_kernel", "ssd_state_resets", "ssd_decay_min",
+)
 # of a batch of packed documents, newest step: the documents in it; the
 # positions that bear a loss; the keys every token sees, summed over the
 # batch, in one layer of a kind (times heads and head size: a product's
@@ -87,12 +98,14 @@ DOC_COUNTERS = (
 # a counter over the layers that report it: the mean of the slots held (and
 # of those whose rows the moe_combine kernel read: as many, or none), the
 # worst load ratio, every slot dropped; the chunks a sequence (the same in
-# every layer), the smallest decay anywhere
+# every layer), the smallest decay anywhere, the resets of every layer
 _REDUCE = {
     "moe_slots_held": jnp.mean, "moe_slots_in_kernel": jnp.mean,
     "moe_load_max_over_mean": jnp.max, "moe_slots_dropped": jnp.sum,
     "rope_rows_in_kernel": jnp.sum, "kda_chunks": jnp.max,
     "kda_chunks_in_kernel": jnp.max, "kda_decay_min": jnp.min,
+    "ssd_chunks": jnp.max, "ssd_chunks_in_kernel": jnp.max,
+    "ssd_state_resets": jnp.sum, "ssd_decay_min": jnp.min,
     **dict.fromkeys(DOC_COUNTERS, jnp.max),  # of the batch: reported once
 }
 
@@ -289,6 +302,7 @@ class DecoderLM:
     """Functional decoder + untied head; see the module docstring."""
 
     counters = COUNTERS + ROPE_COUNTERS
+    doc_counters = DOC_COUNTERS  # beside them on a packed batch
 
     def __init__(
         self,
@@ -313,7 +327,7 @@ class DecoderLM:
         if self.packed:
             self._check_packed()
             self.input_names += ["segment_ids", "positions"]
-            self.counters = self.counters + DOC_COUNTERS
+            self.counters = self.counters + self.doc_counters
         self.blob_shapes: Dict[str, Tuple[int, ...]] = {
             **{name: (b, s) for name in self.input_names}, "loss": (),
             "token_acc": (), **{name: () for name in self.counters},
@@ -498,7 +512,7 @@ class DecoderLM:
         layers' counters, one dict a layer."""
         cfg = self.cfg
         with scope("embed"):
-            x = params["embed"]["tokens"][input_ids].astype(self.compute_dtype)
+            x = self._embed(params["embed"]["tokens"], input_ids)
         counted = []
         for li in range(cfg.num_layers):
             fn = lambda lp, x, li=li: self.layer_apply(li, lp, x, docs)
@@ -508,13 +522,25 @@ class DecoderLM:
             counted.append(counters)
         return x, counted
 
-    def _loss(self, head, x, labels):
+    def _embed(self, table, input_ids):
+        """The rows of the embedding ``table`` for ``input_ids``, in the
+        compute type."""
+        return table[input_ids].astype(self.compute_dtype)
+
+    def _head_weight(self, params):
+        """The head's (hidden, vocabulary) matrix in the compute type."""
+        return params["head"]["lm_w"].astype(self.compute_dtype)
+
+    def _logits(self, xc, lm_w):
+        return mxu_dot(xc, lm_w)  # (chunk, V) f32
+
+    def _loss(self, params, x, labels):
         """(mean next-token NLL, accuracy) over every position — of a
         packed batch, over the positions that bear a label (``labels >=
         0``) — the logits made ``loss_chunk`` tokens at a time and not
         kept."""
-        cfg, cdt = self.cfg, self.compute_dtype
-        x = rms_norm(x, head["norm"], cfg.rms_norm_eps)
+        cfg = self.cfg
+        x = rms_norm(x, params["head"]["norm"], cfg.rms_norm_eps)
         tokens = labels.size
         chunk = min(cfg.loss_chunk, tokens)
         if tokens % chunk:
@@ -523,11 +549,11 @@ class DecoderLM:
             )
         xs = x.reshape(tokens // chunk, chunk, x.shape[-1])
         ys = labels.reshape(tokens // chunk, chunk)
-        lm_w = head["lm_w"].astype(cdt)
+        lm_w = self._head_weight(params)
 
         @jax.checkpoint
         def one(xc, yc):
-            logits = mxu_dot(xc, lm_w)  # (chunk, V) f32
+            logits = self._logits(xc, lm_w)
             with scope("loss"):  # inside lm_head: the head's product is not
                 lse = jax.scipy.special.logsumexp(logits, axis=-1)
                 if self.packed:
@@ -554,31 +580,35 @@ class DecoderLM:
         return nll / tokens, hit / tokens
 
     def _doc_counters(self, batch):
-        """DOC_COUNTERS of a packed batch: a few vector operations on its
-        ``segment_ids`` and ``labels``."""
-        cfg = self.cfg
+        """``doc_counters`` of a packed batch: a few vector operations on
+        its ``segment_ids`` and ``labels`` (the window's two where the
+        model has a window)."""
+        window = getattr(self.cfg, "sliding_window", None)
         seg = batch["segment_ids"]
         at = jnp.arange(seg.shape[1], dtype=jnp.int32)
         start, _ = document_spans(seg)
         seen = at - start + 1  # the keys a token sees in a full layer
         total = lambda x: jnp.sum(x).astype(jnp.float32)
-        return {
+        counted = {
             "doc_count": total(at == start),
             "loss_positions": total(batch["labels"] >= 0),
             "attn_pairs_full": total(seen),
-            "attn_pairs_window": total(jnp.minimum(seen, cfg.sliding_window)),
-            "flash_tiles_docs_full": flash_tiles_documents(seg),
-            "flash_tiles_docs_window": flash_tiles_documents(
-                seg, window=cfg.sliding_window
-            ),
         }
+        if window is not None:
+            counted["attn_pairs_window"] = total(jnp.minimum(seen, window))
+        counted["flash_tiles_docs_full"] = flash_tiles_documents(seg)
+        if window is not None:
+            counted["flash_tiles_docs_window"] = flash_tiles_documents(
+                seg, window=window
+            )
+        return counted
 
     # -- Solver protocol -----------------------------------------------------
     def apply(self, params, state, batch, *, train=None, rng=None):
         docs = (batch["segment_ids"], batch["positions"]) if self.packed else None
         x, counted = self.hidden(params, batch["input_ids"], docs)
         with scope("lm_head"):
-            loss, acc = self._loss(params["head"], x, batch["labels"])
+            loss, acc = self._loss(params, x, batch["labels"])
         blobs = {"loss": loss, "token_acc": acc}
         with scope("counters"):
             if self.packed:
@@ -743,16 +773,29 @@ class HybridConfig:
         return cls(**fields)
 
 
-def causal_conv(x, w, history=None):
+def causal_conv(x, w, history=None, bias=None, segment_ids=None, history_ids=None):
     """Depthwise causal convolution: ``y_t = sum_j w[j] * x_{t-(K-1)+j}``
-    (``w[K-1]`` meets the current token).  ``x``: (B, S, C); ``w``: (K,
-    C); ``history`` (B, K-1, C): the positions before the first, zeros
-    where None."""
+    (``w[K-1]`` meets the current token), plus ``bias`` (C,) where given.
+    ``x``: (B, S, C); ``w``: (K, C); ``history`` (B, K-1, C): the positions
+    before the first, zeros where None.  With ``segment_ids`` (B, S) a tap
+    reads 0 where its token lies in another document than the current
+    token; ``history_ids`` (B, K-1) are the history's ids (None: no
+    document of this call's)."""
     taps, s = w.shape[0], x.shape[1]
     if history is None:
         history = jnp.zeros((x.shape[0], taps - 1, x.shape[2]), x.dtype)
     padded = jnp.concatenate([history, x], axis=1)
-    return sum(padded[:, j:j + s] * w[j] for j in range(taps))
+    if segment_ids is None:
+        out = sum(padded[:, j:j + s] * w[j] for j in range(taps))
+    else:
+        if history_ids is None:
+            history_ids = jnp.full((x.shape[0], taps - 1), -1, segment_ids.dtype)
+        ids = jnp.concatenate([history_ids, segment_ids], axis=1)
+        out = sum(
+            jnp.where((ids[:, j:j + s] == segment_ids)[..., None], padded[:, j:j + s], 0.0)
+            * w[j] for j in range(taps)
+        )
+    return out if bias is None else out + bias
 
 
 def rope_interleaved(x, positions, theta):
@@ -1012,3 +1055,335 @@ class HybridLM(DecoderLM):
         out = out.transpose(0, 2, 1, 3).reshape(b, s, heads * cfg.v_head_dim)
         with scope("attn.proj"):
             return mxu_dot(out, lp["o_w"].astype(cdt))
+
+
+# ---------------------------------------------------------------------------
+# The state-space hybrid: Mamba-2 mixers and NoPE grouped-query attention
+# over dense SwiGLU layers, on packed documents
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class MambaHybridConfig:
+    """A ``granitemoehybrid`` ``config.json`` with no experts, as
+    :class:`MambaHybridLM` needs it; the published keys keep their names."""
+    vocab_size: int
+    hidden_size: int
+    shared_intermediate_size: int  # the dense SwiGLU of every layer
+    num_attention_heads: int
+    num_key_value_heads: int
+    layer_types: Tuple[str, ...]  # MAMBA or ATTENTION
+    mamba_n_heads: int
+    mamba_d_head: int
+    mamba_d_state: int
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    attention_multiplier: float = 1.0
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    rms_norm_eps: float = 1e-5
+    initializer_range: float = 0.02
+    remat: bool = False
+    loss_chunk: int = 4096
+    ssm_segment: int = 2048  # tokens a checkpointed segment of a Mamba mixer
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def mlp_layer_types(self) -> Tuple[str, ...]:
+        return ("dense",) * self.num_layers  # no experts
+
+    @property
+    def head_dim(self) -> int:  # of an attention head
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def ssm_width(self) -> int:  # x, z and y: the heads side by side
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @classmethod
+    def from_published(cls, published: Mapping[str, Any], **overrides):
+        """From the published keys (or a cut: the first
+        ``num_hidden_layers`` of ``layer_types``).  What this model does not
+        compute is refused, not ignored: experts, rotary positions, a bias
+        on a projection, an untied head, more than one group of B and C, an
+        expansion the heads do not fill."""
+        refused = {
+            "num_local_experts": published.get("num_local_experts", 0) != 0,
+            "position_embedding_type": published.get("position_embedding_type") != "nope",
+            "attention_bias": published.get("attention_bias", False),
+            "mamba_proj_bias": published.get("mamba_proj_bias", False),
+            "mamba_conv_bias": not published.get("mamba_conv_bias", True),
+            "tie_word_embeddings": not published.get("tie_word_embeddings", True),
+            "mamba_n_groups": published.get("mamba_n_groups", 1) != 1,
+            "mamba_expand": published["mamba_expand"] * published["hidden_size"]
+            != published["mamba_n_heads"] * published["mamba_d_head"],
+        }
+        if any(refused.values()):
+            raise ValueError(
+                "not modelled: " + ", ".join(k for k, v in refused.items() if v)
+            )
+        n = published["num_hidden_layers"]
+        fields = dict(
+            vocab_size=published["vocab_size"],
+            hidden_size=published["hidden_size"],
+            shared_intermediate_size=published["shared_intermediate_size"],
+            num_attention_heads=published["num_attention_heads"],
+            num_key_value_heads=published["num_key_value_heads"],
+            layer_types=tuple(published["layer_types"][:n]),
+            **{key: published[key] for key in (
+                "mamba_n_heads", "mamba_d_head", "mamba_d_state", "mamba_d_conv",
+                "mamba_chunk_size", "attention_multiplier",
+                "embedding_multiplier", "residual_multiplier", "logits_scaling",
+                "rms_norm_eps",
+            )},
+        )
+        fields.update(overrides)
+        return cls(**fields)
+
+    @classmethod
+    def tiny(cls, **overrides) -> "MambaHybridConfig":
+        """Both kinds of layer at a size for CPU tests: two Mamba layers
+        round an attention layer, 4 heads of 16 in the scan with a state of
+        8, 4 query heads over 2 KV heads of 8 scaled by an eighth of 8^-1/2
+        (as the published 0.015625 is of 64^-1/2), chunks of 8 in segments
+        of 32."""
+        fields = dict(
+            vocab_size=96, hidden_size=32, shared_intermediate_size=48,
+            num_attention_heads=4, num_key_value_heads=2,
+            layer_types=(MAMBA, ATTENTION, MAMBA), mamba_n_heads=4,
+            mamba_d_head=16, mamba_d_state=8, mamba_chunk_size=8,
+            attention_multiplier=8 ** -0.5 / 8, embedding_multiplier=12.0,
+            residual_multiplier=0.22, logits_scaling=2.0, loss_chunk=32,
+            ssm_segment=32,
+        )
+        fields.update(overrides)
+        return cls(**fields)
+
+
+class MambaHybridLM(DecoderLM):
+    """A decoder whose layers are Mamba-2 mixers or grouped-query attention
+    without positions (IBM Granite 4.0-H), each followed by a dense SwiGLU,
+    with a tied embedding and head; DecoderLM's frame otherwise.  On ``h``,
+    per layer: ``a = h + r Mix(RMSNorm(h))``, ``h' = a + r MLP(RMSNorm(a))``
+    (``r`` = ``residual_multiplier``); ``h_0 = embedding_multiplier E[ids]``
+    and ``logits = RMSNorm(h_L) E^T / logits_scaling``.
+
+    - **Mamba-2 mixer**: ``[z | xBC | dt] = u W_in``; ``xBC <- SiLU(conv(xBC)
+      + b)``, a depthwise causal convolution of ``mamba_d_conv`` taps whose
+      taps read 0 before the token's document; ``x | B | C``, ``x`` in
+      ``mamba_n_heads`` heads; ``delta = softplus(dt + dt_bias)``, ``A =
+      -exp(A_log)``; :func:`sparknet_tpu.ops.ssd.ssd_scan` with the ``D``
+      skip, its state reset at every document's first token; ``y <-
+      RMSNorm(y * SiLU(z)) w`` over all the heads; ``y W_out``.  The mixer
+      runs ``ssm_segment`` tokens at a time, each a checkpoint, with the
+      scan's state, the convolution's last inputs and their document ids
+      carried between them (``HybridLM._kda``'s bound on what lives at
+      once).
+    - **Attention**: ``num_attention_heads`` over ``num_key_value_heads`` of
+      ``hidden / heads``, no rotary, scores scaled by
+      ``attention_multiplier``, causal and inside documents, through the
+      flash kernels.
+
+    Counters beside the packed batch's: ``SSD_COUNTERS``."""
+
+    counters = SSD_COUNTERS
+    doc_counters = tuple(c for c in DOC_COUNTERS if "window" not in c)
+    _no_decay = ("A_log", "dt_bias", "D", "conv_b")
+
+    def _check_layers(self) -> None:
+        for kind in set(self.cfg.layer_types):
+            if kind not in (MAMBA, ATTENTION):
+                raise ValueError(f"layer type {kind!r}")
+
+    # -- init ----------------------------------------------------------------
+    def init(self, rng: jax.Array):
+        """Mamba-2's initialisation: ``A_log = log(1 .. heads)``, ``D = 1``,
+        ``dt_bias`` the inverse softplus of a ``dt`` log-uniform in [1e-3,
+        1e-1] a head, the convolution as a depthwise ``Conv1d``'s default
+        (uniform in +-1/sqrt(taps), bias likewise); matrices truncated
+        normal ``initializer_range``, norm scales 1."""
+        cfg = self.cfg
+        h = cfg.hidden_size
+        heads, width = cfg.mamba_n_heads, cfg.ssm_width
+        channels = width + 2 * cfg.mamba_d_state  # x, B, C
+        d = cfg.head_dim
+        keys = iter(jax.random.split(rng, 2 + 12 * cfg.num_layers))
+
+        def trunc(shape):
+            return cfg.initializer_range * jax.random.truncated_normal(
+                next(keys), -2.0, 2.0, shape, jnp.float32
+            )
+
+        def uniform(shape, lo, hi):
+            return jax.random.uniform(next(keys), shape, jnp.float32, lo, hi)
+
+        ones = lambda n=h: jnp.ones((n,), jnp.float32)
+        bound = cfg.mamba_d_conv ** -0.5
+        params: Dict[str, Dict[str, jax.Array]] = {
+            "embed": {"tokens": trunc((cfg.vocab_size, h))}
+        }
+        for li, kind in enumerate(cfg.layer_types):
+            layer = {"attn_norm": ones(), "ffn_norm": ones()}
+            if kind == MAMBA:
+                dt = jnp.exp(uniform((heads,), math.log(1e-3), math.log(1e-1)))
+                layer.update({
+                    "in_proj": trunc((h, 2 * width + 2 * cfg.mamba_d_state + heads)),
+                    "conv_w": uniform((cfg.mamba_d_conv, channels), -bound, bound),
+                    "conv_b": uniform((channels,), -bound, bound),
+                    "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                    "A_log": jnp.log(jnp.arange(1, heads + 1, dtype=jnp.float32)),
+                    "D": ones(heads),
+                    "ssm_norm": ones(width),
+                    "out_proj": trunc((width, h)),
+                })
+            else:
+                kv = cfg.num_key_value_heads
+                layer.update({
+                    "q_w": trunc((h, cfg.num_attention_heads * d)),
+                    "k_w": trunc((h, kv * d)), "v_w": trunc((h, kv * d)),
+                    "o_w": trunc((cfg.num_attention_heads * d, h)),
+                })
+            layer.update({
+                "mlp_in": trunc((h, 2 * cfg.shared_intermediate_size)),
+                "mlp_out": trunc((cfg.shared_intermediate_size, h)),
+            })
+            params[f"layer_{li:02d}"] = layer
+        params["head"] = {"norm": ones()}  # the matrix is the embedding's
+        return params, {}
+
+    # -- the frame's hooks ---------------------------------------------------
+    def _embed(self, table, input_ids):
+        return (table[input_ids] * self.cfg.embedding_multiplier).astype(
+            self.compute_dtype
+        )
+
+    def _head_weight(self, params):
+        return params["embed"]["tokens"].astype(self.compute_dtype).T
+
+    def _logits(self, xc, lm_w):
+        return mxu_dot(xc, lm_w) / self.cfg.logits_scaling
+
+    def layer_apply(self, li: int, lp, x, docs=None):
+        cfg, cdt = self.cfg, self.compute_dtype
+        r = cfg.residual_multiplier
+        mixed, counters = self._mix(
+            li, lp, rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps), docs
+        )
+        with scope("residual"):
+            x = (x.astype(jnp.float32) + r * mixed).astype(cdt)
+        fed, _ = self._ffn(li, lp, rms_norm(x, lp["ffn_norm"], cfg.rms_norm_eps))
+        with scope("residual"):
+            x = (x.astype(jnp.float32) + r * fed).astype(cdt)
+        return x, counters
+
+    def _ffn(self, li: int, lp, u):
+        """The dense SwiGLU, its gate and up in one matrix (gate first)."""
+        cdt, width = u.dtype, self.cfg.shared_intermediate_size
+        with scope("mlp.dense"):
+            both = mxu_dot(u, lp["mlp_in"].astype(cdt))
+            act = jax.nn.silu(both[..., :width]) * both[..., width:]
+            return mxu_dot(act.astype(cdt), lp["mlp_out"].astype(cdt)), {}
+
+    def _mix(self, li: int, lp, u, docs=None):
+        if self.cfg.layer_types[li] == MAMBA:
+            with scope("attn.ssm"):
+                return self._ssm(lp, u, docs)
+        with scope("attn.full"):
+            return self._attention(li, lp, u, docs), {}
+
+    def _attention(self, li: int, lp, u, docs=None):
+        cfg, cdt = self.cfg, self.compute_dtype
+        b, s, _ = u.shape
+        heads, kv, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+
+        def project(w, n):  # (B, n, S, d)
+            with scope("attn.proj"):
+                t = mxu_dot(u, w.astype(cdt))
+            return t.reshape(b, s, n, d).astype(cdt).transpose(0, 2, 1, 3)
+
+        out = attention(
+            project(lp["q_w"], heads), project(lp["k_w"], kv),
+            project(lp["v_w"], kv), causal=True,
+            segment_ids=None if docs is None else docs[0],
+            scale=cfg.attention_multiplier, force=self.attention_impl,
+        )
+        out = out.transpose(0, 2, 1, 3).reshape(b, s, heads * d)
+        with scope("attn.proj"):
+            return mxu_dot(out, lp["o_w"].astype(cdt))
+
+    def _gated_norm(self, lp, y, z):
+        """``RMSNorm(y * SiLU(z)) w`` over all the heads' channels."""
+        return rms_norm(y * jax.nn.silu(z), lp["ssm_norm"], self.cfg.rms_norm_eps)
+
+    def _ssm(self, lp, u, docs=None):
+        """The Mamba-2 mixer (class header), ``ssm_segment`` tokens at a
+        time: (float32 output, the layer's counters)."""
+        cfg, cdt = self.cfg, self.compute_dtype
+        b, s, hidden = u.shape
+        heads, p, n = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
+        width, taps = cfg.ssm_width, cfg.mamba_d_conv
+        seg = min(cfg.ssm_segment, s)
+        if s % seg:
+            raise ValueError(
+                f"{s} tokens a sequence are not whole Mamba segments of "
+                f"{cfg.ssm_segment} (MambaHybridConfig.ssm_segment)"
+            )
+        ids = None if docs is None else docs[0]
+        a = -jnp.exp(lp["A_log"])
+
+        @jax.checkpoint
+        def segment(carry, inputs):
+            state, history, history_ids, state_segment = carry
+            u_s, ids_s = inputs
+            with scope("attn.proj"):
+                zxbcdt = mxu_dot(u_s, lp["in_proj"].astype(cdt))  # float32
+            z, xbc, dt = jnp.split(zxbcdt, [width, 2 * width + 2 * n], axis=-1)
+            with scope("ssm.conv"):
+                xbc_act = jax.nn.silu(causal_conv(
+                    xbc, lp["conv_w"], history, bias=lp["conv_b"],
+                    segment_ids=ids_s, history_ids=history_ids,
+                ))
+            x, bm, cm = jnp.split(xbc_act, [width, width + n], axis=-1)
+            delta = jax.nn.softplus(dt + lp["dt_bias"])
+            y, state = ssd_scan(
+                x.reshape(b, seg, heads, p).astype(cdt), delta, a, bm.astype(cdt),
+                cm.astype(cdt), lp["D"], chunk=cfg.mamba_chunk_size,
+                segment_ids=ids_s, state_segment=state_segment,
+                initial_state=state, return_state=True,
+            )
+            y = self._gated_norm(lp, y.reshape(b, seg, width), z)
+            with scope("attn.proj"):
+                out = mxu_dot(y.astype(cdt), lp["out_proj"].astype(cdt))
+            latest = jnp.concatenate([history, xbc], axis=1)[:, -(taps - 1):]
+            carry = (state, latest, None, None)
+            if ids_s is not None:
+                carry = (
+                    state, latest,
+                    jnp.concatenate([history_ids, ids_s], axis=1)[:, -(taps - 1):],
+                    ids_s[:, -1],
+                )
+            return carry, (out, jnp.min(delta * a))
+
+        by_segment = lambda t: jnp.moveaxis(t.reshape(b, s // seg, seg, *t.shape[2:]), 1, 0)
+        start = (
+            jnp.zeros((b, heads, p, n), jnp.float32),
+            jnp.zeros((b, taps - 1, width + 2 * n), jnp.float32),
+            None if ids is None else jnp.full((b, taps - 1), -1, ids.dtype),
+            None if ids is None else ids[:, 0],
+        )
+        _, (y, least) = jax.lax.scan(
+            segment, start, (by_segment(u), None if ids is None else by_segment(ids))
+        )
+        resets = 0.0 if ids is None else jnp.sum(document_starts(ids)[:, 1:])
+        counters = {
+            "ssd_chunks": jnp.asarray(
+                s // seg * ssd_chunks(seg, cfg.mamba_chunk_size), jnp.float32
+            ),
+            "ssd_chunks_in_kernel": jnp.zeros((), jnp.float32),
+            "ssd_state_resets": jnp.asarray(resets, jnp.float32),
+            "ssd_decay_min": jnp.exp(jnp.min(least)),
+        }
+        return jnp.moveaxis(y, 0, 1).reshape(b, s, hidden), counters

@@ -154,11 +154,19 @@ def _per_test_alarm(request):
 # ``test_mellum.test_the_cell_before_this_one_keeps_all_but_its_place_at_the_end``
 # calls it, holds that this assertion is the first and only one to fail, and
 # asserts what follows it.  A ``benchmark`` PR drops the ``[-5:]`` / ``[-1]``
-# assertion, this entry and that test.
+# assertion, this entry and that test.  Likewise ``test_scope_metrics``'
+# manifest test, which asserts that the ten scope metrics are the LAST of
+# ``per_layer``: the state-space cell's three were appended after them.
+# ``test_granite.test_the_scope_metrics_keep_all_but_their_place_at_the_end``
+# holds it as the other holds ``test_ling``'s.
 _OVERTAKEN = {
     "tests/benchmark/test_ling.py::test_manifest_entries_are_the_issues":
         "asserts its cell's entries are the last of BENCHMARK.json's lists; "
         "PR 35 appended a cell (PERF.md section 7 row 6)",
+    "tests/benchmark/test_scope_metrics.py::test_manifest_entries_are_the_issues_appended_last":
+        "asserts the scope metrics are the last of BENCHMARK.json's per_layer; "
+        "the state-space cell's three were appended after them (PERF.md "
+        "section 7 row 6)",
 }
 
 

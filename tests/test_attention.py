@@ -720,6 +720,30 @@ def test_segment_ids_through_the_flash_kernels_match_reference_fwd_and_grads(cas
         q, k, v, causal=True, window=_PACKED[case][2])).max()) > 1e-2
 
 
+@pytest.mark.parametrize("blocks", [128, (64, 256)], ids=["blocks_128", "blocks_64_256"])
+def test_segment_ids_at_head_size_64_with_a_scale_of_its_own(blocks):
+    """A Mamba hybrid's attention layer: heads of 64, four query heads a KV
+    head, scores scaled by 0.015625 (not 64^-1/2), packed documents, so the
+    banded kernels at a head size under a lane tile: output, dq, dk and dv
+    in interpret mode against ``mha_reference`` with the same scale."""
+    bq, bk = blocks if isinstance(blocks, tuple) else (blocks, blocks)
+    seg = _segments((100, 156, 256), (1, 300, 211))
+    q, k, v, ct = _grouped_qkv(38, 2, 8, 2, 512, 512, d=64)
+    kw = dict(causal=True, segment_ids=seg, scale=0.015625)
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, block_q=bq, block_k=bk, interpret=True, **kw)
+    plain = lambda q, k, v: mha_reference(q, k, v, **kw)
+    np.testing.assert_allclose(flash(q, k, v), plain(q, k, v), atol=2e-5, rtol=2e-5)
+    total = lambda f: (lambda *a: jnp.sum(f(*a) * ct))
+    for g, w, name in zip(jax.grad(total(flash), (0, 1, 2))(q, k, v),
+                          jax.grad(total(plain), (0, 1, 2))(q, k, v),
+                          ("dq", "dk", "dv")):
+        np.testing.assert_allclose(g, w, atol=5e-5, rtol=5e-5, err_msg=name)
+    # the scale is the one passed: 64^-1/2 reads another output
+    other = mha_reference(q, k, v, causal=True, segment_ids=seg)
+    assert float(jnp.abs(other - plain(q, k, v)).max()) > 1e-2
+
+
 @pytest.mark.parametrize("window", [None, 96])
 @pytest.mark.parametrize("hkv", [4, 2])
 def test_one_document_a_sequence_is_the_call_without_ids(window, hkv):

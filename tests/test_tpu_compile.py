@@ -54,6 +54,8 @@ _ATTENTION = {
     # prefetches and the keys' document ends beside q, k, v
     "mellum_full_layer_documents": (32, 4, 8192, 128, None, 4),
     "mellum_sliding_layer_documents": (32, 4, 8192, 128, 1024, 4),
+    # as granite_train_packed16k calls them: the banded kernels at head size 64
+    "granite_attention_layer_documents": (32, 8, 16384, 64, None, 1),
 }
 
 
