@@ -61,6 +61,7 @@ from ..ops.kda import KDA_MIN_LOG_DECAY, kda_chunks, kda_scan, uses_kernels
 from ..ops.matmul import mxu_dot
 from ..ops.rope import rope_tables, rope_to_heads, uses_rope_kernel
 from ..ops.ssd import document_starts, ssd_chunks, ssd_scan
+from ..ops.ssd import uses_kernels as ssd_uses_kernels
 from ..utils.profiling import scope
 from ..parallel.moe import (
     held_experts_ffn, init_held_experts_params, route_grouped, route_sigmoid,
@@ -80,9 +81,10 @@ COUNTERS = (
 ROPE_COUNTERS = ("rope_rows_in_kernel",)
 KDA_COUNTERS = ("kda_chunks", "kda_chunks_in_kernel", "kda_decay_min")
 # the Mamba-2 scan's: the chunks it walks one after another for a sequence;
-# of them those walked inside a Pallas kernel (none: every call is
-# jax.numpy); the document starts inside a sequence at which the carried
-# state was dropped; the smallest decay exp(delta A) of the step
+# of them those walked inside its Pallas kernels (all of them where
+# ops.ssd.uses_kernels says so, none on the jax.numpy path); the document
+# starts inside a sequence at which the carried state was dropped; the
+# smallest decay exp(delta A) of the step
 SSD_COUNTERS = (
     "ssd_chunks", "ssd_chunks_in_kernel", "ssd_state_resets", "ssd_decay_min",
 )
@@ -1352,7 +1354,7 @@ class MambaHybridLM(DecoderLM):
                 x.reshape(b, seg, heads, p).astype(cdt), delta, a, bm.astype(cdt),
                 cm.astype(cdt), lp["D"], chunk=cfg.mamba_chunk_size,
                 segment_ids=ids_s, state_segment=state_segment,
-                initial_state=state, return_state=True,
+                initial_state=state, return_state=True, force=self.attention_impl,
             )
             y = self._gated_norm(lp, y.reshape(b, seg, width), z)
             with scope("attn.proj"):
@@ -1378,11 +1380,13 @@ class MambaHybridLM(DecoderLM):
             segment, start, (by_segment(u), None if ids is None else by_segment(ids))
         )
         resets = 0.0 if ids is None else jnp.sum(document_starts(ids)[:, 1:])
+        chunks = s // seg * ssd_chunks(seg, cfg.mamba_chunk_size)
+        in_kernel = ssd_uses_kernels(
+            (b, seg, heads, p), n, cfg.mamba_chunk_size, self.attention_impl
+        )
         counters = {
-            "ssd_chunks": jnp.asarray(
-                s // seg * ssd_chunks(seg, cfg.mamba_chunk_size), jnp.float32
-            ),
-            "ssd_chunks_in_kernel": jnp.zeros((), jnp.float32),
+            "ssd_chunks": jnp.asarray(chunks, jnp.float32),
+            "ssd_chunks_in_kernel": jnp.asarray(chunks if in_kernel else 0, jnp.float32),
             "ssd_state_resets": jnp.asarray(resets, jnp.float32),
             "ssd_decay_min": jnp.exp(jnp.min(least)),
         }

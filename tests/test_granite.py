@@ -367,6 +367,41 @@ def test_counters_reach_the_blobs_and_the_registry(tiny):
     assert float(metrics["ssd_state_resets"]) == 2 * (float(metrics["doc_count"]) - 2)
 
 
+def test_forced_kernels_walk_every_chunk_and_agree(monkeypatch):
+    """Two Mamba layers whose scan the kernels take (4 heads of 64, a state
+    of 128, one chunk of 128 a segment, two segments a sequence), the
+    kernels in interpret mode: under "flash" ``ssd_chunks_in_kernel`` is
+    ``ssd_chunks``, under "reference" 0, and the loss and every leaf's
+    gradient agree (the scan's own cases: ``tests/test_ssd_kernel.py``)."""
+    from functools import partial
+
+    monkeypatch.setattr(decoder, "ssd_scan", partial(ssd_scan, interpret=True))
+    cfg = MambaHybridConfig.tiny(
+        layer_types=(MAMBA, MAMBA), mamba_d_head=64, mamba_d_state=128,
+        mamba_chunk_size=128, ssm_segment=128)
+    shapes = {k: (2, 256) for k in _SHAPES}
+    batch = packed_batch(cfg, s=256, median=60)
+    forced = MambaHybridLM(cfg, shapes, attention_impl="flash")
+    params = shaken(forced.init(jax.random.PRNGKey(3))[0], 3.0)
+
+    def run(model):
+        loss = lambda p: (lambda out: (out["loss"], out))(model.apply(p, {}, batch, train=True)[0])
+        return jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+
+    with jax.default_matmul_precision("highest"):
+        (loss_k, out_k), grads_k = run(forced)
+        (loss_p, out_p), grads_p = run(MambaHybridLM(cfg, shapes, attention_impl="reference"))
+    assert float(out_k["ssd_chunks"]) == float(out_k["ssd_chunks_in_kernel"]) == 2.0
+    assert float(out_p["ssd_chunks"]) == 2.0 and float(out_p["ssd_chunks_in_kernel"]) == 0.0
+    assert float(out_k["ssd_state_resets"]) == float(out_p["ssd_state_resets"]) > 0
+    np.testing.assert_allclose(loss_k, loss_p, rtol=2e-6)
+    for layer in grads_p:
+        for name, w in grads_p[layer].items():
+            np.testing.assert_allclose(
+                grads_k[layer][name], w, atol=2e-4 * max(float(jnp.abs(w).max()), 1e-12),
+                err_msg=f"{layer}.{name}")
+
+
 def test_the_compiled_step_carries_the_new_scopes_in_their_nesting(tiny):
     """The scope chains of the compiled step's instructions: the Mamba
     mixer's parts under ``attn.ssm`` in forward, backward and recompute,

@@ -234,3 +234,73 @@ def test_the_rope_kernels_sit_under_attn_rope_in_all_three_passes(one_chip, no_c
     # every kernel under a mixer is one or the other: mixer_ms - mixer_glue_ms
     kernels = {name for name, e in table.items() if e.kernel and e.chain[:1] and e.chain[0].startswith("attn")}
     assert kernels == set(rotary) | set(flash)
+
+
+@pytest.mark.parametrize("force", ["flash", "reference"], ids=["kernels", "jax_numpy"])
+def test_ssd_scan_compiles_for_v5e_at_real_widths(force, one_chip, no_compile_cache):
+    """One segment of a Mamba-2 layer of ``granite4_h_micro`` (2048 tokens,
+    64 heads of 64, a state of 128, chunks of 256, a packed segment's ids),
+    forward and backward, in both forms: the Pallas kernels
+    ``ssd_scan_fwd`` and ``ssd_scan_bwd``, and ``jax.numpy``, a loop with
+    no kernel of ours; the scope reaches the compiled program."""
+    from sparknet_tpu.ops.ssd import ssd_scan
+
+    shape = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one_chip)
+
+    def grads(x, delta, a, b, c, d, ids, state):
+        def total(x, delta, a, b, c, d, state):
+            y, end = ssd_scan(
+                x, delta, a, b, c, d, chunk=256, segment_ids=ids, state_segment=ids[:, 0],
+                initial_state=state, return_state=True, force=force,
+            )
+            return y.sum() + end.sum()
+
+        return jax.grad(total, range(7))(x, delta, a, b, c, d, state)
+
+    text = jax.jit(grads).lower(
+        shape((1, 2048, 64, 64), jnp.bfloat16), shape((1, 2048, 64), jnp.float32),
+        shape((64,), jnp.float32), shape((1, 2048, 128), jnp.bfloat16),
+        shape((1, 2048, 128), jnp.bfloat16), shape((64,), jnp.float32),
+        shape((1, 2048), jnp.int32), shape((1, 64, 64, 128), jnp.float32),
+    ).compile().as_text()
+    assert "ssd.scan" in text
+    calls = [ln.split(" = ")[0] for ln in text.splitlines() if "tpu_custom_call" in ln]
+    if force == "reference":
+        assert " while(" in text and not calls
+        return
+    assert len(calls) == 2
+    assert any("ssd_scan_fwd" in c for c in calls) and any("ssd_scan_bwd" in c for c in calls)
+
+
+def test_the_ssd_kernels_sit_under_ssd_scan_in_all_three_passes(one_chip, no_compile_cache):
+    """A small ``MambaHybridLM`` step on packed documents with the layers
+    checkpointed, compiled for v5e: ``utils.profiling.scope_table`` finds
+    the scan's kernels under ``attn.ssm`` / ``ssd.scan`` — the forward
+    kernel forward and recomputed, the backward kernel backward — and no
+    other kernel, so ``ssd_scan_ms`` reads them whole."""
+    from sparknet_tpu.models.decoder import MAMBA, MambaHybridConfig, MambaHybridLM
+    from sparknet_tpu.utils import profiling
+
+    cfg = MambaHybridConfig.tiny(
+        layer_types=(MAMBA, MAMBA), mamba_d_head=64, mamba_d_state=128,
+        mamba_chunk_size=128, ssm_segment=128, remat=True,
+    )
+    names = ("input_ids", "labels", "segment_ids", "positions")
+    model = MambaHybridLM(cfg, {k: (1, 256) for k in names}, attention_impl="flash")
+    put = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    params = put(jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))[0]))
+    batch = {k: jax.ShapeDtypeStruct((1, 256), jnp.int32, sharding=one_chip) for k in names}
+
+    def step(p, batch):
+        return jax.grad(lambda p_: model.apply(p_, {}, batch, train=True)[0]["loss"])(p)
+
+    text = jax.jit(step).lower(params, batch).compile().as_text()
+    table = profiling.scope_table(text, profiling.declared_scopes())
+    kernels = {name: e for name, e in table.items() if e.kernel}
+    assert kernels and all(n.startswith("ssd_scan_") for n in kernels), sorted(kernels)
+    for e in kernels.values():
+        assert e.chain[0] == "attn.ssm" and e.chain[-1] == "ssd.scan", e
+    assert {e.pass_ for n, e in kernels.items() if n.startswith("ssd_scan_fwd")} == {"forward", "recompute"}
+    assert {e.pass_ for n, e in kernels.items() if n.startswith("ssd_scan_bwd")} == {"backward"}
