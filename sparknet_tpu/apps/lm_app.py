@@ -21,15 +21,17 @@ then the most a batch-head walks).  The ``train feed:`` line says what
 traffic the run had, with the pool's mean pairs a sequence (the registry's
 ``attn_pairs_pool`` gauges: what a mean step's attention costs).
 
-``--config`` is ``tiny``, ``tiny_hybrid``, ``tiny_mamba`` or the path of a
-JSON file holding a published ``config.json``'s keys, as cut to this chip's
-share if it is (``num_experts`` held of ``deployment.num_experts_routed``).
+``--config`` is ``tiny``, ``tiny_hybrid``, ``tiny_mamba``, ``tiny_conv`` or
+the path of a JSON file holding a published ``config.json``'s keys, as cut to
+this chip's share if it is (``num_experts`` held of ``deployment.num_experts_routed``).
 Its ``model_type`` picks the model: ``bailing_hybrid`` is
 :class:`~sparknet_tpu.models.decoder.HybridLM` (KDA and MLA layers by
 ``layer_group_size``, ``HybridConfig.from_published``), ``granitemoehybrid``
 :class:`~sparknet_tpu.models.decoder.MambaHybridLM` (Mamba-2 and attention
-layers by ``layer_types``, ``MambaHybridConfig.from_published``), anything
-else :class:`~sparknet_tpu.models.decoder.DecoderLM`
+layers by ``layer_types``, ``MambaHybridConfig.from_published``),
+``lfm2_moe`` :class:`~sparknet_tpu.models.decoder.ConvHybridLM` (gated short
+convolutions and attention by ``layer_types``,
+``ConvHybridConfig.from_published``), anything else :class:`~sparknet_tpu.models.decoder.DecoderLM`
 (``DecoderConfig.from_published``: ``layer_types``, ``mlp_layer_types``,
 ``num_attention_heads_per_layer``, ``rope_parameters`` ...).  Token ids come
 from the config's ``vocab_size``.  The progress line carries the model's
@@ -40,7 +42,9 @@ tokens, 0 where a scatter-add does), ``moe_load_max_over_mean``,
 ``rope_to_heads`` kernel rotated, 0 where ``apply_rope`` did; the hybrid's
 ``kda_chunks``, ``kda_chunks_in_kernel`` and ``kda_decay_min`` in its place;
 the Mamba hybrid's ``ssd_chunks``, ``ssd_chunks_in_kernel``,
-``ssd_state_resets`` and ``ssd_decay_min`` alone);
+``ssd_state_resets`` and ``ssd_decay_min`` alone; the convolutional
+hybrid's ``short_conv_resets`` and ``moe_bias_rerouted`` beside the sparse
+layers' and ``rope_rows_in_kernel``);
 the telemetry registry has them, as every
 solver's newest step metrics, under its source ``train_step``.
 """
@@ -60,18 +64,21 @@ from ..data.text import (
     clm_dataset, clm_feed, packed_dataset, packed_feed, pool_pairs,
 )
 from ..models.decoder import (
-    ATTENTION, MLA, SLIDING, DecoderConfig, DecoderLM, HybridConfig, HybridLM,
-    MambaHybridConfig, MambaHybridLM,
+    CONV, KDA, MAMBA, SLIDING, ConvHybridConfig, ConvHybridLM, DecoderConfig,
+    DecoderLM, HybridConfig, HybridLM, MambaHybridConfig, MambaHybridLM,
 )
 from ..ops.attention import flash_tile_kinds, uses_flash
 from ..solver.trainer import Solver
 from .bert_app import flash_tiles_note, make_solver_param
 
 # a published file's model_type -> its configuration; DecoderConfig otherwise
-_CONFIGS = {"bailing_hybrid": HybridConfig, "granitemoehybrid": MambaHybridConfig}
+_CONFIGS = {
+    "bailing_hybrid": HybridConfig, "granitemoehybrid": MambaHybridConfig,
+    "lfm2_moe": ConvHybridConfig,
+}
 _TINY = {
     "tiny": DecoderConfig, "tiny_hybrid": HybridConfig,
-    "tiny_mamba": MambaHybridConfig,
+    "tiny_mamba": MambaHybridConfig, "tiny_conv": ConvHybridConfig,
 }
 
 
@@ -93,6 +100,8 @@ def model_class(cfg):
         return HybridLM
     if isinstance(cfg, MambaHybridConfig):
         return MambaHybridLM
+    if isinstance(cfg, ConvHybridConfig):
+        return ConvHybridLM
     return DecoderLM
 
 
@@ -160,14 +169,11 @@ def packing_note(args, cfg, ds) -> str:
 
 def flash_tiles(cfg, seq_len: int) -> Dict[str, int]:
     """Score tiles a batch-head of each kind of layer executes in each flash
-    kernel, by whether a mask runs over them (``flash_tile_kinds``); a
-    hybrid's KDA layers and the Mamba hybrid's Mamba layers run no flash
-    kernel."""
+    kernel, by whether a mask runs over them (``flash_tile_kinds``); the
+    hybrids' KDA, Mamba and conv layers run no flash kernel."""
     tiles = {}
     for kind in dict.fromkeys(cfg.layer_types):
-        if isinstance(cfg, HybridConfig) and kind != MLA:
-            continue
-        if isinstance(cfg, MambaHybridConfig) and kind != ATTENTION:
+        if kind in (KDA, MAMBA, CONV):
             continue
         tiles[f"{kind}_unmasked"], tiles[f"{kind}_masked"] = flash_tile_kinds(
             seq_len, seq_len, causal=True,
@@ -179,7 +185,7 @@ def flash_tiles(cfg, seq_len: int) -> Dict[str, int]:
 def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description="Causal-LM pre-training (LmApp)")
     ap.add_argument("--config", default="tiny",
-                    help="'tiny', 'tiny_hybrid', 'tiny_mamba' or a JSON "
+                    help="'tiny', 'tiny_hybrid', 'tiny_mamba', 'tiny_conv' or a JSON "
                          "file of published config keys")
     ap.add_argument("--seq-len", type=int, default=64)
     ap.add_argument("--batch-size", type=int, default=4)

@@ -2,10 +2,12 @@
 :class:`DecoderLM` mixes window and full softmax attention over sparse
 experts; :class:`HybridLM` (further down, with its own header) mixes gated
 delta-rule linear attention (KDA) and latent attention (MLA);
-:class:`MambaHybridLM` (last, with its own header) mixes Mamba-2 state-space
+:class:`MambaHybridLM` (with its own header) mixes Mamba-2 state-space
 mixers and attention without positions over dense layers, on packed
-documents.  The two share DecoderLM's frame: the residual layout, SwiGLU,
-the chunked loss, the counters and the Solver protocol.
+documents; :class:`ConvHybridLM` (last, with its own header) mixes gated
+short convolutions and attention with q/k norms over experts routed by
+sigmoid with a selection bias.  They share DecoderLM's frame: the residual
+layout, SwiGLU, the chunked loss, the counters and the Solver protocol.
 
 DecoderLM is built from a published ``config.json``'s own keys
 (:meth:`DecoderConfig.from_published`): ``layer_types`` (``full_attention``
@@ -71,6 +73,7 @@ from ..parallel.moe import (
 FULL, SLIDING = "full_attention", "sliding_attention"
 KDA, MLA = "kda", "mla"
 MAMBA, ATTENTION = "mamba", "attention"
+CONV = "conv"
 COUNTERS = (
     "moe_slots_held", "moe_slots_in_kernel", "moe_load_max_over_mean",
     "moe_slots_dropped",
@@ -88,6 +91,11 @@ KDA_COUNTERS = ("kda_chunks", "kda_chunks_in_kernel", "kda_decay_min")
 SSD_COUNTERS = (
     "ssd_chunks", "ssd_chunks_in_kernel", "ssd_state_resets", "ssd_decay_min",
 )
+# the gated short convolution's: the document starts inside a sequence at
+# which its taps stopped reading back, summed over the conv layers; and the
+# share of slots whose expert the router's selection bias changed against an
+# unbiased top-k, mean over the sparse layers
+CONV_COUNTERS = ("short_conv_resets", "moe_bias_rerouted")
 # of a batch of packed documents, newest step: the documents in it; the
 # positions that bear a loss; the keys every token sees, summed over the
 # batch, in one layer of a kind (times heads and head size: a product's
@@ -108,6 +116,7 @@ _REDUCE = {
     "kda_chunks_in_kernel": jnp.max, "kda_decay_min": jnp.min,
     "ssd_chunks": jnp.max, "ssd_chunks_in_kernel": jnp.max,
     "ssd_state_resets": jnp.sum, "ssd_decay_min": jnp.min,
+    "short_conv_resets": jnp.sum, "moe_bias_rerouted": jnp.mean,
     **dict.fromkeys(DOC_COUNTERS, jnp.max),  # of the batch: reported once
 }
 
@@ -431,9 +440,11 @@ class DecoderLM:
             with scope("attn.rope"):
                 tables = rope_tables(positions, inv_freq, factor, d)
 
-        def project(w, n, rotate):
+        def project(name, n, rotate):
             with scope("attn.proj"):
-                t = mxu_dot(u, w.astype(cdt))
+                t = mxu_dot(u, lp[name + "_w"].astype(cdt))
+            if rotate:
+                t = self._qk_norm(lp, name, t)
             if rotate and kernel:
                 with scope("attn.rope"):
                     return rope_to_heads(t, *tables, rot, cdt)
@@ -444,9 +455,9 @@ class DecoderLM:
             return t.astype(cdt).transpose(0, 2, 1, 3)  # (B, n, S, D)
 
         out = attention(
-            project(lp["q_w"], heads, True),
-            project(lp["k_w"], kv, True),
-            project(lp["v_w"], kv, False),
+            project("q", heads, True),
+            project("k", kv, True),
+            project("v", kv, False),
             causal=True,
             window=cfg.sliding_window if kind == SLIDING else None,
             segment_ids=segment_ids, force=self.attention_impl,
@@ -456,6 +467,11 @@ class DecoderLM:
             out = mxu_dot(out, lp["o_w"].astype(cdt))
         rows = b * s * (heads + kv) if kernel else 0
         return out, {"rope_rows_in_kernel": jnp.asarray(rows, jnp.float32)}
+
+    def _qk_norm(self, lp, name, t):
+        """Hook on q's or k's float32 projection ``t`` (B, S, heads * d)
+        before rotary (``name`` "q" or "k"): none here."""
+        return t
 
     def _router(self, xt, lp):
         """(weights, experts) a token, by the configuration's scoring."""
@@ -1391,3 +1407,287 @@ class MambaHybridLM(DecoderLM):
             "ssd_decay_min": jnp.exp(jnp.min(least)),
         }
         return jnp.moveaxis(y, 0, 1).reshape(b, s, hidden), counters
+
+
+# ---------------------------------------------------------------------------
+# The convolutional hybrid: gated short convolutions and grouped-query
+# attention with q/k norms, over experts routed by sigmoid with a selection
+# bias, on packed documents
+# ---------------------------------------------------------------------------
+
+# added to the chosen scores' sum before the weights are normalised by it, as
+# transformers' Lfm2MoeSparseMoeBlock does
+ROUTER_EPS = 1e-6
+
+
+@dataclasses.dataclass(frozen=True)
+class ConvHybridConfig:
+    """A ``lfm2_moe`` ``config.json``, as :class:`ConvHybridLM` needs it.
+    The names DecoderConfig has mean the same here (``rms_norm_eps`` is the
+    published ``norm_eps``, ``moe_routed_scaling_factor`` its
+    ``routed_scaling_factor``)."""
+    vocab_size: int
+    hidden_size: int
+    intermediate_size: int  # the leading dense layers' SwiGLU
+    num_attention_heads: int
+    num_key_value_heads: int
+    layer_types: Tuple[str, ...]  # CONV or FULL
+    mlp_layer_types: Tuple[str, ...]
+    conv_L_cache: int = 3  # taps of the short convolution
+    rope_theta: float = 1e6
+    num_experts: int = 0
+    experts_held: Tuple[int, int] = (0, 0)
+    num_experts_per_tok: int = 1
+    moe_intermediate_size: int = 0
+    moe_routed_scaling_factor: float = 1.0
+    # the selection bias is drawn as this times a standard normal (0: zeros,
+    # as a fresh published model starts)
+    expert_bias_std: float = 0.0
+    rms_norm_eps: float = 1e-5
+    initializer_range: float = 0.02
+    remat: bool = False
+    loss_chunk: int = 4096
+
+    shared_expert_intermediate_size = 0  # no shared expert
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def num_attention_heads_per_layer(self) -> Tuple[int, ...]:
+        return (self.num_attention_heads,) * self.num_layers
+
+    @property
+    def rope_parameters(self) -> Mapping[str, Mapping[str, Any]]:
+        return {FULL: {"rope_type": "default", "rope_theta": self.rope_theta}}
+
+    @classmethod
+    def from_published(cls, published: Mapping[str, Any], **overrides):
+        """From the published keys (or a cut: ``deployment.layers_kept``
+        lists the published indices of the ``num_hidden_layers`` layers
+        held, default the first so many; ``num_experts`` counts the experts
+        held of ``deployment.num_experts_routed``).  Published layer *i* has
+        the dense FFN if ``i < num_dense_layers``.  What this model does not
+        compute is refused, not ignored: biases on the convolution's
+        projections, unnormalised routing weights, no selection bias, an
+        untied head."""
+        refused = {
+            "conv_bias": published.get("conv_bias", False),
+            "norm_topk_prob": not published.get("norm_topk_prob", True),
+            "use_expert_bias": not published.get("use_expert_bias", True),
+            "tie_word_embeddings": not published.get("tie_word_embeddings", True),
+        }
+        if any(refused.values()):
+            raise ValueError(
+                "not modelled: " + ", ".join(k for k, v in refused.items() if v)
+            )
+        n = published["num_hidden_layers"]
+        deployment = published.get("deployment", {})
+        kept = list(deployment.get("layers_kept", range(n)))
+        if len(kept) != n:
+            raise ValueError(f"layers_kept {kept} against {n} layers")
+        held = published["num_experts"]
+        fields = dict(
+            vocab_size=published["vocab_size"],
+            hidden_size=published["hidden_size"],
+            intermediate_size=published["intermediate_size"],
+            num_attention_heads=published["num_attention_heads"],
+            num_key_value_heads=published["num_key_value_heads"],
+            layer_types=tuple(published["layer_types"][i] for i in kept),
+            mlp_layer_types=tuple(
+                "dense" if i < published["num_dense_layers"] else "sparse"
+                for i in kept
+            ),
+            conv_L_cache=published["conv_L_cache"],
+            rope_theta=published["rope_theta"],
+            num_experts=deployment.get("num_experts_routed", held),
+            experts_held=(deployment.get("experts_first", 0), held),
+            num_experts_per_tok=published["num_experts_per_tok"],
+            moe_intermediate_size=published["moe_intermediate_size"],
+            moe_routed_scaling_factor=published["routed_scaling_factor"],
+            expert_bias_std=published.get("expert_bias_std", 0.0),
+            rms_norm_eps=published["norm_eps"],
+        )
+        fields.update(overrides)
+        return cls(**fields)
+
+    @classmethod
+    def tiny(cls, **overrides) -> "ConvHybridConfig":
+        """Both kinds of layer and both FFNs at a size for CPU tests: two
+        conv layers round an attention layer, the first dense, 4 query
+        heads over 2 KV heads of 8, 8 experts with 2 held (experts 2 and 3),
+        2 a token times 2.5, a selection bias that moves some of them."""
+        fields = dict(
+            vocab_size=96, hidden_size=32, intermediate_size=64,
+            num_attention_heads=4, num_key_value_heads=2,
+            layer_types=(CONV, FULL, CONV), mlp_layer_types=("dense", "sparse", "sparse"),
+            rope_theta=10000.0, num_experts=8, experts_held=(2, 2),
+            num_experts_per_tok=2, moe_intermediate_size=16,
+            moe_routed_scaling_factor=2.5, expert_bias_std=0.05, loss_chunk=32,
+        )
+        fields.update(overrides)
+        return cls(**fields)
+
+
+class ConvHybridLM(DecoderLM):
+    """A decoder whose layers are gated short convolutions or grouped-query
+    attention with q/k norms (Liquid AI's LFM2), the leading ones with a
+    dense SwiGLU and the rest sparse, with a tied embedding and head;
+    DecoderLM's frame otherwise (pre-norm residuals, a final RMSNorm).
+
+    - **Gated short convolution** (scope ``attn.conv``): ``[B | C | x] = u
+      W_in``; ``v = B * x``; ``z_t = sum_j w_j v_(t-L+1+j)``, a depthwise
+      causal convolution of ``conv_L_cache`` taps whose taps read 0 before
+      the token's document (scope ``conv.short``, with the two gates);
+      ``y = C * z``; ``y W_out``.  No activation, no bias.
+    - **Attention** (``attn.full``): DecoderLM's, with q and k each normed
+      per head (an RMSNorm over the head's ``head_dim`` channels with its
+      own scale, ``q_norm`` / ``k_norm``) before rotary (``default``, the
+      whole head, ``rope_theta``, by position in the document).
+    - **Sparse FFN**: :func:`sparknet_tpu.parallel.moe.route_sigmoid` with
+      the layer's ``router_bias`` (a buffer no step moves) steering the
+      selection of the ``num_experts_per_tok`` experts only, and the weights
+      ``s / (sum(s) + ROUTER_EPS)`` times the scaling factor; this chip's
+      ``experts_held``; no shared expert.
+
+    Counters beside DecoderLM's and the packed batch's: ``CONV_COUNTERS``."""
+
+    counters = COUNTERS + ROPE_COUNTERS + CONV_COUNTERS
+    doc_counters = tuple(c for c in DOC_COUNTERS if "window" not in c)
+    _buffers = ("router_bias",)
+
+    def _check_layers(self) -> None:
+        cfg = self.cfg
+        for kind in set(cfg.layer_types):
+            if kind not in (CONV, FULL):
+                raise ValueError(f"layer type {kind!r}")
+        if cfg.num_attention_heads % cfg.num_key_value_heads:
+            raise ValueError(
+                f"{cfg.num_attention_heads} heads over {cfg.num_key_value_heads} KV heads"
+            )
+
+    # -- init ----------------------------------------------------------------
+    def init(self, rng: jax.Array):
+        """Matrices truncated normal ``initializer_range``, the taps as a
+        depthwise ``Conv1d``'s default (uniform in +-1/sqrt(taps)), norm
+        scales 1, the selection bias ``expert_bias_std`` x a normal."""
+        cfg = self.cfg
+        h, d = cfg.hidden_size, cfg.head_dim
+        heads, kv = cfg.num_attention_heads, cfg.num_key_value_heads
+        keys = iter(jax.random.split(rng, 2 + 12 * cfg.num_layers))
+
+        def trunc(shape):
+            return cfg.initializer_range * jax.random.truncated_normal(
+                next(keys), -2.0, 2.0, shape, jnp.float32
+            )
+
+        ones = lambda n=h: jnp.ones((n,), jnp.float32)
+        bound = cfg.conv_L_cache ** -0.5
+        params: Dict[str, Dict[str, jax.Array]] = {
+            "embed": {"tokens": trunc((cfg.vocab_size, h))}
+        }
+        for li, kind in enumerate(cfg.layer_types):
+            layer = {"attn_norm": ones(), "ffn_norm": ones()}
+            if kind == CONV:
+                layer.update({
+                    "in_proj": trunc((h, 3 * h)),
+                    "conv_w": jax.random.uniform(
+                        next(keys), (cfg.conv_L_cache, h), jnp.float32, -bound, bound
+                    ),
+                    "out_proj": trunc((h, h)),
+                })
+            else:
+                layer.update({
+                    "q_w": trunc((h, heads * d)), "k_w": trunc((h, kv * d)),
+                    "v_w": trunc((h, kv * d)), "o_w": trunc((heads * d, h)),
+                    "q_norm": ones(d), "k_norm": ones(d),
+                })
+            layer.update(self._init_ffn(li, trunc, keys))
+            if cfg.mlp_layer_types[li] == "sparse":
+                layer["router_bias"] = cfg.expert_bias_std * jax.random.normal(
+                    next(keys), (cfg.num_experts,), jnp.float32
+                )
+            params[f"layer_{li:02d}"] = layer
+        params["head"] = {"norm": ones()}  # the matrix is the embedding's
+        return params, {}
+
+    # -- the frame's hooks ---------------------------------------------------
+    def _head_weight(self, params):
+        return params["embed"]["tokens"].astype(self.compute_dtype).T
+
+    def _qk_norm(self, lp, name, t):
+        b, s, width = t.shape
+        d = self.cfg.head_dim
+        heads = t.reshape(b, s, width // d, d)
+        return rms_norm(heads, lp[name + "_norm"], self.cfg.rms_norm_eps).reshape(b, s, width)
+
+    def _mix(self, li: int, lp, u, docs=None):
+        if self.cfg.layer_types[li] == CONV:
+            with scope("attn.conv"):
+                return self._short_conv(lp, u, docs)
+        with scope("attn.full"):
+            return self._attention(li, lp, u, docs)
+
+    def _short_conv(self, lp, u, docs=None):
+        """The gated short convolution (class header): (float32 output, the
+        layer's counters).  The gates and the taps in float32."""
+        cdt = self.compute_dtype
+        ids = None if docs is None else docs[0]
+        with scope("attn.proj"):
+            bcx = mxu_dot(u, lp["in_proj"].astype(cdt))  # float32
+        with scope("conv.short"):
+            y = self._gated_conv(lp, bcx, ids)
+        with scope("attn.proj"):
+            out = mxu_dot(y.astype(cdt), lp["out_proj"].astype(cdt))
+        resets = 0.0 if ids is None else jnp.sum(document_starts(ids)[:, 1:])
+        return out, {"short_conv_resets": jnp.asarray(resets, jnp.float32)}
+
+    def _gated_conv(self, lp, bcx, ids):
+        """``C * conv(B * x)`` of ``bcx = [B | C | x]``, the taps masked by
+        the document ``ids`` (B, S) where given."""
+        b_gate, c_gate, x = jnp.split(bcx, 3, axis=-1)
+        return c_gate * causal_conv(b_gate * x, lp["conv_w"], segment_ids=ids)
+
+    def _router(self, xt, lp):
+        cfg = self.cfg
+        return route_sigmoid(
+            xt, lp["router_w"], cfg.num_experts_per_tok,
+            cfg.moe_routed_scaling_factor, bias=lp["router_bias"], eps=ROUTER_EPS,
+        )
+
+    def _ffn(self, li: int, lp, u):
+        """DecoderLM's, and on a sparse layer the counter
+        ``moe_bias_rerouted``: of the slots, the share whose expert is not
+        among the unbiased top-k of the same token."""
+        cfg = self.cfg
+        if cfg.mlp_layer_types[li] != "sparse":
+            return super()._ffn(li, lp, u)
+        rerouted = []
+
+        def router(xt, lp):
+            weights, experts = self._router(xt, lp)
+            with scope("counters"):
+                logits = jnp.dot(
+                    xt.astype(jnp.float32), lp["router_w"],
+                    preferred_element_type=jnp.float32,
+                )
+                _, plain = jax.lax.top_k(
+                    jax.lax.stop_gradient(logits), cfg.num_experts_per_tok
+                )
+                moved = jnp.all(experts[..., None] != plain[:, None, :], axis=-1)
+                rerouted.append(jnp.mean(moved.astype(jnp.float32)))
+            return weights, experts
+
+        routed, counters = held_experts_ffn(
+            u, lp, experts_held=cfg.experts_held, top_k=cfg.num_experts_per_tok,
+            compute_dtype=self.compute_dtype, router=router,
+            force=self.attention_impl,
+        )
+        counters["moe_bias_rerouted"] = rerouted[0]
+        with scope("moe.experts"):  # the cast is the layer's, not unscoped glue
+            return routed.astype(jnp.float32), counters

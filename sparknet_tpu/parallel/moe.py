@@ -316,18 +316,25 @@ def _top_k(scores: jax.Array, chosen_on: jax.Array, top_k: int):
 
 
 def route_sigmoid(
-    xt: jax.Array, router_w: jax.Array, top_k: int, routed_scale: float
+    xt: jax.Array, router_w: jax.Array, top_k: int, routed_scale: float,
+    bias: Optional[jax.Array] = None, eps: float = 0.0,
 ) -> Tuple[jax.Array, jax.Array]:
-    """(weights, experts), both (T, K): sigmoid scores in float32, the
-    ``top_k`` largest, ``routed_scale * s / sum(s)`` over the chosen."""
+    """(weights, experts), both (T, K): sigmoid scores ``s`` in float32, the
+    ``top_k`` largest (of ``s + bias`` where a ``bias`` is given),
+    ``routed_scale * s / (sum(s) + eps)`` over the chosen.  The bias, as in
+    :func:`route_grouped`, steers the selection only: the weights are the
+    scores without it and no gradient reaches it."""
     scores = jax.nn.sigmoid(
         jnp.dot(
             xt.astype(jnp.float32), router_w,
             preferred_element_type=jnp.float32,
         )
     )
-    top, idx = _top_k(scores, scores, top_k)
-    return routed_scale * top / jnp.sum(top, axis=-1, keepdims=True), idx
+    chosen_on = scores if bias is None else lax.stop_gradient(scores + bias)
+    top, idx = _top_k(scores, chosen_on, top_k)
+    scaled = routed_scale * top
+    total = jnp.sum(top, axis=-1, keepdims=True)
+    return scaled / (total + eps if eps else total), idx
 
 
 def route_softmax(

@@ -158,7 +158,10 @@ def _per_test_alarm(request):
 # manifest test, which asserts that the ten scope metrics are the LAST of
 # ``per_layer``: the state-space cell's three were appended after them.
 # ``test_granite.test_the_scope_metrics_keep_all_but_their_place_at_the_end``
-# holds it as the other holds ``test_ling``'s.
+# holds it as the other holds ``test_ling``'s.  The same for
+# ``test_granite``'s manifest test and that holder itself, which assert that
+# the state-space cell's entries end the lists: the convolutional hybrid's
+# were appended after them, and ``test_lfm2`` holds both the same way.
 _OVERTAKEN = {
     "tests/benchmark/test_ling.py::test_manifest_entries_are_the_issues":
         "asserts its cell's entries are the last of BENCHMARK.json's lists; "
@@ -167,6 +170,14 @@ _OVERTAKEN = {
         "asserts the scope metrics are the last of BENCHMARK.json's per_layer; "
         "the state-space cell's three were appended after them (PERF.md "
         "section 7 row 6)",
+    "tests/benchmark/test_granite.py::test_manifest_entries_are_appended_after_the_others":
+        "asserts its cell's entries are the last of BENCHMARK.json's lists; "
+        "the convolutional hybrid's cell was appended after them (PERF.md "
+        "section 7 row 6)",
+    "tests/benchmark/test_granite.py::test_the_scope_metrics_keep_all_but_their_place_at_the_end":
+        "asserts the state-space cell's three metrics end BENCHMARK.json's "
+        "per_layer; the convolutional hybrid's three were appended after "
+        "them (PERF.md section 7 row 6)",
 }
 
 

@@ -304,3 +304,42 @@ def test_the_ssd_kernels_sit_under_ssd_scan_in_all_three_passes(one_chip, no_com
         assert e.chain[0] == "attn.ssm" and e.chain[-1] == "ssd.scan", e
     assert {e.pass_ for n, e in kernels.items() if n.startswith("ssd_scan_fwd")} == {"forward", "recompute"}
     assert {e.pass_ for n, e in kernels.items() if n.startswith("ssd_scan_bwd")} == {"backward"}
+
+
+def test_the_conv_hybrid_step_runs_the_flash_kernels_at_head_64_under_attn_full(
+    one_chip, no_compile_cache
+):
+    """A small ``ConvHybridLM`` step on packed documents (heads of 64, as
+    ``lfm2_8b_a1b``'s; two conv layers round an attention layer) with the
+    layers checkpointed, compiled for v5e: the flash kernels with segment
+    ids sit under ``attn.full`` — forward and recomputed forward, dq and dkv
+    backward — and no kernel sits under ``attn.conv``: the gated taps are
+    XLA's."""
+    from sparknet_tpu.models.decoder import ConvHybridConfig, ConvHybridLM
+    from sparknet_tpu.utils import profiling
+
+    cfg = ConvHybridConfig.tiny(
+        hidden_size=256, num_attention_heads=4, num_key_value_heads=2,
+        intermediate_size=512, moe_intermediate_size=128, remat=True,
+    )
+    assert cfg.head_dim == 64
+    names = ("input_ids", "labels", "segment_ids", "positions")
+    model = ConvHybridLM(cfg, {k: (2, 256) for k in names}, attention_impl="flash")
+    put = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    params = put(jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))[0]))
+    batch = {k: jax.ShapeDtypeStruct((2, 256), jnp.int32, sharding=one_chip) for k in names}
+
+    def step(p, batch):
+        return jax.grad(lambda p_: model.apply(p_, {}, batch, train=True)[0]["loss"])(p)
+
+    text = jax.jit(step).lower(params, batch).compile().as_text()
+    table = profiling.scope_table(text, profiling.declared_scopes())
+    flash = {name: e for name, e in table.items() if e.kernel and "flash_attention" in name}
+    assert flash and all(e.chain[0] == "attn.full" for e in flash.values()), flash
+    passes = lambda kind: {e.pass_ for n, e in flash.items() if n.startswith(kind)}
+    assert passes("flash_attention_fwd") == {"forward", "recompute"}
+    assert passes("flash_attention_dq") == passes("flash_attention_dkv") == {"backward"}
+    assert not any(e.kernel for e in table.values() if e.chain[:1] == ("attn.conv",))
+    assert any("conv.short" in e.chain for e in table.values())
