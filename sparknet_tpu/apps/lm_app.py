@@ -38,7 +38,9 @@ from the config's ``vocab_size``.  The progress line carries the model's
 counters (the sparse layers' ``moe_slots_held``, ``moe_slots_in_kernel``
 (as many where the ``moe_combine`` kernel sums the experts' rows into their
 tokens, 0 where a scatter-add does), ``moe_load_max_over_mean``,
-``moe_slots_dropped``; ``rope_rows_in_kernel``, the rows of q and k that the
+``moe_slots_dropped``, ``moe_slots_in_gmm`` (as many where the Pallas
+kernels of ``ops/gmm.py`` compute the grouped products, 0 where
+``lax.ragged_dot`` does); ``rope_rows_in_kernel``, the rows of q and k that the
 ``rope_to_heads`` kernel rotated, 0 where ``apply_rope`` did; the hybrid's
 ``kda_chunks``, ``kda_chunks_in_kernel`` and ``kda_decay_min`` in its place;
 the Mamba hybrid's ``ssd_chunks``, ``ssd_chunks_in_kernel``,

@@ -76,7 +76,7 @@ MAMBA, ATTENTION = "mamba", "attention"
 CONV = "conv"
 COUNTERS = (
     "moe_slots_held", "moe_slots_in_kernel", "moe_load_max_over_mean",
-    "moe_slots_dropped",
+    "moe_slots_dropped", "moe_slots_in_gmm",
 )
 # rows of q and k (B * S * (heads + KV heads), summed over the layers) that
 # ops.rope.rope_to_heads rotated in the newest step: all of them where the
@@ -106,11 +106,13 @@ DOC_COUNTERS = (
     "flash_tiles_docs_full", "flash_tiles_docs_window",
 )
 # a counter over the layers that report it: the mean of the slots held (and
-# of those whose rows the moe_combine kernel read: as many, or none), the
+# of those whose rows the moe_combine kernel read, and of those whose
+# grouped products the ops.gmm kernels computed: as many, or none), the
 # worst load ratio, every slot dropped; the chunks a sequence (the same in
 # every layer), the smallest decay anywhere, the resets of every layer
 _REDUCE = {
     "moe_slots_held": jnp.mean, "moe_slots_in_kernel": jnp.mean,
+    "moe_slots_in_gmm": jnp.mean,
     "moe_load_max_over_mean": jnp.max, "moe_slots_dropped": jnp.sum,
     "rope_rows_in_kernel": jnp.sum, "kda_chunks": jnp.max,
     "kda_chunks_in_kernel": jnp.max, "kda_decay_min": jnp.min,

@@ -22,8 +22,11 @@ to a token when an expert is full.
   comparison; the slots are put in the order of their experts, held
   ones first, by one sort that carries slot ids and weights, and their
   inverse permutation by a second (:func:`slot_tables`); the held ones'
-  tokens are gathered a chunk at a time, grouped matrix products
-  (``jax.lax.ragged_dot``) run over the experts held, and in the forward
+  tokens are gathered a chunk at a time, grouped matrix products run over
+  the experts held — on a TPU the Pallas kernels of
+  :mod:`sparknet_tpu.ops.gmm`, which visit a chunk's live rows only,
+  forward and backward (:func:`uses_gmm_kernel`; ``jax.lax.ragged_dot``
+  off a TPU and where the shapes do not fit them) — and in the forward
   pass a chunk's rows are summed into token order by the Pallas kernel
   :func:`moe_combine`, which copies each tile of tokens the runs of rows
   it sent to each expert — off a TPU, or where the shapes do not fit the
@@ -76,6 +79,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..ops.attention import uses_flash
+from ..ops.gmm import gmm, row_tile, tgmm
 from ..ops.matmul import mxu_bmm
 from ..utils.profiling import scope
 
@@ -385,27 +389,79 @@ def route_grouped(
     return routed_scale * top / jnp.sum(top, axis=-1, keepdims=True), idx
 
 
-def _swiglu_rows(xg, gate_up, down, sizes, cdt):
-    """SwiGLU experts on rows grouped by expert: (R, h) -> (R, h) f32.
-    Rows past ``sum(sizes)`` belong to no expert and hold whatever the
-    grouped product left there: nothing may read them unmasked."""
-    ffn = down.shape[1]
-    hid = lax.ragged_dot(
-        xg.astype(cdt), gate_up.astype(cdt), sizes,
-        preferred_element_type=jnp.float32,
-    )
-    act = jax.nn.silu(hid[:, :ffn]) * hid[:, ffn:]
-    return lax.ragged_dot(
-        act.astype(cdt), down.astype(cdt), sizes,
-        preferred_element_type=jnp.float32,
-    )
+def uses_gmm_kernel(
+    hidden: int, ffn: int, rows: int, force: Optional[str] = None
+) -> bool:
+    """Whether a chunk's grouped products run as the Pallas kernels of
+    :mod:`sparknet_tpu.ops.gmm` (``force`` as :func:`uses_combine_kernel`
+    has it), where ``lax.ragged_dot`` runs otherwise: hidden and expert
+    widths of whole 128-lane tiles, chunks of whole row tiles."""
+    fits = hidden % 128 == 0 and ffn % 128 == 0 and row_tile(rows, 1) > 0
+    return fits and uses_flash(force)
 
 
-def _chunk_rows(xg, gate_up, down, wgt, live, sizes, cdt):
+def _activation(hid):
+    ffn = hid.shape[1] // 2
+    return jax.nn.silu(hid[:, :ffn]) * hid[:, ffn:]
+
+
+def _swiglu_rows(xg, gate_up, down, sizes, cdt, gmm_kernel=None):
+    """SwiGLU experts on rows grouped by expert: (R, h) -> (R, h) f32, the
+    two grouped products in ``cdt`` with float32 results: by the Pallas
+    kernels of :mod:`sparknet_tpu.ops.gmm` where ``gmm_kernel`` is given
+    (their ``interpret``; a forward pass only: :func:`_chunk_grads_gmm` is
+    its backward), else by ``lax.ragged_dot``.  Rows past ``sum(sizes)``
+    belong to no expert and hold whatever the grouped product left there:
+    nothing may read them unmasked."""
+    if gmm_kernel is not None:
+        product = functools.partial(gmm, interpret=gmm_kernel)
+    else:
+        product = functools.partial(
+            lax.ragged_dot, preferred_element_type=jnp.float32
+        )
+    hid = product(xg.astype(cdt), gate_up.astype(cdt), sizes)
+    return product(_activation(hid).astype(cdt), down.astype(cdt), sizes)
+
+
+def _chunk_grads_gmm(
+    xg, gate_up, down, wgt, sizes, dy, dgu, ddown, cdt, interpret
+):
+    """The gradients of :func:`_chunk_rows` on the kernels' path — of
+    ``xg`` (in ``cdt``, as ``lax.ragged_dot``'s gradient rounds it), of both
+    expert stacks (float32, summed by ``tgmm`` into the sums ``dgu`` and
+    ``ddown`` of the chunks before, in place) and of the rows' weights —
+    from the rows' cotangent ``dy``, which every product rounds to ``cdt``
+    as it reads it (the caller gathers it in ``cdt`` where a chunk has more
+    rows than the layer has tokens: a float32 (R, h) gather is 0.75 GB at
+    mellum2's chunk, half of it more than a step's memory may grow by).
+    Recomputed: the first product (its float32 ``hid``, which the
+    activation's gradient reads), not the second: with ``u = dy @
+    down[g].T``, the weights' gradient ``dy . y`` is ``act . u`` and the
+    activation's cotangent ``wgt * u``, so the float32 (R, h) output rows
+    are not formed again, nor their weighted cotangent (each 0.75 GB at
+    mellum2's chunk).  Rows past ``sum(sizes)`` are read by no product;
+    what they hold here is masked by the caller."""
+    gu, dn = gate_up.astype(cdt), down.astype(cdt)
+    hid = gmm(xg.astype(cdt), gu, sizes, interpret=interpret)
+    act, act_vjp = jax.vjp(_activation, hid)
+    u = gmm(dy, dn, sizes, transpose_rhs=True, interpret=interpret)
+    with scope("moe.rows"):
+        dw = jnp.sum(act * u, axis=1)
+        dact, wact = u * wgt[:, None], (act * wgt[:, None]).astype(cdt)
+    ddown = tgmm(wact, dy, sizes, ddown, interpret=interpret)
+    dhid = act_vjp(dact)[0].astype(cdt)
+    dgu = tgmm(xg.astype(cdt), dhid, sizes, dgu, interpret=interpret)
+    dxg = gmm(
+        dhid, gu, sizes, transpose_rhs=True, out_dtype=cdt, interpret=interpret
+    )
+    return dxg, dgu, ddown, dw
+
+
+def _chunk_rows(xg, gate_up, down, wgt, live, sizes, cdt, gmm_kernel=None):
     """One chunk of sorted held slots, from their gathered tokens ``xg``:
     run their experts and weight the rows.  ``live`` marks rows that are
     slots (the last chunk's tail is not), the others are zeroed."""
-    y = _swiglu_rows(xg, gate_up, down, sizes, cdt)
+    y = _swiglu_rows(xg, gate_up, down, sizes, cdt, gmm_kernel)
     with scope("moe.rows"):
         return jnp.where(live[:, None], y * wgt[:, None], 0.0)
 
@@ -632,10 +688,10 @@ def _chunk_of(tok, wgt, offsets, n_held, c, rows):
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
 def _held_chunks(
     xt, gate_up, down, tok, wgt, offsets, n_held, tokens_side, rows, cdt,
-    kernel,
+    kernel, gmm_kernel,
 ):
     """Sum over the held slots, ``rows`` at a time: ``out[tok[r]] +=
     wgt[r] * E(xt[tok[r]])`` for the sorted slots r < n_held, whose
@@ -647,11 +703,15 @@ def _held_chunks(
     :func:`moe_combine` from the tokens' side (``tokens_side``: the
     weights in token order, ``key`` and ``pos``, all three (T * K,), and
     :func:`tile_runs`), or by a scatter-add from the rows' side (``tok``,
-    ``wgt``).  The weights' gradient comes back in ``wgt``'s order.
-    Returns (out (T, h) f32, rows computed)."""
+    ``wgt``).  ``gmm_kernel`` (None, or the ``interpret`` of the kernels of
+    :mod:`sparknet_tpu.ops.gmm`) says what computes a chunk's grouped
+    products, in every pass: those kernels (:func:`_swiglu_rows` forward,
+    :func:`_chunk_grads_gmm` backward), or ``lax.ragged_dot``.  The
+    weights' gradient comes back in ``wgt``'s order.  Returns (out (T, h)
+    f32, rows computed)."""
     return _held_chunks_fwd(
         xt, gate_up, down, tok, wgt, offsets, n_held, tokens_side, rows, cdt,
-        kernel,
+        kernel, gmm_kernel,
     )[0]
 
 
@@ -665,7 +725,7 @@ def _combine(prev, y, tokens_side, n_held, c, rows, kernel):
 
 def _held_chunks_fwd(
     xt, gate_up, down, tok, wgt, offsets, n_held, tokens_side, rows, cdt,
-    kernel,
+    kernel, gmm_kernel,
 ):
     def chunk(carry, c):
         def run(carry):
@@ -676,10 +736,12 @@ def _held_chunks_fwd(
             with scope("moe.rows"):
                 xg = xt[tok_c]
             if kernel is None:
-                y = _chunk_rows(xg, gate_up, down, wgt_c, live, sizes, cdt)
+                y = _chunk_rows(
+                    xg, gate_up, down, wgt_c, live, sizes, cdt, gmm_kernel
+                )
                 with scope("moe.rows"):
                     return out.at[tok_c].add(y), done + jnp.sum(sizes)
-            y = _swiglu_rows(xg, gate_up, down, sizes, cdt)
+            y = _swiglu_rows(xg, gate_up, down, sizes, cdt, gmm_kernel)
             out = _combine(out, y, tokens_side, n_held, c, rows, kernel)
             return out, done + jnp.sum(sizes)
 
@@ -690,7 +752,7 @@ def _held_chunks_fwd(
     return carry, (xt, gate_up, down, tok, wgt, offsets, n_held)
 
 
-def _held_chunks_bwd(rows, cdt, kernel, res, cts):
+def _held_chunks_bwd(rows, cdt, kernel, gmm_kernel, res, cts):
     """The same walk backwards: a live chunk recomputes its rows and adds
     its share to dxt, to the weights' gradients and to its slots' weights;
     a skipped one passes the sums on untouched.  dxt takes XLA's
@@ -701,6 +763,10 @@ def _held_chunks_bwd(rows, cdt, kernel, res, cts):
     over the bound of their memory."""
     xt, gate_up, down, tok, wgt, offsets, n_held = res
     dout = cts[0]
+    if gmm_kernel is not None and rows >= dout.shape[0]:
+        # all the kernels read of it (_chunk_grads_gmm): a chunk's gather of
+        # it then takes 2 bytes a value for 4, and the copy costs no more
+        dout = dout.astype(cdt)
 
     def chunk(carry, c):
         def run(carry):
@@ -710,18 +776,25 @@ def _held_chunks_bwd(rows, cdt, kernel, res, cts):
             )
             with scope("moe.rows"):
                 xg, dy = xt[tok_c], dout[tok_c]
-            _, vjp = jax.vjp(
-                lambda xg, gu, dn, w: _chunk_rows(
-                    xg, gu, dn, w, live, sizes, cdt
-                ),
-                xg, gate_up, down, wgt_c,
-            )
-            dxg, dgu_c, ddown_c, dw_c = vjp(dy)
+            if gmm_kernel is None:
+                _, vjp = jax.vjp(
+                    lambda xg, gu, dn, w: _chunk_rows(
+                        xg, gu, dn, w, live, sizes, cdt
+                    ),
+                    xg, gate_up, down, wgt_c,
+                )
+                dxg, dgu_c, ddown_c, dw_c = vjp(dy)
+                dgu, ddown = dgu + dgu_c, ddown + ddown_c
+            else:
+                dxg, dgu, ddown, dw_c = _chunk_grads_gmm(
+                    xg, gate_up, down, wgt_c, sizes, dy, dgu, ddown, cdt,
+                    gmm_kernel,
+                )
             with scope("moe.rows"):
                 dxg = jnp.where(live[:, None], dxg, 0).astype(jnp.float32)
                 dxt = dxt.at[tok_c].add(dxg)
             return (
-                dxt, dgu + dgu_c, ddown + ddown_c,
+                dxt, dgu, ddown,
                 lax.dynamic_update_slice_in_dim(
                     dwgt, jnp.where(live, dw_c, 0.0), c * rows, 0
                 ),
@@ -777,8 +850,10 @@ def held_experts_ffn(
     num_experts if the router is even), ``moe_slots_in_kernel`` (as many
     where :func:`moe_combine` sums their rows into ``out``, 0 where the
     scatter-add does), ``moe_load_max_over_mean`` (the fullest held expert
-    over their mean) and ``moe_slots_dropped`` (held slots that were not
-    computed: 0, by construction).
+    over their mean), ``moe_slots_dropped`` (held slots that were not
+    computed: 0, by construction) and ``moe_slots_in_gmm`` (as many as held
+    where the Pallas kernels of :mod:`sparknet_tpu.ops.gmm` compute their
+    grouped products, 0 where ``lax.ragged_dot`` does).
 
     The slots are put in the order of their experts by
     :func:`slot_tables`; rows are processed ``chunk_rows`` at a time
@@ -786,7 +861,10 @@ def held_experts_ffn(
     as many chunks as hold all T * top_k slots, and each chunk's rows are
     summed into their tokens by :func:`moe_combine` or, where
     :func:`uses_combine_kernel` says no (``force``; ``interpret`` runs the
-    kernel in Pallas's interpreter), by a scatter-add."""
+    kernel in Pallas's interpreter), by a scatter-add.  A chunk's grouped
+    products run as the kernels of :mod:`sparknet_tpu.ops.gmm`, or, where
+    :func:`uses_gmm_kernel` says no (the same ``force`` and ``interpret``),
+    as ``lax.ragged_dot``."""
     first, held = experts_held
     num_experts = params["router_w"].shape[-1]
     if held != params["experts_down"].shape[0]:
@@ -814,7 +892,9 @@ def held_experts_ffn(
         if pad:
             tok = jnp.pad(tok, (0, pad))
             wgt = jnp.pad(wgt, (0, pad))
-        kernel = tokens_side = None
+        kernel = tokens_side = gmm_kernel = None
+        if uses_gmm_kernel(xt.shape[1], params["experts_down"].shape[1], rows, force):
+            gmm_kernel = interpret
         if uses_combine_kernel(t, xt.shape[1], top_k, rows, force):
             kernel = interpret
             tokens_side = (
@@ -825,6 +905,7 @@ def held_experts_ffn(
         out, done = _held_chunks(
             xt, params["experts_gate_up"], params["experts_down"], tok, wgt,
             offsets, n_held, tokens_side, rows, compute_dtype, kernel,
+            gmm_kernel,
         )
         out = out.reshape(orig_shape).astype(x.dtype)
     with scope("counters"):
@@ -839,5 +920,8 @@ def held_experts_ffn(
                 jnp.mean(loads), 1.0 / held
             ),
             "moe_slots_dropped": (n_held - done).astype(jnp.float32),
+            "moe_slots_in_gmm": (
+                jnp.zeros_like(held_f) if gmm_kernel is None else held_f
+            ),
         }
     return out, counters

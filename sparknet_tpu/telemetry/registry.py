@@ -162,7 +162,8 @@ class LastStep:
     when somebody reads — a scrape, a benchmark reader, the periodic
     ``telemetry:`` line.  That is how a model's counters (the decoder's
     ``moe_slots_held``, ``moe_slots_in_kernel``, ``moe_load_max_over_mean``,
-    ``moe_slots_dropped``, ``rope_rows_in_kernel``) reach the registry."""
+    ``moe_slots_dropped``, ``moe_slots_in_gmm``, ``rope_rows_in_kernel``)
+    reach the registry."""
 
     def __init__(self):
         self.metrics: Dict[str, object] = {}

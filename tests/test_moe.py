@@ -614,8 +614,9 @@ def test_a_decoder_step_through_the_kernel_agrees_and_counts_its_slots(monkeypat
 
 
 def test_the_counter_reaches_the_progress_line_and_the_registry(capsys):
-    """``moe_slots_in_kernel`` beside ``moe_slots_held`` on the app's
-    progress line and in the registry: 0 off a TPU."""
+    """``moe_slots_in_kernel`` and ``moe_slots_in_gmm`` beside
+    ``moe_slots_held`` on the app's progress line and in the registry: 0 off
+    a TPU."""
     from sparknet_tpu.apps import lm_app
     from sparknet_tpu.telemetry.registry import REGISTRY
 
@@ -630,8 +631,124 @@ def test_the_counter_reaches_the_progress_line_and_the_registry(capsys):
     out = capsys.readouterr().out
     assert re.search(r"moe_slots_held = [\d.]+, moe_slots_in_kernel = 0, moe_load_max_over_mean = ", out)
     assert metrics["moe_slots_in_kernel"] == 0.0 < metrics["moe_slots_held"]
+    assert "moe_slots_in_gmm = 0" in out and metrics["moe_slots_in_gmm"] == 0.0
     read = REGISTRY.sources()["train_step"].snapshot()
     assert read["moe_slots_in_kernel"] == 0.0 and read["moe_slots_held"] == metrics["moe_slots_held"]
+    assert read["moe_slots_in_gmm"] == 0.0
+
+
+# ---------------------------------------------------------------------------
+# the grouped products as Pallas kernels (ops/gmm.py), in the interpreter
+# ---------------------------------------------------------------------------
+
+# name: (group sizes over 512 rows in tiles of 128, the rows' width)
+GMM_CASES = {
+    # boundaries inside tiles, two empty experts, 130 rows past the last
+    "straddling_and_empty": ([100, 0, 150, 130, 0], 256),
+    "seven_lane_tiles": ([40, 300, 0, 60, 90], 896),
+    "one_expert_all_rows": ([0, 0, 512, 0, 0], 128),
+    "no_live_rows": ([0, 0, 0, 0, 0], 128),
+}
+
+
+@pytest.mark.parametrize("name", list(GMM_CASES))
+def test_gmm_and_tgmm_equal_ragged_dot_and_its_gradients(name):
+    """``gmm`` forward and against the weights read transposed (dx), and
+    ``tgmm`` (dW), against ``lax.ragged_dot`` and its ``jax.vjp``, float32
+    to 1e-6 of the largest value.  Rows past ``sum(sizes)`` hold NaN in
+    both operands: a kernel that read one into a product fails; an empty
+    expert's weight gradient is what was summed before it."""
+    from sparknet_tpu.ops import gmm as ops_gmm
+
+    sizes, n = GMM_CASES[name]
+    sizes = jnp.asarray(sizes, jnp.int32)
+    rows, k, live = 512, 128, int(sizes.sum())
+    assert ops_gmm.row_tile(rows, sizes.shape[0]) == 128
+    r = jax.random.split(jax.random.PRNGKey(7), 3)
+    nan_past = lambda a: a.at[live:].set(jnp.nan)
+    x = nan_past(jax.random.normal(r[0], (rows, k)))
+    w = jax.random.normal(r[1], (sizes.shape[0], k, n))
+    dy = nan_past(jax.random.normal(r[2], (rows, n)))
+    with jax.default_matmul_precision("highest"):
+        want, vjp = jax.vjp(lambda x, w: jax.lax.ragged_dot(x[:live], w, sizes), x, w)
+        want_dx, want_dw = vjp(dy[:live])
+        got = ops_gmm.gmm(x, w, sizes, interpret=True)
+        got_dx = ops_gmm.gmm(dy, w, sizes, transpose_rhs=True, interpret=True)
+        prior = jax.random.normal(r[0], w.shape)  # the chunks' sum before this one
+        got_dw = ops_gmm.tgmm(x, dy, sizes, prior, interpret=True) - prior
+    close = lambda g, w: np.testing.assert_allclose(
+        g, w, atol=1e-6 * np.abs(np.asarray(w)).max(initial=1.0)
+    )
+    close(got[:live], want)
+    close(got_dx[:live], want_dx[:live])
+    assert np.isfinite(np.asarray(got_dw)).all()
+    close(got_dw, want_dw)
+    assert not np.asarray(got_dw)[np.asarray(sizes) == 0].any()
+
+
+def test_group_visits_take_each_tile_of_a_group_once_in_order():
+    """The visits of ``group_visits``: every group takes the tiles that hold
+    its rows, in order, an empty one none (or, for ``tgmm``, one), and the
+    tiles past the live rows none."""
+    from sparknet_tpu.ops.gmm import group_visits
+
+    sizes = jnp.asarray([100, 0, 150, 130, 0], jnp.int32)
+    group, tile, offsets, n = group_visits(sizes, 512, 128, empty=False)
+    assert int(n) == 5 and group.shape == tile.shape == (8,)
+    assert list(zip(group[:5].tolist(), tile[:5].tolist())) == [(0, 0), (2, 0), (2, 1), (3, 1), (3, 2)]
+    assert offsets.tolist() == [0, 100, 100, 250, 380, 380]
+    group, tile, _, n = group_visits(sizes, 512, 128, empty=True)
+    assert list(zip(group[:int(n)].tolist(), tile[:int(n)].tolist())) == [
+        (0, 0), (1, 0), (2, 0), (2, 1), (3, 1), (3, 2), (4, 2)
+    ]
+
+
+def test_the_gmm_kernels_are_taken_by_what_the_call_shows():
+    """Off a TPU never, unless forced; forced, where the hidden and expert
+    widths are whole lane tiles and the chunk whole row tiles.  Tiles at the
+    four decoder cells' shapes come from the widths and the rows alone."""
+    from sparknet_tpu.ops.gmm import _VMEM_BUDGET, lane_tiles, row_tile
+    from sparknet_tpu.parallel.moe import uses_gmm_kernel
+
+    assert not uses_gmm_kernel(2304, 896, 81920)  # no TPU here
+    assert not uses_gmm_kernel(2304, 896, 81920, "reference")
+    for hidden, ffn, rows in ((2304, 896, 81920), (2048, 512, 20480), (2560, 768, 2560), (2048, 1792, 40960)):
+        assert uses_gmm_kernel(hidden, ffn, rows, "flash")
+    assert not uses_gmm_kernel(2300, 896, 81920, "flash")  # lanes
+    assert not uses_gmm_kernel(2304, 900, 81920, "flash")
+    assert not uses_gmm_kernel(2304, 896, 81920 - 64, "flash")  # row tiles
+    # mellum, lfm2: 512 rows; laguna's 32 and ling's 8 experts in short chunks: 128
+    assert [row_tile(r, g) for r, g in ((81920, 16), (40960, 8), (20480, 32), (2560, 8))] == [512, 512, 128, 128]
+    tk, tn = lane_tiles(512, 2304, 1792, 2, grouped_k=False)
+    assert (tk, tn) == (2304, 1792)  # a group's whole gate and up stay in VMEM
+    assert lane_tiles(512, 1792, 2304, 2, grouped_k=False)[1] == 1152
+    tk, tn = lane_tiles(512, 2304, 1792, 2, grouped_k=True)
+    assert 2304 % tk == 1792 % tn == 0 and tk * tn * 4 <= _VMEM_BUDGET // 2
+
+
+@pytest.mark.parametrize("chunk_rows", [None, 128], ids=["one_chunk", "four_chunks"])
+def test_held_experts_through_the_gmm_kernels_match_ragged_dot(chunk_rows):
+    """``held_experts_ffn`` with the products forced through the kernels
+    (interpreted) against ``lax.ragged_dot``'s path: the output and the
+    gradients of the tokens, the router and both expert stacks to float32
+    tolerance; ``moe_slots_in_gmm`` equals ``moe_slots_held`` there and is 0
+    off it."""
+    from sparknet_tpu.parallel.moe import held_experts_ffn
+
+    p, x, kw = _held_setup(h=128, f=128, tokens=256)
+    kw = dict(kw, chunk_rows=chunk_rows)
+    forced = dict(force="flash", interpret=True)
+    run = lambda extra: lambda x, p: held_experts_ffn(x, p, **kw, **extra)
+    with jax.default_matmul_precision("highest"):
+        (got, c_got), (want, c_want) = run(forced)(x, p), run({})(x, p)
+        np.testing.assert_allclose(got, want, atol=1e-5 * float(jnp.abs(want).max()))
+        g_got = _grads(lambda x, p: run(forced)(x, p)[0], x, p)
+        g_want = _grads(lambda x, p: run({})(x, p)[0], x, p)
+    for g, w in zip(jax.tree_util.tree_leaves(g_got), jax.tree_util.tree_leaves(g_want)):
+        np.testing.assert_allclose(g, w, atol=1e-5 * float(jnp.abs(w).max()) + 1e-7)
+    assert float(c_got["moe_slots_in_gmm"]) == float(c_got["moe_slots_held"]) > 0
+    assert float(c_want["moe_slots_in_gmm"]) == 0.0 == float(c_got["moe_slots_dropped"])
+    assert float(c_got["moe_slots_in_kernel"]) == 0.0  # 512 slots: no whole moe_combine tile
 
 
 # ------------------------------------------------------------- on the chip
@@ -666,3 +783,50 @@ def test_compiled_moe_combine_at_the_cells_shapes_on_hardware(tokens, h, experts
         )
     )(y)
     np.testing.assert_allclose(got, want, atol=1e-5 * float(jnp.abs(want).max()))
+
+
+@pytest.mark.skipif(
+    jax.default_backend() != "tpu", reason="the compiled kernels need a TPU"
+)
+@pytest.mark.parametrize(
+    "tokens,h,f,experts,held", [(32768, 2304, 896, 64, 16), (16384, 2048, 512, 256, 32)],
+    ids=["mellum2", "laguna_xs2"],
+)
+def test_compiled_gmm_kernels_at_the_cells_shapes_on_hardware(tokens, h, f, experts, held):
+    """One chunk of a cell's expert layer, forward and backward, through the
+    compiled ``ops.gmm`` kernels against ``lax.ragged_dot``, 80 % of its rows
+    live over uneven experts: the sum and every gradient within 2 % of the
+    largest value (both round their operands to bfloat16, not at the same
+    places).  Tensors are the jit's arguments, not constants."""
+    from sparknet_tpu.parallel import moe
+
+    rows = moe.held_chunk_rows(tokens * 8, held, experts)
+    assert moe.uses_gmm_kernel(h, f, rows)
+    k = jax.random.split(jax.random.PRNGKey(42), 7)
+    share = jax.random.gamma(k[0], 8.0, (held,))
+    sizes = jnp.floor(0.8 * rows * share / share.sum()).astype(jnp.int32)
+    offsets = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(sizes)])
+    args = (
+        jax.random.normal(k[1], (tokens, h), jnp.bfloat16),
+        0.02 * jax.random.normal(k[2], (held, h, 2 * f)),
+        0.02 * jax.random.normal(k[3], (held, f, h)),
+        jax.random.uniform(k[4], (rows,)),
+        jax.random.normal(k[5], (tokens, h)),
+    )
+    tok = jax.random.randint(k[6], (rows,), 0, tokens)
+
+    def grads(gmm_kernel):
+        def total(xt, gu, dn, wgt, probe):
+            out, _ = moe._held_chunks(
+                xt, gu, dn, tok, wgt, offsets, offsets[-1], None, rows,
+                jnp.bfloat16, None, gmm_kernel,
+            )
+            return jnp.sum(out * probe)
+        return jax.jit(jax.value_and_grad(total, range(4)))(*args)
+
+    (got, g_got), (want, g_want) = grads(False), grads(None)
+    assert abs(float(got - want)) <= 1e-3 * abs(float(want))
+    for name, g, w in zip(("xt", "gate_up", "down", "wgt"), g_got, g_want):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.isfinite(g).all(), name
+        np.testing.assert_allclose(g, w, atol=2e-2 * np.abs(w).max(), err_msg=name)

@@ -97,11 +97,17 @@ _HELD = {
 
 @pytest.mark.parametrize("case", sorted(_HELD))
 def test_held_experts_compile_to_the_grouped_product_for_v5e(case, one_chip, no_compile_cache):
-    """One sparse layer's held experts at a cell's shapes, the kernel
-    forced as a TPU takes it: the products are XLA's grouped ones, forward
-    and backward; a chunk's rows reach their tokens through ``moe_combine``
-    in the forward pass (the backward pass's scatter-add stays)."""
+    """One sparse layer's held experts at a cell's shapes, the kernels
+    forced as a TPU takes them: the grouped products are the Pallas kernels
+    of ``ops/gmm.py`` in every pass — forward the two ``gmm`` products, and
+    in the chunk's backward its recomputed first product, the cotangent's
+    product with the down weights, the rows' gradient (``gmm``) and both
+    weight gradients (``tgmm``) — all under ``moe.experts`` and outside
+    ``moe.rows``, and no ``ragged-dot`` of XLA's is left; a chunk's rows
+    reach their tokens through ``moe_combine`` in the forward pass (the
+    backward pass's scatter-add stays)."""
     from sparknet_tpu.parallel.moe import held_experts_ffn, route_sigmoid
+    from sparknet_tpu.utils import profiling
 
     tokens, h, f, experts, held = _HELD[case]
     shape = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one_chip)
@@ -122,7 +128,14 @@ def test_held_experts_compile_to_the_grouped_product_for_v5e(case, one_chip, no_
         )(x, params)
 
     text = jax.jit(grads).lower(shape((*tokens, h), jnp.bfloat16), params).compile().as_text()
-    assert text.count("ragged-dot") >= 6  # gate+up and down, and both gradients of each
+    table = profiling.scope_table(text, profiling.declared_scopes())
+    grouped = {name: e for name, e in table.items() if "ragged-dot" in name}
+    assert all(e.kernel for e in grouped.values()), sorted(grouped)  # none of XLA's
+    passes = lambda kind: sorted(e.pass_ for n, e in grouped.items() if n.startswith(kind + "."))
+    assert passes("ragged-dot-gmm") == ["backward"] * 3 + ["forward"] * 2
+    assert passes("ragged-dot-tgmm") == ["backward"] * 2
+    for e in grouped.values():
+        assert "moe.experts" in e.chain and "moe.rows" not in e.chain, e
     calls = [
         ln for ln in text.splitlines()
         if " custom-call(" in ln and "moe_combine" in ln.split(" = ")[0]
