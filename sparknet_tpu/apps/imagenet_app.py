@@ -96,6 +96,22 @@ def make_device_feed(
     return ds.batches(batch_size, shuffle=True, seed=seed, transform=transform)
 
 
+def lrn_elems_in_kernel(net) -> int:
+    """Σ N·H·W·C over ``net``'s LRN layers that run as the Pallas kernels
+    (``ops.lrn.uses_lrn_kernel``; 0 off a TPU), set once as the registry's
+    ``lrn_elems_in_kernel`` gauge."""
+    from ..nets import layers
+    from ..telemetry.registry import REGISTRY
+
+    n = sum(
+        int(np.prod(net.blob_shapes[lp.bottom[0]])) for lp in net.layers
+        if lp.type == "LRN"
+        and layers.uses_lrn_kernel(net.blob_shapes[lp.bottom[0]], layers.LRN._geom(lp)[4])
+    )
+    REGISTRY.gauge("lrn_elems_in_kernel").set(n)
+    return n
+
+
 def make_args(**overrides) -> argparse.Namespace:
     """Programmatic equivalent of the CLI (tests, notebooks)."""
     args = parser().parse_args([])
@@ -409,13 +425,15 @@ def main(argv=None):
 
     raw_train_feed = train_feed
     train_feed = maybe_prefetch(train_feed, args, args.parallel)
+    lrn_elems = lrn_elems_in_kernel(solver.train_net)
     if multihost.is_primary():
         if args.restore:
             print(f"Restoring previous solver status from {args.restore} "
                   f"(iter {solver.iter})")
         print(
             f"ImageNetApp: net={solver.net_param.name} "
-            f"params={W.num_params(solver.params)} max_iter={solver.sp.max_iter}"
+            f"params={W.num_params(solver.params)} max_iter={solver.sp.max_iter} "
+            f"lrn_elems_in_kernel={lrn_elems}"
         )
     from .. import telemetry
     from ..utils.profiling import trace

@@ -26,7 +26,6 @@ equivalent).
 from __future__ import annotations
 
 import math
-import os
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -36,6 +35,7 @@ import numpy as np
 from jax import lax
 
 from ..proto.caffe_pb import Filler, LayerParameter
+from ..ops.lrn import lrn_nhwc, uses_lrn_kernel
 from ..ops.matmul import mxu_dot
 
 Shape = Tuple[int, ...]
@@ -596,9 +596,8 @@ class Log:
 
 class LRN:
     """Local response normalization (AlexNet/GoogLeNet). ACROSS_CHANNELS
-    runs the window over the channel axis — last in NHWC, so the rolling
-    sum is a reduce_window over a minor axis, which XLA vectorizes well.
-    """
+    runs the window over the channel axis, last in NHWC; WITHIN_CHANNEL
+    averages over a size x size spatial window."""
 
     @staticmethod
     def _geom(lp):
@@ -622,39 +621,13 @@ class LRN:
     def apply(lp, params, state, inputs, ctx):
         size, alpha, beta, k, region = LRN._geom(lp)
         x = inputs[0]
-        if (
-            region == "ACROSS_CHANNELS"
-            and x.ndim == 4
-            and x.shape[-1] <= 512  # (C,C) f32 band must fit VMEM
-            # alongside the double-buffered row tiles (1 MB at C=512)
-            and jax.default_backend() == "tpu"
-            and os.environ.get("SPARKNET_LRN_PALLAS", "0") not in ("", "0")
-        ):
-            # fused one-pass kernel (ops/lrn.py). OFF by default: the
-            # round-5 on-chip A/B measured it 2x SLOWER end to end
-            # (86 vs 43 ms AlexNet bs512 step) — mid-network XLA
-            # assigns the neighbouring convs exotic layouts (e.g.
-            # batch-minor {0,3,2,1}) and a pallas_call pins row-major
-            # operands, so every LRN pays conv-sized relayout copies
-            # both ways that dwarf the temp-chain saving. Kept
-            # reachable for standalone/row-major contexts.
-            from ..ops.lrn import lrn_nhwc
-
-            return [
-                lrn_nhwc(x, size=size, alpha=alpha, beta=beta, k=k)
-            ], None
-        # The squared/windowed temps follow the net's compute dtype:
-        # under bf16 the conv activations feeding this are already
-        # bf16-rounded, and keeping LRN's conv-sized temp chain at f32
-        # doubles its HBM bytes for ~3 extra digits in d that the
-        # surrounding net can't use. (The bf16 temp chain read faster
-        # at AlexNet bs512 — measured once in round 5 on a set-up that
-        # no longer exists; not re-measured.) f32 nets are untouched (x
-        # is f32); SPARKNET_LRN_F32=1 restores f32 temps under bf16 for
-        # an apples-to-apples numerics comparison.
-        out_dtype = x.dtype
-        if os.environ.get("SPARKNET_LRN_F32", "0") not in ("", "0"):
-            x = x.astype(jnp.float32)
+        if uses_lrn_kernel(x.shape, region):
+            # one Pallas pass each way (ops/lrn.py), in the orientation
+            # whose row-major form is the layout XLA gives the tensor
+            return [lrn_nhwc(x, size=size, alpha=alpha, beta=beta, k=k)], None
+        # The jax.numpy form: off a TPU, WITHIN_CHANNEL, and shapes that
+        # fit neither of the kernels' orientations; the kernels' oracle.
+        # Its temps follow the input's dtype.
         sq = jnp.square(x)
         half = size // 2
         if region == "ACROSS_CHANNELS":
@@ -668,25 +641,7 @@ class LRN:
             k = 1.0
         ssum = lax.reduce_window(sq, 0.0, lax.add, window, (1, 1, 1, 1), padding)
         d = k + scale * ssum
-        # x * d^-beta. Round-4 rewrote the pow into rsqrt/sqrt chains on
-        # VPU-transcendental theory; the chain read slower at AlexNet
-        # bs512 (measured once in round 5 on a set-up that no longer
-        # exists; not re-measured) — LRN is HBM-bound, and the longer
-        # chain plus its VJP materialises more conv-sized temps than it
-        # saves in transcendentals. A single pow (and its single-temp
-        # VJP) is the default; SPARKNET_LRN_CHAIN=1 keeps the chain
-        # reachable (ROADMAP D3 deletes the loser).
-        chain = os.environ.get("SPARKNET_LRN_CHAIN", "0") not in ("", "0")
-        if chain and beta == 0.75:
-            t = jnp.sqrt(lax.rsqrt(d))  # d^(-1/4)
-            inv = t * t * t
-        elif chain and beta == 0.5:
-            inv = lax.rsqrt(d)
-        elif chain and beta == 1.0:
-            inv = 1.0 / d
-        else:
-            inv = jnp.power(d, -beta)
-        return [(x * inv).astype(out_dtype)], None
+        return [(x * jnp.power(d, -beta)).astype(x.dtype)], None
 
 
 class Dropout:

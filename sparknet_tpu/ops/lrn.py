@@ -1,213 +1,289 @@
-"""Pallas LRN (ACROSS_CHANNELS): one fused stencil pass each way.
+"""Pallas LRN (ACROSS_CHANNELS): one pass forward, one pass backward.
 
-Caffe's LRN (reference vendored engine, SURVEY.md §2; mount empty) is
-AlexNet/GoogLeNet's only non-conv normalization:
+Caffe's LRN is AlexNet's and GoogLeNet's normalization across channels:
 
     d(c) = k + (alpha/size) * sum_{c' in [c-a, c+b]} x(c')^2
     y(c) = x(c) * d(c)^-beta          (a = size//2, b = size-1-a)
 
-The jnp path (``nets/layers.py``) is numerically fine but XLA
-materialises the squared tensor, the windowed sum, the power and its
-VJP chain as separate conv-sized HBM temps — cost analysis reports
-~5x the activation size in bytes accessed, which makes the two AlexNet
-LRNs a candidate slice of the whole train step (ROADMAP S6: not
-measured on the current code). LRN is a pure 1-D stencil along the minor
-(channel) axis, so one Pallas pass holds the whole window in VMEM:
+It is a 5-tap stencil along the channel axis, a power and a product, so it
+is bound by HBM bandwidth.  The forward kernel reads x and writes y; the
+backward kernel (``custom_vjp``) reads x and the cotangent g and writes
 
-- forward: read x, write y and the residual d — no squared/windowed
-  HBM temps, and d^-beta is built in-register (rsqrt/sqrt chain for
-  the dyadic betas — free here precisely because nothing round-trips
-  to HBM, unlike the round-4 XLA-level attempt the A/B reverted).
-- backward (custom VJP): dx = g*d^-beta - 2*(alpha/size)*beta * x *
-  W^T(g * x * d^(-beta-1)); one pass reading g, x, d and writing dx.
-  W^T flips the window's (a, b) asymmetry; for the usual odd
-  ``local_size`` it equals W.
+    dx = g*d^-beta - 2*(alpha/size)*beta * x * W^T(g * x * d^(-beta-1))
 
-Rows (N*H*W) are independent, so the grid tiles a flattened (M, C)
-view; C rides the 128-lane axis (C < 128 pads — zero lanes contribute
-zero to the window sum and d = k > 0 keeps the power finite).
+with d formed again from x in VMEM: the only residual is x itself, which
+the layer's input already is.  ``W^T`` is the window with (a, b) swapped.
+Inside a tile everything is float32 but the window, which is one product
+with a (C, C) 0/1 band on the MXU, its operands in the input's dtype and
+its sums in float32 (on a v5e at AlexNet's sizes the kernels take about
+1.3 x their bytes' time so; rolled copies on the vector units took 2.2-3 x).
 
-The jnp path remains the oracle and the DEFAULT (the kernel is opt-in
-via SPARKNET_LRN_PALLAS=1): inside the AlexNet train step the kernel
-read about twice as slow (measured once in round 5 on a set-up that no
-longer exists; not re-measured) — XLA assigns the neighbouring convs
-exotic layouts (batch-minor {0,3,2,1} activations) and a pallas_call
-pins row-major operands, so each LRN pays two conv-sized relayout
-copies that dwarf the temp-chain saving. ROADMAP D3 deletes this
-module. The kernel wins only where the operand is already
-row-major (standalone use); equivalence incl. grads is pinned in
-tests/test_lrn_pallas.py (interpret mode on CPU).
+**Orientation.**  A ``pallas_call`` takes its operands row-major, and the
+layers around an LRN leave its tensor in a layout of XLA's choosing.  The
+kernel reads the logical transpose whose row-major form is that layout, so
+the transpose in and out lowers to a bitcast and not to a copy of the
+tensor.  In AlexNet's step norm1's ``[1024,55,55,96]`` is batch-minor
+(physically H, W, C, N) from conv1 to pool1: no copy.  norm2's
+``[1024,27,27,256]`` leaves conv2 batch-minor and enters pool2
+channel-minor (H, W, N, C), so one copy each way stays in either
+orientation; the channels form keeps the step's memory lower (the
+``jax.numpy`` form's step holds three such copies).
+
+- ``"channels"``: C a whole number of 128-lane tiles; the NHWC -> HWNC
+  transpose viewed as (H*W, N, C), the window along the lanes;
+- ``"batch"``: otherwise, N a whole number of lane tiles; the NHWC -> HWCN
+  transpose viewed as (H*W, C, N), the window along the sublanes.
+
+:func:`uses_lrn_kernel` decides from the input's shape and the backend
+(``nets/layers.py`` ``LRN.apply`` asks it); elsewhere the layer's
+``jax.numpy`` form runs, which is also the oracle of
+``tests/test_lrn_pallas.py``.  :func:`lrn_nhwc` itself takes any NHWC
+shape (a shape that fits neither orientation goes through the channels
+form).  A program that XLA partitions over several devices cannot hold a
+Mosaic kernel: there the same oriented view lowers to the ``jax.numpy``
+form (:func:`_whole_program`), inside a ``shard_map`` to the kernels.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.extend.core import Primitive
+from jax.interpreters import mlir
+
+from .attention import uses_flash
+
+_LANES = 128
+_MAX_CHANNELS = 512
+_BLOCK_BYTES = 1 << 21  # of x a grid step reads
+_TILE_ELEMS = 1 << 17  # float32 elements of a tile the body works on at once
 
 
-def _band(c: int, a: int, b: int) -> jax.Array:
-    """(C, C) 0/1 band: (t @ band)[c] = sum t[c-a .. c+b].
+def orientation(shape, region: str = "ACROSS_CHANNELS") -> Optional[str]:
+    """``"channels"``, ``"batch"`` or None (module docstring) for an NHWC
+    ``shape``: channels on the lanes where C is whole lane tiles (and N
+    whole 16-row tiles), else the batch on the lanes where N is whole lane
+    tiles (and C whole 8-row tiles); never more than 512 channels, never
+    WITHIN_CHANNEL."""
+    if region != "ACROSS_CHANNELS" or len(shape) != 4:
+        return None
+    n, _, _, c = shape
+    if c > _MAX_CHANNELS:
+        return None
+    if c % _LANES == 0 and n % 16 == 0:
+        return "channels"
+    if n % _LANES == 0 and c % 8 == 0:
+        return "batch"
+    return None
 
-    The channel stencil as a matmul: lane-shifted slices are the slow
-    path on the VPU (measured 2x worse than the jnp fallback end to
-    end), while a (rows, C) x (C, C) dot rides the MXU for free — the
-    band lives in VMEM for the whole grid (1 MB at the C=512 cap the
-    layer gate enforces, alongside ~6 MB of double-buffered row
-    tiles)."""
-    i = jnp.arange(c)[:, None]  # source channel
-    j = jnp.arange(c)[None, :]  # output channel
-    return ((j - a <= i) & (i <= j + b)).astype(jnp.float32)
+
+def uses_lrn_kernel(
+    shape, region: str = "ACROSS_CHANNELS", force: Optional[str] = None
+) -> bool:
+    """Whether an LRN over an input of ``shape`` runs as the kernels
+    (``force`` as :func:`sparknet_tpu.ops.attention.attention` has it:
+    "flash" the kernels where the shape fits an orientation, "reference"
+    never, None the kernels on a TPU)."""
+    return orientation(shape, region) is not None and uses_flash(force)
 
 
-def _inv_beta(d: jax.Array, beta: float) -> jax.Array:
-    """d^-beta in registers; rsqrt/sqrt chains for the dyadic betas."""
+def _inv_beta(d, t, beta: float):
+    """d^-beta, given t = rsqrt(d); rsqrt/sqrt chains for the usual betas."""
     if beta == 0.75:
-        t = jax.lax.rsqrt(d)  # d^-0.5
-        return jnp.sqrt(t * t * t)  # (d^-1.5)^0.5
+        return jnp.sqrt(t * t * t)
     if beta == 0.5:
-        return jax.lax.rsqrt(d)
+        return t
     if beta == 1.0:
-        return 1.0 / d
+        return t * t
     return jnp.exp(jnp.log(d) * -beta)
 
 
-def _fwd_kernel(x_ref, w_ref, y_ref, d_ref, *, scale, k, beta):
-    x = x_ref[...].astype(jnp.float32)
-    acc = jnp.dot(x * x, w_ref[...], preferred_element_type=jnp.float32)
-    d = k + scale * acc
-    y_ref[...] = (x * _inv_beta(d, beta)).astype(y_ref.dtype)
-    d_ref[...] = d
+def _band(n: int, axis: int, lo: int, hi: int, dtype):
+    """The (n, n) 0/1 band that sums ``t[c+lo .. c+hi]`` along ``axis`` of a
+    2-D tile ``t`` as one product (:func:`_window`)."""
+    src = lax.broadcasted_iota(jnp.int32, (n, n), 1 - axis)
+    out = lax.broadcasted_iota(jnp.int32, (n, n), axis)
+    return ((src - out >= lo) & (src - out <= hi)).astype(dtype)
 
 
-def _fwd_only_kernel(x_ref, w_ref, y_ref, *, scale, k, beta):
-    # primal-only variant: no d residual, so inference pays no extra
-    # f32 HBM write (pallas outputs are opaque to XLA's DCE)
-    x = x_ref[...].astype(jnp.float32)
-    acc = jnp.dot(x * x, w_ref[...], preferred_element_type=jnp.float32)
-    y_ref[...] = (x * _inv_beta(k + scale * acc, beta)).astype(y_ref.dtype)
+def _window(t, band, axis: int):
+    """``sum_{o=lo..hi} t[c+o]`` along ``axis``, zero past either edge, on
+    the MXU: operands in the band's dtype, float32 accumulation."""
+    precision = lax.Precision.HIGHEST if band.dtype == jnp.float32 else None
+    pair = (t.astype(band.dtype), band) if axis else (band, t.astype(band.dtype))
+    return jnp.dot(*pair, precision=precision, preferred_element_type=jnp.float32)
 
 
-def _bwd_kernel(g_ref, x_ref, d_ref, w_ref, dx_ref, *, scale, beta):
-    g = g_ref[...].astype(jnp.float32)
-    x = x_ref[...].astype(jnp.float32)
-    d = d_ref[...]
-    inv = _inv_beta(d, beta)
-    u = g * x * inv / d  # g * x * d^(-beta-1)
-    # adjoint window = the band transposed (identical for odd sizes)
-    wt = jnp.dot(u, w_ref[...].T, preferred_element_type=jnp.float32)
-    dx_ref[...] = (g * inv - (2.0 * scale * beta) * x * wt).astype(
-        dx_ref.dtype
+def _tile_rows(c: int) -> int:
+    """Images a channels-form tile holds: ``_TILE_ELEMS`` float32 at most."""
+    return max(16, _TILE_ELEMS // c // 16 * 16)
+
+
+def _fwd_kernel(x_ref, y_ref, *, orient, a, b, scale, k, beta):
+    """A block of positions, a 2-D tile each (rows independent)."""
+    axis = 0 if orient == "batch" else 1
+    band = _band(x_ref.shape[1 + axis], axis, -a, b, x_ref.dtype)
+
+    def tile(j, carry):
+        x = x_ref[j].astype(jnp.float32)
+        d = k + scale * _window(x * x, band, axis)
+        y_ref[j] = (x * _inv_beta(d, lax.rsqrt(d), beta)).astype(y_ref.dtype)
+        return carry
+
+    lax.fori_loop(0, x_ref.shape[0], tile, 0)
+
+
+def _bwd_kernel(x_ref, g_ref, dx_ref, *, orient, a, b, scale, k, beta):
+    axis = 0 if orient == "batch" else 1
+    c = x_ref.shape[1 + axis]
+    band = _band(c, axis, -a, b, x_ref.dtype)
+    adjoint = _band(c, axis, -b, a, x_ref.dtype)
+
+    def tile(j, carry):
+        x = x_ref[j].astype(jnp.float32)
+        g = g_ref[j].astype(jnp.float32)
+        d = k + scale * _window(x * x, band, axis)
+        t = lax.rsqrt(d)
+        inv = _inv_beta(d, t, beta)
+        u = g * x * (inv * (t * t))  # g * x * d^(-beta-1)
+        wt = _window(u, adjoint, axis)
+        dx_ref[j] = (g * inv - (2.0 * scale * beta) * x * wt).astype(dx_ref.dtype)
+        return carry
+
+    lax.fori_loop(0, x_ref.shape[0], tile, 0)
+
+
+def _blocks(shape, itemsize: int, orient: str):
+    """(grid, block shape) over the oriented view (H*W, C, N) or
+    (H*W, N, C): a 2-D tile of at most ``_TILE_ELEMS`` a position, about
+    ``_BLOCK_BYTES`` of x a block; a last block that runs past the end is
+    read as padding and not written back."""
+    hw, p, q = shape
+    if orient == "batch":
+        tile = (p, next(
+            (t for t in (1024, 512, 256) if q % t == 0 and p * t <= _TILE_ELEMS),
+            _LANES if q % _LANES == 0 else q,
+        ))
+    else:
+        tile = (min(p, _tile_rows(q)), q)
+    rows = max(1, min(hw, _BLOCK_BYTES // (tile[0] * tile[1] * itemsize)))
+    return (pl.cdiv(hw, rows), pl.cdiv(p, tile[0]), pl.cdiv(q, tile[1])), (rows, *tile)
+
+
+def _pallas(kernel, name, orient, geometry, interpret, *operands):
+    """One kernel over an oriented view; the output is shaped as operand 0.
+    ``geometry``: the kernel's a, b, scale, k and beta."""
+    shape = operands[0].shape
+    grid, block = _blocks(shape, operands[0].dtype.itemsize, orient)
+    spec = pl.BlockSpec(block, lambda i, j, l: (i, j, l))
+    return pl.pallas_call(
+        functools.partial(kernel, orient=orient, **geometry),
+        name=name,
+        grid=grid,
+        in_specs=[spec] * len(operands),
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(  # varying as x over a shard_map's axes
+            shape, operands[0].dtype, vma=jax.typeof(operands[0]).vma
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3,
+            vmem_limit_bytes=32 * 1024 * 1024,
+        ),
+        interpret=interpret,
+    )(*operands)
+
+
+def _reference(xo, orient, size, alpha, beta, k):
+    """The layer's ``jax.numpy`` form on an oriented view: a windowed sum
+    along C, temps in x's dtype."""
+    axis = 1 if orient == "batch" else 2
+    window, pad = [1, 1, 1], [(0, 0)] * 3
+    window[axis], pad[axis] = size, (size // 2, size - 1 - size // 2)
+    ssum = lax.reduce_window(jnp.square(xo), 0.0, lax.add, window, (1, 1, 1), pad)
+    return (xo * jnp.power(k + alpha / size * ssum, -beta)).astype(xo.dtype)
+
+
+def _reference_vjp(xo, g, **lrn):
+    return jax.vjp(functools.partial(_reference, **lrn), xo)[1](g)[0]
+
+
+# A Mosaic kernel runs on the shapes it is given: XLA's partitioner cannot
+# split one.  So the choice between a kernel and its ``jax.numpy`` form is
+# made where the program is lowered, from what the lowering knows: one
+# device, or every axis of a ``shard_map`` manual, runs the kernel; a
+# program XLA partitions over several devices (``jit`` with shardings, as
+# the data-parallel solvers build theirs) runs the plain form.
+_per_program = Primitive("lrn")
+_per_program.def_impl(lambda *operands, kernel, plain: kernel(*operands))
+_per_program.def_abstract_eval(lambda *avals, kernel, plain: avals[0])
+
+
+def _whole_program(axis_context) -> bool:
+    """Whether a Mosaic kernel may be lowered here: the condition of jax's
+    own lowering of one (``tpu_custom_call``), which raises otherwise."""
+    manual = getattr(axis_context, "manual_axes", None)
+    if manual is not None:  # inside a shard_map
+        every = set(manual) | set(axis_context.mesh.manual_axes)
+        return not manual or every == set(axis_context.mesh.axis_names)
+    return getattr(axis_context, "num_devices", 1) == 1
+
+
+def _lower(ctx, *operands, kernel, plain):
+    fn = kernel if _whole_program(ctx.module_context.axis_context) else plain
+    return mlir.lower_fun(fn, multiple_results=False)(ctx, *operands)
+
+
+mlir.register_lowering(_per_program, _lower)
+
+
+def _geometry(size, alpha, beta, k):
+    return dict(a=size // 2, b=size - 1 - size // 2, scale=alpha / size, k=k, beta=beta)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
+def _lrn(xo, orient, size, alpha, beta, k, interpret):
+    """LRN over the oriented view ``xo``: (H*W, C, N) for ``"batch"``,
+    (H*W, N, C) for ``"channels"``."""
+    geometry = _geometry(size, alpha, beta, k)
+    return _per_program.bind(
+        xo,
+        kernel=functools.partial(_pallas, _fwd_kernel, "lrn_fwd", orient, geometry, interpret),
+        plain=functools.partial(_reference, orient=orient, size=size, alpha=alpha, beta=beta, k=k),
     )
 
 
-def _tiles(m: int, c: int, block_rows: int) -> Tuple[int, int]:
-    """(padded_rows, block): rows padded up to a whole number of
-    sublane-aligned blocks; the pad rows are dead weight (<1 block).
-
-    The row block shrinks with C to bound VMEM: ~1 MB per f32
-    (block, C) tile keeps x/y/d plus the (C, C) band and Mosaic's
-    double-buffering comfortably inside a v5e's ~16 MB."""
-    vmem_rows = max(8, ((1 << 18) // max(c, 1)) & ~7)  # 256K f32 ≈ 1 MB
-    block = max(8, min(block_rows, vmem_rows, m + (-m % 8)))
-    block += -block % 8
-    return m + (-m % block), block
+def _lrn_fwd(xo, orient, size, alpha, beta, k, interpret):
+    return _lrn(xo, orient, size, alpha, beta, k, interpret), xo
 
 
-@functools.partial(
-    jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6)
-)
-def lrn_pallas(x, size, alpha, beta, k, block_rows=1024, interpret=False):
-    """LRN over the last axis of 2-D ``x`` (rows independent).
-
-    Callers flatten NHWC to (N*H*W, C); use :func:`lrn_nhwc` for the
-    4-D convenience wrapper. Differentiable via the fused backward;
-    the primal (inference) call runs a no-residual kernel."""
-    m, c = x.shape
-    a, b = size // 2, size - 1 - size // 2
-    pm, block = _tiles(m, c, block_rows)
-    if pm != m:
-        x = jnp.pad(x, ((0, pm - m), (0, 0)))
-    kern = functools.partial(
-        _fwd_only_kernel, scale=alpha / size, k=k, beta=beta
+def _lrn_bwd(orient, size, alpha, beta, k, interpret, xo, g):
+    geometry = _geometry(size, alpha, beta, k)
+    dx = _per_program.bind(
+        xo, g.astype(xo.dtype),
+        kernel=functools.partial(_pallas, _bwd_kernel, "lrn_bwd", orient, geometry, interpret),
+        plain=functools.partial(_reference_vjp, orient=orient, size=size, alpha=alpha, beta=beta, k=k),
     )
-    y = pl.pallas_call(
-        kern,
-        grid=(pm // block,),
-        in_specs=[
-            pl.BlockSpec((block, c), lambda i: (i, 0)),
-            pl.BlockSpec((c, c), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block, c), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((pm, c), x.dtype),
-        interpret=interpret,
-    )(x, _band(c, a, b))
-    return y[:m]
+    return (dx,)
 
 
-def _lrn_fwd_impl(x, size, alpha, beta, k, block_rows, interpret):
-    m, c = x.shape
-    a, b = size // 2, size - 1 - size // 2
-    scale = alpha / size
-    pm, block = _tiles(m, c, block_rows)
-    if pm != m:
-        x = jnp.pad(x, ((0, pm - m), (0, 0)))
-    kern = functools.partial(_fwd_kernel, scale=scale, k=k, beta=beta)
-    y, d = pl.pallas_call(
-        kern,
-        grid=(pm // block,),
-        in_specs=[
-            pl.BlockSpec((block, c), lambda i: (i, 0)),
-            pl.BlockSpec((c, c), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block, c), lambda i: (i, 0)),
-            pl.BlockSpec((block, c), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((pm, c), x.dtype),
-            jax.ShapeDtypeStruct((pm, c), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x, _band(c, a, b))
-    return y[:m], (x, d)
-
-
-def _lrn_bwd_impl(size, alpha, beta, k, block_rows, interpret, res, g):
-    xp, d = res  # xp is already row-padded; d matches it
-    pm, c = xp.shape
-    m = g.shape[0]  # true (unpadded) row count, from the cotangent
-    a, b = size // 2, size - 1 - size // 2
-    scale = alpha / size
-    _, block = _tiles(m, c, block_rows)
-    if m != pm:
-        g = jnp.pad(g, ((0, pm - m), (0, 0)))
-    kern = functools.partial(_bwd_kernel, scale=scale, beta=beta)
-    dx = pl.pallas_call(
-        kern,
-        grid=(pm // block,),
-        in_specs=[
-            pl.BlockSpec((block, c), lambda i: (i, 0)),
-            pl.BlockSpec((block, c), lambda i: (i, 0)),
-            pl.BlockSpec((block, c), lambda i: (i, 0)),
-            pl.BlockSpec((c, c), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block, c), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((pm, c), xp.dtype),
-        interpret=interpret,
-    )(g, xp, d, _band(c, a, b))
-    return (dx[:m],)
-
-
-lrn_pallas.defvjp(_lrn_fwd_impl, _lrn_bwd_impl)
+_lrn.defvjp(_lrn_fwd, _lrn_bwd)
 
 
 def lrn_nhwc(x, *, size, alpha, beta, k, interpret=False):
-    """ACROSS_CHANNELS LRN on an NHWC tensor via the fused kernel."""
+    """ACROSS_CHANNELS LRN of an NHWC tensor through the kernels, in the
+    orientation :func:`orientation` gives its shape (the channels form
+    where it gives none); differentiable."""
     n, h, w, c = x.shape
-    flat = x.reshape(n * h * w, c)
-    y = lrn_pallas(flat, size, alpha, beta, k, 1024, interpret)
-    return y.reshape(n, h, w, c)
+    if orientation(x.shape) == "batch":
+        xo = jnp.transpose(x, (1, 2, 3, 0)).reshape(h * w, c, n)
+        y = _lrn(xo, "batch", size, alpha, beta, k, interpret)
+        return jnp.transpose(y.reshape(h, w, c, n), (3, 0, 1, 2))
+    xo = jnp.transpose(x, (1, 2, 0, 3)).reshape(h * w, n, c)
+    y = _lrn(xo, "channels", size, alpha, beta, k, interpret)
+    return jnp.transpose(y.reshape(h, w, n, c), (2, 0, 1, 3))

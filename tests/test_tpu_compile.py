@@ -356,3 +356,163 @@ def test_the_conv_hybrid_step_runs_the_flash_kernels_at_head_64_under_attn_full(
     assert passes("flash_attention_dq") == passes("flash_attention_dkv") == {"backward"}
     assert not any(e.kernel for e in table.values() if e.chain[:1] == ("attn.conv",))
     assert any("conv.short" in e.chain for e in table.values())
+
+
+_LRN = {
+    # NHWC input, dtype: shapes the cell's step does not hold
+    "batch16_norm2": ((16, 27, 27, 256), jnp.bfloat16),  # 16 images a tile
+    "caffenet_float32": ((10, 13, 13, 256), jnp.float32),
+    "googlenet_c192": ((128, 56, 56, 192), jnp.bfloat16),  # batch form, 512 lanes
+    "googlenet_c64_float32": ((128, 28, 28, 64), jnp.float32),
+    "c512": ((256, 27, 27, 512), jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LRN))
+def test_lrn_kernels_compile_for_v5e(case, one_chip, no_compile_cache):
+    """``ops/lrn.py``'s two kernels, forward and backward, at shapes of the
+    zoo's other LRN nets, in both orientations and dtypes."""
+    from sparknet_tpu.ops.lrn import lrn_nhwc
+
+    shape, dtype = _LRN[case]
+
+    def grad(x):
+        y = lambda t: lrn_nhwc(t, size=5, alpha=1e-4, beta=0.75, k=1.0).astype(jnp.float32)
+        return jax.grad(lambda t: jnp.sum(y(t) ** 2))(x)
+
+    text = jax.jit(grad).lower(jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)).compile().as_text()
+    calls = [ln.split(" = ")[0] for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert any("lrn_fwd" in c for c in calls) and any("lrn_bwd" in c for c in calls), calls
+
+
+@pytest.fixture(scope="module")
+def four_chips(one_chip):
+    """The described v5e:2x2's four chips as a ``dp`` mesh."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    return Mesh(np.array(topo.devices), ("dp",))
+
+
+def test_the_lrn_kernels_run_per_shard_in_a_shard_map_and_not_in_a_partitioned_jit(
+    four_chips, no_compile_cache
+):
+    """Over four chips XLA's partitioner cannot split a Mosaic kernel: under
+    ``jit`` with the batch sharded (as ``--parallel sync`` builds AlexNet's
+    step) the LRN compiles to its ``jax.numpy`` form, with no gather of the
+    batch; inside a ``shard_map`` to the two kernels on each shard."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from sparknet_tpu.ops.lrn import lrn_nhwc
+
+    def grad(x):
+        y = lambda t: lrn_nhwc(t, size=5, alpha=1e-4, beta=0.75, k=1.0).astype(jnp.float32)
+        return jax.grad(lambda t: jnp.sum(y(t) ** 2))(x)
+
+    x = jax.ShapeDtypeStruct((512, 27, 27, 96), jnp.bfloat16)
+    sharded = NamedSharding(four_chips, P("dp"))
+    jit = jax.jit(grad, in_shardings=sharded, out_shardings=sharded).lower(x).compile().as_text()
+    assert "tpu_custom_call" not in jit and "all-gather" not in jit
+    per_shard = jax.jit(
+        jax.shard_map(grad, mesh=four_chips, in_specs=P("dp"), out_specs=P("dp"))
+    ).lower(x).compile().as_text()
+    calls = [ln.split(" = ")[0] for ln in per_shard.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 2 and any("lrn_fwd" in c for c in calls) and any("lrn_bwd" in c for c in calls)
+
+
+_NORM1, _NORM2 = 1024 * 55 * 55 * 96, 1024 * 27 * 27 * 256  # alexnet_live's LRN inputs
+
+
+@pytest.fixture(scope="module")
+def alexnet_steps(one_chip):
+    """``alexnet_live``'s step — the Solver's own program through
+    ``lower_step``, batch 1024, bfloat16, with the Solver's compiler option
+    — compiled for v5e twice: ``{"kernels": text, "jax_numpy": text}``, the
+    LRN layers steered to the Pallas kernels and to the ``jax.numpy`` form
+    (on this backend the rule picks the second by itself).  Module-scoped:
+    two whole-step compiles, read by two tests."""
+    import functools
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from sparknet_tpu.apps.imagenet_app import ZOO
+    from sparknet_tpu.nets import layers
+    from sparknet_tpu.ops import lrn
+    from sparknet_tpu.proto import caffe_pb
+    from sparknet_tpu.solver.trainer import Solver
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    shapes = {"data": (1024, 227, 227, 3), "label": (1024,)}
+    solver = Solver(
+        caffe_pb.load_solver(f"{ZOO}/bvlc_alexnet_solver.prototxt"), shapes,
+        net_param=caffe_pb.load_net(f"{ZOO}/bvlc_alexnet_train_val.prototxt"),
+        test_input_shapes={"data": (2, 227, 227, 3), "label": (2,)},
+        compute_dtype=jnp.bfloat16,
+    )
+    put = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    solver.params, solver.state, solver.opt_state, solver.rng = map(
+        put, (solver.params, solver.state, solver.opt_state, solver.rng)
+    )
+    batch = put({k: jax.ShapeDtypeStruct(v, jnp.float32 if k == "data" else jnp.int32)
+                 for k, v in shapes.items()})
+    texts = {}
+    with pytest.MonkeyPatch.context() as mp:
+        for form, force in (("kernels", "flash"), ("jax_numpy", "reference")):
+            mp.setattr(layers, "uses_lrn_kernel", functools.partial(lrn.uses_lrn_kernel, force=force))
+            jax.clear_caches()  # the step traces again under the other rule
+            texts[form] = solver.lower_step(batch).compile(
+                # trainer._step_compiler_options' on a TPU backend
+                compiler_options={"xla_tpu_scoped_vmem_limit_kib": "32768"}
+            ).as_text()
+    yield texts
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def test_the_lrn_kernels_sit_under_lrn_norm1_and_norm2_both_ways(alexnet_steps):
+    """In ``alexnet_live``'s step the forward kernel sits under each LRN
+    layer's scope in the forward pass and the backward kernel in the
+    backward pass, and no other kernel is in the step; the ``jax.numpy``
+    form's step holds none."""
+    from sparknet_tpu.utils import profiling
+
+    table = profiling.scope_table(alexnet_steps["kernels"], profiling.declared_scopes())
+    kernels = sorted(
+        (e.chain, e.pass_, "lrn_fwd" if "lrn_fwd" in n else "lrn_bwd" if "lrn_bwd" in n else n)
+        for n, e in table.items() if e.kernel
+    )
+    assert kernels == [
+        (("lrn.norm1",), "backward", "lrn_bwd"), (("lrn.norm1",), "forward", "lrn_fwd"),
+        (("lrn.norm2",), "backward", "lrn_bwd"), (("lrn.norm2",), "forward", "lrn_fwd"),
+    ]
+    assert "tpu_custom_call" not in alexnet_steps["jax_numpy"]
+
+
+def test_the_lrn_kernels_add_no_copy_of_an_lrn_sized_tensor(alexnet_steps):
+    """The step holds no more ``copy`` or ``transpose`` instructions on a
+    tensor of norm1's or norm2's number of elements, in any shape, than the
+    ``jax.numpy`` form's step (three, all of norm2; the kernels' step keeps
+    two of norm2's, where conv2 gives the tensor batch-minor and pool2 takes
+    it channel-minor), none of norm1's (read as (N*H*W, C) rows it would add
+    one), and every convolution keeps its layout."""
+    import math
+    import re
+
+    def moves(text):
+        shapes = re.findall(r" = \w+\[([\d,]+)\]\{[^}]*\} (?:copy|transpose)\(", text)
+        sizes = [math.prod(int(d) for d in s.split(",")) for s in shapes]
+        return sorted(n for n in sizes if n in (_NORM1, _NORM2))
+
+    def convolutions(text):
+        return sorted(re.findall(r" = (\w+\[[\d,]+\]\{[^}]*\}) convolution\(", text))
+
+    kernels, jax_numpy = alexnet_steps["kernels"], alexnet_steps["jax_numpy"]
+    assert len(moves(kernels)) <= len(moves(jax_numpy)), (moves(kernels), moves(jax_numpy))
+    assert _NORM1 not in moves(kernels)
+    assert convolutions(kernels) == convolutions(jax_numpy)
