@@ -478,7 +478,7 @@ def main(argv=None) -> Dict[str, float]:
     tiles = flash_tiles_note(dict(zip(
         ("unmasked", "masked"),
         flash_tile_kinds(seq, seq, causal=False, key_mask=True),
-    )) if uses_flash(args.attention or None, cfg.attention_dropout > 0) else {})
+    )) if uses_flash(args.attention or None) else {})
     primary = multihost.is_primary()
     if primary:
         if args.restore:

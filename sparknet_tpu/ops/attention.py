@@ -31,9 +31,13 @@ for the BERT family and the long-context path:
   at the block holding its last row's document end), from two small
   tables a call; inside a tile a key is seen while the query is not past
   the end of the key's document.
-  With ``window=None``, equal head counts and no ``segment_ids`` the
-  traced kernels are the plain ones.  Per score tile the forward does
-  its two products, the
+  One set of three kernels (forward, dq, dkv) and one custom VJP serve
+  every call: the band is the whole key axis where no causal mask,
+  window or document bounds it (the kernels then guard no step), the
+  group is 1 where every query head has its own KV head, and the
+  dropout rate is 0 where none was asked (dropout runs at group 1
+  without a window or documents).  Per score tile the forward does its
+  two products, the
   masks that apply (the key mask only if one was passed or keys were
   padded; the position mask on every tile of a causal call), one max and
   one sum across lanes, two ``exp``, and keeps its running max and sum
@@ -51,7 +55,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 from typing import Optional
 
 import jax
@@ -261,7 +264,7 @@ def flash_tile_kinds(
     tile: two bodies, one for tiles wholly inside the band, measured
     slower — PERF.md §6, PR 32) and of a call that passed a key mask or
     padded its keys; none of any other call.  A q block that sees no key
-    at all counts one tile, which the banded kernels walk.  For a call
+    at all counts one tile, which the kernels walk.  For a call
     with ``segment_ids`` this is the most it executes: what the documents
     of a batch leave of it is :func:`flash_tiles_documents`."""
     pad_q, pad_k, blk_q, blk_k = _resolve_blocks(sq, sk, block_q, block_k)
@@ -296,7 +299,7 @@ def document_spans(segment_ids: jax.Array):
 
 
 def _document_tables(segment_ids, rows_q, rows_k, blk_q, blk_k):
-    """What the banded kernels take for packed documents, from
+    """What the kernels take for packed documents, from
     ``segment_ids`` (B, S) with S <= the padded lengths ``rows_q``,
     ``rows_k`` (padding is a document of its own): the keys' document
     ends, sublane-broadcast (B, 8, rows_k) as the key mask is; the first
@@ -423,39 +426,65 @@ def _dropout_keep(seed, rate, head_id, qi, kb, blk_q, blk_k):
     return bits.astype(jnp.int32) >= jnp.int32(threshold)
 
 
+def _whole(first, last) -> bool:
+    """Whether ``_band`` gave the whole axis: Python ints, as it does
+    where no causal mask, window or document table bounds a block (the
+    band then starts at block 0)."""
+    return isinstance(first, int) and isinstance(last, int)
+
+
+def _band_block(first, step, last):
+    """The block grid step ``step`` of a band addresses: ``first + step``,
+    held at ``last`` past the band's end (a block already fetched, so not
+    fetched again)."""
+    return step if _whole(first, last) else jnp.minimum(first + step, last)
+
+
+def _in_band(block, first, last, compute) -> None:
+    """Run ``compute`` on a grid step whose ``block`` lies in the band
+    ``first .. last``; a step past the band's end (above the diagonal,
+    past a window or a document, or past the last block) computes
+    nothing.  The whole axis needs no guard."""
+    if _whole(first, last):
+        compute()
+    else:
+        pl.when(block <= last)(compute)
+
+
 def _fwd_kernel(
-    off_ref,  # SMEM (3,): [q_offset, kv_offset, dropout_seed]
-    q_ref,    # (1, F, blk_q, d) — F heads folded per grid step
-    k_ref,    # (1, F, blk_k, d)   — streamed over the last grid dim
-    v_ref,    # (1, F, blk_k, d)
+    off_ref,  # SMEM (3,), scalar-prefetched: [q_offset, kv_offset, seed]
+    q_ref,    # (1, F, blk_q, d) — F query heads folded per grid step
+    k_ref,    # (1, F_kv, blk_k, d) — streamed over the last grid dim
+    v_ref,    # (1, F_kv, blk_k, dv)
     m_ref,    # (1, 8, blk_k) int8 kv mask block (sublane-broadcast: TPU
               # requires >=8 sublanes per block; head-independent), or
               # None: the caller passed no mask and padded no key
-    o_ref,    # (1, F, blk_q, d)
+    o_ref,    # (1, F, blk_q, dv)
     lse_ref,  # (1, F, blk_q, 128) f32, lane-replicated
-    acc_s,    # VMEM (F, blk_q, d) f32 — running numerator per head
+    acc_s,    # VMEM (F, blk_q, dv) f32 — running numerator per head
     m_s,      # VMEM (F, blk_q, 128) f32 — running max (lane-replicated)
     l_s,      # VMEM (F, blk_q, 128) f32 — running denominator (likewise)
     *,
     causal: bool,
     scale: float,
     nkb: int,
+    total_kb: int,
     dropout_rate: float,
     fold: int,
-    window: Optional[int] = None,
-    kv_fold: Optional[int] = None,
-    total_kb: Optional[int] = None,
+    kv_fold: int,
+    window: Optional[int],
     doc_refs=None,
 ):
-    """``nkb`` is the length of the last grid axis.  Plain calls walk
-    every key block; a banded call (``total_kb`` given: a window or
-    grouped KV heads) walks the ``nkb`` blocks from the first one its q
-    block can see, of ``total_kb``.  ``kv_fold`` KV heads arrive per
-    step (``fold`` where every query head has its own).  ``doc_refs``
-    (packed documents; ``_document_tables``): the scalar-prefetched first
-    key block of each (batch row, q block), which tightens the band, and
-    this step's (1, 8, blk_k) block of the keys' document ends, which
-    masks inside a tile.
+    """Grid (b, H/F, nq, ``nkb``): the last axis walks the ``nkb`` key
+    blocks, of ``total_kb``, from the first one q block ``qi`` can see
+    (``_band``: all of them where no causal mask, window or document
+    bounds it).  ``kv_fold`` KV heads arrive per step: ``fold`` where
+    every query head has its own, else the one the step's ``fold`` query
+    heads of one group read.  ``doc_refs`` (packed documents;
+    ``_document_tables``): the scalar-prefetched first key block of each
+    (batch row, q block), which tightens the band, and this step's
+    (1, 8, blk_k) block of the keys' document ends, which masks inside a
+    tile.
 
     What a score tile costs besides its two products sets the kernel's
     pace, and what cost most was the shape of ``m`` and ``l`` (PERF.md
@@ -471,16 +500,12 @@ def _fwd_kernel(
     blk_k = k_ref.shape[2]
     q_offset = off_ref[0]
     kv_offset = off_ref[1]
-    kv_fold = fold if kv_fold is None else kv_fold
-    if total_kb is None:
-        kb = step
-    else:
-        first_kb, last_kb = _band(
-            qi, q_offset - kv_offset, blk_q, blk_k, total_kb,
-            None if window is None else window - 1, 0 if causal else None,
-            doc_lo=_table_entry(doc_refs, qi),
-        )
-        kb = first_kb + step
+    first_kb, last_kb = _band(
+        qi, q_offset - kv_offset, blk_q, blk_k, total_kb,
+        None if window is None else window - 1, 0 if causal else None,
+        doc_lo=_table_entry(doc_refs, qi),
+    )
+    kb = first_kb + step
 
     @pl.when(step == 0)
     def _init():
@@ -524,24 +549,7 @@ def _fwd_kernel(
                 preferred_element_type=jnp.float32,
             )
 
-    if total_kb is not None:
-        # a step past the band's end (above the diagonal, or past the
-        # last block) re-addresses the band's last block, which is not
-        # fetched again, and computes nothing
-        @pl.when(kb <= last_kb)
-        def _():
-            compute()
-    elif causal:
-        # blocks fully above the diagonal contribute nothing: skip the
-        # matmuls (state simply persists to the next grid step)
-        last_q = qi * blk_q + blk_q - 1 + q_offset
-        first_k = kb * blk_k + kv_offset
-
-        @pl.when(first_k <= last_q)
-        def _():
-            compute()
-    else:
-        compute()
+    _in_band(kb, first_kb, last_kb, compute)
 
     @pl.when(step == nkb - 1)
     def _finalize():
@@ -566,30 +574,24 @@ def _fwd_kernel(
 
 def _bwd_dq_kernel(
     off_ref, q_ref, k_ref, v_ref, m_ref, do_ref, lse_ref, delta_ref,
-    dq_ref, dq_s, *, causal: bool, scale: float, nkb: int,
-    dropout_rate: float, fold: int, window: Optional[int] = None,
-    kv_fold: Optional[int] = None, total_kb: Optional[int] = None,
+    dq_ref, dq_s, *, causal: bool, scale: float, nkb: int, total_kb: int,
+    dropout_rate: float, fold: int, kv_fold: int, window: Optional[int],
     doc_refs=None,
 ):
-    """Grid (b, h/F, nq, nk): K/V stream over the last dim, dq (per
-    folded head) accumulates in VMEM scratch, written on the final k
-    step.  ``window``/``kv_fold``/``total_kb``/``doc_refs`` as in the
-    forward."""
+    """Grid (b, H/F, nq, ``nkb``): K/V stream over the forward's band of
+    key blocks, dq (per folded head) accumulates in VMEM scratch, written
+    on the final step.  The keywords as in the forward."""
     qi = pl.program_id(2)
     step = pl.program_id(3)
     blk_q = q_ref.shape[2]
     blk_k = k_ref.shape[2]
     q_offset, kv_offset = off_ref[0], off_ref[1]
-    kv_fold = fold if kv_fold is None else kv_fold
-    if total_kb is None:
-        kb = step
-    else:
-        first_kb, last_kb = _band(
-            qi, q_offset - kv_offset, blk_q, blk_k, total_kb,
-            None if window is None else window - 1, 0 if causal else None,
-            doc_lo=_table_entry(doc_refs, qi),
-        )
-        kb = first_kb + step
+    first_kb, last_kb = _band(
+        qi, q_offset - kv_offset, blk_q, blk_k, total_kb,
+        None if window is None else window - 1, 0 if causal else None,
+        doc_lo=_table_entry(doc_refs, qi),
+    )
+    kb = first_kb + step
 
     @pl.when(step == 0)
     def _init():
@@ -633,19 +635,7 @@ def _bwd_dq_kernel(
                 preferred_element_type=jnp.float32,
             )
 
-    if total_kb is not None:
-        @pl.when(kb <= last_kb)
-        def _():
-            compute()
-    elif causal:
-        last_q = qi * blk_q + blk_q - 1 + q_offset
-        first_k = kb * blk_k + kv_offset
-
-        @pl.when(first_k <= last_q)
-        def _():
-            compute()
-    else:
-        compute()
+    _in_band(kb, first_kb, last_kb, compute)
 
     @pl.when(step == nkb - 1)
     def _finalize():
@@ -656,34 +646,27 @@ def _bwd_dq_kernel(
 def _bwd_dkv_kernel(
     off_ref, q_ref, k_ref, v_ref, m_ref, do_ref, lse_ref, delta_ref,
     dk_ref, dv_ref, dk_s, dv_s, *, causal: bool, scale: float, nqb: int,
-    dropout_rate: float, fold: int, window: Optional[int] = None,
-    kv_fold: Optional[int] = None, total_qb: Optional[int] = None,
-    band_qb: Optional[int] = None, doc_refs=None,
+    total_qb: int, band_qb: int, dropout_rate: float, fold: int,
+    kv_fold: int, window: Optional[int], doc_refs=None,
 ):
-    """Grid (b, h/F, nk, nq): Q/dO/lse/delta stream over the last dim,
-    dk/dv (per folded head) accumulate in VMEM scratch, written once on
-    the final q step.  A banded call (``total_qb`` given) has the grid
-    (b, H_kv/kv_fold, nk, R * band_qb): the last axis walks, for each of
-    the R blocks of ``fold`` query heads that read these KV heads, the
-    ``band_qb`` q blocks from the first that can see this key block, so
-    dk/dv come out summed over the group; ``nqb`` is that axis' length.
-    ``doc_refs``: the scalar-prefetched last q block of each (batch row,
-    key block) and this key block's document ends."""
+    """Grid (b, H_kv/kv_fold, nk, ``nqb`` = R * ``band_qb``): the last
+    axis walks, for each of the R blocks of ``fold`` query heads that read
+    these KV heads, the ``band_qb`` q blocks, of ``total_qb``, from the
+    first that can see this key block; Q/dO/lse/delta stream over it and
+    dk/dv accumulate in VMEM scratch, summed over the group, written once
+    on the final step.  ``doc_refs``: the scalar-prefetched last q block
+    of each (batch row, key block) and this key block's document ends."""
     ki = pl.program_id(2)
     step = pl.program_id(3)
     blk_k = k_ref.shape[2]
     blk_q = q_ref.shape[2]
     q_offset, kv_offset = off_ref[0], off_ref[1]
-    kv_fold = fold if kv_fold is None else kv_fold
-    if total_qb is None:
-        qb = step
-    else:
-        first_qb, last_qb = _band(
-            ki, kv_offset - q_offset, blk_k, blk_q, total_qb,
-            0 if causal else None, None if window is None else window - 1,
-            doc_hi=_table_entry(doc_refs, ki),
-        )
-        qb = first_qb + step % band_qb
+    first_qb, last_qb = _band(
+        ki, kv_offset - q_offset, blk_k, blk_q, total_qb,
+        0 if causal else None, None if window is None else window - 1,
+        doc_hi=_table_entry(doc_refs, ki),
+    )
+    qb = first_qb + step % band_qb
 
     @pl.when(step == 0)
     def _init():
@@ -715,7 +698,9 @@ def _bwd_dkv_kernel(
             )  # (blk_q, blk_k)
             if dropout_rate > 0.0:
                 # mask coordinates are (q-block, k-block) — matches
-                # fwd/dq; head id is absolute, fold-invariant
+                # fwd/dq; the head id is absolute and fold-invariant,
+                # right at one query head a KV head (R = 1), the only
+                # group dropout runs at
                 keep = _dropout_keep(
                     off_ref[2], dropout_rate,
                     pl.program_id(1) * fold + hh, qb, ki, blk_q, blk_k,
@@ -739,20 +724,7 @@ def _bwd_dkv_kernel(
                 preferred_element_type=jnp.float32,
             )
 
-    if total_qb is not None:
-        @pl.when(qb <= last_qb)
-        def _():
-            compute()
-    elif causal:
-        # q blocks fully before the diagonal can't see this k block
-        last_q = qb * blk_q + blk_q - 1 + q_offset
-        first_k = ki * blk_k + kv_offset
-
-        @pl.when(first_k <= last_q)
-        def _():
-            compute()
-    else:
-        compute()
+    _in_band(qb, first_qb, last_qb, compute)
 
     @pl.when(step == nqb - 1)
     def _finalize():
@@ -784,23 +756,22 @@ def _without_mask(kernel, in_specs, args):
     """(kernel, in_specs, args) of a call whose ``args[4]``, the key mask,
     is None — nothing was passed and no key was padded: the mask's spec
     and argument go, and the kernel finds None in its ``m_ref``'s place
-    and applies no key mask.  ``in_specs`` of a banded call start after
-    the scalar-prefetched offsets."""
+    and applies no key mask.  ``in_specs`` start after the
+    scalar-prefetched offsets, ``args[0]``."""
     if args[4] is not None:
         return kernel, in_specs, args
-    at = 4 - (len(args) - len(in_specs))
     return (
         lambda *refs, **kw: kernel(*refs[:4], None, *refs[4:], **kw),
-        in_specs[:at] + in_specs[at + 1:],
+        in_specs[:3] + in_specs[4:],
         args[:4] + args[5:],
     )
 
 
 def _with_docs(kernel, in_specs, args, table, doc_end, end_spec):
-    """(kernel, in_specs, args, scalar prefetches) of a banded call: as
-    given and 1 without documents; with them ``table`` (a flat int32 bound
-    per batch row and block) is prefetched beside the offsets, ``doc_end``
-    is the last input, and the kernel finds both in its ``doc_refs``."""
+    """(kernel, in_specs, args, scalar prefetches) of a call: as given
+    and 1 without documents; with them ``table`` (a flat int32 bound per
+    batch row and block) is prefetched beside the offsets, ``doc_end`` is
+    the last input, and the kernel finds both in its ``doc_refs``."""
     if table is None:
         return kernel, in_specs, args, 1
     n = len(args) - 1  # the inputs after the offsets
@@ -814,21 +785,20 @@ def _with_docs(kernel, in_specs, args, table, doc_end, end_spec):
     )
 
 
-def _fold_heads(h: int, blk_q: int, blk_k: int, d: int) -> int:
-    """Heads folded per grid step (the F in the kernels' (1, F, blk, d)
-    blocks). Folding amortises per-grid-step overhead — the round-5
-    fwd prototype measured ~20 % off wall-clock at BERT shapes — but
-    every folded head multiplies the VMEM working set, so F is the
-    largest divisor of ``h`` whose estimated footprint (double-buffered
-    in AND out blocks + f32 scratch + lse/delta) fits a 14 MB budget
-    (F=4 at BERT shapes ≈ 13.6 MB, compile- and bench-validated on
-    v5e; the margin to the 16 MB VMEM is thin by design — Mosaic's own
-    accounting rejects anything the estimate misses at compile time,
-    not at runtime). SPARKNET_FLASH_FOLD=1 pins F=1 (the pre-fold
-    layout); consulted at trace time — see ``flash_attention(fold=)``
-    for a jit-cache-honest override."""
-    if os.environ.get("SPARKNET_FLASH_FOLD", "") == "1":
-        return 1
+def _fold_heads(h, group, blk_q, blk_k, d):
+    """(fold, kv_fold, R): query heads and KV heads per grid step (the F
+    and F_kv in the kernels' (1, F, blk, d) blocks), and the blocks of
+    ``fold`` query heads per block of KV heads.  Grouped heads fold within
+    one group, so that a step reads one KV head; heads with a KV head each
+    fold together.  Folding amortises per-grid-step overhead — the
+    round-5 fwd prototype measured ~20 % off wall-clock at BERT shapes —
+    but every folded head multiplies the VMEM working set, so F is the
+    largest divisor of ``h`` (of ``group``) whose estimated footprint
+    (double-buffered in AND out blocks + f32 scratch + lse/delta) fits a
+    14 MB budget (F=4 at BERT shapes ≈ 13.6 MB, compile- and
+    bench-validated on v5e; the margin to the 16 MB VMEM is thin by design
+    — Mosaic's own accounting rejects anything the estimate misses at
+    compile time, not at runtime)."""
     per = (
         2 * 2 * (2 * blk_q * d + 2 * blk_k * d)   # bf16 q/do + k/v, 2x buf
         + 2 * 2 * 4 * blk_q * 128                 # f32 lse+delta in, 2x buf
@@ -838,152 +808,24 @@ def _fold_heads(h: int, blk_q: int, blk_k: int, d: int) -> int:
         + 2 * 2 * (blk_q * d + 2 * blk_k * d)
         + 2 * 4 * blk_q * 128
     )
-    f = max(1, (14 * 2**20) // per)
-    while h % f:
-        f -= 1
-    return f
-
-
-def _qk_specs(blk_q, blk_k, d, dv, fold):
-    """in_specs for (offsets, q, k, v, mask) on a (b, h/F, nq, nk)
-    grid: q indexed by the q-block dim, k/v/mask streamed over the
-    k-block dim, F heads per step; q and k are ``d`` wide, v ``dv``. The
-    kv mask arrives sublane-broadcast as (b, 8, sk) and is
-    head-independent."""
-    return [
-        pl.BlockSpec(memory_space=pltpu.SMEM),  # offsets (3,)
-        pl.BlockSpec(
-            (1, fold, blk_q, d), lambda b_, g, i, j: (b_, g, i, 0)
-        ),
-        pl.BlockSpec(
-            (1, fold, blk_k, d), lambda b_, g, i, j: (b_, g, j, 0)
-        ),
-        pl.BlockSpec(
-            (1, fold, blk_k, dv), lambda b_, g, i, j: (b_, g, j, 0)
-        ),
-        pl.BlockSpec((1, 8, blk_k), lambda b_, g, i, j: (b_, 0, j)),
-    ]
-
-
-def _banded_fold(fold, h, group, blk_q, blk_k, d):
-    """(fold, kv_fold, R) of a banded call: query heads and KV heads per
-    grid step, and the blocks of ``fold`` query heads per block of KV
-    heads.  Grouped heads fold within one group, so that a step reads
-    one KV head; plain heads fold as ever."""
-    if fold is None:
-        fold = _fold_heads(h if group == 1 else group, blk_q, blk_k, d)
+    heads = h if group == 1 else group
+    fold = max(1, (14 * 2**20) // per)
+    while heads % fold:
+        fold -= 1
     if group == 1:
         return fold, fold, 1
-    if group % fold:
-        raise ValueError(
-            f"fold ({fold}) must divide the query heads per KV head ({group})"
-        )
     return fold, 1, group // fold
 
 
-def _flash_fwd(
-    q, k, v, kv_mask, offsets, causal, scale, blk_q, blk_k, interpret,
-    dropout_rate, fold=None, band=None, shift=None, docs=None,
-):
-    """``band`` = (window, group) makes the call banded (see the module
-    header): scalar-prefetched offsets, a last grid axis over the band of
-    key blocks, K/V addressed by group.  ``shift`` is ``q_offset -
-    kv_offset`` where both are Python ints (an exact band), else None.
-    ``docs`` (``_document_tables``, banded calls only): packed documents."""
-    b, h, sq, d = q.shape
-    sk, dv = k.shape[2], v.shape[3]
-    nkb = sk // blk_k
-    out_shape = [
-        jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
-        # lane-replicated: TPU blocks need a 128-lane trailing dim
-        jax.ShapeDtypeStruct((b, h, sq, 128), jnp.float32),
-    ]
-    if band is None:
-        if fold is None:
-            fold = _fold_heads(h, blk_q, blk_k, max(d, dv))
-        grid = (b, h // fold, sq // blk_q, nkb)
-        kernel, in_specs, args = _without_mask(
-            functools.partial(
-                _fwd_kernel, causal=causal, scale=scale, nkb=nkb,
-                dropout_rate=dropout_rate, fold=fold,
-            ),
-            _qk_specs(blk_q, blk_k, d, dv, fold),
-            (offsets, q, k, v, kv_mask),
-        )
-        out, lse = pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec(
-                    (1, fold, blk_q, dv), lambda b_, g, i, j: (b_, g, i, 0)
-                ),
-                pl.BlockSpec(
-                    (1, fold, blk_q, 128), lambda b_, g, i, j: (b_, g, i, 0)
-                ),
-            ],
-            out_shape=out_shape,
-            scratch_shapes=[
-                pltpu.VMEM((fold, blk_q, dv), jnp.float32),
-                pltpu.VMEM((fold, blk_q, 128), jnp.float32),
-                pltpu.VMEM((fold, blk_q, 128), jnp.float32),
-            ],
-            name="flash_attention_fwd",
-            **_params(interpret),
-        )(*args)
-        return out, lse
-
-    window, group = band
-    fold, kv_fold, _ = _banded_fold(fold, h, group, blk_q, blk_k, max(d, dv))
-    nqb = sq // blk_q
-    back, fwd = (None if window is None else window - 1), (0 if causal else None)
-    steps = _band_steps(nqb, shift, blk_q, blk_k, nkb, back, fwd)
-    in_specs, _, o_spec, lane_spec = _banded_qk_specs(
-        blk_q, blk_k, d, dv, fold, kv_fold, group, nkb, back, fwd, nqb
-    )
-    end_spec = in_specs[3]  # a row a key block, as the key mask
-    kernel, in_specs, args = _without_mask(
-        functools.partial(
-            _fwd_kernel, causal=causal, scale=scale, nkb=steps,
-            dropout_rate=dropout_rate, fold=fold, window=window,
-            kv_fold=kv_fold, total_kb=nkb,
-        ),
-        in_specs,
-        (offsets, q, k, v, kv_mask),
-    )
-    doc_end, first_kb, _ = docs or (None, None, None)
-    kernel, in_specs, args, prefetched = _with_docs(
-        kernel, in_specs, args, first_kb, doc_end, end_spec
-    )
-    out, lse = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=prefetched,
-            grid=(b, h // fold, nqb, steps),
-            in_specs=in_specs,
-            out_specs=[o_spec, lane_spec],
-            scratch_shapes=[
-                pltpu.VMEM((fold, blk_q, dv), jnp.float32),
-                pltpu.VMEM((fold, blk_q, 128), jnp.float32),
-                pltpu.VMEM((fold, blk_q, 128), jnp.float32),
-            ],
-        ),
-        out_shape=out_shape,
-        name="flash_attention_fwd",
-        **_params(interpret),
-    )(*args)
-    return out, lse
-
-
-def _banded_qk_specs(
+def _band_specs(
     blk_q, blk_k, d, dv, fold, kv_fold, group, nkb, back, fwd, nqb
 ):
     """(in_specs for (q, k, v, mask), the q-shaped spec, the o-shaped
-    spec, the lane-replicated spec) of a banded call on a (b, h/F, nq,
-    band) grid; what is scalar-prefetched (``pre``: the offsets and, with
-    documents, the flat (b, ``nqb``) table of first key blocks) arrives
-    last in every index map.  Step j of q block i addresses key block
-    ``min(first + j, last)``."""
+    spec, the lane-replicated spec) on a (b, h/F, nq, band) grid; what is
+    scalar-prefetched (``pre``: the offsets and, with documents, the flat
+    (b, ``nqb``) table of first key blocks) arrives last in every index
+    map.  Step j of q block i addresses key block ``min(first + j,
+    last)``."""
 
     def kv_block(b_, i, j, pre):
         off = pre[0]
@@ -991,7 +833,7 @@ def _banded_qk_specs(
             i, off[0] - off[1], blk_q, blk_k, nkb, back, fwd,
             doc_lo=pre[1][b_ * nqb + i] if len(pre) > 1 else None,
         )
-        return jnp.minimum(first + j, last)
+        return _band_block(first, j, last)
 
     q_rows = lambda width: pl.BlockSpec(
         (1, fold, blk_q, width), lambda b_, g, i, j, *pre: (b_, g, i, 0)
@@ -1013,185 +855,85 @@ def _banded_qk_specs(
     )
 
 
-@functools.partial(
-    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11)
-)
-def _flash(
-    q, k, v, kv_mask, offsets, causal, scale, blk_q, blk_k, interpret,
-    dropout_rate, fold,
+def _flash_fwd(
+    q, k, v, kv_mask, offsets, docs, causal, scale, blk_q, blk_k, interpret,
+    dropout_rate, band, shift,
 ):
-    out, _ = _flash_fwd(
-        q, k, v, kv_mask, offsets, causal, scale, blk_q, blk_k, interpret,
-        dropout_rate, fold=fold,
-    )
-    return out
-
-
-def _flash_vjp_fwd(
-    q, k, v, kv_mask, offsets, causal, scale, blk_q, blk_k, interpret,
-    dropout_rate, fold,
-):
-    out, lse = _flash_fwd(
-        q, k, v, kv_mask, offsets, causal, scale, blk_q, blk_k, interpret,
-        dropout_rate, fold=fold,
-    )
-    # residual keeps one lane of the lane-replicated lse — 1/128th the
-    # HBM; the backward broadcasts it back transiently (like delta)
-    return out, (q, k, v, kv_mask, offsets, out, lse[..., 0])
-
-
-def _flash_vjp_bwd(
-    causal, scale, blk_q, blk_k, interpret, dropout_rate, fold, res, do,
-    band=None, shift=None, docs=None,
-):
-    q, k, v, kv_mask, offsets, out, lse = res
-    b, h, sq, _ = q.shape
-    lse = jnp.broadcast_to(lse[..., None], (b, h, sq, 128))
-    delta = jnp.broadcast_to(
-        jnp.sum(
-            do.astype(jnp.float32) * out.astype(jnp.float32),
-            axis=-1, keepdims=True,
-        ),
-        (b, h, sq, 128),
-    )  # lane-replicated, same layout as lse
-    dq, dk, dv = _flash_bwd(
-        q, k, v, kv_mask, offsets, do, lse, delta, causal=causal,
-        scale=scale, blk_q=blk_q, blk_k=blk_k, interpret=interpret,
-        dropout_rate=dropout_rate, fold=fold, band=band, shift=shift,
-        docs=docs,
-    )
-    return dq, dk, dv, None, None
-
-
-def _flash_bwd(
-    q, k, v, kv_mask, offsets, do, lse, delta, *, causal, scale,
-    blk_q, blk_k, interpret, dropout_rate, fold=None, band=None, shift=None,
-    docs=None,
-):
-    """The two backward pallas calls, reusable per ring block: ``lse``
-    and ``delta`` arrive lane-replicated (b, h, sq, 128) and may be the
-    GLOBAL (ring-merged) values — p = exp(s - lse) then yields the
-    exact global softmax probabilities for this kv block, which is what
-    makes flash-per-block ring backward exact."""
-    b, h, sq, d = q.shape
-    sk, dv = k.shape[2], v.shape[3]
-    nqb, nkb = sq // blk_q, sk // blk_k
-    if band is not None:
-        return _flash_bwd_banded(
-            q, k, v, kv_mask, offsets, do, lse, delta, causal=causal,
-            scale=scale, blk_q=blk_q, blk_k=blk_k, interpret=interpret,
-            dropout_rate=dropout_rate, fold=fold, band=band, shift=shift,
-            docs=docs,
-        )
-    if fold is None:
-        fold = _fold_heads(h, blk_q, blk_k, max(d, dv))
-
-    # dq: grid (b, h/F, nq, nk) — K/V streamed, dq carried in scratch
-    dq_specs = _qk_specs(blk_q, blk_k, d, dv, fold) + [
-        pl.BlockSpec(
-            (1, fold, blk_q, dv), lambda b_, g, i, j: (b_, g, i, 0)
-        ),  # do
-        pl.BlockSpec(
-            (1, fold, blk_q, 128), lambda b_, g, i, j: (b_, g, i, 0)
-        ),  # lse
-        pl.BlockSpec(
-            (1, fold, blk_q, 128), lambda b_, g, i, j: (b_, g, i, 0)
-        ),  # delta
-    ]
-    args = (offsets, q, k, v, kv_mask, do, lse, delta)
-    kernel, dq_specs, dq_args = _without_mask(
-        functools.partial(
-            _bwd_dq_kernel, causal=causal, scale=scale, nkb=nkb,
-            dropout_rate=dropout_rate, fold=fold,
-        ),
-        dq_specs, args,
-    )
-    dq = pl.pallas_call(
-        kernel,
-        grid=(b, h // fold, nqb, nkb),
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec(
-            (1, fold, blk_q, d), lambda b_, g, i, j: (b_, g, i, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((fold, blk_q, d), jnp.float32)],
-        name="flash_attention_dq",
-        **_params(interpret),
-    )(*dq_args)
-
-    # dkv: grid (b, h/F, nk, nq) — q/do/lse/delta streamed over q
-    # blocks, dk/dv carried in scratch
-    dkv_specs = [
-        pl.BlockSpec(memory_space=pltpu.SMEM),
-        pl.BlockSpec(
-            (1, fold, blk_q, d), lambda b_, g, i, j: (b_, g, j, 0)
-        ),  # q
-        pl.BlockSpec(
-            (1, fold, blk_k, d), lambda b_, g, i, j: (b_, g, i, 0)
-        ),  # k
-        pl.BlockSpec(
-            (1, fold, blk_k, dv), lambda b_, g, i, j: (b_, g, i, 0)
-        ),  # v
-        pl.BlockSpec((1, 8, blk_k), lambda b_, g, i, j: (b_, 0, i)),  # mask
-        pl.BlockSpec(
-            (1, fold, blk_q, dv), lambda b_, g, i, j: (b_, g, j, 0)
-        ),  # do
-        pl.BlockSpec(
-            (1, fold, blk_q, 128), lambda b_, g, i, j: (b_, g, j, 0)
-        ),  # lse
-        pl.BlockSpec(
-            (1, fold, blk_q, 128), lambda b_, g, i, j: (b_, g, j, 0)
-        ),  # delta
-    ]
-    kernel, dkv_specs, dkv_args = _without_mask(
-        functools.partial(
-            _bwd_dkv_kernel, causal=causal, scale=scale, nqb=nqb,
-            dropout_rate=dropout_rate, fold=fold,
-        ),
-        dkv_specs, args,
-    )
-    dk, dv = pl.pallas_call(
-        kernel,
-        grid=(b, h // fold, nkb, nqb),
-        in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec(
-                (1, fold, blk_k, d), lambda b_, g, i, j: (b_, g, i, 0)
-            ),
-            pl.BlockSpec(
-                (1, fold, blk_k, dv), lambda b_, g, i, j: (b_, g, i, 0)
-            ),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((fold, blk_k, d), jnp.float32),
-            pltpu.VMEM((fold, blk_k, dv), jnp.float32),
-        ],
-        name="flash_attention_dkv",
-        **_params(interpret),
-    )(*dkv_args)
-    return dq, dk, dv
-
-
-def _flash_bwd_banded(
-    q, k, v, kv_mask, offsets, do, lse, delta, *, causal, scale,
-    blk_q, blk_k, interpret, dropout_rate, fold, band, shift, docs=None,
-):
-    """The two backward calls of a banded forward (``_flash_fwd``'s
-    ``band``).  dq walks the same band of key blocks as the forward.
-    dkv's grid is (b, H_kv/kv_fold, nk, R * band): for each of the R
-    blocks of query heads that read a block of KV heads, the band of q
-    blocks that can see the key block; dk/dv leave summed over them.
-    With ``docs`` the forward's band starts, and dkv's ends, where the
-    documents' bounds say."""
+    """The forward call: (out, lse lane-replicated (b, h, sq, 128)).
+    ``band`` = (window, group): the last grid axis walks the band of key
+    blocks a q block can touch (the whole axis where no causal mask,
+    window or document bounds it), and K/V are addressed by group (1:
+    every query head has its own).  The offsets are scalar-prefetched;
+    ``shift`` is ``q_offset - kv_offset`` where both are Python ints (an
+    exact band), else None.  ``docs`` (``_document_tables``, or None):
+    packed documents."""
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
     nqb, nkb = sq // blk_q, sk // blk_k
     window, group = band
-    fold, kv_fold, reps = _banded_fold(fold, h, group, blk_q, blk_k, max(d, dv))
+    fold, kv_fold, _ = _fold_heads(h, group, blk_q, blk_k, max(d, dv))
+    back, fwd = (None if window is None else window - 1), (0 if causal else None)
+    steps = _band_steps(nqb, shift, blk_q, blk_k, nkb, back, fwd)
+    in_specs, _, o_spec, lane_spec = _band_specs(
+        blk_q, blk_k, d, dv, fold, kv_fold, group, nkb, back, fwd, nqb
+    )
+    end_spec = in_specs[3]  # a row a key block, as the key mask
+    kernel, in_specs, args = _without_mask(
+        functools.partial(
+            _fwd_kernel, causal=causal, scale=scale, nkb=steps,
+            total_kb=nkb, dropout_rate=dropout_rate, fold=fold,
+            kv_fold=kv_fold, window=window,
+        ),
+        in_specs,
+        (offsets, q, k, v, kv_mask),
+    )
+    doc_end, first_kb, _ = docs or (None, None, None)
+    kernel, in_specs, args, prefetched = _with_docs(
+        kernel, in_specs, args, first_kb, doc_end, end_spec
+    )
+    out, lse = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=prefetched,
+            grid=(b, h // fold, nqb, steps),
+            in_specs=in_specs,
+            out_specs=[o_spec, lane_spec],
+            scratch_shapes=[
+                pltpu.VMEM((fold, blk_q, dv), jnp.float32),
+                pltpu.VMEM((fold, blk_q, 128), jnp.float32),
+                pltpu.VMEM((fold, blk_q, 128), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
+            # lane-replicated: TPU blocks need a 128-lane trailing dim
+            jax.ShapeDtypeStruct((b, h, sq, 128), jnp.float32),
+        ],
+        name="flash_attention_fwd",
+        **_params(interpret),
+    )(*args)
+    return out, lse
+
+
+def _flash_bwd(
+    q, k, v, kv_mask, offsets, do, lse, delta, *, docs, causal, scale,
+    blk_q, blk_k, interpret, dropout_rate, band, shift,
+):
+    """The two backward calls of ``_flash_fwd``'s, reusable per ring
+    block: ``lse`` and ``delta`` arrive lane-replicated (b, h, sq, 128)
+    and may be the GLOBAL (ring-merged) values — p = exp(s - lse) then
+    yields the exact global softmax probabilities for this kv block,
+    which is what makes flash-per-block ring backward exact.  dq walks the
+    forward's band of key blocks.  dkv's grid is (b, H_kv/kv_fold, nk,
+    R * band): for each of the R blocks of query heads that read a block
+    of KV heads, the band of q blocks that can see the key block; dk/dv
+    leave summed over them.  With ``docs`` the forward's band starts, and
+    dkv's ends, where the documents' bounds say."""
+    b, h, sq, d = q.shape
+    sk, dv = k.shape[2], v.shape[3]
+    nqb, nkb = sq // blk_q, sk // blk_k
+    window, group = band
+    fold, kv_fold, reps = _fold_heads(h, group, blk_q, blk_k, max(d, dv))
     span = None if window is None else window - 1
     edge = 0 if causal else None
     static = dict(
@@ -1200,7 +942,7 @@ def _flash_bwd_banded(
     )
 
     steps = _band_steps(nqb, shift, blk_q, blk_k, nkb, span, edge)
-    in_specs, q_spec, o_spec, lane_spec = _banded_qk_specs(
+    in_specs, q_spec, o_spec, lane_spec = _band_specs(
         blk_q, blk_k, d, dv, fold, kv_fold, group, nkb, span, edge, nqb
     )
     doc_end, first_kb, last_qb = docs or (None, None, None)
@@ -1236,7 +978,7 @@ def _flash_bwd_banded(
             i, off[1] - off[0], blk_k, blk_q, nqb, edge, span,
             doc_hi=pre[1][b_ * nkb + i] if len(pre) > 1 else None,
         )
-        return jnp.minimum(first + t % steps, last)
+        return _band_block(first, t % steps, last)
 
     q_rows = lambda width: pl.BlockSpec(
         (1, fold, blk_q, width),
@@ -1284,49 +1026,52 @@ def _flash_bwd_banded(
     return dq, dk, dv
 
 
-_flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
-
-
 @functools.partial(
     jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11, 12, 13)
 )
-def _flash_banded(
+def _flash(
     q, k, v, kv_mask, offsets, docs, causal, scale, blk_q, blk_k, interpret,
-    fold, band, shift,
+    dropout_rate, band, shift,
 ):
-    """``_flash`` for a window, grouped KV heads and/or packed documents
-    (``docs``: ``_document_tables``' arrays, or None); no dropout."""
+    """``_flash_fwd``'s output, differentiable through ``_flash_bwd``;
+    ``docs``: ``_document_tables``' arrays, or None."""
     return _flash_fwd(
-        q, k, v, kv_mask, offsets, causal, scale, blk_q, blk_k, interpret,
-        0.0, fold=fold, band=band, shift=shift, docs=docs,
+        q, k, v, kv_mask, offsets, docs, causal, scale, blk_q, blk_k,
+        interpret, dropout_rate, band, shift,
     )[0]
 
 
-def _flash_banded_vjp_fwd(
-    q, k, v, kv_mask, offsets, docs, causal, scale, blk_q, blk_k, interpret,
-    fold, band, shift,
-):
-    out, lse = _flash_fwd(
-        q, k, v, kv_mask, offsets, causal, scale, blk_q, blk_k, interpret,
-        0.0, fold=fold, band=band, shift=shift, docs=docs,
-    )
-    return out, ((q, k, v, kv_mask, offsets, out, lse[..., 0]), docs)
+def _flash_vjp_fwd(*args):
+    out, lse = _flash_fwd(*args)
+    q, k, v, kv_mask, offsets, docs = args[:6]
+    # residual keeps one lane of the lane-replicated lse — 1/128th the
+    # HBM; the backward broadcasts it back transiently (like delta)
+    return out, (q, k, v, kv_mask, offsets, out, lse[..., 0], docs)
 
 
-def _flash_banded_vjp_bwd(
-    causal, scale, blk_q, blk_k, interpret, fold, band, shift, res, do
+def _flash_vjp_bwd(
+    causal, scale, blk_q, blk_k, interpret, dropout_rate, band, shift, res,
+    do,
 ):
-    res, docs = res
-    return (
-        *_flash_vjp_bwd(
-            causal, scale, blk_q, blk_k, interpret, 0.0, fold, res, do,
-            band=band, shift=shift, docs=docs,
+    q, k, v, kv_mask, offsets, out, lse, docs = res
+    b, h, sq, _ = q.shape
+    lse = jnp.broadcast_to(lse[..., None], (b, h, sq, 128))
+    delta = jnp.broadcast_to(
+        jnp.sum(
+            do.astype(jnp.float32) * out.astype(jnp.float32),
+            axis=-1, keepdims=True,
         ),
-        None,
+        (b, h, sq, 128),
+    )  # lane-replicated, same layout as lse
+    dq, dk, dv = _flash_bwd(
+        q, k, v, kv_mask, offsets, do, lse, delta, docs=docs, causal=causal,
+        scale=scale, blk_q=blk_q, blk_k=blk_k, interpret=interpret,
+        dropout_rate=dropout_rate, band=band, shift=shift,
     )
+    return dq, dk, dv, None, None, None
 
 
-_flash_banded.defvjp(_flash_banded_vjp_fwd, _flash_banded_vjp_bwd)
+_flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -1371,7 +1116,8 @@ def flash_block_fwd(
 ):
     """One ring block's flash forward: (out, lse) with lse (B,H,Sq) —
     normalized over THIS kv block only; merge across blocks via
-    logaddexp weights (see sequence.ring_attention's flash path)."""
+    logaddexp weights (see sequence.ring_attention's flash path).  The
+    offsets are traced, so the band is the whole key axis."""
     kv_mask8, blk_q, blk_k = _ring_conditioning(
         q, k, kv_mask, block_q, block_k
     )
@@ -1381,8 +1127,8 @@ def flash_block_fwd(
         jnp.asarray(seed, jnp.int32),
     ])
     out, lse = _flash_fwd(
-        q, k, v, kv_mask8, offsets, causal, scale, blk_q, blk_k,
-        interpret, float(dropout_rate),
+        q, k, v, kv_mask8, offsets, None, causal, scale, blk_q, blk_k,
+        interpret, float(dropout_rate), (None, 1), None,
     )
     return out, lse[..., 0]
 
@@ -1408,9 +1154,10 @@ def flash_block_bwd(
     lse128 = jnp.broadcast_to(lse[..., None], (b, h, sq, 128))
     delta128 = jnp.broadcast_to(delta[..., None], (b, h, sq, 128))
     return _flash_bwd(
-        q, k, v, kv_mask8, offsets, do, lse128, delta128, causal=causal,
-        scale=scale, blk_q=blk_q, blk_k=blk_k, interpret=interpret,
-        dropout_rate=float(dropout_rate),
+        q, k, v, kv_mask8, offsets, do, lse128, delta128, docs=None,
+        causal=causal, scale=scale, blk_q=blk_q, blk_k=blk_k,
+        interpret=interpret, dropout_rate=float(dropout_rate),
+        band=(None, 1), shift=None,
     )
 
 
@@ -1460,7 +1207,6 @@ def flash_attention(
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
-    fold: Optional[int] = None,
     window: Optional[int] = None,
     segment_ids: Optional[jax.Array] = None,
 ) -> jax.Array:
@@ -1482,8 +1228,7 @@ def flash_attention(
     blocks that hold only other documents are neither computed nor
     fetched, in all three kernels, as a window's are (two small int32
     tables a call, scalar-prefetched beside the offsets:
-    ``_document_tables``).  Such a call takes the banded kernels whatever
-    its group and window.
+    ``_document_tables``).
 
     Attention-probability dropout runs inside the kernels via the TPU
     PRNG, seeded per (batch, head, q-block, k-block) so forward and both
@@ -1528,10 +1273,6 @@ def flash_attention(
             seed,
         ]
     )
-    # fold: explicit heads-per-grid-step override (must divide H).
-    # Passing it here (rather than flipping SPARKNET_FLASH_FOLD after a
-    # trace) keys the jit cache honestly — a different fold is a
-    # different traced argument, so an A/B actually recompiles.
     static = isinstance(q_offset, int) and isinstance(kv_offset, int)
     docs = None
     if segment_ids is not None:
@@ -1546,33 +1287,26 @@ def flash_attention(
         docs = _document_tables(
             segment_ids, sq + pad_q, sk + pad_k, block_q, block_k
         )
-    if window is None and group == 1 and docs is None:
-        out = _flash(
-            q, k, v, kv_mask, offsets, causal, scale, block_q, block_k,
-            interpret, float(dropout_rate), fold,
+    if dropout_rate > 0.0 and (
+        window is not None or group > 1 or docs is not None
+    ):
+        # the dkv kernel's dropout head id is right at group 1 only
+        raise NotImplementedError(
+            "attention dropout with a window, grouped KV heads or "
+            "segment_ids"
         )
-    else:
-        if dropout_rate > 0.0:
-            raise NotImplementedError(
-                "attention dropout with a window, grouped KV heads or "
-                "segment_ids"
-            )
-        out = _flash_banded(
-            q, k, v, kv_mask, offsets, docs, causal, scale, block_q, block_k,
-            interpret, fold, (window, group),
-            q_offset - kv_offset if static else None,
-        )
+    out = _flash(
+        q, k, v, kv_mask, offsets, docs, causal, scale, block_q, block_k,
+        interpret, float(dropout_rate), (window, group),
+        q_offset - kv_offset if static else None,
+    )
     return out[:, :, :sq] if pad_q else out
 
 
-def uses_flash(force: Optional[str] = None, dropping: bool = False) -> bool:
-    """Whether :func:`attention` takes the flash kernels (its ``force``;
-    ``dropping``: attention-probability dropout is on)."""
-    flash_dropout_ok = bool(int(os.environ.get("SPARKNET_FLASH_DROPOUT", "1")))
+def uses_flash(force: Optional[str] = None) -> bool:
+    """Whether :func:`attention` takes the flash kernels (its ``force``)."""
     return force == "flash" or (
-        force is None
-        and jax.default_backend() == "tpu"
-        and (not dropping or flash_dropout_ok)
+        force is None and jax.default_backend() == "tpu"
     )
 
 
@@ -1588,13 +1322,12 @@ def attention(
     Attention-probability dropout exists on both paths; the flash
     kernels implement it via the in-kernel TPU PRNG, burned in on real
     v5e hardware (keep-rate and fwd/bwd mask-consistency measured), so
-    dropout rides the flash path by default on TPU.
-    ``SPARKNET_FLASH_DROPOUT=0`` opts back out to the reference path.
+    dropout rides the flash path on TPU.
     Note the interpret-mode PRNG is stubbed to constant bits=0 (keeps
     all for rate <= 0.5, drops all above): dropout statistics are only
     meaningful on hardware.
     """
-    if uses_flash(force, dropout_rate > 0.0 and dropout_rng is not None):
+    if uses_flash(force):
         return flash_attention(
             q, k, v, causal=causal, kv_mask=kv_mask, scale=scale,
             q_offset=q_offset, kv_offset=kv_offset,

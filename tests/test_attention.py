@@ -402,9 +402,8 @@ def test_window_grid_walks_the_band_not_the_axis():
 
 
 def test_window_none_is_todays_call_bit_for_bit():
-    """``window=None`` with equal head counts traces the plain kernels: the
-    same jaxpr as a call that does not name the argument, the banded call
-    not in it, and the same bits out."""
+    """``window=None`` is the call that does not name the argument: the
+    same jaxpr, forward and backward, and the same bits out."""
     rng = np.random.default_rng(3)
     q, k, v = rand_qkv(rng, sq=256, sk=256)
     named = lambda q, k, v: flash_attention(
@@ -417,16 +416,54 @@ def test_window_none_is_todays_call_bit_for_bit():
     assert text == str(
         jax.make_jaxpr(jax.grad(lambda *a: unnamed(*a).sum(), (0, 1, 2)))(q, k, v)
     )
-    assert "_flash_banded" not in text
-    banded = str(jax.make_jaxpr(lambda *a: flash_attention(
-        *a, causal=True, window=64, interpret=True, block_q=64, block_k=128
-    ))(q, k, v))
-    assert "_flash_banded" in banded
     np.testing.assert_array_equal(named(q, k, v), unnamed(q, k, v))
     np.testing.assert_array_equal(
         mha_reference(q, k, v, causal=True, window=None),
         mha_reference(q, k, v, causal=True),
     )
+
+
+def _kernels_and_vjps(f, *args):
+    """(the names of the kernels ``jax.grad`` of ``f`` traces, the
+    backward rules of the custom VJPs ``f`` traces)."""
+    def walk(jaxpr, eqns):
+        for e in jaxpr.eqns:
+            eqns.append(e)
+            for p in e.params.values():
+                inner = getattr(p, "jaxpr", p)
+                if hasattr(inner, "eqns"):
+                    walk(inner, eqns)
+        return eqns
+
+    grad = jax.make_jaxpr(jax.grad(lambda *a: f(*a).sum(), (0, 1, 2)))(*args)
+    kernels = [e.params["name"] for e in walk(grad.jaxpr, [])
+               if e.primitive.name == "pallas_call"]
+    vjps = [e.params["bwd"].f for e in walk(jax.make_jaxpr(f)(*args).jaxpr, [])
+            if e.primitive.name == "custom_vjp_call"]
+    return kernels, vjps
+
+
+@pytest.mark.parametrize("call", ["bert_key_mask", "causal_equal_heads",
+                                  "grouped_heads", "documents"])
+def test_every_call_traces_the_one_custom_vjp_and_the_three_kernels(call):
+    """``bert_mlm``'s call (not causal, a key mask), a causal call with a KV
+    head a query head, grouped KV heads and packed documents: one custom
+    VJP, ``_flash``'s, and its three kernels forward, dq and dkv, no more."""
+    from sparknet_tpu.ops import attention as A
+
+    q, k, v, _ = _grouped_qkv(4, 2, 4, 2 if call == "grouped_heads" else 4, 256, 256)
+    kw = {
+        "bert_key_mask": dict(kv_mask=jnp.arange(256)[None, :] < jnp.asarray([[256], [200]])),
+        "causal_equal_heads": dict(causal=True),
+        "grouped_heads": dict(causal=True),
+        "documents": dict(causal=True, segment_ids=_segments((100, 156), (256,))),
+    }[call]
+    kernels, vjps = _kernels_and_vjps(
+        lambda q, k, v: flash_attention(
+            q, k, v, block_q=128, block_k=128, interpret=True, **kw), q, k, v)
+    assert sorted(kernels) == [
+        "flash_attention_dkv", "flash_attention_dq", "flash_attention_fwd"]
+    assert vjps == [A._flash_vjp_bwd]
 
 
 def test_window_and_grouping_refuse_what_they_do_not_do():
@@ -437,8 +474,6 @@ def test_window_and_grouping_refuse_what_they_do_not_do():
         mha_reference(q, k, v, window=16)
     with pytest.raises(ValueError, match="H_kv"):
         mha_reference(q, k[:, :1].repeat(3, 1), v[:, :1].repeat(3, 1))
-    with pytest.raises(ValueError, match="fold"):
-        flash_attention(q, k, v, causal=True, fold=4, interpret=True)
     with pytest.raises(NotImplementedError, match="dropout"):
         flash_attention(
             q, k, v, causal=True, window=16, interpret=True,
@@ -526,11 +561,10 @@ def _flash_out_and_lse(q, k, v, mask, c, qo, ko):
         )
     offsets = jnp.stack([jnp.asarray(qo, jnp.int32), jnp.asarray(ko, jnp.int32),
                          jnp.asarray(0, jnp.int32)])
-    banded = c["window"] is not None or c["h"] != c["hkv"]
     out, lse = A._flash_fwd(
-        q, k, v, mask, offsets, c["causal"], 1.0 / np.sqrt(d), bq, bk, True,
-        0.0, band=(c["window"], c["h"] // c["hkv"]) if banded else None,
-        shift=None if c["traced"] or not banded else qo - ko,
+        q, k, v, mask, offsets, None, c["causal"], 1.0 / np.sqrt(d), bq, bk,
+        True, 0.0, (c["window"], c["h"] // c["hkv"]),
+        None if c["traced"] else qo - ko,
     )
     return out[:, :, :sq], lse[:, :, :sq]
 
